@@ -20,20 +20,8 @@ A ground-up JAX/XLA/Pallas re-design of the capabilities of terasum/mythril
 x64 mode is required for u64 limb intermediates and is enabled on import.
 """
 
-import os
-
 import jax
 
 jax.config.update("jax_enable_x64", True)
-
-# Some site configurations force a platform preference that overrides the
-# JAX_PLATFORMS environment variable; an explicit env setting is user
-# intent, so re-assert it (e.g. JAX_PLATFORMS=cpu for CI boxes).
-_env_platforms = os.environ.get("JAX_PLATFORMS")
-if _env_platforms:
-    try:
-        jax.config.update("jax_platforms", _env_platforms)
-    except RuntimeError:
-        pass  # backend already initialized
 
 __version__ = "0.1.0"

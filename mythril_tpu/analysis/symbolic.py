@@ -173,8 +173,7 @@ def coverage_summary(tx_contexts) -> dict:
     """Lost-coverage accounting over a run's per-tx context snapshots.
 
     The reference silently discards VmException states; here every loss
-    channel is counted so parity claims are auditable (VERDICT.md round-1
-    weak #4): lanes errored per trap cause, forks dropped to capacity,
+    channel is counted so parity claims are auditable: lanes errored per trap cause, forks dropped to capacity,
     saturated event logs, and propagation kills.
     """
     final = tx_contexts[-1].sf
@@ -265,8 +264,12 @@ class SymExecWrapper:
 
         import jax
 
+        from .. import compile_cache
         from ..core.frontier import CREATOR_ADDRESS
         from ..plugin.loader import LaserPluginLoader
+
+        # every process that drives the engine does so through here
+        compile_cache.enable()
 
         # cross-wrapper warm-shape sharing: sym_run is one module-level
         # jit, so its XLA cache is PROCESS-wide — a second wrapper of
@@ -299,7 +302,7 @@ class SymExecWrapper:
                             "beam": "beam"}[strategy]
         self.timed_out = False
         self.checkpoint_dir = checkpoint_dir
-        # spill machinery (SURVEY §5.7, VERDICT r3 ask #3): starved forks
+        # spill machinery (SURVEY §5.7): starved forks
         # DEFER instead of dropping (the lane parks on its branch and
         # retries), and the host re-seeds persistently parked lanes into
         # other blocks' free slots between chunks
@@ -448,7 +451,7 @@ class SymExecWrapper:
                 # chunk//4, and one sub-q remainder).
                 if q < n < self._chunk:
                     n = q
-                # deadline granularity (VERDICT r3 weak #8): when the
+                # deadline granularity: when the
                 # remaining budget would not cover a full chunk, fall to
                 # the small chunk instead of overshooting by seconds.
                 if (self._deadline_at is not None and sec_per_step

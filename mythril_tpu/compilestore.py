@@ -19,12 +19,13 @@ fleet artifact with two halves:
    copy. A lost read-merge-update race costs at most one hit-count
    increment, never a bucket.
 
-2. **Shared XLA cache dir** ``<data-dir>/compile_store/xla_cache/``:
-   the ``MYTHRIL_WORKER_JAX_CACHE`` contract extended fleet-wide —
+2. **The shared XLA cache** (``mythril_tpu/compile_cache.py``:
+   ``$JAX_COMPILATION_CACHE_DIR``, else ``<checkout>/.jax_cache``):
    worker children, respawned workers, and sibling replicas all point
    at one persistent compilation cache, so a registry-driven prewarm
    (or even a lazy first compile) after restart is a cache *hit*, not
-   a recompile.
+   a recompile. It is cache, not data, so it does not live under the
+   data dir; this store only inspects and prunes it.
 
 **Single-owner GC contract** (mirrors the segstore compactor): any
 replica may read and record; only ONE process at a time may run
@@ -50,6 +51,7 @@ import threading
 import time
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
+from . import compile_cache
 from .obs import metrics as obs_metrics
 from .obs import trace as obs_trace
 from .utils.checkpoint import (
@@ -60,7 +62,6 @@ log = logging.getLogger(__name__)
 #: registry record schema
 BUCKET_SCHEMA = 1
 BUCKET_DIR = "buckets"
-XLA_CACHE_DIR = "xla_cache"
 #: default recency cap: buckets beyond this are evicted oldest-first
 DEFAULT_CAP = 256
 
@@ -129,12 +130,11 @@ class CompileStore:
         self.cap = int(cap)
         self._lock = threading.Lock()
         os.makedirs(os.path.join(self.root, BUCKET_DIR), exist_ok=True)
-        os.makedirs(self.xla_cache_dir(), exist_ok=True)
 
     # --- layout --------------------------------------------------------
 
     def xla_cache_dir(self) -> str:
-        return os.path.join(self.root, XLA_CACHE_DIR)
+        return compile_cache.cache_dir()
 
     def _bucket_dir(self) -> str:
         return os.path.join(self.root, BUCKET_DIR)
@@ -142,26 +142,6 @@ class CompileStore:
     def _path(self, tier: str, shape: Sequence[int], cfh: str) -> str:
         return os.path.join(self._bucket_dir(),
                             bucket_name(tier, shape, cfh))
-
-    def install_cache(self) -> str:
-        """Point the worker-cache contract at this store: set
-        ``MYTHRIL_WORKER_JAX_CACHE`` for child workers IFF the operator
-        hasn't already pinned one (tests do — first writer wins), and
-        mirror it into an already-imported jax's persistent-cache
-        config when that too is unset. Returns the cache dir in force."""
-        cache = os.environ.setdefault("MYTHRIL_WORKER_JAX_CACHE",
-                                      self.xla_cache_dir())
-        import sys
-        if "jax" in sys.modules:  # never force the import ourselves
-            try:
-                import jax
-                if jax.config.jax_compilation_cache_dir is None:
-                    jax.config.update("jax_compilation_cache_dir", cache)
-                    jax.config.update(
-                        "jax_persistent_cache_min_compile_time_secs", 1.0)
-            except Exception:  # noqa: BLE001 — cache config is best-effort
-                pass
-        return cache
 
     # --- events / metrics ---------------------------------------------
 

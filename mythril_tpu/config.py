@@ -60,7 +60,7 @@ class ResilienceConfig:
     pacing; the watchdog exists for unattended corpus campaigns).
     ``init_timeout`` bounds the subprocess backend probe — 75 s
     comfortably covers a healthy TPU init (~20 s measured) while a
-    wedged runtime hangs forever (docs/tpu-wedge-round5.md)."""
+    wedged runtime hangs forever."""
 
     batch_timeout: float | None = None  # seconds per campaign batch
     init_timeout: float = 75.0          # seconds per backend-init probe

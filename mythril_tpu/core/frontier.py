@@ -31,8 +31,7 @@ class Trap:
 
     The reference raises typed VmExceptions and silently discards the
     state (⚠unv); here every masked trap is attributed so the report can
-    say exactly what coverage was lost to which static cap (VERDICT.md
-    round-1 weak #4)."""
+    say exactly what coverage was lost to which static cap."""
 
     NONE = 0
     STACK = 1            # stack under/overflow vs max_stack cap

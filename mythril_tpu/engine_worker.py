@@ -64,6 +64,7 @@ import sys
 import time
 from typing import BinaryIO, Dict, List, Optional
 
+from . import compile_cache
 from .obs import metrics as obs_metrics
 from .obs import trace as obs_trace
 
@@ -188,8 +189,6 @@ if f == "segv":
 if f == "hang":
     time.sleep(3600)
 import jax
-jax.config.update("jax_compilation_cache_dir", sys.argv[1])
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 import jax.numpy as jnp
 jax.jit(lambda x: x + 1)(jnp.zeros((8,), jnp.int32)).block_until_ready()
 """
@@ -205,10 +204,14 @@ def probe_cache(cache: str, timeout: Optional[float] = None) -> bool:
     if timeout is None:
         timeout = float(os.environ.get(
             "MYTHRIL_CACHE_PROBE_TIMEOUT", "180"))
+    # the child is pointed at the suspect dir from outside, the way
+    # every process of this repo is (mythril_tpu/compile_cache.py)
     env = dict(os.environ)
     env.setdefault("JAX_PLATFORMS", "cpu")
+    env[compile_cache.ENV] = cache
+    env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
     try:
-        r = subprocess.run([sys.executable, "-c", _PROBE_SRC, cache],
+        r = subprocess.run([sys.executable, "-c", _PROBE_SRC],
                            capture_output=True, timeout=timeout,
                            env=env)
         return r.returncode == 0
@@ -261,14 +264,8 @@ def _build_campaign(config: Dict):
     wedged fleet."""
     import mythril_tpu  # noqa: F401  (enables x64)
 
-    cache = os.environ.get("MYTHRIL_WORKER_JAX_CACHE")
-    if cache:
-        cache = _maybe_probe_cache(cache)
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          1.0)
+    _maybe_probe_cache(compile_cache.cache_dir())
+    compile_cache.enable()
     if config.get("solver_store"):
         from .smt import portfolio as smt_portfolio
 
@@ -435,15 +432,25 @@ def worker_main() -> int:
                     # and ship them back with each batch reply
                     obs_trace.configure(buffer=True)
                     msnap = obs_metrics.REGISTRY.snapshot()
+                device = None
                 if not stub:
                     camp = _build_campaign(msg.get("config") or {})
+                    # build the engine now (its tables take the
+                    # backend) and say which device that was: the
+                    # supervisor refuses a worker that came up on
+                    # another tier than it was spawned for
+                    import mythril_tpu.symbolic.engine  # noqa: F401
+                    from .backend import device_record
+
+                    device = device_record()
                 # the child monotonic reading is half of the clock
                 # handshake: the parent computes
                 # offset = parent_mono - child_mono for span stitching
                 reply = {"ok": True,
                          "value": {"pid": os.getpid(), "stub": stub,
                                    "protocol": PROTOCOL_VERSION,
-                                   "mono": time.monotonic()}}
+                                   "mono": time.monotonic(),
+                                   "device": device}}
             elif op == "ping":
                 reply = {"ok": True, "value": {"pid": os.getpid(),
                                                "rss": _rss_bytes()}}

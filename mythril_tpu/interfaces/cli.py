@@ -513,9 +513,11 @@ def create_parser() -> argparse.ArgumentParser:
                          "engine_tier_* metrics (docs/serving.md)")
     sv.add_argument("--compile-store", metavar="DIR", default=None,
                     help="fleet compile-artifact store: durable "
-                         "shape-bucket registry + shared persistent "
-                         "XLA cache, so restarted/sibling replicas and "
-                         "re-promoted tiers come back warm (default: "
+                         "shape-bucket registry, so restarted/sibling "
+                         "replicas and re-promoted tiers prewarm "
+                         "through the shared persistent XLA cache "
+                         "(JAX_COMPILATION_CACHE_DIR, else "
+                         "<checkout>/.jax_cache) (default: "
                          "<data-dir>/compile_store; docs/serving.md "
                          "'Compile artifacts & prewarm')")
     sv.add_argument("--prewarm", dest="prewarm", action="store_true",
@@ -789,14 +791,14 @@ def _resolve_hosts(args):
     process is host 0 of 1."""
     n, i = args.num_hosts, args.host_index
     if n <= 0 or i < 0:
-        try:  # initialized only on real multi-host launches
-            import jax
+        import jax
 
-            if jax.process_count() > 1:
-                n = n if n > 0 else jax.process_count()
-                i = i if i >= 0 else jax.process_index()
-        except Exception:  # noqa: BLE001 — backend may not be up yet
-            pass
+        # ask the distributed runtime, not jax.process_count(): that
+        # would initialize a backend, and a supervising parent must
+        # leave the accelerator to its engine worker
+        if jax.distributed.is_initialized() and jax.process_count() > 1:
+            n = n if n > 0 else jax.process_count()
+            i = i if i >= 0 else jax.process_index()
     n = n if n > 0 else 1
     i = i if i >= 0 else 0
     return n, i
@@ -879,8 +881,7 @@ def _exec_campaign(args) -> int:
         raise SystemExit(2)
 
     # backend probe FIRST, while this process is still backend-free: a
-    # wedged TPU runtime hangs jax.devices() forever (docs/
-    # tpu-wedge-round5.md); the probe wedges a subprocess instead, and
+    # wedged TPU runtime hangs jax.devices() forever; the probe wedges a subprocess instead, and
     # the campaign degrades to the CPU backend with the event on record
     try:
         backend_tiers = (parse_tiers(args.backend_tiers)

@@ -12,17 +12,23 @@ pure-Python evaluator when the compiler or the load fails
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
+
+log = logging.getLogger(__name__)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _LOCK = threading.Lock()
 _lib = None
 _tried = False
+_built = False      # compiled from tape_eval.c by THIS process
+_error = None       # why the Python evaluator is in use, if it is
 
 
 def _build_and_load():
+    global _built
     src = os.path.join(_HERE, "tape_eval.c")
     so = os.path.join(_HERE, "_tape_eval.so")
     if (not os.path.exists(so)
@@ -34,6 +40,7 @@ def _build_and_load():
                     [cc, "-O2", "-shared", "-fPIC", src, "-o", tmp],
                     check=True, capture_output=True, timeout=120)
                 os.replace(tmp, so)
+                _built = True
                 break
             except (OSError, subprocess.SubprocessError):
                 continue
@@ -53,18 +60,29 @@ def _build_and_load():
 
 def tape_eval_lib():
     """The loaded native library, or None (build failure / opt-out)."""
-    global _lib, _tried
+    global _lib, _tried, _error
     if _tried:
         return _lib
     with _LOCK:
         if _tried:
             return _lib
         if os.environ.get("MYTHRIL_NO_NATIVE") == "1":
-            _lib, _tried = None, True
+            _lib, _tried, _error = None, True, "MYTHRIL_NO_NATIVE=1"
             return None
         try:
             _lib = _build_and_load()
-        except Exception:
-            _lib = None
+        except Exception as e:  # noqa: BLE001 — degrade, but say so
+            _lib, _error = None, repr(e)[:300]
+            log.warning("native tape evaluator unavailable (%s); the "
+                        "witness search runs on the ~12x slower Python "
+                        "evaluator", _error)
         _tried = True
     return _lib
+
+
+def status() -> dict:
+    """``loaded``: the C evaluator is in use; ``built``: this process
+    compiled it from ``tape_eval.c``; ``tried`` False = no solver query
+    has needed it yet."""
+    return {"tried": _tried, "loaded": _lib is not None,
+            "built": _built, "error": _error}

@@ -36,9 +36,13 @@ import types
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
-from ..analysis.module.base import DetectionModule
-from ..analysis.module.loader import register_module
 from .interface import LaserPlugin, PluginBuilder
+
+# The analysis package is imported where a discovered object is
+# classified, not here: it pulls in the engine, whose tables initialize
+# a JAX backend, and discovery also runs in processes that only
+# supervise an engine worker. With no plugin installed nothing below
+# needs it; with one, the campaign runs in-process anyway.
 
 log = logging.getLogger(__name__)
 
@@ -62,6 +66,9 @@ class DiscoveredPlugins:
 
 def _classify(obj, name: str, out: DiscoveredPlugins) -> bool:
     """Install one resolved object into the right registry."""
+    from ..analysis.module.base import DetectionModule
+    from ..analysis.module.loader import register_module
+
     if isinstance(obj, type):
         if issubclass(obj, DetectionModule):
             register_module(obj)
@@ -88,6 +95,8 @@ def _classify(obj, name: str, out: DiscoveredPlugins) -> bool:
 
 def _scan_module(mod: types.ModuleType, name: str,
                  out: DiscoveredPlugins) -> None:
+    from ..analysis.module.base import DetectionModule
+
     declared = getattr(mod, "MYTHRIL_PLUGINS", None)
     if declared is not None:
         for i, obj in enumerate(declared):

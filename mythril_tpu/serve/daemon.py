@@ -279,6 +279,12 @@ class AnalysisDaemon:
         tiers = self.scheduler.tier_status()
         if tiers:
             doc["backend_tiers"] = tiers
+        # which device each config's engine actually got (a worker that
+        # came up on the CPU must not look like accelerator capacity)
+        engines = self.scheduler.engine_status()
+        if engines:
+            doc["engines"] = engines
+            doc["device"] = engines[0].get("device")
         # compile-artifact prewarm state (docs/serving.md "Compile
         # artifacts & prewarm"): what the background pass did / is
         # doing, so an orchestrator can tell "came back warm" from
@@ -333,10 +339,6 @@ class AnalysisDaemon:
             from ..compilestore import CompileStore
 
             self.compile_store = CompileStore(self.compile_store_dir)
-            # point the worker-cache contract at the shared dir BEFORE
-            # any campaign spawns a worker (setdefault: an operator /
-            # test-pinned MYTHRIL_WORKER_JAX_CACHE wins)
-            self.compile_store.install_cache()
             self.scheduler.compile_store = self.compile_store
         self.scheduler.start()
         self.httpd = ServeHTTPServer((self.host, self._port), self)

@@ -297,7 +297,6 @@ class Scheduler:
                     camp._warm_shapes = self._warm_shapes
                 if (self.compile_store is not None
                         and hasattr(camp, "attach_compile_store")):
-                    self.compile_store.install_cache()
                     camp.attach_compile_store(self.compile_store,
                                               cfh=cfh)
                 self._campaigns[cfh] = camp
@@ -529,6 +528,22 @@ class Scheduler:
         return out
 
 
+    def engine_status(self) -> List[Dict]:
+        """Per-config engine report (``/healthz`` ``engines``): the
+        device each resident campaign's engine got — from its worker's
+        init reply, or read in-process after the first batch — plus
+        host-callback support, native evaluator and compile counters.
+        Empty until a campaign has an engine."""
+        out: List[Dict] = []
+        for cfh, camp in list(self._campaigns.items()):
+            status = getattr(camp, "engine_status", None)
+            st = status() if callable(status) else None
+            if st:
+                st["config"] = cfh
+                out.append(st)
+        return out
+
+
 class StoreOnlyScheduler:
     """The null scheduler behind ``serve --store-only`` (docs/serving.md
     "Verdict segments & edge replicas"): an edge replica has NO engine
@@ -563,6 +578,9 @@ class StoreOnlyScheduler:
         return 0
 
     def tier_status(self) -> List[Dict]:
+        return []
+
+    def engine_status(self) -> List[Dict]:
         return []
 
     def warm_counts(self) -> tuple:

@@ -1,4 +1,4 @@
-"""Unsat proofs for host tapes (VERDICT r3 ask #4a/4b).
+"""Unsat proofs for host tapes.
 
 The witness search (``smt/solver.py``) can only ever answer sat-or-
 unknown; every `unknown` is a potential silent false negative. This

@@ -35,7 +35,7 @@ class UnsatError(Exception):
 class SolverStatistics:
     """Run counters for the witness search (reference:
     ``laser/smt/solver/solver_statistics.py`` ⚠unv, SURVEY.md §5.1).
-    ``unknown`` is the silent false-negative channel (VERDICT r2 weak #3):
+    ``unknown`` is the silent false-negative channel:
     every query that returns None and therefore drops a candidate finding
     is counted here, so the undecided rate is observable in the report."""
 
@@ -103,7 +103,7 @@ SOLVER_STATS = SolverStatistics()
 
 
 def _dump_unknown(tape: HostTape) -> None:
-    """Residue collection (VERDICT r4 ask #3): with
+    """Residue collection: with
     ``MYTHRIL_DUMP_UNKNOWN=<dir>`` every query the search gives up on is
     serialized for offline analysis — the evidence base for deciding
     which inverter/refuter extension actually shrinks the unknown rate."""
@@ -466,7 +466,7 @@ def solve_tape_ex(tape: HostTape, seed: int = 0, max_iters: int = 400,
     docs/solver.md): canonical-hash LRU → structural refutation →
     model probe → durable cross-campaign verdict store → the witness
     search below. Proven UNSAT is recorded distinctly from
-    search-exhausted UNKNOWN in ``SOLVER_STATS`` (VERDICT r3 ask #4);
+    search-exhausted UNKNOWN in ``SOLVER_STATS``;
     per-stage attempt/hit/latency lands in
     ``portfolio.PORTFOLIO_STATS`` and the metrics registry.
     ``base``-seeded queries skip every cache (the seed assignment is an

@@ -11,7 +11,7 @@ row "Solidity frontend") shells out to solc. Two paths here:
   data, one process boundary earlier, for hermetic environments.
 
 Issues then map to source lines, which the reference's golden reports
-include (VERDICT r2 missing #6).
+include.
 
 Source-map format (solc docs, public spec): ``s:l:f:j:m`` entries
 separated by ``;``, empty fields inheriting the previous entry; one entry

@@ -9,16 +9,28 @@ interpretation over the tape (known-bits + unsigned intervals), with a
 model-search fallback instead of Z3 (not available in this image).
 """
 
-from .ops import SymOp, FreeKind, WELL_KNOWN, N_WELL_KNOWN, calldata_arg_offsets
-from .state import SymFrontier, make_sym_frontier, SymSpec
-from .engine import (sym_superstep, sym_run, expand_forks, append_node,
-                     between_txs, migrate_parked_device)
-from .propagate import propagate_feasibility, kill_infeasible
+import importlib
 
-__all__ = [
-    "SymOp", "FreeKind", "WELL_KNOWN", "N_WELL_KNOWN", "calldata_arg_offsets",
-    "SymFrontier", "make_sym_frontier", "SymSpec",
-    "sym_superstep", "sym_run", "expand_forks", "append_node", "between_txs",
-    "migrate_parked_device",
-    "propagate_feasibility", "kill_infeasible",
-]
+#: export -> submodule. Resolved on first access (PEP 562): ``state`` and
+#: ``engine`` build jnp tables at import, which initializes a JAX backend
+#: — and on a machine whose accelerator belongs to one process, a
+#: supervisor that only needs ``SymSpec`` must leave it to its worker.
+_EXPORTS = {
+    "SymOp": "ops", "FreeKind": "ops", "WELL_KNOWN": "ops",
+    "N_WELL_KNOWN": "ops", "calldata_arg_offsets": "ops",
+    "SymSpec": "spec",
+    "SymFrontier": "state", "make_sym_frontier": "state",
+    "sym_superstep": "engine", "sym_run": "engine",
+    "expand_forks": "engine", "append_node": "engine",
+    "between_txs": "engine", "migrate_parked_device": "engine",
+    "propagate_feasibility": "propagate", "kill_infeasible": "propagate",
+}
+
+__all__ = list(_EXPORTS)
+
+
+def __getattr__(name):
+    if name in _EXPORTS:
+        return getattr(importlib.import_module(
+            f"{__name__}.{_EXPORTS[name]}"), name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -13,7 +13,7 @@ any contradicted constraint are provably infeasible and get killed.
 The two domains are complementary: intervals decide magnitude reasoning
 (LT/GT bounds, dispatcher ranges); known-bits decide mask/alignment
 reasoning intervals cannot — e.g. ``(x | 1) == 2`` is unsat because bit 0
-of the LHS is known 1 (VERDICT r2 ask #7).
+of the LHS is known 1.
 
 Soundness direction: both domains only ever over-approximate, so a kill
 is always correct; undecided lanes stay alive (the reference keeps unsat

@@ -17,9 +17,6 @@ fresh unconstrained variables rather than wrong values):
 
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import Any
-
 import numpy as np
 import jax.numpy as jnp
 from flax import struct
@@ -27,6 +24,7 @@ from flax import struct
 from ..config import LimitsConfig, DEFAULT_LIMITS
 from ..core.frontier import Frontier, make_frontier
 from .ops import SymOp, WELL_KNOWN, N_WELL_KNOWN
+from .spec import SymSpec  # noqa: F401  (re-exported: its historical home)
 
 I32 = jnp.int32
 U32 = jnp.uint32
@@ -54,38 +52,6 @@ def tape_row_hash(op, a, b, imm):
     h = h ^ (h >> 16)
     h = h * U32(0x7FEB352D)
     return h ^ (h >> 15)
-
-
-@dataclass(frozen=True)
-class SymSpec:
-    """Static (trace-time) choice of which inputs are symbolic.
-
-    Mirrors the reference's symbolic tx setup (``execute_message_call``
-    builds symbolic calldata/callvalue/caller ⚠unv, SURVEY.md §2
-    "Transaction models")."""
-
-    calldata: bool = True
-    callvalue: bool = True
-    caller: bool = False       # reference default: concrete ATTACKER address
-    storage: bool = True       # unknown initial storage -> fresh STORAGE leaves
-    block_env: bool = True     # timestamp/number/... symbolic (PredictableVars)
-    # When the frontier's lane axis is sharded over a device mesh, the
-    # precompile host callbacks must round-trip only shard-local lanes —
-    # a bare pure_callback inside pjit gets a {maximal device=0} sharding
-    # and XLA inserts a full gather/rescatter ("Involuntary full
-    # rematerialization") that would serialize every superstep on a pod.
-    # Setting ``mesh`` (a hashable jax.sharding.Mesh; part of the jit
-    # cache key via static spec) routes them through jax.shard_map over
-    # ``lane_axis`` instead. None = single-device path, no shard_map.
-    mesh: Any = None
-    lane_axis: str = "dp"
-    # numeric storage-alias probe (VERDICT r4 ask #6): demote symbolic
-    # keys with fully-known bits to their value at SSTORE/SLOAD so
-    # provably-equal keys connect. Trace-time static: False compiles the
-    # probe out entirely (~0-15% cost on storage-heavy CPU workloads,
-    # noise-limited — see docs/perf-round5-cpu-ab.md; the soundness win
-    # is the default, the flag exists for perf runs and A/B measurement).
-    alias_probe: bool = True
 
 
 @struct.dataclass

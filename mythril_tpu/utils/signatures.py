@@ -9,7 +9,7 @@ REMOTE 4byte.directory-shaped endpoint (``MYTHRIL_4BYTE_URL`` or the
 local table. The public 4byte.directory is unreachable in this
 zero-egress image, so the remote tier is loopback-tested the same way
 the RPC client is (tests/test_signatures_remote.py). ``Issue.function``
-is labeled through this (VERDICT r2: "Signature DB absent").
+is labeled through this ("Signature DB absent").
 """
 
 from __future__ import annotations

@@ -2,7 +2,7 @@
 
 "Uniswap-V2 core+periphery, inter-contract call depth 3 (multi-tx
 symbolic)" — BASELINE.json configs[3]. No solc exists in this image, so
-this is the hand-assembled structural equivalent (VERDICT r4 ask #5):
+this is the hand-assembled structural equivalent:
 
   caller (periphery user entry)
     └─ CALL → router (periphery)

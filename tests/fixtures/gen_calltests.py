@@ -1,6 +1,6 @@
 """Generate independent CALL-frame test vectors (calltests.json).
 
-VERDICT r2 weak #6: the CALL/frame machinery — the riskiest part of the
+The CALL/frame machinery — the riskiest part of the
 engine — was tested only against the author's own expectations. These
 vectors use deliberately independent machinery (same philosophy as
 ``gen_vmtests.py``):

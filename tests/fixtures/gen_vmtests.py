@@ -5,7 +5,7 @@ reference ⚠unv, SURVEY.md §4 — "the key correctness oracle") cannot be
 vendored in this image (no network). This generator hand-transcribes the
 same *style* of vector with deliberately independent machinery so the
 fixtures do not share code — or misconceptions — with the interpreter
-under test (VERDICT.md round-1 weak #6):
+under test:
 
 - bytecode is emitted by the 10-line mini-assembler below (NOT
   ``mythril_tpu.disassembler.asm``);

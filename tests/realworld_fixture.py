@@ -1,4 +1,4 @@
-"""Real-world-shaped smoke corpus (VERDICT r4 ask #9).
+"""Real-world-shaped smoke corpus.
 
 This image has zero network egress, so genuine Etherscan bytecode cannot
 be vendored. What CAN be, faithfully:

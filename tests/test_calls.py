@@ -1,6 +1,6 @@
 """Sub-transaction layer: cross-contract CALL/DELEGATECALL/STATICCALL.
 
-VERDICT.md round-1 item #1: real callee frames (save/restore, calldata/
+Real callee frames (save/restore, calldata/
 returndata plumbing, storage + balance rollback on revert) replacing the
 success-push stubs. Reference: ``mythril/laser/ethereum/call.py`` +
 ``transaction/transaction_models.py`` (⚠unv, SURVEY.md §3.2).
@@ -213,7 +213,7 @@ def test_unknown_callee_still_gets_symbolic_retval():
 
 
 def test_requirements_violation_fires_cross_contract():
-    # VERDICT done-criterion: two-contract fixture with a require in the
+    # two-contract fixture with a require in the
     # callee explored cross-contract, SWC-123 firing on it
     callee = assemble(
         0, "CALLDATALOAD", 100, "SWAP1", "LT",  # arg < 100 ?
@@ -304,7 +304,7 @@ def test_balance_reads_not_forced_equal_across_transfer():
 
 
 def test_calldataload_beyond_window_havocs_not_zero():
-    # VERDICT r2 weak #4: a concrete-offset CALLDATALOAD past the modeled
+    # a concrete-offset CALLDATALOAD past the modeled
     # window must havoc (both branches reachable), not read concrete 0
     off = L.calldata_bytes  # first byte past the window
     code = assemble(
@@ -369,7 +369,7 @@ def test_selfdestruct_symbolic_beneficiary_only_zeroes_self():
 
 
 def test_symbolic_callee_enumerates_account_table():
-    """VERDICT r3 ask #2: a CALL whose target word is SYMBOLIC (the proxy
+    """A CALL whose target word is SYMBOLIC (the proxy
     pattern — implementation address loaded from unconstrained storage)
     must fork one lane per candidate account instead of havocking: the
     lane constrained to the known implementation executes its code."""

@@ -1,6 +1,6 @@
 """Independent CALL-frame vectors vs the symbolic engine.
 
-VERDICT r2 ask #10: the frame machinery gets an oracle whose bytecode and
+The frame machinery gets an oracle whose bytecode and
 expectations share NO code with the engine (see
 ``tests/fixtures/gen_calltests.py`` — raw-byte assembler + integer
 formulas). Every vector runs the same 4-lane shape so the whole suite

@@ -1,4 +1,4 @@
-"""Corpus campaign driver (VERDICT r3 ask #6, BASELINE configs 2-3):
+"""Corpus campaign driver (BASELINE configs 2-3):
 constant-shape batches, one compiled engine, checkpoint/resume."""
 
 import numpy as np

@@ -1,4 +1,4 @@
-"""Execution deadline + frontier checkpoint/resume (VERDICT r2 ask #9).
+"""Execution deadline + frontier checkpoint/resume.
 
 Reference: ``--execution-timeout`` degrade semantics (SURVEY §5.3);
 checkpointing is ABSENT in the reference — SURVEY §5.4 requires it here

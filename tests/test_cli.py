@@ -1,4 +1,4 @@
-"""CLI + orchestration layer (VERDICT r2 ask #6).
+"""CLI + orchestration layer.
 
 Reference: ``tests/cmd_line_test.py`` / ``tests/test_cli_opts.py`` (⚠unv,
 SURVEY.md §4 "CLI tests") — arg parsing, output formats, command flow.
@@ -119,7 +119,7 @@ def test_analyze_jsonv2(capsys):
     assert issues[0]["locations"][0]["sourceMap"].count(":") == 2
 
 
-# --- round-4 command completeness (VERDICT r3 ask #7) ---
+# --- round-4 command completeness ---
 
 def test_function_to_hash(capsys):
     rc, out = run_cli(capsys, "function-to-hash", "transfer(address,uint256)")
@@ -240,7 +240,7 @@ def test_analyze_sol_without_solc_fails_clearly(tmp_path, capsys, monkeypatch):
     assert ei.value.code == 2
 
 
-# --- round-5 reference flag parity (VERDICT r4 ask #7) ---
+# --- round-5 reference flag parity ---
 
 def test_parser_round5_parity_flags():
     p = create_parser()

@@ -1,6 +1,6 @@
 """BASELINE config-4 end-to-end: 3 contracts, call depth 3, multi-tx.
 
-VERDICT r4 ask #5 — first pinned evidence that the frame machinery
+First pinned evidence that the frame machinery
 (engine.py `_h_sym_call` + frame stack) earns its complexity on its
 target workload: a drain inside the CORE contract witnessed from the
 PERIPHERY entry point through two real CALL hops. Reference analog:

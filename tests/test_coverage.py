@@ -1,6 +1,6 @@
 """Lost-coverage accounting: masked traps are attributed and reported.
 
-VERDICT.md round-1 weak #4: a lane tripping a static cap must not vanish
+A lane tripping a static cap must not vanish
 silently — the report carries a coverage block saying what was lost.
 """
 

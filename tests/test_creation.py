@@ -1,6 +1,6 @@
 """Contract-creation transactions + in-tx CREATE semantics.
 
-VERDICT r2 ask #2: constructor-established invariants (owner set in the
+Constructor-established invariants (owner set in the
 constructor) must be visible to the message-call transactions, removing
 the storage-havoc over-approximation FP on owner-guarded code.
 Reference: ``execute_contract_creation`` + ``ContractCreationTransaction``
@@ -67,7 +67,7 @@ def test_creation_storage_persists_into_message_tx():
 
 
 def test_no_etherthief_fp_when_constructor_sets_owner():
-    # VERDICT done-criterion: with the creation tx modeled and no storage
+    # with the creation tx modeled and no storage
     # havoc, the owner guard is concrete (owner == CREATOR != ATTACKER) and
     # the drain is unreachable
     sym = SymExecWrapper(
@@ -169,7 +169,7 @@ def test_call_to_created_account_stays_symbolic():
     assert got == {1, 2}, "both success outcomes must be explored"
 
 
-# --- in-tx CREATE/CREATE2 init-code execution (VERDICT r3 ask #2) ---
+# --- in-tx CREATE/CREATE2 init-code execution ---
 
 # child init code: storage[0] = 1 on the CHILD account, deploy empty code
 CHILD_INIT_EMPTY = assemble(1, 0, "SSTORE", 0, 0, "RETURN")

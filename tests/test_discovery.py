@@ -1,4 +1,4 @@
-"""Outer plugin discovery (VERDICT r3 missing #10; reference:
+"""Outer plugin discovery (reference:
 ``mythril/plugin/discovery.py`` entry-point loading ⚠unv, SURVEY §2 row
 "Mythril plugin system (outer)").
 

@@ -7,7 +7,7 @@ corpus doesn't hold (computed at runtime — no PUSH20 for the static
 prefetch to find), the seam fetches its code over the (mocked) RPC
 client, and tx 2's re-entry resolves into the REAL callee code, where a
 finding is witnessed. This closes the "mid-execution dynld" half of
-VERDICT r4 missing #1; the static-reference half is the pre-pass in
+The static-reference half is the pre-pass in
 ``utils/loader.py:prefetch_callees``.
 """
 

@@ -273,6 +273,36 @@ def test_worker_isolation_auto_resolution(tmp_path):
 
 # --- the headline acceptance scenario (real engine) -----------------------
 
+# --- the worker says which device it got -----------------------------------
+
+@pytest.mark.parametrize("expect,refused", [("cpu", False), ("tpu", True)])
+def test_real_worker_reports_device_and_tier_mismatch_is_refused(
+        expect, refused):
+    """A REAL (non-stub) worker's init reply names the device its
+    engine got — cpu under the tests' pin. A worker spawned for another
+    tier than it came up on is a failed spawn (a death, feeding the
+    breaker), not quiet CPU capacity."""
+    sup = WorkerSupervisor(
+        config={"limits": TEST_LIMITS, "batch_size": 2,
+                "lanes_per_contract": 8, "max_steps": 16},
+        backoff_base=0.01, spawn_timeout=240.0, expect_tier=expect)
+    try:
+        if refused:
+            with pytest.raises(WorkerDied, match="spawned for the tpu"):
+                sup._spawn_and_init()
+            assert not sup.alive()
+            assert "worker_death" in kinds(sup.events)
+        else:
+            sup._spawn_and_init()
+            assert sup.alive()
+        spawn = [e for e in sup.events if e["kind"] == "worker_spawn"][-1]
+        assert spawn["device"]["platform"] == "cpu"
+        assert spawn["device"]["count"] >= 1 and spawn["device"]["kind"]
+        assert sup.status()["device"] == spawn["device"]
+    finally:
+        sup.close()
+
+
 @pytest.mark.slow
 def test_real_engine_segv_mid_superstep_survival(tmp_path):
     """ISSUE 10 acceptance: with worker_isolation=on, a SIGSEGV

@@ -1,6 +1,6 @@
 """Gas fidelity: EIP-2929 warm/cold accounting + EIP-150 63/64 forwarding.
 
-VERDICT r3 ask #5 done-criterion: vmtests-style vectors with cold/warm
+Vmtests-style vectors with cold/warm
 SLOAD / EXTCODE* and a CALL match hand-computed gas exactly. Expected
 values are derived from the yellow-paper/EIP schedules in the comments —
 NOT from the implementation's own tables.

@@ -1,6 +1,6 @@
 """Golden-report corpus: the in-repo behavioral spec for the SWC suite.
 
-VERDICT r3 ask #8 — the reference's ``tests/testdata/outputs_expected``
+The reference's ``tests/testdata/outputs_expected``
 oracle is unreachable (mount empty), so these goldens pin the suite's
 behavior issue-for-issue: each fixture (vulnerable + safe sibling per
 SWC class) has an expected-issue JSON under ``tests/fixtures/goldens/``;

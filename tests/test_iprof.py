@@ -1,4 +1,4 @@
-"""Per-opcode instruction profiler (VERDICT r3 missing #9; reference:
+"""Per-opcode instruction profiler (reference:
 ``--enable-iprof``'s InstructionProfiler table ⚠unv, SURVEY §5.1).
 
 The histogram rides the frontier as an optional ``[P, 256]`` leaf

@@ -1,4 +1,4 @@
-"""Known-bits propagation domain + solver observability (VERDICT r2 ask #7).
+"""Known-bits propagation domain + solver observability.
 
 The kills asserted here are ones INTERVALS ALONE CANNOT make: the OR
 lower bound / AND alignment facts live in bit positions, not magnitudes.

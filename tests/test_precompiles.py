@@ -1,4 +1,4 @@
-"""Precompile dispatch 0x1-0x9 (VERDICT r2 ask #4).
+"""Precompile dispatch 0x1-0x9.
 
 Reference: ``mythril/laser/ethereum/natives.py`` + the dispatch in
 ``call.py`` (⚠unv). sha256/identity/modexp compute on device; ecrecover
@@ -111,7 +111,7 @@ def test_ecrecover_symbolic_input_is_leaf():
 
 def test_ecrecover_concrete_invalid_returns_empty():
     # all-zero signature: the precompile returns EMPTY output; the
-    # output word stays concrete zero (VERDICT r3 weak #6)
+    # output word stays concrete zero
     code = assemble(
         *call_pre(1, args=(0, 128), ret=(0, 32)),
         "POP", 0, "MLOAD", 1, "SSTORE", "STOP",

@@ -1,4 +1,4 @@
-"""Bounded loops + dependency pruner (VERDICT r2 ask #3).
+"""Bounded loops + dependency pruner.
 
 Reference: ``strategy/extensions/bounded_loops.py`` (drop states past
 --loop-bound) and ``laser/plugin/plugins/dependency_pruner.py`` (skip

@@ -1,5 +1,4 @@
-"""Real-world-shaped smoke corpus through the full suite (VERDICT r4
-ask #9): EIP-1167 proxy (exact spec bytes) delegating to a full ERC-20,
+"""Real-world-shaped smoke corpus through the full suite: EIP-1167 proxy (exact spec bytes) delegating to a full ERC-20,
 plus ERC-721 and a 2-of-3 multisig — the largest, most solc-shaped
 bytecodes in the tree. Issue sets pinned as a golden; any trap storm
 these expose is visible in the pinned coverage numbers.

@@ -1,7 +1,7 @@
 """Fault-isolated campaign runner (resilience layer).
 
 The failure modes this repo has actually hit — a wedged backend that
-hangs ``jax.devices()`` forever (docs/tpu-wedge-round5.md), a hung XLA
+hangs ``jax.devices()`` forever, a hung XLA
 compile, a pathological contract crashing a batch — must cost a 10k
 campaign at most the poison contracts, never the run. All fault paths
 are exercised deterministically on CPU via the injection hook; the

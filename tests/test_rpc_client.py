@@ -1,5 +1,4 @@
-"""HTTP JSON-RPC client over a real loopback transport (VERDICT r4 ask
-#8; reference: ``tests/rpc_test.py`` mocks its node the same way ⚠unv,
+"""HTTP JSON-RPC client over a real loopback transport (reference: ``tests/rpc_test.py`` mocks its node the same way ⚠unv,
 SURVEY.md §4 "RPC tests"). No egress exists in this image, so the "node"
 is a threaded ``http.server`` on 127.0.0.1 serving canned JSON-RPC
 responses — the full client path (request encoding, transport, retry,
@@ -146,8 +145,7 @@ def test_dynloader_over_http(node):
 
 
 def test_read_storage_cli_end_to_end(node, capsys):
-    # `read-storage --rpc http://...` drives the real client (VERDICT r4
-    # ask #8 done-criterion)
+    # `read-storage --rpc http://...` drives the real client
     from mythril_tpu.interfaces.cli import main
 
     rc = main(["read-storage", "0x0", "0x" + "ab" * 20, "--rpc", node])

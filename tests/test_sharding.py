@@ -1,6 +1,6 @@
 """Sharded SYMBOLIC execution over the virtual 8-device CPU mesh.
 
-VERDICT r2 ask #5: the multichip story must certify the symbolic engine,
+The multichip story must certify the symbolic engine,
 not just the concrete interpreter. Block-local fork compaction
 (``fork_block``) makes ``expand_forks`` shard-local; with equal blocking
 the sharded and unsharded runs are bit-identical.
@@ -114,8 +114,7 @@ def test_block_local_forks_stay_in_block():
            "frontier across the pod'); xfail keeps tier-1 signal "
            "clean without hiding a future fix (an XPASS will show).")
 def test_precompile_callback_on_sharded_frontier():
-    """A precompile host callback on a SHARDED frontier (VERDICT r4 ask
-    #2): with ``SymSpec.mesh`` set, the ecrecover/natives pure_callbacks
+    """A precompile host callback on a SHARDED frontier: with ``SymSpec.mesh`` set, the ecrecover/natives pure_callbacks
     run under jax.shard_map — each shard round-trips only its own lanes,
     no {maximal device=0} gather (the round-4 SPMD remat hazard). The
     sharded result must match the unsharded run bit-for-bit."""

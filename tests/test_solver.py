@@ -102,7 +102,7 @@ def test_unsat_contradiction_returns_none():
     s = Solver(tape, max_iters=50)
     s.add(node, not sign)
     # round 4: the refutation pass PROVES this contradiction instead of
-    # burning search budget and degrading to unknown (VERDICT r3 ask #4)
+    # burning search budget and degrading to unknown
     assert s.check() == "unsat"
 
 
@@ -119,7 +119,7 @@ def test_solver_front_door_sat_and_model():
     assert bytes(m.calldata[:4]) == bytes.fromhex("a9059cbb")
 
 
-# --- round-4 unsat verdicts + model cache (VERDICT r3 ask #4) ---
+# --- round-4 unsat verdicts + model cache ---
 
 def _mk_tape(nodes, constraints):
     from mythril_tpu.smt.tape import HostTape

@@ -1,6 +1,6 @@
 """Spill-to-host fork deferral + cross-block lane rebalancing.
 
-VERDICT r3 ask #3 (SURVEY §5.7/§5.8): forks past block capacity must not
+SURVEY §5.7/§5.8: forks past block capacity must not
 be silently lost — a starved fork parks its lane, retries, and the host
 re-seeds persistently parked lanes into other blocks' free slots between
 chunks. Done-criterion: a branchy+quiet contract mix that drops forks

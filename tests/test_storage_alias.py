@@ -1,4 +1,4 @@
-"""Numeric storage-alias probe (VERDICT r4 ask #6; SURVEY §7 hard part
+"""Numeric storage-alias probe (SURVEY §7 hard part
 #1 — the reference gets this from Z3 ``Array`` semantics ⚠unv).
 
 A write through a symbolic key ``f(x)`` and a read through a

@@ -1,4 +1,4 @@
-"""Fork-admission strategies (VERDICT r3 ask #10; reference strategy/
+"""Fork-admission strategies (reference strategy/
 {basic,beam}.py + coverage wrapper ⚠unv, SURVEY §1 row 7).
 
 The frontier steps breadth-first by construction, so "strategy" here

@@ -209,7 +209,7 @@ def _snapshot_solver_stats():
 
 
 def test_unknown_rate_bound_across_suite():
-    """VERDICT r3 ask #4 done-criterion: across the SWC-suite fixtures the
+    """Across the SWC-suite fixtures the
     solver must DECIDE (sat or unsat) >= 90% of queries — every unknown is
     a silently dropped candidate finding. Runs last in this file (pytest
     preserves definition order)."""

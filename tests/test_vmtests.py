@@ -1,6 +1,6 @@
 """Consensus-style VM test vectors vs the concrete interpreter.
 
-The independent oracle (VERDICT.md round-1 weak #6): fixtures in
+The independent oracle: fixtures in
 ``tests/fixtures/vmtests.json`` were generated with machinery deliberately
 disjoint from the engine (raw-byte mini-assembler + Python big-int formula
 expectations — see ``tests/fixtures/gen_vmtests.py``). The whole suite

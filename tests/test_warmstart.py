@@ -125,7 +125,12 @@ def test_recency_cap_evicts_oldest(tmp_path):
     assert counter("compile_store_evicted_total") == e0 + 2
 
 
-def test_gc_sweeps_tmps_and_aged_corpses(tmp_path):
+def test_gc_sweeps_tmps_and_aged_corpses(tmp_path, monkeypatch):
+    # the XLA cache is placed from outside (mythril_tpu/compile_cache):
+    # point it at a scratch dir so the cache-ttl sweep prunes only that
+    xla = tmp_path / "xla"
+    xla.mkdir()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(xla))
     store = CompileStore(str(tmp_path))
     store.record("cpu", (2, 4, 16, 1), "c" * 16)
     bdir = os.path.join(str(tmp_path), "buckets")
@@ -331,7 +336,7 @@ def test_supervisor_flags_cache_dirty_on_worker_death(tmp_path,
 
     cache = str(tmp_path / "wk_cache")
     os.makedirs(cache)
-    monkeypatch.setenv("MYTHRIL_WORKER_JAX_CACHE", cache)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache)
     sup = stub_supervisor()
     try:
         sup.run_batch(0, ["a"], [b"\x00"])
@@ -341,6 +346,55 @@ def test_supervisor_flags_cache_dirty_on_worker_death(tmp_path,
     finally:
         sup.close()
     assert os.path.exists(os.path.join(cache, ".dirty"))
+
+
+# --- one compile cache, placed from outside --------------------------------
+
+def test_compile_cache_is_placed_from_outside(tmp_path, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set, no code path of the repo sets
+    ``jax_compilation_cache_dir`` (JAX reads the variable itself);
+    unset, the in-process engine, the worker and the daemon's compile
+    store all resolve ONE fixed path under the checkout."""
+    import subprocess
+    import sys
+
+    from mythril_tpu import compile_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    child = (
+        "import sys; sys.path.insert(0, %r)\n"
+        "import jax\n"
+        "from mythril_tpu import compile_cache\n"
+        "from mythril_tpu.compilestore import CompileStore\n"
+        "seen = []\n"
+        "real = jax.config.update\n"
+        "jax.config.update = lambda k, v: (seen.append(k), real(k, v))\n"
+        "inproc = compile_cache.enable()\n"
+        "worker = compile_cache.cache_dir()\n"
+        "daemon = CompileStore(sys.argv[1]).xla_cache_dir()\n"
+        "assert inproc == worker == daemon, (inproc, worker, daemon)\n"
+        "print(inproc)\n"
+        "print('jax_compilation_cache_dir' in seen)\n"
+        "print(jax.config.jax_compilation_cache_dir)\n" % repo)
+
+    def run(env_dir):
+        env = {k: v for k, v in os.environ.items()
+               if k != compile_cache.ENV}
+        if env_dir:
+            env[compile_cache.ENV] = env_dir
+        r = subprocess.run([sys.executable, "-c", child, str(tmp_path)],
+                           capture_output=True, text=True, env=env,
+                           timeout=120)
+        assert r.returncode == 0, r.stderr[-2000:]
+        return r.stdout.split()
+
+    outside = str(tmp_path / "outside")
+    assert run(outside) == [outside, "False", outside]
+    fixed = os.path.join(repo, ".jax_cache")
+    assert run(None) == [fixed, "True", fixed]
+    # the store keeps its bucket registry under the data dir (data),
+    # and nothing cache-shaped beside it
+    assert os.listdir(str(tmp_path)) == ["buckets"]
 
 
 # --- end to end: restart comes back warm --------------------------------

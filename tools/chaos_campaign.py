@@ -988,8 +988,8 @@ def _cell_compile_cache_quarantine(d: str, contracts,
     with open(os.path.join(cache, ".dirty"), "w") as fh:
         fh.write("pid=0 t=0\n")
     saved = {k: os.environ.get(k) for k in
-             ("MYTHRIL_WORKER_JAX_CACHE", "MYTHRIL_CACHE_PROBE_FAULT")}
-    os.environ["MYTHRIL_WORKER_JAX_CACHE"] = cache
+             ("JAX_COMPILATION_CACHE_DIR", "MYTHRIL_CACHE_PROBE_FAULT")}
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache
     os.environ["MYTHRIL_CACHE_PROBE_FAULT"] = "segv"
     try:
         res = _campaign(contracts, os.path.join(d, "ck"),
