@@ -10,8 +10,7 @@ from __future__ import annotations
 
 from typing import List
 
-import numpy as np
-
+from ....obs.device import fetch
 from ....smt.tape import attacker_controlled
 from ...report import Issue
 from ..base import DetectionModule, EntryPoint
@@ -28,9 +27,9 @@ class ArbitraryJump(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        dest = np.asarray(ctx.sf.sym_jump_dest)
-        pcs = np.asarray(ctx.sf.sym_jump_pc)
-        cids = np.asarray(ctx.sf.sym_jump_cid)
+        dest = fetch(ctx.sf.sym_jump_dest, "sym_jump_dest")
+        pcs = fetch(ctx.sf.sym_jump_pc, "sym_jump_pc")
+        cids = fetch(ctx.sf.sym_jump_cid, "sym_jump_cid")
         for lane in ctx.lanes():
             node = int(dest[lane])
             pc = int(pcs[lane])

@@ -10,8 +10,7 @@ from __future__ import annotations
 
 from typing import List
 
-import numpy as np
-
+from ....obs.device import fetch
 from ....smt.tape import attacker_controlled, keccak_derived
 from ...report import Issue
 from ..base import DetectionModule, EntryPoint
@@ -28,9 +27,9 @@ class ArbitraryStorage(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        key_node = np.asarray(ctx.sf.arb_key_node)
-        key_pc = np.asarray(ctx.sf.arb_key_pc)
-        cids = np.asarray(ctx.sf.arb_key_cid)
+        key_node = fetch(ctx.sf.arb_key_node, "arb_key_node")
+        key_pc = fetch(ctx.sf.arb_key_pc, "arb_key_pc")
+        cids = fetch(ctx.sf.arb_key_cid, "arb_key_cid")
         for lane in ctx.lanes():
             pc = int(key_pc[lane])
             node = int(key_node[lane])

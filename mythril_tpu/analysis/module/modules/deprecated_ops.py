@@ -10,8 +10,7 @@ from __future__ import annotations
 
 from typing import List
 
-import numpy as np
-
+from ....obs.device import fetch
 from ...report import Issue
 from ..base import DetectionModule, EntryPoint
 from ..loader import register_module
@@ -29,7 +28,7 @@ class DeprecatedOperations(DetectionModule):
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
         calls = CallLog(ctx.sf)
-        origin_read = np.asarray(ctx.sf.origin_read)
+        origin_read = fetch(ctx.sf.origin_read, "origin_read")
         for lane in ctx.lanes():
             used_origin = bool(origin_read[lane])
             findings = []

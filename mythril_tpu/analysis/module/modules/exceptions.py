@@ -9,8 +9,7 @@ from __future__ import annotations
 
 from typing import List
 
-import numpy as np
-
+from ....obs.device import fetch
 from ...report import Issue
 from ..base import DetectionModule, EntryPoint
 from ..loader import register_module
@@ -26,8 +25,8 @@ class Exceptions(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        inv_pc = np.asarray(ctx.sf.inv_pc)
-        cids = np.asarray(ctx.sf.inv_cid)
+        inv_pc = fetch(ctx.sf.inv_pc, "inv_pc")
+        cids = fetch(ctx.sf.inv_cid, "inv_cid")
         # INVALID halts exceptionally, so these lanes carry error=True
         for lane in ctx.lanes(include_errors=True):
             pc = int(inv_pc[lane])

@@ -22,8 +22,7 @@ from __future__ import annotations
 
 from typing import List
 
-import numpy as np
-
+from ....obs.device import fetch
 from ....symbolic.ops import SymOp
 from ....smt.tape import HostNode, HostTape, cone, intern_node
 from ....smt.solver import solve_tape
@@ -49,25 +48,28 @@ class IntegerArithmetics(DetectionModule):
         permissive on lanes with calls/logs/returns, whose payloads are
         not fully recorded as node ids."""
         out = []
-        for arr in (sf.st_val_sym, sf.st_key_sym):
-            row = np.asarray(arr[lane])
+        for name in ("st_val_sym", "st_key_sym"):
+            # the slice is a small device kernel queued behind whatever
+            # runs there, then a read of its result
+            row = fetch(lambda: getattr(sf, name)[lane],
+                        f"kernel:{name}[lane]")
             out.extend(int(x) for x in row[row > 0])
         return out
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
         sf = ctx.sf
-        n_arith = np.asarray(sf.n_arith)
-        arith_op = np.asarray(sf.arith_op)
-        arith_a = np.asarray(sf.arith_a)
-        arith_b = np.asarray(sf.arith_b)
-        arith_r = np.asarray(sf.arith_r)
-        arith_pc = np.asarray(sf.arith_pc)
-        arith_cid = np.asarray(sf.arith_cid)
-        retval_len = np.asarray(sf.base.retval_len)
-        n_calls = np.asarray(sf.n_calls)
-        n_logs = np.asarray(sf.base.n_logs)
-        rv_havoc = np.asarray(sf.rv_havoc)
+        n_arith = fetch(sf.n_arith, "n_arith")
+        arith_op = fetch(sf.arith_op, "arith_op")
+        arith_a = fetch(sf.arith_a, "arith_a")
+        arith_b = fetch(sf.arith_b, "arith_b")
+        arith_r = fetch(sf.arith_r, "arith_r")
+        arith_pc = fetch(sf.arith_pc, "arith_pc")
+        arith_cid = fetch(sf.arith_cid, "arith_cid")
+        retval_len = fetch(sf.base.retval_len, "base.retval_len")
+        n_calls = fetch(sf.n_calls, "n_calls")
+        n_logs = fetch(sf.base.n_logs, "base.n_logs")
+        rv_havoc = fetch(sf.rv_havoc, "rv_havoc")
         A = int(sf.base.acct_used.shape[1])
         for lane in ctx.lanes():
             n = int(n_arith[lane])

@@ -11,8 +11,7 @@ from __future__ import annotations
 
 from typing import List
 
-import numpy as np
-
+from ....obs.device import fetch
 from ....symbolic.ops import FreeKind
 from ....smt.tape import support
 from ...report import Issue
@@ -39,7 +38,7 @@ class PredictableVariables(DetectionModule):
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
         calls = CallLog(ctx.sf)
-        sd = np.asarray(ctx.sf.base.selfdestructed)
+        sd = fetch(ctx.sf.base.selfdestructed, "base.selfdestructed")
         for lane in ctx.lanes():
             # only paths that move value (call with possible value or
             # selfdestruct) — pure reads of block vars are not findings

@@ -10,8 +10,7 @@ from __future__ import annotations
 
 from typing import List
 
-import numpy as np
-
+from ....obs.device import fetch
 from ....smt.tape import attacker_controlled
 from ...report import Issue
 from ..base import DetectionModule, EntryPoint
@@ -29,8 +28,8 @@ class StateChangeAfterCall(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        pc_arr = np.asarray(ctx.sf.sstore_after_call_pc)
-        cids = np.asarray(ctx.sf.sstore_ac_cid)
+        pc_arr = fetch(ctx.sf.sstore_after_call_pc, "sstore_after_call_pc")
+        cids = fetch(ctx.sf.sstore_ac_cid, "sstore_ac_cid")
         calls = CallLog(ctx.sf)
         for lane in ctx.lanes():
             pc = int(pc_arr[lane])

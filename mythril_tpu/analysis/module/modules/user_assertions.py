@@ -10,8 +10,7 @@ from __future__ import annotations
 
 from typing import List
 
-import numpy as np
-
+from ....obs.device import fetch
 from ...report import Issue
 from ..base import DetectionModule, EntryPoint
 from ..loader import register_module
@@ -39,10 +38,10 @@ class UserAssertions(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        reverted = np.asarray(ctx.sf.base.reverted)
-        retval = np.asarray(ctx.sf.base.retval)
-        retval_len = np.asarray(ctx.sf.base.retval_len)
-        pcs = np.asarray(ctx.sf.base.pc)
+        reverted = fetch(ctx.sf.base.reverted, "base.reverted")
+        retval = fetch(ctx.sf.base.retval, "base.retval")
+        retval_len = fetch(ctx.sf.base.retval_len, "base.retval_len")
+        pcs = fetch(ctx.sf.base.pc, "base.pc")
         for lane in ctx.lanes(include_reverted=True):
             if not bool(reverted[lane]) or int(retval_len[lane]) < 36:
                 continue

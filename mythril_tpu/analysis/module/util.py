@@ -6,8 +6,7 @@ from __future__ import annotations
 
 from typing import Iterator, List
 
-import numpy as np
-
+from ...obs.device import fetch
 from ...ops import u256
 
 
@@ -24,14 +23,14 @@ class CallLog:
     """Host copy of the per-lane external-call records."""
 
     def __init__(self, sf):
-        self.n = np.asarray(sf.n_calls)
-        self.op = np.asarray(sf.call_op)
-        self.pc = np.asarray(sf.call_pc)
-        self.cid = np.asarray(sf.call_cid)
-        self.to_sym = np.asarray(sf.call_to_sym)
-        self.to = np.asarray(sf.call_to)
-        self.value_sym = np.asarray(sf.call_value_sym)
-        self.value = np.asarray(sf.call_value)
+        self.n = fetch(sf.n_calls, "n_calls")
+        self.op = fetch(sf.call_op, "call_op")
+        self.pc = fetch(sf.call_pc, "call_pc")
+        self.cid = fetch(sf.call_cid, "call_cid")
+        self.to_sym = fetch(sf.call_to_sym, "call_to_sym")
+        self.to = fetch(sf.call_to, "call_to")
+        self.value_sym = fetch(sf.call_value_sym, "call_value_sym")
+        self.value = fetch(sf.call_value, "call_value")
 
     def lane(self, lane: int) -> Iterator[CallEvent]:
         for j in range(min(int(self.n[lane]), self.op.shape[1])):

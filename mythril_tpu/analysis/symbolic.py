@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import hashlib
 import logging
+from operator import attrgetter
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
@@ -21,6 +22,7 @@ from ..core import Corpus, make_env
 from ..core.frontier import ATTACKER_ADDRESS, CAP_TRAPS, TRAP_NAMES
 from ..disassembler import ContractImage
 from ..obs import metrics as obs_metrics
+from ..obs.device import fetch, tally
 from ..obs import trace as obs_trace
 from ..smt.eval import Assignment
 from ..smt.solver import solve_tape
@@ -61,9 +63,9 @@ class AnalysisContext:
         so predicates witnessed only on a revert path (e.g. the guard
         branch of a SafeMath add) are not findings. The Exceptions module
         opts into error lanes explicitly."""
-        act = np.asarray(self.sf.base.active)
-        err = np.asarray(self.sf.base.error)
-        rev = np.asarray(self.sf.base.reverted)
+        act = fetch(self.sf.base.active, "base.active")
+        err = fetch(self.sf.base.error, "base.error")
+        rev = fetch(self.sf.base.reverted, "base.reverted")
         keep = act.copy()
         if not include_errors:
             keep &= ~err
@@ -125,7 +127,10 @@ class AnalysisContext:
                           max_time=self.solver_timeout)
 
     def contract_of(self, lane: int) -> int:
-        return int(np.asarray(self.sf.base.contract_id[lane]))
+        # the index is a small device kernel queued behind whatever runs
+        # there, then a read of its result
+        return int(fetch(lambda: self.sf.base.contract_id[lane],
+                         "kernel:base.contract_id[lane]"))
 
     def cid_name(self, cid: int) -> str:
         """Display name for a recorded contract id (modules should prefer a
@@ -186,32 +191,35 @@ def coverage_summary(tx_contexts) -> dict:
             for name, n in c.trap_counts.items():
                 errored[name] = errored.get(name, 0) + n
     else:
-        errored = _count_traps(np.asarray(final.base.err_code))
+        errored = _count_traps(fetch(final.base.err_code, "base.err_code"))
     cap_names = {TRAP_NAMES[c] for c in CAP_TRAPS}
     cap_lost = sum(n for name, n in errored.items() if name in cap_names)
     # event logs reset per tx, so saturation counts sum across snapshots
     sat_calls = sum(
-        int((np.asarray(c.sf.n_calls) > limits.call_log).sum()) for c in tx_contexts
+        int((fetch(c.sf.n_calls, "n_calls") > limits.call_log).sum())
+        for c in tx_contexts
     )
     sat_arith = sum(
-        int((np.asarray(c.sf.n_arith) > limits.arith_log).sum()) for c in tx_contexts
+        int((fetch(c.sf.n_arith, "n_arith") > limits.arith_log).sum())
+        for c in tx_contexts
     )
     out = {
-        "lanes": int(np.asarray(final.base.active).shape[0]),
+        "lanes": int(fetch(final.base.active, "base.active").shape[0]),
         "surviving_paths": int(
-            (np.asarray(final.base.active) & ~np.asarray(final.base.error)).sum()
+            (fetch(final.base.active, "base.active")
+             & ~fetch(final.base.error, "base.error")).sum()
         ),
         "lanes_errored": errored,
         "lanes_lost_to_caps": cap_lost,
-        "dropped_forks": int(np.asarray(final.dropped_total)),
-        "killed_infeasible": int(np.asarray(final.killed_total)),
+        "dropped_forks": int(fetch(final.dropped_total, "dropped_total")),
+        "killed_infeasible": int(fetch(final.killed_total, "killed_total")),
         "saturated_call_logs": sat_calls,
         "saturated_arith_logs": sat_arith,
     }
     if any(getattr(c, "timed_out", False) for c in tx_contexts):
-        still_running = int((np.asarray(final.base.active)
-                             & ~np.asarray(final.base.halted)
-                             & ~np.asarray(final.base.error)).sum())
+        still_running = int((fetch(final.base.active, "base.active")
+                             & ~fetch(final.base.halted, "base.halted")
+                             & ~fetch(final.base.error, "base.error")).sum())
         out["deadline_expired_running"] = still_running
     return out
 
@@ -408,6 +416,14 @@ class SymExecWrapper:
         if enable_iprof:
             sf = sf.replace(base=sf.base.attach_iprof())
         env = make_env(P)
+        # host mirror of the frontier's run-total superstep counter (a
+        # chunk's count is the difference across its sym_run call)
+        self._steps_seen = 0
+        # from shapes and dtypes alone: no device sync
+        obs_metrics.REGISTRY.gauge(
+            "frontier_bytes",
+            help="bytes of the symbolic frontier's device leaves").set(
+            sum(x.nbytes for x in jax.tree.leaves(sf)))
 
         # multi-tx outer loop (reference: execute_transactions iterating
         # open_states ⚠unv SURVEY.md §3.2): snapshot a context after each
@@ -423,26 +439,73 @@ class SymExecWrapper:
             import time as _time
 
             runner = sym_run_donated if self._donate else sym_run
+            warm_shapes: set = getattr(self, "_warm_chunk_shapes", set())
+            self._warm_chunk_shapes = warm_shapes
+
+            def superstep(sf, n, shape, also=(), run_kw=None, **attrs):
+                """One ``sym_run`` call of at most ``n`` supersteps,
+                inside a span that ends when the device does: the call
+                only enqueues the program, and the read of its results
+                (the coverage bitmap, the run-total step counter and
+                the ``also`` leaves of the new frontier, in ONE
+                transfer) is what waits for it. ``shape`` keys the
+                compiled program in ``warm_shapes``. Returns the
+                frontier, the supersteps that ran, the span's seconds,
+                whether the call compiled, and the fetched ``also``
+                leaves."""
+                cold = shape not in warm_shapes
+                w0 = tally()[1]
+                with obs_trace.timer("superstep", tx=self._cur_tx,
+                                     steps=n, cold=cold, **attrs) as sp:
+                    sf, vis = runner(
+                        sf, env, self.corpus, spec, limits,
+                        max_steps=n, track_coverage=True,
+                        fork_policy=self.fork_policy,
+                        fork_block=self.fork_block,
+                        fork_impl=self.fork_impl, unroll=self.unroll,
+                        **(run_kw or {}))
+                    enqueue_s = sp.elapsed
+                    got = fetch(
+                        (vis, sf.steps_total)
+                        + tuple(attrgetter(a)(sf) for a in also),
+                        ",".join(("visited", "steps_total", *also)))
+                    steps_run = int(got[1]) - self._steps_seen
+                    sp.attrs.update(
+                        steps_run=steps_run,
+                        enqueue_s=round(enqueue_s, 6),
+                        device_wait_s=round(tally()[1] - w0, 6))
+                self._steps_seen = int(got[1])
+                self._visited |= got[0]
+                reg = obs_metrics.REGISTRY
+                if cold:
+                    warm_shapes.add(shape)
+                    reg.counter(
+                        "engine_compiles_total",
+                        help="distinct chunk shapes compiled").inc()
+                reg.counter(
+                    "engine_supersteps_total",
+                    help="supersteps sym_run's loop ran (quiescence "
+                         "ends a call early)").inc(steps_run)
+                reg.counter(
+                    "engine_supersteps_budget_total",
+                    help="supersteps the sym_run calls were allowed "
+                         "(sum of max_steps)").inc(n)
+                return sf, steps_run, sp.dur, cold, got[2:]
+
             if (self._deadline_at is None and self.checkpoint_dir is None
                     and not self.spill):
                 # execute + fork fuse inside the jitted superstep loop;
                 # the host-visible unit (and the span) is the whole call
-                with obs_trace.span("superstep", tx=self._cur_tx,
-                                    steps=max_steps):
-                    sf, vis = runner(sf, env, self.corpus, spec, limits,
-                                     max_steps=max_steps,
-                                     track_coverage=True,
-                                     fork_policy=self.fork_policy,
-                                     fork_block=self.fork_block,
-                                     fork_impl=self.fork_impl,
-                                     unroll=self.unroll)
-                self._visited |= np.asarray(vis)
+                sf, *_ = superstep(sf, max_steps, ("whole", max_steps),
+                                   done=0)
                 return sf
             steps_done = 0
             sec_per_step = 0.0
-            warm_shapes: set = getattr(self, "_warm_chunk_shapes", set())
-            self._warm_chunk_shapes = warm_shapes
             q = max(1, self._chunk // 4)
+            chunk_kw = dict(defer_starved=self.spill,
+                            migrate_every=self.migrate_every)
+            telemetry = (obs_metrics.REGISTRY.enabled
+                         or obs_trace.active())
             while steps_done < max_steps:
                 n = min(self._chunk, max_steps - steps_done)
                 # max_steps is a static jit arg: every distinct n is a
@@ -459,48 +522,28 @@ class SymExecWrapper:
                     remaining = self._deadline_at - _time.monotonic()
                     if remaining < sec_per_step * n:
                         n = q
-                cold = n not in warm_shapes
-                with obs_trace.timer("superstep", tx=self._cur_tx,
-                                     steps=n, done=steps_done,
-                                     cold=cold) as sp:
-                    sf, vis = runner(
-                        sf, env, self.corpus, spec, limits,
-                        max_steps=n,
-                        track_coverage=True, fork_policy=self.fork_policy,
-                        fork_block=self.fork_block,
-                        defer_starved=self.spill,
-                        migrate_every=self.migrate_every,
-                        fork_impl=self.fork_impl,
-                        unroll=self.unroll)
-                self._visited |= np.asarray(vis)
-                # a shape's first run pays XLA compilation — not a sample
-                if cold:
-                    warm_shapes.add(n)
-                    obs_metrics.REGISTRY.counter(
-                        "engine_compiles_total",
-                        help="distinct chunk shapes compiled").inc()
-                else:
-                    sec_per_step = max(sec_per_step, sp.elapsed / n)
-                obs_metrics.REGISTRY.counter("engine_supersteps_total").inc(n)
+                # ONE device→host transfer per chunk, shared by EVERY
+                # seam consumer: the span's end, the step counter, the
+                # rebalance planner, the telemetry gauges AND the loop's
+                # quiescence check ride the same fetch (each separate
+                # read is a blocking sync). A bare run with telemetry
+                # off and spill off reads only ``running`` beside the
+                # bitmap. (Reusing the pre-rebalance fetch for the
+                # quiescence check is exact: rebalance RELOCATES lanes —
+                # it never changes whether any lane is running.)
+                seam = (("base.active", "fork_req", "base.running")
+                        if self.spill or telemetry else ("base.running",))
+                sf, steps_run, dur, cold, got = superstep(
+                    sf, n, n, also=seam, run_kw=chunk_kw,
+                    done=steps_done)
+                act_h, freq_h = got[:2] if len(got) == 3 else (None, None)
+                run_h = got[-1]
+                # a shape's first run pays XLA compilation — not a
+                # sample; the rate is the device's (the span ends when
+                # the device does) over the supersteps that ran
+                if not cold and steps_run:
+                    sec_per_step = max(sec_per_step, dur / steps_run)
                 steps_done += n
-                # ONE device→host transfer per chunk boundary, shared by
-                # EVERY seam consumer: the rebalance planner, the
-                # telemetry gauges, AND the loop's quiescence check ride
-                # the same (active, fork_req, running) fetch. Each
-                # separate np.asarray is a blocking sync — the quiescence
-                # check used to pay its own regardless of cadence (the
-                # "refetch on every seam" gap), and now only a bare run
-                # with telemetry off and spill off falls back to the
-                # single running read. (Reusing the pre-rebalance fetch
-                # for the quiescence check is exact: rebalance RELOCATES
-                # lanes — it never changes whether any lane is running.)
-                act_h = freq_h = None
-                if self.spill or (obs_metrics.REGISTRY.enabled
-                                  or obs_trace.active()):
-                    act_h, freq_h, run_h = jax.device_get(
-                        (sf.base.active, sf.fork_req, sf.base.running))
-                else:
-                    run_h = np.asarray(sf.base.running)
                 if self.spill:
                     with obs_trace.span("rebalance", tx=self._cur_tx):
                         sf, moved = rebalance_parked(sf, self.fork_block,
@@ -529,8 +572,8 @@ class SymExecWrapper:
                 with obs_trace.span("drain", tx=self._cur_tx):
                     # one fetch per drain round, shared with the
                     # rebalance planner and the final parked count
-                    act_h, freq_h = jax.device_get(
-                        (sf.base.active, sf.fork_req))
+                    act_h, freq_h = fetch((sf.base.active, sf.fork_req),
+                                          "base.active,fork_req")
                     parked = freq_h & act_h
                     for _ in range(4):
                         if not parked.any():
@@ -546,21 +589,11 @@ class SymExecWrapper:
                         self._rebalanced += moved
                         obs_metrics.REGISTRY.counter(
                             "rebalanced_lanes_total").inc(moved)
-                        with obs_trace.span("superstep", tx=self._cur_tx,
-                                            steps=self._chunk, drain=True):
-                            sf, vis = runner(
-                                sf, env, self.corpus, spec, limits,
-                                max_steps=self._chunk,
-                                track_coverage=True,
-                                fork_policy=self.fork_policy,
-                                fork_block=self.fork_block,
-                                defer_starved=True,
-                                migrate_every=self.migrate_every,
-                                fork_impl=self.fork_impl,
-                                unroll=self.unroll)
-                        self._visited |= np.asarray(vis)
-                        act_h, freq_h = jax.device_get(
-                            (sf.base.active, sf.fork_req))
+                        # the chunk loop's program (same static args)
+                        sf, _, _, _, (act_h, freq_h) = superstep(
+                            sf, self._chunk, self._chunk,
+                            also=("base.active", "fork_req"),
+                            run_kw=chunk_kw, drain=True)
                         parked = freq_h & act_h
                 # forks still parked after draining are lost coverage —
                 # count them in the drop channel for honesty (reusing
@@ -576,7 +609,8 @@ class SymExecWrapper:
             with obs_trace.span("harvest", tx=self._cur_tx):
                 # err_code is zeroed by between_txs, so every nonzero
                 # code here is a loss from THIS transaction
-                trap_counts = _count_traps(np.asarray(sf.base.err_code))
+                trap_counts = _count_traps(
+                    fetch(sf.base.err_code, "base.err_code"))
                 ctx = AnalysisContext(
                     sf=sf, corpus=self.corpus, limits=limits,
                     contract_names=names, solver_iters=solver_iters,
@@ -586,7 +620,7 @@ class SymExecWrapper:
                 self.tx_contexts.append(ctx)
                 if self.enable_iprof:
                     import jax.numpy as jnp
-                    self._iprof += np.asarray(sf.base.op_hist).sum(
+                    self._iprof += fetch(sf.base.op_hist, "base.op_hist").sum(
                         axis=0, dtype=np.int64)
                     repl = {"op_hist": jnp.zeros_like(sf.base.op_hist)}
                     if sf.base.op_resid is not None:
@@ -594,8 +628,9 @@ class SymExecWrapper:
                         # orphaned by slot recycling / lane movement
                         # since the last harvest (per-lane rows stay
                         # attributable)
-                        self._iprof += np.asarray(
-                            sf.base.op_resid).astype(np.int64)
+                        self._iprof += fetch(
+                            sf.base.op_resid,
+                            "base.op_resid").astype(np.int64)
                         repl["op_resid"] = jnp.zeros_like(sf.base.op_resid)
                     sf = sf.replace(base=sf.base.replace(**repl))
             self.plugin_loader.fire("on_tx_end", ctx)
@@ -635,7 +670,7 @@ class SymExecWrapper:
         for t in range(transaction_count):
             if self.timed_out:
                 break  # deadline: report what was explored so far
-            if not bool(np.asarray(sf.base.active).any()):
+            if not bool(fetch(sf.base.active, "base.active").any()):
                 break  # nothing survived: no state left to extend
             sf = run_one_tx(sf, is_last=(t == transaction_count - 1))
             self._cur_tx += 1
@@ -670,17 +705,17 @@ class SymExecWrapper:
         if budget <= 0:
             return sf
         b = sf.base
-        n = np.asarray(sf.n_calls)
+        n = fetch(sf.n_calls, "n_calls")
         CL = sf.call_to.shape[1]
         # ADVICE r5: harvest only from non-error lanes — a trapped path's
         # call log can hold garbage targets computed past the failure
         # point, and on a live network junk-that-happens-to-hold-code
         # would burn dynld budget and account-table columns
-        ok_lane = ~np.asarray(b.error)
+        ok_lane = ~fetch(b.error, "base.error")
         conc = ((np.arange(CL)[None, :] < n[:, None])
-                & (np.asarray(sf.call_to_sym) == 0)
+                & (fetch(sf.call_to_sym, "call_to_sym") == 0)
                 & ok_lane[:, None])
-        to = np.asarray(sf.call_to)
+        to = fetch(sf.call_to, "call_to")
         cand = {int(u256.to_int(to[p, j])) for p, j in zip(*np.where(conc))}
         skip = self._known_addrs | self._dynld_miss
         fetched = []
@@ -716,7 +751,7 @@ class SymExecWrapper:
             fetched.append((a, code))
         if not fetched:
             return sf
-        used = np.asarray(b.acct_used)
+        used = fetch(b.acct_used, "base.acct_used")
         free_cols = np.where(~used.any(axis=0))[0]
         if len(free_cols) < len(fetched):
             log.warning(
@@ -728,8 +763,8 @@ class SymExecWrapper:
             fetched = fetched[:len(free_cols)]
             if not fetched:
                 return sf
-        addr_np = np.asarray(b.acct_addr).copy()
-        code_np = np.asarray(b.acct_code).copy()
+        addr_np = fetch(b.acct_addr, "base.acct_addr").copy()
+        code_np = fetch(b.acct_code, "base.acct_code").copy()
         used_np = used.copy()
         for col, (a, code) in zip(free_cols, fetched):
             idx = len(self.images)
@@ -792,8 +827,9 @@ class SymExecWrapper:
         reg = obs_metrics.REGISTRY
         if not (reg.enabled or obs_trace.active()):
             return
-        act = np.asarray(sf.base.active) if active is None else active
-        freq = np.asarray(sf.fork_req) if fork_req is None else fork_req
+        act = (fetch(sf.base.active, "base.active") if active is None
+               else active)
+        freq = fetch(sf.fork_req, "fork_req") if fork_req is None else fork_req
         parked = int((freq & act).sum())
         reg.gauge("frontier_active_lanes",
                   help="live lanes after the last chunk").set(float(act.sum()))

@@ -65,6 +65,7 @@ import time
 from typing import BinaryIO, Dict, List, Optional
 
 from . import compile_cache
+from .obs import device as obs_device
 from .obs import metrics as obs_metrics
 from .obs import trace as obs_trace
 
@@ -308,8 +309,8 @@ def _run_batch(camp, stub: bool, msg: Dict,
         if stub:
             if "__hang__" in names:
                 time.sleep(3600)
-            with obs_trace.timer("device_phase", bi=bi,
-                                 n=len(names)) as dv:
+            with obs_device.phase_timer("device_phase", bi=bi,
+                                        n=len(names)) as dv:
                 if fault is not None:
                     fault.fire("mid-superstep", nth)
             return {"issues": [], "paths": len(names), "dropped": 0,
@@ -322,8 +323,8 @@ def _run_batch(camp, stub: bool, msg: Dict,
                 or ("cpu" if msg.get("on_cpu") else None))
         cm = camp._tier_device(tier) if tier else None
         with (cm if cm is not None else contextlib.nullcontext()):
-            with obs_trace.timer("device_phase", bi=bi,
-                                 n=len(names)) as dv:
+            with obs_device.phase_timer("device_phase", bi=bi,
+                                        n=len(names)) as dv:
                 sym = camp._explore_batch(bi, names, codes, lanes,
                                           width)
                 if fault is not None:
@@ -331,7 +332,7 @@ def _run_batch(camp, stub: bool, msg: Dict,
                     # harvest: the closest honest stand-in for
                     # "mid-superstep" a process boundary allows
                     fault.fire("mid-superstep", nth)
-            with obs_trace.timer("host_phase", bi=bi) as hp:
+            with obs_device.phase_timer("host_phase", bi=bi) as hp:
                 out = camp._harvest_batch(bi, sym)
         out["phases"] = {"device": dv.dur or 0.0, "host": hp.dur or 0.0}
         # the chunk step-counts this worker has compiled through the

@@ -60,6 +60,7 @@ if TYPE_CHECKING:  # import is heavy at runtime (engine); lazy below
 
 from ..config import DEFAULT_LIMITS, DEFAULT_RESILIENCE, LimitsConfig
 from ..fleet import corpus_fingerprint
+from ..obs import device as obs_device
 from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
 from ..resilience import (BackendManager, BatchTimeout, DeviceLostError,
@@ -861,10 +862,16 @@ class CorpusCampaign:
 
     def _harvest_batch(self, bi: int, sym) -> Dict:
         """HOST phase of one batch: detection modules + witness search +
-        report merge over a finished exploration. Pure host work (the
-        engine arrays were already pulled during the wrapper's per-tx
-        harvest), so the pipelined campaign runs it on a worker thread
-        while the NEXT batch explores on the device."""
+        report merge over a finished exploration. NOT pure host work:
+        the wrapper's per-tx harvest pulled only the trap codes, so the
+        modules, the tape cache and the coverage summary read the
+        leaves of each transaction's frontier from the device here
+        (every read through ``obs.device.fetch``). The pipelined
+        campaign runs this on a worker thread while the NEXT batch
+        explores on the device; on one chip a read that dispatches a
+        kernel (a device leaf sliced per lane) then queues behind the
+        running ``sym_run`` call. The phase's span says how long it
+        waited (``device_wait_s``) beside its CPU seconds (``cpu_s``)."""
         from ..analysis import fire_lasers
 
         report = fire_lasers(
@@ -934,9 +941,10 @@ class CorpusCampaign:
         sub-batches. Each phase runs inside its own span, and the
         durations feed the per-request stage attribution
         (docs/observability.md "Per-stage latency")."""
-        with obs_trace.timer("device_phase", bi=bi, n=len(names)) as dv:
+        with obs_device.phase_timer("device_phase", bi=bi,
+                                    n=len(names)) as dv:
             sym = self._explore_batch(bi, names, codes, lanes, width)
-        with obs_trace.timer("host_phase", bi=bi) as hp:
+        with obs_device.phase_timer("host_phase", bi=bi) as hp:
             out = self._harvest_batch(bi, sym)
         acc = getattr(self, "_phase_acc", None)
         if acc is not None:
@@ -1345,7 +1353,7 @@ class CorpusCampaign:
         re-enters the submitting thread's trace scope (contextvars
         don't cross the pool boundary on their own)."""
         with obs_trace.apply_context(tctx):
-            sp = obs_trace.timer("host_phase", bi=bi).start()
+            sp = obs_device.phase_timer("host_phase", bi=bi).start()
             try:
                 out = self._host_phase_work(bi, handle)
             finally:
@@ -1757,8 +1765,8 @@ class CorpusCampaign:
                 items = self.contracts[
                     bi * self.batch_size:(bi + 1) * self.batch_size]
                 t_wall, t_mono = time.time(), time.monotonic()
-                dev_sp = obs_trace.timer("device_phase", bi=bi,
-                                         n=len(items)).start()
+                dev_sp = obs_device.phase_timer(
+                    "device_phase", bi=bi, n=len(items)).start()
                 handle = None
                 first_err: Optional[BaseException] = None
                 try:

@@ -1,0 +1,132 @@
+"""Where the host meets the device: every read of a device array on the
+host goes through :func:`fetch`, and the campaign's phase spans say how
+much of their wall clock was such reads and how much was CPU.
+
+A read (``np.asarray`` / ``jax.device_get`` of a device array) blocks
+until the array is there. A plain copy of a leaf that already exists
+is quick even while the chip runs a program; an expression that first
+dispatches a small kernel (``leaf[lane]``) queues behind whatever runs
+there, and on a busy chip is a wait for the device, not a copy
+(PERF.md §5). ``fetch`` does what those calls did, no caching and no
+extra sync, and always times the blocking call (clock reads only, like
+``trace.timer``):
+
+- ``device_fetches_total`` / ``device_fetch_seconds_total`` in
+  ``REGISTRY`` count every read of the process;
+- a per-thread tally (:func:`tally`) lets a span difference the reads
+  made on its own thread: :func:`phase_timer` spans (``device_phase``,
+  ``host_phase``) gain ``device_fetches``, ``device_wait_s`` and
+  ``cpu_s`` (``time.thread_time()`` outside the reads) at their end,
+  so that ``dur = cpu_s + device_wait_s + rest`` and ``rest`` is
+  waiting for a lock, a pool or another thread;
+- only when ``trace.active()``, each read is a ``device_fetch`` span
+  with ``what`` (the leaf's name), ``bytes`` and ``mono`` at its start.
+
+What the tally misses: work the phase hands to other threads (the
+module pool of ``--solver-workers`` > 1, the watchdog thread of
+``--batch-timeout``) lands on those threads' tallies and CPU clocks, so
+the phase's own ``device_fetches`` / ``cpu_s`` read low and ``rest``
+grows by it; the registry's totals still hold every read.
+
+No top-level jax (or numpy) import: supervisors load ``obs`` without a
+backend (tests/test_light_imports.py).
+"""
+
+from __future__ import annotations
+
+import threading
+import time
+from typing import Tuple
+
+from . import metrics as obs_metrics
+from . import trace as obs_trace
+
+_TALLY = threading.local()
+
+
+def tally() -> Tuple[int, float, float]:
+    """``(reads, seconds blocked in them, CPU seconds burnt inside
+    them)`` of the calling thread since it started."""
+    return (getattr(_TALLY, "n", 0), getattr(_TALLY, "seconds", 0.0),
+            getattr(_TALLY, "cpu", 0.0))
+
+
+def fetch(x, what: str):
+    """Read device array ``x`` on the host: ``np.asarray(x)`` for one
+    array, ``jax.device_get(x)`` for a tuple or list of them. ``x`` may
+    be a callable that returns either: an expression that dispatches a
+    small kernel and reads its result (``lambda: leaf[lane]``) is then
+    timed whole, and ``what`` says so (``"kernel:..."``)."""
+    c0 = time.thread_time()
+    t0 = time.monotonic()
+    if callable(x):
+        x = x()
+    if isinstance(x, (tuple, list)):
+        import jax
+
+        out = jax.device_get(x)
+        nbytes = sum(getattr(a, "nbytes", 0) for a in out)
+    else:
+        import numpy as np
+
+        out = np.asarray(x)
+        nbytes = out.nbytes
+    dt = time.monotonic() - t0
+    # the copy itself burns CPU inside the wait: kept apart (the CPU
+    # clock is read outside the wall clock's interval), so that a
+    # phase's ``cpu_s`` and ``device_wait_s`` never count a second twice
+    cpu = time.thread_time() - c0
+    n, seconds, cpu0 = tally()
+    _TALLY.n, _TALLY.seconds, _TALLY.cpu = n + 1, seconds + dt, cpu0 + cpu
+    reg = obs_metrics.REGISTRY
+    reg.counter("device_fetches_total",
+                help="host reads of device arrays (obs.device."
+                     "fetch)").inc()
+    reg.counter("device_fetch_seconds_total",
+                help="seconds the host stood blocked in those "
+                     "reads").inc(dt)
+    if obs_trace.active():
+        obs_trace.complete("device_fetch", dt, mono=t0, what=what,
+                           bytes=int(nbytes))
+    return out
+
+
+class PhaseSpan(obs_trace.Span):
+    """An always-measuring span (``trace.timer``) that at its end also
+    carries what its own thread did meanwhile: ``device_fetches``,
+    ``device_wait_s`` (the tally's differences) and ``cpu_s`` (the
+    thread's CPU seconds outside the reads), so that
+    ``cpu_s + device_wait_s <= dur``."""
+
+    __slots__ = ("_n0", "_w0", "_c0")
+
+    def __enter__(self) -> "PhaseSpan":
+        super().__enter__()
+        self._n0, self._w0, in_reads = tally()
+        self._c0 = time.thread_time() - in_reads
+        return self
+
+    start = __enter__
+
+    def __exit__(self, *exc) -> bool:
+        n1, w1, in_reads = tally()
+        cpu = time.thread_time() - in_reads - self._c0
+        wait = round(w1 - self._w0, 6)
+        # both clocks were read inside the span, so the sum cannot pass
+        # its duration; rounding to the microsecond must not either
+        so_far = round(self.elapsed, 6)
+        cpu = round(max(0.0, min(cpu, so_far - wait)), 6)
+        if cpu + wait > so_far:
+            cpu = max(0.0, round(cpu - 1e-6, 6))
+        self.attrs.update(device_fetches=n1 - self._n0,
+                          device_wait_s=wait, cpu_s=cpu)
+        return super().__exit__(*exc)
+
+
+def phase_timer(name: str, **attrs) -> PhaseSpan:
+    """``trace.timer`` for a campaign phase: start and stop it on the
+    thread that does the phase's work."""
+    return PhaseSpan(obs_trace.get_tracer(), name, attrs)
+
+
+__all__ = ["PhaseSpan", "fetch", "phase_timer", "tally"]

@@ -10,8 +10,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import List, Tuple
 
-import numpy as np
-
+from ..obs.device import fetch
 from ..ops import u256
 from ..symbolic.ops import SymOp, FreeKind
 
@@ -205,15 +204,15 @@ class TapeHostCache:
     per finished frontier and thread it through."""
 
     def __init__(self, sf):
-        self.tape_len = np.asarray(sf.tape_len)
-        self.tape_op = np.asarray(sf.tape_op)
-        self.tape_a = np.asarray(sf.tape_a)
-        self.tape_b = np.asarray(sf.tape_b)
-        self.tape_imm = np.asarray(sf.tape_imm)
-        self.con_len = np.asarray(sf.con_len)
-        self.con_node = np.asarray(sf.con_node)
-        self.con_sign = np.asarray(sf.con_sign)
-        self.con_pc = np.asarray(sf.con_pc)
+        self.tape_len = fetch(sf.tape_len, "tape_len")
+        self.tape_op = fetch(sf.tape_op, "tape_op")
+        self.tape_a = fetch(sf.tape_a, "tape_a")
+        self.tape_b = fetch(sf.tape_b, "tape_b")
+        self.tape_imm = fetch(sf.tape_imm, "tape_imm")
+        self.con_len = fetch(sf.con_len, "con_len")
+        self.con_node = fetch(sf.con_node, "con_node")
+        self.con_sign = fetch(sf.con_sign, "con_sign")
+        self.con_pc = fetch(sf.con_pc, "con_pc")
 
 
 def extract_tape(sf, lane: int, extra_constraints=(),

@@ -987,6 +987,7 @@ def _apply_precompiles(sf: SymFrontier, pre, pid, a_off, a_len, r_off,
         """
         Pl = inp_l.shape[0]
 
+        @jax.named_scope("host_ecrecover")
         def _run_ecr(_):
             return jax.pure_callback(
                 _host_ecr,
@@ -1002,6 +1003,7 @@ def _apply_precompiles(sf: SymFrontier, pre, pid, a_off, a_len, r_off,
             0,
         )
 
+        @jax.named_scope("host_natives")
         def _run_nat(_):
             return jax.pure_callback(
                 _host_nat,
@@ -1403,6 +1405,7 @@ def _push_create_frame(sf: SymFrontier, mi, is_c2, slot, sin, off, ln, salt,
     )
 
 
+@jax.named_scope("pop_frames")
 def pop_frames(sf: SymFrontier, corpus: Corpus) -> SymFrontier:
     """Return control to the caller for every lane whose sub-frame ended.
 
@@ -2230,6 +2233,7 @@ _POP_FRAME_WRITES = (
 )
 
 
+@jax.named_scope("sym_superstep")
 def sym_superstep(sf: SymFrontier, env: Env, corpus: Corpus,
                   spec: SymSpec = SymSpec(),
                   limits: LimitsConfig = DEFAULT_LIMITS) -> SymFrontier:
@@ -2503,6 +2507,7 @@ def between_txs(sf: SymFrontier, require_mutation: bool = True,
     )
 
 
+@jax.named_scope("plan_fork_map")
 def plan_fork_map(req2, free2, key, fork_policy: str = "fifo",
                   fork_impl: str = "packed"):
     """The fork source→destination mapping machinery, factored out of
@@ -2606,6 +2611,7 @@ def plan_fork_map(req2, free2, key, fork_policy: str = "fifo",
     return src2, is_copy, slot
 
 
+@jax.named_scope("expand_forks")
 def expand_forks(sf: SymFrontier, loop_bound: int = 0,
                  fork_block: int = 0,
                  fork_policy: str = "fifo",
@@ -2917,6 +2923,7 @@ def rebalance_parked(sf: SymFrontier, fork_block: int = 0,
     ), len(src_idx)
 
 
+@jax.named_scope("migrate_parked_device")
 def migrate_parked_device(sf: SymFrontier, fork_block: int,
                           mig_cap: int = 8) -> SymFrontier:
     """In-jit cross-block migration of starved fork-requesting lanes.
@@ -3171,7 +3178,12 @@ def _sym_run_impl(sf: SymFrontier, env: Env, corpus: Corpus,
             s, visited = one_step(i + k, s, visited)
         return i + unroll, s, visited
 
-    _, sf, visited = lax.while_loop(cond, body, (jnp.int32(0), sf, visited0))
+    steps, sf, visited = lax.while_loop(cond, body,
+                                        (jnp.int32(0), sf, visited0))
+    # the loop counter as the loop left it (with ``unroll`` > 1 it
+    # advances by ``unroll``): the only record of how many supersteps a
+    # call that ended on quiescence really ran
+    sf = sf.replace(steps_total=sf.steps_total + steps)
     return (sf, visited) if track_coverage else sf
 
 

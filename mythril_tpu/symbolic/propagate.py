@@ -347,6 +347,7 @@ def propagate_feasibility(sf: SymFrontier):
     return sf, infeasible
 
 
+@jax.named_scope("kill_infeasible")
 def kill_infeasible(sf: SymFrontier) -> SymFrontier:
     """Deactivate lanes whose path condition is provably unsatisfiable."""
     sf, inf = propagate_feasibility(sf)

@@ -144,6 +144,8 @@ class SymFrontier:
     fork_dest: jnp.ndarray   # i32[P] jump target of the taken branch
     dropped_forks: jnp.ndarray  # i32[P] forks lost to capacity (reported)
     dropped_total: jnp.ndarray  # i32[] run total of dropped forks
+    steps_total: jnp.ndarray  # i32[] run total of supersteps sym_run's loop ran
+    # (each call adds its final loop counter; quiescence ends a call early)
     # symbolic-callee enumeration (CALL with symbolic target forks one
     # candidate account per superstep; the fork copy re-executes the CALL
     # with the target stack slot concretized — see _h_sym_call)
@@ -304,6 +306,7 @@ def make_sym_frontier(
         fork_cval=jnp.zeros((P, 8), dtype=U32),
         dropped_forks=z(P),
         dropped_total=jnp.zeros((), dtype=I32),
+        steps_total=jnp.zeros((), dtype=I32),
         sym_jump_dest=z(P),
         sym_jump_pc=jnp.full(P, -1, dtype=I32),
         sym_jump_cid=z(P),

@@ -296,6 +296,12 @@ def _load_frontier_inner(path: str, template) -> Tuple[Any, Dict]:
                 # harvested or lost with the old format's fold-in)
                 leaves.append(np.asarray(tmpl_leaf))
                 continue
+            if name.endswith("steps_total"):
+                # frontiers written before the superstep counter: the
+                # count of what ran before the checkpoint is not known,
+                # so it resumes at 0
+                leaves.append(np.zeros_like(np.asarray(tmpl_leaf)))
+                continue
             raise CheckpointCorrupt(
                 f"{path}: checkpoint missing leaf {name!r}")
         arr = by_name[name]

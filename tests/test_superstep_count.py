@@ -1,0 +1,79 @@
+"""The run-total superstep counter on the frontier (``steps_total``):
+``sym_run`` adds its loop counter where the loop ran, so a call that
+ended on quiescence says how many supersteps it took."""
+
+import numpy as np
+import pytest
+
+import mythril_tpu  # noqa: F401
+from mythril_tpu.config import TEST_LIMITS
+from mythril_tpu.core import Corpus, make_env
+from mythril_tpu.disassembler import ContractImage
+from mythril_tpu.disassembler.asm import assemble
+from mythril_tpu.symbolic import SymSpec, make_sym_frontier, sym_run
+
+# halts after five instructions
+QUIESCES = assemble(1, 2, "ADD", "POP", "STOP")
+# JUMPDEST; PUSH1 0; JUMP: runs until the bounded-loops policy ends it
+SPINS = bytes([0x5B, 0x60, 0x00, 0x56])
+
+
+def build(code: bytes, n_lanes: int = 4):
+    img = ContractImage.from_bytecode(code, TEST_LIMITS.max_code)
+    active = np.zeros(n_lanes, dtype=bool)
+    active[0] = True
+    sf = make_sym_frontier(n_lanes, TEST_LIMITS, active=active)
+    return sf, make_env(n_lanes), Corpus.from_images([img])
+
+
+def steps_of(sf) -> int:
+    return int(np.asarray(sf.steps_total))
+
+
+def executed(sf) -> int:
+    """Instructions the one seeded lane executed: it runs one a
+    superstep until it stops, so this is the number of loop iterations
+    in which any lane ran, counted by the interpreter itself."""
+    return int(np.asarray(sf.base.n_steps).max())
+
+
+BUDGET = 8
+
+
+def run(sf, env, corpus, unroll=1):
+    return sym_run(sf, env, corpus, SymSpec(), TEST_LIMITS,
+                   max_steps=BUDGET, propagate_every=0, unroll=unroll)
+
+
+@pytest.mark.parametrize("unroll", [1, 2])
+def test_counts_the_loop_of_a_program_that_quiesces_early(unroll):
+    sf, env, corpus = build(QUIESCES)
+    assert steps_of(sf) == 0
+    out = run(sf, env, corpus, unroll)
+    ran = executed(out)
+    assert 0 < ran < BUDGET
+    # the counter advances by ``unroll``: the last block may hold tail
+    # steps that found the frontier quiescent and did nothing
+    assert steps_of(out) == -(-ran // unroll) * unroll
+
+
+@pytest.mark.parametrize("unroll", [1, 2])
+def test_counts_the_budget_of_a_program_that_outlasts_it(unroll):
+    sf, env, corpus = build(SPINS)
+    out = run(sf, env, corpus, unroll)
+    assert bool(np.asarray(out.base.running).any())
+    assert steps_of(out) == executed(out) == BUDGET
+
+
+def test_adds_up_over_chunked_calls():
+    # the bounded-loops policy ends SPINS inside the second chunk
+    sf, env, corpus = build(SPINS)
+    sf = run(sf, env, corpus)
+    assert steps_of(sf) == executed(sf) == BUDGET
+    sf = run(sf, env, corpus)
+    total = executed(sf)
+    assert BUDGET < total < 2 * BUDGET
+    assert steps_of(sf) == total
+    # a call on a quiescent frontier runs nothing and adds nothing
+    sf = run(sf, env, corpus)
+    assert steps_of(sf) == total

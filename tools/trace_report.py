@@ -132,6 +132,19 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
             out.append(f"{name:<18}{len(durs):>7}{_fmt_s(sum(durs)):>10}"
                        f"{_fmt_s(sum(durs) / len(durs)):>10}"
                        f"{_fmt_s(max(durs)):>10}")
+            if name == "superstep":
+                # spans that end when the device does carry what ran
+                ran = [s for s in spans if s["name"] == "superstep"
+                       and "steps_run" in s["args"]]
+                warm = [s for s in ran if not s["args"].get("cold")]
+                n_warm = sum(s["args"]["steps_run"] for s in warm)
+                if ran:
+                    out.append(
+                        f"  steps_run {sum(s['args']['steps_run'] for s in ran)}"
+                        f" of {sum(s['args'].get('steps', 0) for s in ran)}"
+                        " allowed" + (
+                            f", warm {_fmt_s(sum(s['dur'] for s in warm) / n_warm).strip()}"
+                            " a superstep" if n_warm else ""))
     else:
         out.append("(no spans)")
 
@@ -219,6 +232,16 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
                    f"{_fmt_s(dev_tot).strip()}")
         out.append(f"host phases:   {len(host):>4}  total "
                    f"{_fmt_s(host_tot).strip()}")
+        split = [s for s in host if "cpu_s" in s["args"]]
+        if split:
+            cpu = sum(s["args"]["cpu_s"] for s in split)
+            wait = sum(s["args"]["device_wait_s"] for s in split)
+            rest = sum(s["dur"] for s in split) - cpu - wait
+            out.append(
+                f"  of which cpu {_fmt_s(cpu).strip()}, device reads "
+                f"{_fmt_s(wait).strip()} "
+                f"({sum(s['args']['device_fetches'] for s in split)} "
+                f"fetches), rest (lock, pool) {_fmt_s(rest).strip()}")
         out.append(f"stall device-waits-host: {_fmt_s(dwh).strip()}   "
                    f"host-waits-device: {_fmt_s(hwd).strip()}")
         if host_tot > 0:
