@@ -10,7 +10,6 @@ from __future__ import annotations
 
 from typing import List
 
-from ....obs.device import fetch
 from ....smt.tape import attacker_controlled
 from ...report import Issue
 from ..base import DetectionModule, EntryPoint
@@ -27,9 +26,9 @@ class ArbitraryJump(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        dest = fetch(ctx.sf.sym_jump_dest, "sym_jump_dest")
-        pcs = fetch(ctx.sf.sym_jump_pc, "sym_jump_pc")
-        cids = fetch(ctx.sf.sym_jump_cid, "sym_jump_cid")
+        dest = ctx.host("sym_jump_dest")
+        pcs = ctx.host("sym_jump_pc")
+        cids = ctx.host("sym_jump_cid")
         for lane in ctx.lanes():
             node = int(dest[lane])
             pc = int(pcs[lane])

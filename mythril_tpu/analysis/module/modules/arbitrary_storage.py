@@ -10,7 +10,6 @@ from __future__ import annotations
 
 from typing import List
 
-from ....obs.device import fetch
 from ....smt.tape import attacker_controlled, keccak_derived
 from ...report import Issue
 from ..base import DetectionModule, EntryPoint
@@ -27,9 +26,9 @@ class ArbitraryStorage(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        key_node = fetch(ctx.sf.arb_key_node, "arb_key_node")
-        key_pc = fetch(ctx.sf.arb_key_pc, "arb_key_pc")
-        cids = fetch(ctx.sf.arb_key_cid, "arb_key_cid")
+        key_node = ctx.host("arb_key_node")
+        key_pc = ctx.host("arb_key_pc")
+        cids = ctx.host("arb_key_cid")
         for lane in ctx.lanes():
             pc = int(key_pc[lane])
             node = int(key_node[lane])

@@ -26,7 +26,7 @@ class DelegateCallToUntrustedContract(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        calls = CallLog(ctx.sf)
+        calls = CallLog(ctx)
         for lane in ctx.lanes():
             for ev in calls.lane(lane):
                 if ev.op != 0xF4:

@@ -10,7 +10,6 @@ from __future__ import annotations
 
 from typing import List
 
-from ....obs.device import fetch
 from ...report import Issue
 from ..base import DetectionModule, EntryPoint
 from ..loader import register_module
@@ -27,8 +26,8 @@ class DeprecatedOperations(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        calls = CallLog(ctx.sf)
-        origin_read = fetch(ctx.sf.origin_read, "origin_read")
+        calls = CallLog(ctx)
+        origin_read = ctx.host("origin_read")
         for lane in ctx.lanes():
             used_origin = bool(origin_read[lane])
             findings = []

@@ -28,7 +28,7 @@ class EtherThief(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        calls = CallLog(ctx.sf)
+        calls = CallLog(ctx)
         for lane in ctx.lanes():
             for ev in calls.lane(lane):
                 if ev.op not in (0xF1, 0xF2):

@@ -9,7 +9,6 @@ from __future__ import annotations
 
 from typing import List
 
-from ....obs.device import fetch
 from ...report import Issue
 from ..base import DetectionModule, EntryPoint
 from ..loader import register_module
@@ -25,8 +24,8 @@ class Exceptions(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        inv_pc = fetch(ctx.sf.inv_pc, "inv_pc")
-        cids = fetch(ctx.sf.inv_cid, "inv_cid")
+        inv_pc = ctx.host("inv_pc")
+        cids = ctx.host("inv_cid")
         # INVALID halts exceptionally, so these lanes carry error=True
         for lane in ctx.lanes(include_errors=True):
             pc = int(inv_pc[lane])

@@ -22,7 +22,6 @@ from __future__ import annotations
 
 from typing import List
 
-from ....obs.device import fetch
 from ....symbolic.ops import SymOp
 from ....smt.tape import HostNode, HostTape, cone, intern_node
 from ....smt.solver import solve_tape
@@ -40,7 +39,7 @@ class IntegerArithmetics(DetectionModule):
     pre_hooks = ["ADD", "SUB", "MUL", "EXP"]
 
     @staticmethod
-    def _lane_sinks(sf, lane: int) -> list:
+    def _lane_sinks(ctx, lane: int) -> list:
         """Node ids where a wrapped result becomes an effect the chain
         can observe (reference: the OverUnderflowAnnotation is reported
         only when it reaches an SSTORE/CALL-family/state sink ⚠unv).
@@ -49,28 +48,24 @@ class IntegerArithmetics(DetectionModule):
         not fully recorded as node ids."""
         out = []
         for name in ("st_val_sym", "st_key_sym"):
-            # the slice is a small device kernel queued behind whatever
-            # runs there, then a read of its result
-            row = fetch(lambda: getattr(sf, name)[lane],
-                        f"kernel:{name}[lane]")
+            row = ctx.host(name)[lane]
             out.extend(int(x) for x in row[row > 0])
         return out
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        sf = ctx.sf
-        n_arith = fetch(sf.n_arith, "n_arith")
-        arith_op = fetch(sf.arith_op, "arith_op")
-        arith_a = fetch(sf.arith_a, "arith_a")
-        arith_b = fetch(sf.arith_b, "arith_b")
-        arith_r = fetch(sf.arith_r, "arith_r")
-        arith_pc = fetch(sf.arith_pc, "arith_pc")
-        arith_cid = fetch(sf.arith_cid, "arith_cid")
-        retval_len = fetch(sf.base.retval_len, "base.retval_len")
-        n_calls = fetch(sf.n_calls, "n_calls")
-        n_logs = fetch(sf.base.n_logs, "base.n_logs")
-        rv_havoc = fetch(sf.rv_havoc, "rv_havoc")
-        A = int(sf.base.acct_used.shape[1])
+        n_arith = ctx.host("n_arith")
+        arith_op = ctx.host("arith_op")
+        arith_a = ctx.host("arith_a")
+        arith_b = ctx.host("arith_b")
+        arith_r = ctx.host("arith_r")
+        arith_pc = ctx.host("arith_pc")
+        arith_cid = ctx.host("arith_cid")
+        retval_len = ctx.host("base.retval_len")
+        n_calls = ctx.host("n_calls")
+        n_logs = ctx.host("base.n_logs")
+        rv_havoc = ctx.host("rv_havoc")
+        A = int(ctx.sf.base.acct_used.shape[1])
         for lane in ctx.lanes():
             n = int(n_arith[lane])
             if n == 0:
@@ -97,7 +92,7 @@ class IntegerArithmetics(DetectionModule):
                 and int(n_logs[lane]) == 0 and not bool(rv_havoc[lane])
             )
             if all_outlets_tracked:
-                sinks = self._lane_sinks(sf, lane)
+                sinks = self._lane_sinks(ctx, lane)
                 sinks.extend(int(nd) for nd, _ in base.constraints)
                 if sinks:
                     sink_cone = cone(base, sinks, storage_key_div=A)

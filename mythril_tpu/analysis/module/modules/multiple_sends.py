@@ -26,7 +26,7 @@ class MultipleSends(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        calls = CallLog(ctx.sf)
+        calls = CallLog(ctx)
         for lane in ctx.lanes():
             evs = [e for e in calls.lane(lane) if e.op in (0xF1, 0xF2, 0xF4, 0xFA)]
             if len(evs) < 2:

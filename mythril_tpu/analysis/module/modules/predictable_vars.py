@@ -11,7 +11,6 @@ from __future__ import annotations
 
 from typing import List
 
-from ....obs.device import fetch
 from ....symbolic.ops import FreeKind
 from ....smt.tape import support
 from ...report import Issue
@@ -37,8 +36,8 @@ class PredictableVariables(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        calls = CallLog(ctx.sf)
-        sd = fetch(ctx.sf.base.selfdestructed, "base.selfdestructed")
+        calls = CallLog(ctx)
+        sd = ctx.host("base.selfdestructed")
         for lane in ctx.lanes():
             # only paths that move value (call with possible value or
             # selfdestruct) — pure reads of block vars are not findings

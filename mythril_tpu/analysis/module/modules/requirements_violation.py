@@ -12,7 +12,6 @@ from __future__ import annotations
 
 from typing import List
 
-from ....obs.device import fetch
 from ...report import Issue
 from ..base import DetectionModule, EntryPoint
 from ..loader import register_module
@@ -30,8 +29,8 @@ class RequirementsViolation(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        sub_pc = fetch(ctx.sf.sub_revert_pc, "sub_revert_pc")
-        cids = fetch(ctx.sf.sub_revert_cid, "sub_revert_cid")
+        sub_pc = ctx.host("sub_revert_pc")
+        cids = ctx.host("sub_revert_cid")
         for lane in ctx.lanes(include_reverted=True):
             pc = int(sub_pc[lane])
             if pc < 0:

@@ -10,7 +10,6 @@ from __future__ import annotations
 
 from typing import List
 
-from ....obs.device import fetch
 from ....smt.tape import attacker_controlled
 from ...report import Issue
 from ..base import DetectionModule, EntryPoint
@@ -28,9 +27,9 @@ class StateChangeAfterCall(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        pc_arr = fetch(ctx.sf.sstore_after_call_pc, "sstore_after_call_pc")
-        cids = fetch(ctx.sf.sstore_ac_cid, "sstore_ac_cid")
-        calls = CallLog(ctx.sf)
+        pc_arr = ctx.host("sstore_after_call_pc")
+        cids = ctx.host("sstore_ac_cid")
+        calls = CallLog(ctx)
         for lane in ctx.lanes():
             pc = int(pc_arr[lane])
             if pc < 0:

@@ -9,7 +9,6 @@ from __future__ import annotations
 
 from typing import List
 
-from ....obs.device import fetch
 from ....smt.tape import attacker_controlled
 from ...report import Issue
 from ..base import DetectionModule, EntryPoint
@@ -26,10 +25,10 @@ class AccidentallyKillable(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        sd = fetch(ctx.sf.base.selfdestructed, "base.selfdestructed")
-        sd_sym = fetch(ctx.sf.sd_to_sym, "sd_to_sym")
-        pcs = fetch(ctx.sf.sd_pc, "sd_pc")  # recorded SELFDESTRUCT pc, not live pc
-        cids = fetch(ctx.sf.sd_cid, "sd_cid")  # contract whose code executed it
+        sd = ctx.host("base.selfdestructed")
+        sd_sym = ctx.host("sd_to_sym")
+        pcs = ctx.host("sd_pc")  # recorded SELFDESTRUCT pc, not live pc
+        cids = ctx.host("sd_cid")  # contract whose code executed it
         for lane in ctx.lanes():
             if not bool(sd[lane]) or int(pcs[lane]) < 0:
                 continue

@@ -31,7 +31,7 @@ class TransactionOrderDependence(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        calls = CallLog(ctx.sf)
+        calls = CallLog(ctx)
         for lane in ctx.lanes():
             transfer = [e for e in calls.lane(lane)
                         if e.op in (0xF1, 0xF2) and (e.value_sym or e.value > 0)]

@@ -28,7 +28,7 @@ class UncheckedRetval(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        calls = CallLog(ctx.sf)
+        calls = CallLog(ctx)
         for lane in ctx.lanes():
             tape = ctx.tape(lane)
             checked_ids, _ = constraint_support(tape)

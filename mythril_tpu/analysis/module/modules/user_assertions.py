@@ -10,7 +10,6 @@ from __future__ import annotations
 
 from typing import List
 
-from ....obs.device import fetch
 from ...report import Issue
 from ..base import DetectionModule, EntryPoint
 from ..loader import register_module
@@ -38,10 +37,10 @@ class UserAssertions(DetectionModule):
 
     def _execute(self, ctx) -> List[Issue]:
         issues: List[Issue] = []
-        reverted = fetch(ctx.sf.base.reverted, "base.reverted")
-        retval = fetch(ctx.sf.base.retval, "base.retval")
-        retval_len = fetch(ctx.sf.base.retval_len, "base.retval_len")
-        pcs = fetch(ctx.sf.base.pc, "base.pc")
+        reverted = ctx.host("base.reverted")
+        retval = ctx.host("base.retval")
+        retval_len = ctx.host("base.retval_len")
+        pcs = ctx.host("base.pc")
         for lane in ctx.lanes(include_reverted=True):
             if not bool(reverted[lane]) or int(retval_len[lane]) < 36:
                 continue

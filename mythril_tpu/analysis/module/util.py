@@ -6,7 +6,6 @@ from __future__ import annotations
 
 from typing import Iterator, List
 
-from ...obs.device import fetch
 from ...ops import u256
 
 
@@ -22,15 +21,15 @@ class CallEvent:
 class CallLog:
     """Host copy of the per-lane external-call records."""
 
-    def __init__(self, sf):
-        self.n = fetch(sf.n_calls, "n_calls")
-        self.op = fetch(sf.call_op, "call_op")
-        self.pc = fetch(sf.call_pc, "call_pc")
-        self.cid = fetch(sf.call_cid, "call_cid")
-        self.to_sym = fetch(sf.call_to_sym, "call_to_sym")
-        self.to = fetch(sf.call_to, "call_to")
-        self.value_sym = fetch(sf.call_value_sym, "call_value_sym")
-        self.value = fetch(sf.call_value, "call_value")
+    def __init__(self, ctx):
+        self.n = ctx.host("n_calls")
+        self.op = ctx.host("call_op")
+        self.pc = ctx.host("call_pc")
+        self.cid = ctx.host("call_cid")
+        self.to_sym = ctx.host("call_to_sym")
+        self.to = ctx.host("call_to")
+        self.value_sym = ctx.host("call_value_sym")
+        self.value = ctx.host("call_value")
 
     def lane(self, lane: int) -> Iterator[CallEvent]:
         for j in range(min(int(self.n[lane]), self.op.shape[1])):

@@ -67,8 +67,8 @@ def fire_lasers(target, white_list: Optional[List[str]] = None,
         if parallel and len(modules) > 1:
             from concurrent.futures import ThreadPoolExecutor
 
-            # pre-build the shared tape cache serially: module threads
-            # then only read it (lazy per-lane extraction under the GIL
+            # copy the tape's leaves to the host serially: module threads
+            # then only read them (lazy per-lane extraction under the GIL
             # is benign — duplicate work at worst, never a wrong tape)
             lanes = ctx.lanes(include_errors=True, include_reverted=True)
             if len(lanes):

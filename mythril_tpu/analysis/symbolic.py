@@ -22,12 +22,11 @@ from ..core import Corpus, make_env
 from ..core.frontier import ATTACKER_ADDRESS, CAP_TRAPS, TRAP_NAMES
 from ..disassembler import ContractImage
 from ..obs import metrics as obs_metrics
-from ..obs.device import fetch, tally
+from ..obs.device import HostLeaves, fetch, tally
 from ..obs import trace as obs_trace
 from ..smt.eval import Assignment
 from ..smt.solver import solve_tape
-from ..smt.tape import (HostNode, HostTape, TapeHostCache, extract_tape,
-                        intern_node)
+from ..smt.tape import HostNode, HostTape, extract_tape, intern_node
 from ..symbolic import SymSpec, between_txs, make_sym_frontier, sym_run
 from ..symbolic.engine import rebalance_parked, sym_run_donated
 
@@ -52,8 +51,21 @@ class AnalysisContext:
     # quiescence (reference: --execution-timeout degrade, SURVEY §5.3)
     timed_out: bool = False
     _tapes: Dict[int, HostTape] = field(default_factory=dict)
-    _tape_cache: Optional[TapeHostCache] = field(default=None, repr=False)
     _tape_idx: Dict[int, dict] = field(default_factory=dict, repr=False)
+    _leaves: HostLeaves = field(init=False, repr=False)
+
+    def __post_init__(self):
+        self._leaves = HostLeaves(self.sf)
+
+    def host(self, name: str) -> np.ndarray:
+        """Leaf ``name`` of the frontier (``"n_arith"``,
+        ``"base.active"``) as a NumPy array: copied whole from the
+        device on the first request, the same read-only array on every
+        later one. Every per-lane or per-event index belongs on this
+        copy: an index of the device leaf dispatches a program, which
+        queues behind the ``sym_run`` call of the next batch's device
+        phase (PERF.md §5 item 2)."""
+        return self._leaves(name)
 
     def lanes(self, include_errors: bool = False,
               include_reverted: bool = False) -> np.ndarray:
@@ -63,22 +75,17 @@ class AnalysisContext:
         so predicates witnessed only on a revert path (e.g. the guard
         branch of a SafeMath add) are not findings. The Exceptions module
         opts into error lanes explicitly."""
-        act = fetch(self.sf.base.active, "base.active")
-        err = fetch(self.sf.base.error, "base.error")
-        rev = fetch(self.sf.base.reverted, "base.reverted")
-        keep = act.copy()
+        keep = self.host("base.active").copy()
         if not include_errors:
-            keep &= ~err
+            keep &= ~self.host("base.error")
         if not include_reverted:
-            keep &= ~rev
+            keep &= ~self.host("base.reverted")
         return np.where(keep)[0]
 
     def tape(self, lane: int) -> HostTape:
         if lane not in self._tapes:
-            if self._tape_cache is None:
-                self._tape_cache = TapeHostCache(self.sf)
             self._tapes[lane] = extract_tape(self.sf, lane,
-                                             cache=self._tape_cache)
+                                             cache=self._leaves)
         return self._tapes[lane]
 
     def tape_index(self, lane: int) -> dict:
@@ -127,10 +134,7 @@ class AnalysisContext:
                           max_time=self.solver_timeout)
 
     def contract_of(self, lane: int) -> int:
-        # the index is a small device kernel queued behind whatever runs
-        # there, then a read of its result
-        return int(fetch(lambda: self.sf.base.contract_id[lane],
-                         "kernel:base.contract_id[lane]"))
+        return int(self.host("base.contract_id")[lane])
 
     def cid_name(self, cid: int) -> str:
         """Display name for a recorded contract id (modules should prefer a
@@ -181,8 +185,8 @@ def coverage_summary(tx_contexts) -> dict:
     channel is counted so parity claims are auditable: lanes errored per trap cause, forks dropped to capacity,
     saturated event logs, and propagation kills.
     """
-    final = tx_contexts[-1].sf
-    limits = tx_contexts[-1].limits
+    final = tx_contexts[-1]
+    limits = final.limits
     errored: dict = {}
     if all(c.trap_counts is not None for c in tx_contexts):
         # per-tx tallies (exact even when expand_forks recycled an errored
@@ -191,35 +195,33 @@ def coverage_summary(tx_contexts) -> dict:
             for name, n in c.trap_counts.items():
                 errored[name] = errored.get(name, 0) + n
     else:
-        errored = _count_traps(fetch(final.base.err_code, "base.err_code"))
+        errored = _count_traps(final.host("base.err_code"))
     cap_names = {TRAP_NAMES[c] for c in CAP_TRAPS}
     cap_lost = sum(n for name, n in errored.items() if name in cap_names)
     # event logs reset per tx, so saturation counts sum across snapshots
     sat_calls = sum(
-        int((fetch(c.sf.n_calls, "n_calls") > limits.call_log).sum())
+        int((c.host("n_calls") > limits.call_log).sum())
         for c in tx_contexts
     )
     sat_arith = sum(
-        int((fetch(c.sf.n_arith, "n_arith") > limits.arith_log).sum())
+        int((c.host("n_arith") > limits.arith_log).sum())
         for c in tx_contexts
     )
+    active = final.host("base.active")
+    error = final.host("base.error")
     out = {
-        "lanes": int(fetch(final.base.active, "base.active").shape[0]),
-        "surviving_paths": int(
-            (fetch(final.base.active, "base.active")
-             & ~fetch(final.base.error, "base.error")).sum()
-        ),
+        "lanes": int(active.shape[0]),
+        "surviving_paths": int((active & ~error).sum()),
         "lanes_errored": errored,
         "lanes_lost_to_caps": cap_lost,
-        "dropped_forks": int(fetch(final.dropped_total, "dropped_total")),
-        "killed_infeasible": int(fetch(final.killed_total, "killed_total")),
+        "dropped_forks": int(final.host("dropped_total")),
+        "killed_infeasible": int(final.host("killed_total")),
         "saturated_call_logs": sat_calls,
         "saturated_arith_logs": sat_arith,
     }
     if any(getattr(c, "timed_out", False) for c in tx_contexts):
-        still_running = int((fetch(final.base.active, "base.active")
-                             & ~fetch(final.base.halted, "base.halted")
-                             & ~fetch(final.base.error, "base.error")).sum())
+        still_running = int(
+            (active & ~final.host("base.halted") & ~error).sum())
         out["deadline_expired_running"] = still_running
     return out
 

@@ -23,9 +23,10 @@ import numpy as np
 from ..config import DEFAULT_LIMITS, LimitsConfig
 from ..core import Corpus, make_env
 from ..disassembler import ContractImage
+from ..obs.device import HostLeaves
 from ..smt.eval import Assignment, evaluate
 from ..smt.solver import solve_tape
-from ..smt.tape import HostTape, TapeHostCache, extract_tape
+from ..smt.tape import HostTape, extract_tape
 from ..symbolic import SymSpec, make_sym_frontier, sym_run
 
 
@@ -54,12 +55,12 @@ def _satisfied(tape: HostTape, asn: Assignment) -> bool:
 
 
 def find_trace_lane(sf, seed: Assignment,
-                    cache: Optional[TapeHostCache] = None) -> Optional[int]:
+                    cache: Optional[HostLeaves] = None) -> Optional[int]:
     """Lane whose path condition the seed input satisfies (the concrete
     trace the reference's ``concrete_execution`` would record ⚠unv)."""
-    cache = cache or TapeHostCache(sf)
-    act = np.asarray(sf.base.active)
-    err = np.asarray(sf.base.error)
+    cache = cache or HostLeaves(sf)
+    act = cache("base.active")
+    err = cache("base.error")
     for lane in np.where(act & ~err)[0]:
         if _satisfied(extract_tape(sf, int(lane), cache=cache), seed):
             return int(lane)
@@ -95,13 +96,13 @@ def concolic_execution(
     sf = sym_run(sf, env, corpus, SymSpec(), limits, max_steps=max_steps)
 
     seed = _seed_assignment(seed_calldata, callvalue, caller)
-    cache = TapeHostCache(sf)
+    cache = HostLeaves(sf)
     lane = find_trace_lane(sf, seed, cache=cache)
     if lane is None:
         return []  # seed diverged (e.g. exploration capped before halt)
 
     tape = extract_tape(sf, lane, cache=cache)
-    con_pc = np.asarray(sf.con_pc)[lane]
+    con_pc = cache("con_pc")[lane]
     out: List[FlippedBranch] = []
     for j, (node, sign) in enumerate(tape.constraints):
         pc = int(con_pc[j]) if j < len(con_pc) else -1

@@ -864,13 +864,14 @@ class CorpusCampaign:
         """HOST phase of one batch: detection modules + witness search +
         report merge over a finished exploration. NOT pure host work:
         the wrapper's per-tx harvest pulled only the trap codes, so the
-        modules, the tape cache and the coverage summary read the
-        leaves of each transaction's frontier from the device here
-        (every read through ``obs.device.fetch``). The pipelined
-        campaign runs this on a worker thread while the NEXT batch
-        explores on the device; on one chip a read that dispatches a
-        kernel (a device leaf sliced per lane) then queues behind the
-        running ``sym_run`` call. The phase's span says how long it
+        modules, the tape extraction and the coverage summary read the
+        leaves of each transaction's frontier from the device here:
+        each leaf whole and once a context (``AnalysisContext.host``),
+        every index on the host copy. The pipelined campaign runs this
+        on a worker thread while the NEXT batch explores on the device;
+        on one chip a read that dispatches a program (a device leaf
+        sliced per lane) would queue behind the running ``sym_run``
+        call, a plain copy does not. The phase's span says how long it
         waited (``device_wait_s``) beside its CPU seconds (``cpu_s``)."""
         from ..analysis import fire_lasers
 

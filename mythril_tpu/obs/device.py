@@ -7,12 +7,16 @@ until the array is there. A plain copy of a leaf that already exists
 is quick even while the chip runs a program; an expression that first
 dispatches a small kernel (``leaf[lane]``) queues behind whatever runs
 there, and on a busy chip is a wait for the device, not a copy
-(PERF.md §5). ``fetch`` does what those calls did, no caching and no
-extra sync, and always times the blocking call (clock reads only, like
+(PERF.md §5). So the host phase copies a leaf whole, once a frontier,
+and indexes the copy (:class:`HostLeaves`), and dispatches nothing.
+``fetch`` does what those calls did, no caching and no extra sync, and
+always times the blocking call (clock reads only, like
 ``trace.timer``):
 
 - ``device_fetches_total`` / ``device_fetch_seconds_total`` in
-  ``REGISTRY`` count every read of the process;
+  ``REGISTRY`` count every read of the process, and
+  ``device_kernel_reads_total`` those handed over as a callable (the
+  ``kernel:`` form: 0 in a host phase);
 - a per-thread tally (:func:`tally`) lets a span difference the reads
   made on its own thread: :func:`phase_timer` spans (``device_phase``,
   ``host_phase``) gain ``device_fetches``, ``device_wait_s`` and
@@ -36,6 +40,7 @@ from __future__ import annotations
 
 import threading
 import time
+from operator import attrgetter
 from typing import Tuple
 
 from . import metrics as obs_metrics
@@ -59,7 +64,11 @@ def fetch(x, what: str):
     timed whole, and ``what`` says so (``"kernel:..."``)."""
     c0 = time.thread_time()
     t0 = time.monotonic()
+    reg = obs_metrics.REGISTRY
     if callable(x):
+        reg.counter("device_kernel_reads_total",
+                    help="reads that first dispatch a program on the "
+                         "device (fetch handed a callable)").inc()
         x = x()
     if isinstance(x, (tuple, list)):
         import jax
@@ -78,7 +87,6 @@ def fetch(x, what: str):
     cpu = time.thread_time() - c0
     n, seconds, cpu0 = tally()
     _TALLY.n, _TALLY.seconds, _TALLY.cpu = n + 1, seconds + dt, cpu0 + cpu
-    reg = obs_metrics.REGISTRY
     reg.counter("device_fetches_total",
                 help="host reads of device arrays (obs.device."
                      "fetch)").inc()
@@ -89,6 +97,36 @@ def fetch(x, what: str):
         obs_trace.complete("device_fetch", dt, mono=t0, what=what,
                            bytes=int(nbytes))
     return out
+
+
+class HostLeaves:
+    """The leaves of one finished frontier that are on the host: the
+    first request for a name copies the whole leaf (``fetch(leaf,
+    name)``), every later one gets the same NumPy array, and callers
+    index that. Names are attribute paths from the frontier
+    (``"st_val_sym"``, ``"base.active"``). The arrays are what
+    ``np.asarray`` of a device array gives (read-only): a caller that
+    mutates copies first. ``host_leaf_reads_total{result}`` counts
+    copies and hits. Threads that race for one leaf copy it twice and
+    keep either; the memo is one dict assignment."""
+
+    __slots__ = ("sf", "_memo")
+
+    def __init__(self, sf):
+        self.sf = sf
+        self._memo: dict = {}
+
+    def __call__(self, name: str):
+        out = self._memo.get(name)
+        hit = out is not None
+        if not hit:
+            out = self._memo[name] = fetch(attrgetter(name)(self.sf), name)
+        obs_metrics.REGISTRY.counter(
+            "host_leaf_reads_total",
+            help="requests for a frontier leaf on the host: copied "
+                 "from the device, or served from the context's copy",
+            labels={"result": "hit" if hit else "copy"}).inc()
+        return out
 
 
 class PhaseSpan(obs_trace.Span):
@@ -129,4 +167,4 @@ def phase_timer(name: str, **attrs) -> PhaseSpan:
     return PhaseSpan(obs_trace.get_tracer(), name, attrs)
 
 
-__all__ = ["PhaseSpan", "fetch", "phase_timer", "tally"]
+__all__ = ["HostLeaves", "PhaseSpan", "fetch", "phase_timer", "tally"]

@@ -571,8 +571,8 @@ class Solver:
 def solve_lane(sf, lane: int, extra_constraints=(), seed: int = 0,
                max_iters: int = 400, cache=None) -> Optional[Assignment]:
     """Witness for lane `lane`'s path condition + extra (node, sign)
-    pairs. Pass a ``TapeHostCache`` when solving many lanes of one
-    frontier — the cacheless default bulk-copies the tape arrays per
+    pairs. Pass the frontier's ``HostLeaves`` when solving many lanes of
+    one frontier — the cacheless default bulk-copies the tape arrays per
     call."""
     from .tape import extract_tape
 

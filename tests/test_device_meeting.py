@@ -126,6 +126,236 @@ def test_phase_timer_splits_its_wall_clock():
         "device_fetches_total"] == 2.0
 
 
+# --- the leaves a context keeps on the host ----------------------------------
+
+def _frontier(lanes=4):
+    """A real frontier that no engine ran: building it compiles
+    nothing, and its leaves are device arrays."""
+    from mythril_tpu.symbolic import make_sym_frontier
+
+    return make_sym_frontier(lanes, TEST_LIMITS)
+
+
+def _context(sf):
+    from mythril_tpu.analysis.symbolic import AnalysisContext
+
+    return AnalysisContext(sf=sf, corpus=None, limits=TEST_LIMITS,
+                           contract_names=["c0"])
+
+
+def _leaf_reads():
+    snap = obs_metrics.REGISTRY.snapshot()["counters"]
+    return (snap.get('host_leaf_reads_total{result="copy"}', 0.0),
+            snap.get('host_leaf_reads_total{result="hit"}', 0.0))
+
+
+def test_context_copies_a_leaf_once_and_serves_the_copy_afterwards():
+    import jax.numpy as jnp
+
+    sf = _frontier()
+    sf = sf.replace(base=sf.base.replace(
+        contract_id=jnp.asarray([3, 2, 1, 0], dtype=jnp.int32)))
+    ctx = _context(sf)
+    tr = obs_trace.configure(buffer=True)
+    first = ctx.host("st_val_sym")
+    assert isinstance(first, np.ndarray)
+    assert np.array_equal(first, np.asarray(sf.st_val_sym))
+    assert ctx.host("st_val_sym") is first
+    assert _leaf_reads() == (1.0, 1.0)
+    # a dotted name resolves through sf.base, and is a leaf of its own
+    cid = ctx.host("base.contract_id")
+    assert cid.tolist() == [3, 2, 1, 0]
+    assert ctx.host("base.contract_id") is cid
+    assert [ctx.contract_of(lane) for lane in range(4)] == [3, 2, 1, 0]
+    assert _leaf_reads() == (2.0, 6.0)
+    # a scalar leaf too
+    assert int(ctx.host("dropped_total")) == 0
+    # what reached the device: one whole-leaf copy a name, no kernel
+    whats = [r["what"] for r in spans_of(tr.drain_buffer(), "device_fetch")]
+    assert whats == ["st_val_sym", "base.contract_id", "dropped_total"]
+    snap = obs_metrics.REGISTRY.snapshot()["counters"]
+    assert snap["device_fetches_total"] == 3.0
+    assert "device_kernel_reads_total" not in snap
+    # another context of the same frontier keeps copies of its own
+    assert _context(sf).host("st_val_sym") is not first
+    with pytest.raises(AttributeError):
+        ctx.host("no_such_leaf")
+
+
+def test_threads_racing_for_leaves_tear_nothing():
+    """``--solver-workers`` > 1 runs the modules of one context on pool
+    threads: a leaf that two of them copy at once is copied twice and
+    either copy kept, every request is answered with the leaf's
+    values, and every request is counted once."""
+    import sys
+    import threading
+    from operator import attrgetter
+
+    sf = _frontier()
+    ctx = _context(sf)
+    names = ["st_val_sym", "st_key_sym", "base.active", "base.error",
+             "n_arith", "tape_op", "con_node", "dropped_total"]
+    want = {n: np.asarray(attrgetter(n)(sf)) for n in names}
+    n_threads, rounds = 16, 20
+    wrong, start = [], threading.Barrier(n_threads)
+
+    def worker():
+        start.wait(timeout=30)
+        for _ in range(rounds):
+            for n in names:
+                if not np.array_equal(ctx.host(n), want[n]):
+                    wrong.append(n)
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=worker) for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(was)
+    assert not any(t.is_alive() for t in threads)
+    assert wrong == []
+    copies, hits = _leaf_reads()
+    assert len(names) <= copies <= n_threads * len(names)
+    assert copies + hits == n_threads * rounds * len(names)
+    # the race over, every thread is served the one copy that was kept
+    assert all(ctx.host(n) is ctx.host(n) for n in names)
+    assert _leaf_reads()[0] == copies
+
+
+def test_fetch_counts_a_kernel_read():
+    x = _leaf()
+    obs_device.fetch(x, "x")
+    snap = obs_metrics.REGISTRY.snapshot()["counters"]
+    assert "device_kernel_reads_total" not in snap
+    obs_device.fetch(lambda: x[2], "kernel:x[2]")
+    snap = obs_metrics.REGISTRY.snapshot()["counters"]
+    assert snap["device_kernel_reads_total"] == 1.0
+    assert snap["device_fetches_total"] == 2.0
+
+
+def _lane_sinks_by_device_slices(sf, lane):
+    """``IntegerArithmetics._lane_sinks`` as it was: each leaf indexed
+    on the device per lane, the row read back."""
+    out = []
+    for name in ("st_val_sym", "st_key_sym"):
+        row = np.asarray(getattr(sf, name)[lane])
+        out.extend(int(x) for x in row[row > 0])
+    return out
+
+
+_SINK_CASES = {
+    # (st_val_sym, st_key_sym), lane, the sinks in order
+    "no_slots": (np.zeros((3, 0), np.int32), np.zeros((3, 0), np.int32),
+                 1, []),
+    "all_zeros": (np.zeros((3, 4), np.int32), np.zeros((3, 4), np.int32),
+                  0, []),
+    "mixed": (np.array([[9, 9, 9, 9], [0, 17, -1, 15], [8, 8, 8, 8]],
+                       np.int32),
+              np.array([[7, 7, 7, 7], [21, 0, 0, 14], [6, 6, 6, 6]],
+                       np.int32),
+              1, [17, 15, 21, 14]),
+    "last_lane": (np.array([[1, 2], [3, 4], [0, 5]], np.int32),
+                  np.array([[6, 7], [8, 9], [10, 0]], np.int32),
+                  2, [5, 10]),
+    "keys_only": (np.zeros((2, 3), np.int32),
+                  np.array([[0, 0, 0], [0, 0, 12]], np.int32),
+                  1, [12]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SINK_CASES))
+def test_lane_sinks_from_the_host_copy_are_the_device_slices(case):
+    import jax.numpy as jnp
+
+    from mythril_tpu.analysis.module.modules.integer import (
+        IntegerArithmetics)
+
+    val, key, lane, want = _SINK_CASES[case]
+    sf = _frontier(val.shape[0]).replace(
+        st_val_sym=jnp.asarray(val), st_key_sym=jnp.asarray(key))
+    ctx = _context(sf)
+    for ln in range(val.shape[0]):
+        got = IntegerArithmetics._lane_sinks(ctx, ln)
+        assert got == _lane_sinks_by_device_slices(sf, ln)
+        assert all(isinstance(x, int) and x > 0 for x in got)
+    assert IntegerArithmetics._lane_sinks(ctx, lane) == want
+    # both leaves came over once, whatever the number of lanes asked
+    assert _leaf_reads()[0] == 2.0
+
+
+def _frontier_with_a_wrapping_add():
+    """Lane 1 recorded ``calldata[4] + calldata[36]`` at pc 7 and stored
+    the sum: every outlet of the lane is tracked, so the sink gate runs
+    (``_lane_sinks``) and the solver finds the overflow."""
+    import jax.numpy as jnp
+
+    from mythril_tpu.symbolic.ops import SymOp
+
+    sf = _frontier()
+    n = int(np.asarray(sf.tape_len)[1])      # the preset leaves
+    a, b, r = 11, 12, n                      # calldata words at 4 and 36
+    return sf.replace(
+        tape_len=sf.tape_len.at[1].set(n + 1),
+        tape_op=sf.tape_op.at[1, r].set(int(SymOp.ADD)),
+        tape_a=sf.tape_a.at[1, r].set(a),
+        tape_b=sf.tape_b.at[1, r].set(b),
+        n_arith=sf.n_arith.at[1].set(1),
+        arith_op=sf.arith_op.at[1, 0].set(0x01),
+        arith_a=sf.arith_a.at[1, 0].set(a),
+        arith_b=sf.arith_b.at[1, 0].set(b),
+        arith_r=sf.arith_r.at[1, 0].set(r),
+        arith_pc=sf.arith_pc.at[1, 0].set(7),
+        st_val_sym=sf.st_val_sym.at[1, 0].set(r),
+        base=sf.base.replace(
+            contract_id=jnp.zeros_like(sf.base.contract_id)))
+
+
+def test_integer_module_dispatches_nothing_and_reads_no_leaf_twice():
+    from mythril_tpu.analysis.module.modules.integer import (
+        IntegerArithmetics)
+
+    ctx = _context(_frontier_with_a_wrapping_add())
+    tr = obs_trace.configure(buffer=True)
+    mod = IntegerArithmetics()
+    issues = mod._execute(ctx)
+    assert [(i.swc_id, i.address, i.lane, i.contract) for i in issues] == [
+        ("101", 7, 1, "c0")]
+    assert ctx.contract_name(1) == "c0"
+    # a second module pass over the same context copies nothing anew
+    copies, _ = _leaf_reads()
+    mod._cache.clear()
+    assert len(mod._execute(ctx)) == 1
+    assert _leaf_reads()[0] == copies
+    whats = [r["what"] for r in spans_of(tr.drain_buffer(), "device_fetch")]
+    assert len(whats) == len(set(whats)) == copies
+    assert not [w for w in whats if w.startswith("kernel:")]
+    assert {"st_val_sym", "st_key_sym", "base.contract_id", "tape_imm",
+            "base.active", "n_arith"} <= set(whats)
+    snap = obs_metrics.REGISTRY.snapshot()["counters"]
+    assert "device_kernel_reads_total" not in snap
+    assert snap["device_fetches_total"] == copies
+
+
+def test_host_phase_code_hands_fetch_no_callable():
+    """A callable is how a read says it dispatches a program first
+    (``kernel:``): the layers that run beside a device phase have
+    none."""
+    import pathlib
+    import re
+
+    root = pathlib.Path(mythril_tpu.__file__).parent
+    files = [p for d in ("analysis", "smt")
+             for p in sorted((root / d).rglob("*.py"))]
+    assert len(files) > 20
+    bad = [str(p.relative_to(root)) for p in files
+           if re.search(r"fetch\(\s*lambda", p.read_text())]
+    assert bad == []
+
+
 # --- superstep spans -------------------------------------------------------
 
 def _explore(**kw):
@@ -262,6 +492,50 @@ def test_host_phase_of_a_pipelined_campaign_says_where_its_time_went():
         assert inside
         assert sum(s["device_wait_s"] for s in inside) <= (
             sp["device_wait_s"] + 1e-5 * len(inside))
+
+
+# --- the report's split of a host phase -------------------------------------
+
+@pytest.mark.parametrize("kernel_reads", [0, 2])
+def test_trace_report_counts_the_kernel_reads_of_each_host_phase(
+        kernel_reads, tmp_path):
+    import importlib.util
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "trace_report", os.path.join(root, "tools", "trace_report.py"))
+    report = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(report)
+
+    def span(name, mono, dur, tid, **attrs):
+        return dict(schema=1, kind="span", name=name, t=0.0, mono=mono,
+                    dur=dur, tid=tid, **attrs)
+
+    recs = [span("host_phase", 10.0, 5.0, 7, bi=0, device_fetches=3,
+                 device_wait_s=0.5, cpu_s=4.0),
+            span("host_phase", 20.0, 5.0, 7, bi=1, device_fetches=1,
+                 device_wait_s=0.1, cpu_s=4.5),
+            span("device_fetch", 11.0, 0.01, 7, what="st_val_sym",
+                 bytes=256),
+            # in batch 1's time, but on the device phase's thread
+            span("device_fetch", 21.0, 0.2, 9, what="kernel:elsewhere",
+                 bytes=4),
+            span("device_fetch", 22.0, 0.01, 7, what="tape_imm",
+                 bytes=64)]
+    recs += [span("device_fetch", 12.0 + i, 0.2, 7,
+                  what="kernel:base.contract_id[lane]", bytes=4)
+             for i in range(kernel_reads)]
+    path = tmp_path / "t.jsonl"
+    path.write_text("".join(json.dumps(r) + "\n" for r in recs))
+    text = report.report(*report.load_trace(str(path)))
+    (bi0,) = [ln for ln in text.splitlines() if ln.startswith("  bi 0:")]
+    (bi1,) = [ln for ln in text.splitlines() if ln.startswith("  bi 1:")]
+    assert f"3 fetches, {kernel_reads} kernel: reads)" in bi0
+    assert ("dispatches on the device" in bi0) == bool(kernel_reads)
+    assert "1 fetches, 0 kernel: reads)" in bi1
+    assert "dispatches on the device" not in bi1
 
 
 # --- checkpoints written before the counter ----------------------------------

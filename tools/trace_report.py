@@ -8,9 +8,12 @@ Reads either output of the span tracer — the Chrome-trace JSON
   2. a batch stall table (slowest campaign batches with their status),
   3. the degrade timeline (every ladder step, in order),
   4. a checkpoint summary (saves/loads, total and worst latency),
-  5. a pipeline overlap summary (device/host phase totals, stall time
-     by direction, and how much host-phase time the pipelined campaign
-     hid behind device execution — docs/performance.md),
+  5. a pipeline overlap summary (device/host phase totals, each host
+     phase's split into CPU and device reads with its count of
+     ``kernel:`` reads — reads that dispatched a program, marked when
+     not 0 — stall time by direction, and how much host-phase time the
+     pipelined campaign hid behind device execution —
+     docs/performance.md),
   6. a fleet summary (unit leases claimed/committed/reclaimed/lost and
      the reclaim/lost timeline — docs/fleet.md),
   7. solver totals (attempts / sat / unsat / unknown and the unknown
@@ -78,6 +81,7 @@ def _from_chrome(events: List[Dict]) -> Tuple[List[Dict], List[Dict]]:
             spans.append({"name": e.get("name", "?"),
                           "dur": float(e.get("dur", 0.0)) / 1e6,
                           "mono": float(e.get("ts", 0.0)) / 1e6,
+                          "tid": e.get("tid"),
                           "args": e.get("args", {}) or {}})
         elif ph == "i":
             instants.append({"kind": e.get("name", "?"),
@@ -97,7 +101,8 @@ def _from_jsonl(lines: List[Dict]) -> Tuple[List[Dict], List[Dict]]:
         if e.get("kind") == "span":
             spans.append({"name": e.get("name", "?"),
                           "dur": float(e.get("dur", 0.0)),
-                          "mono": mono, "args": args})
+                          "mono": mono, "tid": e.get("tid"),
+                          "args": args})
         else:
             t = e.get("t", 0.0)
             instants.append({"kind": e.get("kind", "?"),
@@ -242,6 +247,23 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
                 f"{_fmt_s(wait).strip()} "
                 f"({sum(s['args']['device_fetches'] for s in split)} "
                 f"fetches), rest (lock, pool) {_fmt_s(rest).strip()}")
+            # a read whose ``what`` starts with "kernel:" dispatched a
+            # program first, which queues behind the device phase that
+            # runs beside the host phase: a host phase should have none
+            kernels = [s for s in spans if s["name"] == "device_fetch"
+                       and str(s["args"].get("what", "")
+                               ).startswith("kernel:")]
+            for s in split:
+                n = sum(1 for k in kernels
+                        if k.get("tid") == s.get("tid")
+                        and s["mono"] <= k["mono"] <= s["mono"] + s["dur"])
+                a = s["args"]
+                out.append(
+                    f"  bi {a.get('bi', '?')}: {_fmt_s(s['dur']).strip()}, "
+                    f"cpu {_fmt_s(a['cpu_s']).strip()}, device reads "
+                    f"{_fmt_s(a['device_wait_s']).strip()} "
+                    f"({a['device_fetches']} fetches, {n} kernel: reads)"
+                    + ("  <-- dispatches on the device" if n else ""))
         out.append(f"stall device-waits-host: {_fmt_s(dwh).strip()}   "
                    f"host-waits-device: {_fmt_s(hwd).strip()}")
         if host_tot > 0:
