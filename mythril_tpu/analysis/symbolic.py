@@ -28,7 +28,8 @@ from ..smt.eval import Assignment
 from ..smt.solver import solve_tape
 from ..smt.tape import HostNode, HostTape, extract_tape, intern_node
 from ..symbolic import SymSpec, between_txs, make_sym_frontier, sym_run
-from ..symbolic.engine import rebalance_parked, sym_run_donated
+from ..symbolic.engine import (rebalance_parked, relieve_starved,
+                               sym_run_donated)
 
 log = logging.getLogger(__name__)
 
@@ -153,6 +154,7 @@ class AnalysisContext:
         entry per symbolic transaction). All `calldatasize` bytes are
         emitted — trimming zeros would change CALLDATASIZE on replay and
         can flip size-check branches."""
+        from ..core.frontier import CREATOR_ADDRESS
         from ..symbolic.ops import FreeKind
 
         origin = asn.scalars.get((int(FreeKind.ORIGIN), 0), asn.caller)
@@ -166,6 +168,11 @@ class AnalysisContext:
                 "origin": hex(origin),
                 "caller": hex(t.caller),
             })
+        if out and getattr(self.corpus, "deploys", None) is not None:
+            # the run deployed: step 0 is the creation transaction, sent
+            # by the creator (its input: the constructor's arguments)
+            out[0].update(origin=hex(CREATOR_ADDRESS),
+                          caller=hex(CREATOR_ADDRESS))
         return out
 
 
@@ -237,7 +244,18 @@ class SymExecWrapper:
     the ``transaction_count`` attacker message calls. Constructor
     arguments (appended to init code in real deployments) read as zero
     bytes past the compiled length; the RETURN payload is not re-derived —
-    the caller supplies the runtime image, as solc artifacts do.
+    the caller supplies the runtime image, as solc artifacts do (so the
+    deploy epilogue's copy of it is cut at the memory model's end and
+    does not trap: ``Corpus.deploys``).
+
+    Telemetry: the ``superstep`` / ``drain`` / ``harvest`` spans carry
+    ``tx_kind`` (``creation`` | ``message``), a ``tx_seam`` span times each
+    handoff to the next transaction (``carried``: the lanes that go on),
+    and ``engine_paths_total{tx}`` / ``engine_dropped_forks_total{tx}``
+    count, per transaction index, the paths that survived it and the
+    forks it lost to the budget (the ``harvest`` span carries the two
+    as ``paths`` / ``dropped``). All of it rides the reads the harvest
+    and the seam make anyway.
     """
 
     def __init__(
@@ -368,7 +386,8 @@ class SymExecWrapper:
             images = runtime_imgs
             runtime_base = 0
         self.images = images
-        self.corpus = Corpus.from_images(images)
+        self._n_creation = C if with_creation else 0
+        self.corpus = Corpus.from_images(images, self._n_creation)
         self._visited = np.zeros(
             (len(images), limits.max_code), dtype=bool)
         # mid-execution dynamic loading (reference: DynLoader.dynld
@@ -392,7 +411,6 @@ class SymExecWrapper:
         self._dynld_sha: List[str] = []    # sha256 of each loaded image
         P = C * lanes_per_contract
         cid0 = np.repeat(np.arange(C, dtype=np.int32), lanes_per_contract)
-        cid_runtime = cid0 + runtime_base
         active = np.zeros(P, dtype=bool)
         active[::lanes_per_contract] = True  # one seed lane per contract
         sf = make_sym_frontier(
@@ -458,6 +476,7 @@ class SymExecWrapper:
                 cold = shape not in warm_shapes
                 w0 = tally()[1]
                 with obs_trace.timer("superstep", tx=self._cur_tx,
+                                     tx_kind=self._tx_kind,
                                      steps=n, cold=cold, **attrs) as sp:
                     sf, vis = runner(
                         sf, env, self.corpus, spec, limits,
@@ -493,6 +512,38 @@ class SymExecWrapper:
                     help="supersteps the sym_run calls were allowed "
                          "(sum of max_steps)").inc(n)
                 return sf, steps_run, sp.dur, cold, got[2:]
+
+            # what a seam of the spill machinery reads of the frontier,
+            # in the one transfer of its ``superstep`` call
+            SEAM = ("base.active", "fork_req", "base.running",
+                    "base.home_contract")
+
+            def rebalance(sf, act_h, freq_h, run_h, home_h):
+                """A seam's scheduling step: parked lanes move to blocks
+                with free lanes; where the whole frontier is full and
+                stuck, the contracts it starves are relieved (the
+                lanes given up are lost forks, counted with those still
+                parked at the end)."""
+                with obs_trace.span("rebalance", tx=self._cur_tx):
+                    sf, moved = rebalance_parked(sf, self.fork_block,
+                                                 active=act_h,
+                                                 fork_req=freq_h)
+                    evicted = 0
+                    if not moved:
+                        sf, evicted = relieve_starved(
+                            sf, C, act_h, freq_h, run_h, home_h)
+                self._rebalanced += moved
+                self._parked_end += evicted
+                reg = obs_metrics.REGISTRY
+                reg.counter(
+                    "rebalanced_lanes_total",
+                    help="parked lanes re-seeded at host seams").inc(moved)
+                reg.counter(
+                    "evicted_lanes_total",
+                    help="parked lanes given up at a full frontier's "
+                         "fixpoint for a contract under its floor"
+                ).inc(evicted)
+                return sf
 
             if (self._deadline_at is None and self.checkpoint_dir is None
                     and not self.spill):
@@ -531,15 +582,16 @@ class SymExecWrapper:
                 # read is a blocking sync). A bare run with telemetry
                 # off and spill off reads only ``running`` beside the
                 # bitmap. (Reusing the pre-rebalance fetch for the
-                # quiescence check is exact: rebalance RELOCATES lanes —
-                # it never changes whether any lane is running.)
-                seam = (("base.active", "fork_req", "base.running")
-                        if self.spill or telemetry else ("base.running",))
+                # quiescence check is exact: rebalance RELOCATES lanes,
+                # or retires parked ones for another that goes on
+                # waiting — never changing whether any lane is running.)
+                seam = (SEAM if self.spill
+                        else SEAM[:3] if telemetry else SEAM[2:3])
                 sf, steps_run, dur, cold, got = superstep(
                     sf, n, n, also=seam, run_kw=chunk_kw,
                     done=steps_done)
-                act_h, freq_h = got[:2] if len(got) == 3 else (None, None)
-                run_h = got[-1]
+                act_h, freq_h = got[:2] if len(got) >= 3 else (None, None)
+                run_h = got[2] if len(got) >= 3 else got[0]
                 # a shape's first run pays XLA compilation — not a
                 # sample; the rate is the device's (the span ends when
                 # the device does) over the supersteps that ran
@@ -547,14 +599,7 @@ class SymExecWrapper:
                     sec_per_step = max(sec_per_step, dur / steps_run)
                 steps_done += n
                 if self.spill:
-                    with obs_trace.span("rebalance", tx=self._cur_tx):
-                        sf, moved = rebalance_parked(sf, self.fork_block,
-                                                     active=act_h,
-                                                     fork_req=freq_h)
-                    self._rebalanced += moved
-                    obs_metrics.REGISTRY.counter(
-                        "rebalanced_lanes_total",
-                        help="parked lanes re-seeded at host seams").inc(moved)
+                    sf = rebalance(sf, *got)
                 self._observe_frontier(sf, active=act_h, fork_req=freq_h)
                 self.plugin_loader.fire("on_chunk", sf, steps_done)
                 if self.checkpoint_dir is not None:
@@ -571,12 +616,13 @@ class SymExecWrapper:
                 # admitted late through no fault of their path, so they
                 # get bounded extra chunks (reference analog: the work
                 # list drains until empty or timeout)
-                with obs_trace.span("drain", tx=self._cur_tx):
+                with obs_trace.span("drain", tx=self._cur_tx,
+                                    tx_kind=self._tx_kind):
                     # one fetch per drain round, shared with the
                     # rebalance planner and the final parked count
-                    act_h, freq_h = fetch((sf.base.active, sf.fork_req),
-                                          "base.active,fork_req")
-                    parked = freq_h & act_h
+                    got = fetch(tuple(attrgetter(a)(sf) for a in SEAM),
+                                ",".join(SEAM))
+                    parked = got[1] & got[0]
                     for _ in range(4):
                         if not parked.any():
                             break
@@ -584,19 +630,12 @@ class SymExecWrapper:
                                 self._deadline_at is not None
                                 and _time.monotonic() >= self._deadline_at):
                             break  # the drain respects the wall clock too
-                        with obs_trace.span("rebalance", tx=self._cur_tx):
-                            sf, moved = rebalance_parked(
-                                sf, self.fork_block,
-                                active=act_h, fork_req=freq_h)
-                        self._rebalanced += moved
-                        obs_metrics.REGISTRY.counter(
-                            "rebalanced_lanes_total").inc(moved)
+                        sf = rebalance(sf, *got)
                         # the chunk loop's program (same static args)
-                        sf, _, _, _, (act_h, freq_h) = superstep(
-                            sf, self._chunk, self._chunk,
-                            also=("base.active", "fork_req"),
+                        sf, _, _, _, got = superstep(
+                            sf, self._chunk, self._chunk, also=SEAM,
                             run_kw=chunk_kw, drain=True)
-                        parked = freq_h & act_h
+                        parked = got[1] & got[0]
                 # forks still parked after draining are lost coverage —
                 # count them in the drop channel for honesty (reusing
                 # the drain loop's final fetch — no extra sync)
@@ -608,11 +647,20 @@ class SymExecWrapper:
             sf = explore(sf)
             # harvest: pull per-tx results (traps, iprof rows) off the
             # device and snapshot the context modules will consume
-            with obs_trace.span("harvest", tx=self._cur_tx):
+            with obs_trace.timer("harvest", tx=self._cur_tx,
+                                 tx_kind=self._tx_kind) as harvest:
                 # err_code is zeroed by between_txs, so every nonzero
-                # code here is a loss from THIS transaction
-                trap_counts = _count_traps(
-                    fetch(sf.base.err_code, "base.err_code"))
+                # code here is a loss from THIS transaction. The
+                # per-transaction path and dropped-fork counts ride
+                # the same transfer
+                err_h, act_h, bad_h, dropped_h = fetch(
+                    (sf.base.err_code, sf.base.active, sf.base.error,
+                     sf.dropped_total),
+                    "base.err_code,base.active,base.error,dropped_total")
+                trap_counts = _count_traps(err_h)
+                paths, lost = self._count_tx(
+                    int((act_h & ~bad_h).sum()), int(dropped_h))
+                harvest.attrs.update(paths=paths, dropped=lost)
                 ctx = AnalysisContext(
                     sf=sf, corpus=self.corpus, limits=limits,
                     contract_names=names, solver_iters=solver_iters,
@@ -645,10 +693,20 @@ class SymExecWrapper:
                 # with a creation tx, the first MESSAGE call is tx_id 1 —
                 # the dependency pruner must not retire its paths
                 kw.setdefault("first_message_tx", 1 if with_creation else 0)
-                sf = between_txs(sf, **kw)
+                with obs_trace.timer("tx_seam", tx=self._cur_tx,
+                                     tx_kind=self._tx_kind) as seam:
+                    sf = between_txs(sf, **kw)
+                    # the read the next transaction starts with (is
+                    # anything left to extend?), made here so that the
+                    # span ends when the handoff has run on the device
+                    self._carried = fetch(sf.base.active, "base.active")
+                    seam.attrs["carried"] = int(self._carried.sum())
             return sf
 
         self._cur_tx = 0
+        self._tx_kind = "creation" if with_creation else "message"
+        self._dropped_seen = 0
+        self._carried = None    # ``active`` as the last seam left it
         self.plugin_loader.fire("initialize", self)
         if with_creation:
             # --create-timeout (reference: a separate wall-clock budget
@@ -662,8 +720,9 @@ class SymExecWrapper:
                                      else min(outer_deadline, cd))
             # a constructor needn't mutate storage for the deploy to count
             sf = run_one_tx(sf, is_last=False, handoff_kw=dict(
-                require_mutation=False, new_contract_id=cid_runtime))
+                require_mutation=False, runtime_offset=runtime_base))
             self._cur_tx += 1
+            self._tx_kind = "message"
             if create_timeout is not None:
                 self._deadline_at = outer_deadline
                 if self.timed_out and (outer_deadline is None
@@ -672,13 +731,35 @@ class SymExecWrapper:
         for t in range(transaction_count):
             if self.timed_out:
                 break  # deadline: report what was explored so far
-            if not bool(fetch(sf.base.active, "base.active").any()):
+            alive, self._carried = self._carried, None
+            if alive is None:
+                alive = fetch(sf.base.active, "base.active")
+            if not bool(alive.any()):
                 break  # nothing survived: no state left to extend
             sf = run_one_tx(sf, is_last=(t == transaction_count - 1))
             self._cur_tx += 1
         self.sf = sf
         self.ctx = self.tx_contexts[-1]
         self.plugin_loader.fire("on_run_end", self)
+
+    def _count_tx(self, paths: int, dropped_total: int) -> tuple:
+        """One transaction's ``engine_paths_total{tx}`` and
+        ``engine_dropped_forks_total{tx}``: the lanes that ended it
+        alive and without error, and the forks it lost: the engine's
+        running total (what it dropped at the budget) less what the
+        transactions before had, plus those still parked when its drain
+        ended (``_parked_end``, which the coverage adds the same way)."""
+        lost = dropped_total + self._parked_end - self._dropped_seen
+        self._dropped_seen += lost
+        reg = obs_metrics.REGISTRY
+        labels = {"tx": str(self._cur_tx)}
+        reg.counter("engine_paths_total",
+                    help="paths alive and without error at the end of "
+                         "a transaction", labels=labels).inc(paths)
+        reg.counter("engine_dropped_forks_total",
+                    help="forks a transaction lost to the lane budget",
+                    labels=labels).inc(lost)
+        return paths, lost
 
     def _dynld_between_txs(self, sf, names):
         """Fetch code for this tx's concrete-but-unknown call targets.
@@ -781,7 +862,7 @@ class SymExecWrapper:
             used_np[:, col] = True
             log.info("dynld: loaded 0x%040x (%d bytes) as corpus #%d",
                      a, len(code), idx)
-        self.corpus = Corpus.from_images(self.images)
+        self.corpus = Corpus.from_images(self.images, self._n_creation)
         # ADVICE r5: the grown corpus is a NEW static shape — every chunk
         # size recompiles, so the warm-shape set must reset or the next
         # tx's first (compile-dominated) sample feeds sec_per_step and

@@ -101,7 +101,8 @@ def semantic_config_hash(config: Dict) -> str:
 def bucket_name(tier: str, shape: Sequence[int], cfh: str) -> str:
     """``{tier}__{w}x{l}x{ms}x{tx}__{cfh}.json`` — the flat, greppable
     key schema (docs/serving.md has the table). ``shape`` is the
-    campaign's ``_shape_key`` tuple: (width, lanes, max_steps, tx)."""
+    campaign's ``_shape_key`` tuple: (width, lanes, max_steps, tx), and a
+    fifth field 1 for the class of batches that deploy."""
     dims = "x".join(str(int(d)) for d in shape)
     return f"{tier}__{dims}__{cfh}.json"
 

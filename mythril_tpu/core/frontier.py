@@ -305,9 +305,17 @@ class Corpus:
     is_jumpdest: jnp.ndarray  # bool[C, MAX_CODE]
     code_hash: jnp.ndarray  # u32[C, 8] keccak256 of each image, host-
     # precomputed once so EXTCODEHASH answers concretely for corpus code
+    deploys: Optional[jnp.ndarray] = None  # bool[C]: the image is the
+    # creation code of a top-level deploy. Its epilogue copies the
+    # runtime code it embeds to memory and RETURNs it; the caller
+    # supplies that image, so the payload is never read and its copy is
+    # cut at ``mem_bytes`` instead of trapping (a runtime of 5-24 KB
+    # does not fit the memory model). None for a corpus without
+    # creation images: no leaf, the programs compiled for it unchanged
 
     @staticmethod
-    def from_images(images) -> "Corpus":
+    def from_images(images, n_creation: int = 0) -> "Corpus":
+        """``n_creation``: the first so many images are creation code."""
         from ..ops.keccak import keccak256_host_int
 
         hashes = np.stack([
@@ -319,6 +327,8 @@ class Corpus:
             code_len=jnp.asarray(np.array([im.code_len for im in images], dtype=np.int32)),
             is_jumpdest=jnp.asarray(np.stack([im.is_jumpdest for im in images])),
             code_hash=jnp.asarray(hashes),
+            deploys=(jnp.arange(len(images)) < n_creation
+                     if n_creation else None),
         )
 
 

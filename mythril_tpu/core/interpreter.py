@@ -482,7 +482,15 @@ def _h_copy(f: Frontier, env: Env, corpus: Corpus, op, m, old_pc):
     src64 = u256.to_u64_saturating(src).astype(I64)
     ln64 = u256.to_u64_saturating(ln).astype(I64)
 
-    f, oob = _expand_memory(f, m & (ln64 > 0), dst64 + ln64)
+    # (a corpus without creation images lowers exactly as it did before
+    # ``Corpus.deploys``, operand order included: the persistent
+    # compile cache keys on the program's text)
+    if corpus.deploys is None:
+        f, oob = _expand_memory(f, m & (ln64 > 0), dst64 + ln64)
+    else:
+        f, oob = _expand_memory(
+            f, m & (ln64 > 0),
+            _deploy_end(f, corpus, op == 0x39, dst64 + ln64))
     ok = m & ~oob
 
     P, M = f.memory.shape
@@ -522,6 +530,16 @@ def _h_copy(f: Frontier, env: Env, corpus: Corpus, op, m, old_pc):
     words = (ln64 + 31) // 32
     f = _charge(f, ok, 3 * words)
     return f.replace(memory=memory.astype(U8)), {}
+
+
+def _deploy_end(f: Frontier, corpus: Corpus, is_payload, end_bytes):
+    """The end of a memory window of a deploy's runtime payload
+    (``Corpus.deploys``): the CODECOPY of top-level creation code and
+    its RETURN reach no further than the memory model. What lies past
+    it is not kept, and nothing can read it: every read there traps."""
+    cut = is_payload & corpus.deploys[f.contract_id] & (f.depth == 0)
+    return jnp.where(cut, jnp.minimum(end_bytes, f.memory.shape[1]),
+                     end_bytes)
 
 
 def _take_per_lane(buf, idx, limit):
@@ -652,7 +670,11 @@ def _h_halt(f: Frontier, env: Env, corpus: Corpus, op, m, old_pc):
 
     off = u256.to_u64_saturating(_peek(f, 0)).astype(I64)
     ln = u256.to_u64_saturating(_peek(f, 1)).astype(I64)
-    f, oob = _expand_memory(f, m & has_data & (ln > 0), off + ln)
+    if corpus.deploys is None:
+        f, oob = _expand_memory(f, m & has_data & (ln > 0), off + ln)
+    else:
+        f, oob = _expand_memory(f, m & has_data & (ln > 0),
+                                _deploy_end(f, corpus, is_return, off + ln))
     RD = f.retval.shape[1]
     cap_len = jnp.clip(ln, 0, RD).astype(I32)
     data = _gather_bytes(f.memory, off, RD, jnp.full_like(off, f.memory.shape[1]))

@@ -27,8 +27,10 @@ Protocol (every frame = 8-byte big-endian length + pickle):
   and serves batches through its ``_explore_batch``/``_harvest_batch``
   seam, so batch semantics (padding, warm shapes, pad filtering) are
   the campaign's own code, not a re-implementation.
-- ``{"op": "batch", "bi", "names", "codes", "lanes", "width",
-  "on_cpu"}`` → ``{"ok": True, "value": {issues/paths/dropped/iprof}}``
+- ``{"op": "batch", "bi", "names", "codes", "creations", "lanes",
+  "width", "on_cpu"}`` (``creations``: the contracts' creation codes,
+  None where one has none, or None for a batch that does not deploy)
+  → ``{"ok": True, "value": {issues/paths/dropped/iprof}}``
   or ``{"ok": False, "etype", "emsg", "classify"}``.
 - ``{"op": "ping"}`` → rss diagnostics; ``{"op": "exit"}`` → clean 0.
 
@@ -298,6 +300,7 @@ def _run_batch(camp, stub: bool, msg: Dict,
     bi = int(msg["bi"])
     names = list(msg["names"])
     codes = list(msg["codes"])
+    creations = msg.get("creations")
     lanes = msg.get("lanes")
     width = msg.get("width")
     # re-enter the parent's request trace scope: every span/event this
@@ -326,7 +329,7 @@ def _run_batch(camp, stub: bool, msg: Dict,
             with obs_device.phase_timer("device_phase", bi=bi,
                                         n=len(names)) as dv:
                 sym = camp._explore_batch(bi, names, codes, lanes,
-                                          width)
+                                          width, creations)
                 if fault is not None:
                     # after the device work ran, before the host
                     # harvest: the closest honest stand-in for
@@ -340,7 +343,8 @@ def _run_batch(camp, stub: bool, msg: Dict,
         # compile-store bucket so a RESTARTED daemon's prewarm can seed
         # them and keep engine_compiles_total flat across the restart
         out["warm_chunks"] = sorted(
-            {int(c) for c in camp._warm_set(lanes, width)
+            {int(c) for c in camp._warm_set(lanes, width,
+                                            creations is not None)
              if not isinstance(c, tuple)})
         return out
 
@@ -373,20 +377,22 @@ def _run_prewarm(camp, stub: bool, msg: Dict) -> Dict:
         # executables live in the shared persistent cache), so mark
         # them before exploring: the compile counter must read this
         # pass as cache traffic, not fresh compilation
-        camp._warm_set(lanes, width).update(
-            int(c) for c in b.get("chunks") or ())
+        deploys = bool(b.get("deploys"))
+        warm = camp._warm_set(lanes, width, deploys)
+        warm.update(int(c) for c in b.get("chunks") or ())
         tier = b.get("tier") or msg.get("on_tier")
         cm = camp._tier_device(tier) if tier else None
         with (cm if cm is not None else contextlib.nullcontext()):
             with obs_trace.timer("prewarm_compile", lanes=lanes,
                                  width=width, tier=tier or ""):
-                sym = camp._explore_batch(-1, [], [], lanes, width)
+                sym = camp._explore_batch(
+                    -1, [], [], lanes, width,
+                    creations=[] if deploys else None)
                 # the wrapper compiles lazily as chunks run; touching
                 # the exploration result forces every chunk through
                 camp._harvest_batch(-1, sym)
         warm_chunks.append(sorted(
-            {int(c) for c in camp._warm_set(lanes, width)
-             if not isinstance(c, tuple)}))
+            {int(c) for c in warm if not isinstance(c, tuple)}))
         done += 1
     return {"done": done, "total": len(buckets), "stub": stub,
             "warm_chunks": warm_chunks}

@@ -64,17 +64,31 @@ _MANIFEST = "manifest.json"
 _UNITS_DIR = "units"
 
 
+def contract_record(item: Sequence) -> Tuple[str, bytes, Optional[bytes]]:
+    """One contract of a corpus as ``(name, runtime code, creation code
+    or None)``. A pair is a record without creation code: the form every
+    corpus had before campaigns could deploy."""
+    name, code, *rest = item
+    return name, code, (rest[0] if rest and rest[0] else None)
+
+
 def corpus_fingerprint(contracts: Sequence[tuple]) -> str:
-    """Stable identity of an ordered ``(name, bytecode)`` corpus slice:
-    16 hex chars of sha256 over names + per-contract code digests. Two
-    corpora of equal length but different content fingerprint apart —
-    the property the checkpoint shard stamp and the fleet manifest both
-    need (a count alone cannot tell "same corpus" from "same size")."""
+    """Stable identity of an ordered corpus slice of ``(name, bytecode)``
+    pairs or ``(name, bytecode, creation code)`` records: 16 hex chars
+    of sha256 over names + per-contract code digests. Two corpora of
+    equal length but different content fingerprint apart — the property
+    the checkpoint shard stamp and the fleet manifest both need (a count
+    alone cannot tell "same corpus" from "same size"). Creation code is
+    content: two corpora that differ only in a constructor deploy to
+    different storage. A pair hashes as it always did."""
     h = hashlib.sha256()
-    for name, code in contracts:
+    for name, code, creation in map(contract_record, contracts):
         h.update(str(name).encode())
         h.update(b"\0")
         h.update(hashlib.sha256(bytes(code)).digest())
+        if creation is not None:
+            h.update(b"\1")
+            h.update(hashlib.sha256(bytes(creation)).digest())
     return h.hexdigest()[:16]
 
 
@@ -328,7 +342,8 @@ class WorkLedger:
 
     def feed_unit(self, contracts: Sequence[tuple],
                   config: Optional[Dict] = None) -> str:
-        """Append one work unit of ``(name, bytecode)`` pairs. The unit
+        """Append one work unit of ``(name, bytecode)`` pairs or
+        ``(name, bytecode, creation code)`` records. The unit
         DESCRIPTOR (names + bytecode hex + analysis config) lands
         durably BEFORE the manifest's unit count exposes it, so a
         worker can never claim a unit whose bytecode is not yet
@@ -337,11 +352,15 @@ class WorkLedger:
             raise ValueError("feed_unit() on a static ledger")
         index = self.n_units
         uid = self.uid(index)
-        names = [str(n) for n, _ in contracts]
+        recs = [contract_record(c) for c in contracts]
+        names = [str(n) for n, _, _ in recs]
         desc = {"unit": uid, "names": names,
-                "codes": [bytes(c).hex() for _, c in contracts],
+                "codes": [bytes(c).hex() for _, c, _ in recs],
                 "config": dict(config or {}),
                 "t": round(time.time(), 3)}
+        if any(k is not None for _, _, k in recs):
+            desc["creations"] = [None if k is None else bytes(k).hex()
+                                 for _, _, k in recs]
         if not _exclusive_write(self._unit_desc_path(uid),
                                 json.dumps(desc, sort_keys=True).encode()):
             raise ValueError(
@@ -371,14 +390,24 @@ class WorkLedger:
     def feed_closed(self) -> bool:
         return self.closed
 
+    def read_unit_items(self, uid: str) -> Tuple[List[tuple], Dict]:
+        """A fed unit's contracts, as they were fed (pairs, or records
+        where the feeder gave creation code), and its config."""
+        with open(self._unit_desc_path(uid)) as fh:
+            doc = json.load(fh)
+        names = [str(n) for n in doc.get("names") or []]
+        codes = [bytes.fromhex(c) for c in doc.get("codes") or []]
+        creations = doc.get("creations")
+        items = (list(zip(names, codes)) if not creations else
+                 [(n, c, bytes.fromhex(k)) if k else (n, c)
+                  for n, c, k in zip(names, codes, creations)])
+        return items, dict(doc.get("config") or {})
+
     def read_unit(self, uid: str) -> Tuple[List[str], List[bytes], Dict]:
         """A fed unit's ``(names, bytecodes, config)`` from its
         descriptor file."""
-        with open(self._unit_desc_path(uid)) as fh:
-            doc = json.load(fh)
-        return ([str(n) for n in doc.get("names") or []],
-                [bytes.fromhex(c) for c in doc.get("codes") or []],
-                dict(doc.get("config") or {}))
+        items, config = self.read_unit_items(uid)
+        return [i[0] for i in items], [i[1] for i in items], config
 
     def result_record(self, uid: str) -> Optional[Dict]:
         """The committed result of one unit, or None while pending /

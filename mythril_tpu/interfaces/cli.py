@@ -117,7 +117,9 @@ def create_parser() -> argparse.ArgumentParser:
                    help="campaign mode: analyze every *.hex/*.bin under "
                         "DIR in constant-shape batches (one compiled "
                         "engine), with checkpoint/resume; prints a "
-                        "throughput+issues JSON")
+                        "throughput+issues JSON. X.bin beside "
+                        "X.bin-runtime (solc --bin --bin-runtime) is one "
+                        "contract whose constructor runs first")
     a.add_argument("--batch-size", type=int, default=32,
                    help="contracts per compiled batch (campaign mode)")
     a.add_argument("--checkpoint-dir", metavar="DIR",

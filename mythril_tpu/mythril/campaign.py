@@ -59,7 +59,7 @@ if TYPE_CHECKING:  # import is heavy at runtime (engine); lazy below
     from ..symbolic import SymSpec
 
 from ..config import DEFAULT_LIMITS, DEFAULT_RESILIENCE, LimitsConfig
-from ..fleet import corpus_fingerprint
+from ..fleet import contract_record, corpus_fingerprint
 from ..obs import device as obs_device
 from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
@@ -82,6 +82,11 @@ log = logging.getLogger(__name__)
 #: no issues, negligible lane cost)
 _PAD_BYTECODE = b"\x00"
 
+#: constructor of a contract that came without creation code in a batch
+#: that deploys: plain STOP (it writes nothing), as single-contract
+#: ``analyze`` fills in (mythril/orchestration.py)
+_PAD_CREATION = b"\x00"
+
 #: warm-shape marker for worker-isolated batches: the ENGINE WORKER's
 #: process-wide XLA cache is warm for the shape class, not this
 #: process's — the token is discarded when the worker dies (a fresh
@@ -90,23 +95,47 @@ _WORKER_WARM = ("worker-resident",)
 
 
 def load_corpus_dir(path: str) -> List[tuple]:
-    """(name, runtime bytecode) for every *.hex / *.bin / *.bin-runtime
-    file under ``path`` (hex-encoded, 0x prefix optional), sorted for a
-    stable batch order."""
+    """The corpus under ``path``, sorted for a stable batch order: one
+    ``(name, runtime bytecode)`` pair for every *.hex / *.bin /
+    *.bin-runtime file (hex-encoded, 0x prefix optional), except that
+    ``X.bin`` beside ``X.bin-runtime`` (what ``solc --bin --bin-runtime``
+    writes) is ONE contract, ``(X, runtime, creation bytecode)``: the
+    campaign runs its constructor and starts the message calls from
+    the storage it left."""
     from ..disassembler.disassembly import _to_bytes
 
-    out = []
-    for fn in sorted(os.listdir(path)):
-        if not fn.endswith((".hex", ".bin", ".bin-runtime")):
-            continue
+    def read(fn):
         with open(os.path.join(path, fn)) as fh:
-            text = fh.read().strip()
+            return fh.read().strip()
+
+    files = sorted(os.listdir(path))
+    # X.bin-runtime -> the X.bin beside it
+    creation_of = {fn: fn[:-len("-runtime")] for fn in files
+                   if fn.endswith(".bin-runtime")
+                   and fn[:-len("-runtime")] in files}
+    out = []
+    for fn in files:
+        if not fn.endswith((".hex", ".bin", ".bin-runtime")) \
+                or fn in creation_of.values():
+            continue
+        text = read(fn)
         if not text:
             continue
-        out.append((fn.rsplit(".", 1)[0], _to_bytes(text)))
+        rec = (fn.rsplit(".", 1)[0], _to_bytes(text))
+        creation = read(creation_of[fn]) if fn in creation_of else ""
+        out.append(rec + (_to_bytes(creation),) if creation else rec)
     if not out:
         raise ValueError(f"no *.hex / *.bin corpus files under {path}")
     return out
+
+
+def _split_records(items: Sequence[tuple]):
+    """``(names, runtime codes, creation codes)`` of a batch; the last
+    is None when no contract of it has creation code."""
+    recs = [contract_record(i) for i in items]
+    creations = [k for _, _, k in recs]
+    return ([n for n, _, _ in recs], [c for _, c, _ in recs],
+            creations if any(k is not None for k in creations) else None)
 
 
 @dataclass
@@ -196,7 +225,7 @@ class CorpusCampaign:
 
     def __init__(
         self,
-        contracts: Sequence[tuple],            # (name, runtime bytecode)
+        contracts: Sequence[tuple],  # (name, runtime bytecode[, creation])
         batch_size: int = 32,
         lanes_per_contract: int = 32,
         limits: LimitsConfig = DEFAULT_LIMITS,
@@ -596,7 +625,8 @@ class CorpusCampaign:
     def _explore_batch(self, bi: int, names: List[str],
                        codes: List[bytes],
                        lanes: Optional[int] = None,
-                       width: Optional[int] = None):
+                       width: Optional[int] = None,
+                       creations: Optional[List[Optional[bytes]]] = None):
         """DEVICE phase of one batch: pad to the compiled width and run
         the exploration (SymExecWrapper packs the corpus and drives the
         ``sym_run`` chunks — the dispatches are async under JAX; only
@@ -607,7 +637,13 @@ class CorpusCampaign:
         set: a smaller shape is a new (cheaper) compile, and the
         tighter fork capacity is absorbed by the engine's park/spill
         machinery (``defer_starved`` + rebalance) instead of dropping
-        paths. Returns the finished wrapper for :meth:`_harvest_batch`."""
+        paths. With ``creations`` (the batch's creation codes, None
+        where a contract has none) the batch DEPLOYS: every lane first
+        runs its contract's constructor from ``CREATOR_ADDRESS`` and the
+        message calls start from the storage it left; the corpus then
+        holds ``2 x width`` images, an engine shape class of its own,
+        explored as every other batch is (one lane pool, chunks, spill,
+        drain). Returns the finished wrapper for :meth:`_harvest_batch`."""
         from ..analysis import SymExecWrapper
 
         width = self.batch_size if width is None else width
@@ -618,6 +654,15 @@ class CorpusCampaign:
         while len(codes) < width:
             names.append(f"_pad_{len(codes)}")
             codes.append(_PAD_BYTECODE)
+        if creations is not None:
+            creations = [k if k is not None else _PAD_CREATION
+                         for k in creations]
+            creations += [_PAD_CREATION] * (width - len(creations))
+            obs_metrics.REGISTRY.counter(
+                "campaign_contracts_deployed_total",
+                help="contracts whose constructor a campaign batch "
+                     "ran").inc(len(names) - sum(
+                         n.startswith("_pad_") for n in names))
         sym = SymExecWrapper(
             codes, contract_names=names, limits=self.limits,
             spec=self.spec,
@@ -626,9 +671,11 @@ class CorpusCampaign:
             solver_iters=self.solver_iters,
             solver_timeout=self.solver_timeout,
             transaction_count=self.transaction_count,
+            creation_bytecodes=creations,
             plugins=self.plugins,
             enable_iprof=self.enable_iprof,
-            warm_shapes=self._warm_set(lanes, width),
+            warm_shapes=self._warm_set(lanes, width,
+                                       creations is not None),
         )
         # compile counters as of the END of this device phase: device
         # phases never overlap each other, so a batch that compiled no
@@ -641,27 +688,35 @@ class CorpusCampaign:
         return sym
 
     def _shape_key(self, lanes: Optional[int] = None,
-                   width: Optional[int] = None) -> tuple:
+                   width: Optional[int] = None,
+                   deploys: bool = False) -> tuple:
         """Identity of one compiled engine shape class: every batch with
         this key replays the same sym_run executables (the corpus is
         padded to ``width`` contracts x ``lanes`` lanes, and max_steps /
         transaction_count are static jit args). Degrade rungs shrink
-        lanes/width and thus land in their own (cheaper) class."""
-        return (self.batch_size if width is None else width,
-                self.lanes_per_contract if lanes is None else lanes,
-                self.max_steps, self.transaction_count)
+        lanes/width and thus land in their own (cheaper) class. A batch
+        that deploys runs over ``2 x width`` images (creation + runtime)
+        and is a class of its own: a fifth field, 1; a batch of pairs
+        keeps the four it always had."""
+        key = (self.batch_size if width is None else width,
+               self.lanes_per_contract if lanes is None else lanes,
+               self.max_steps, self.transaction_count)
+        return key + (1,) if deploys else key
 
     def _warm_set(self, lanes: Optional[int] = None,
-                  width: Optional[int] = None) -> set:
-        return self._warm_shapes.setdefault(self._shape_key(lanes, width),
-                                            set())
+                  width: Optional[int] = None,
+                  deploys: bool = False) -> set:
+        return self._warm_shapes.setdefault(
+            self._shape_key(lanes, width, deploys), set())
 
     def shape_is_warm(self, lanes: Optional[int] = None,
-                      width: Optional[int] = None) -> bool:
+                      width: Optional[int] = None,
+                      deploys: bool = False) -> bool:
         """Whether this campaign has already compiled (some chunk of)
         the given engine shape class — the serve scheduler's
         warm-compile-hit predicate (docs/serving.md)."""
-        return bool(self._warm_shapes.get(self._shape_key(lanes, width)))
+        return bool(self._warm_shapes.get(
+            self._shape_key(lanes, width, deploys)))
 
     # --- fleet compile-artifact store (docs/serving.md "Compile
     # --- artifacts & prewarm") ------------------------------------------
@@ -723,7 +778,8 @@ class CorpusCampaign:
             return "cpu"
 
     def _store_record(self, lanes: Optional[int] = None,
-                      width: Optional[int] = None) -> None:
+                      width: Optional[int] = None,
+                      deploys: bool = False) -> None:
         """Durably record one warm observation (hit count + the chunk
         step-counts now warm) for this shape class. Never raises — a
         full disk or torn registry must not fail the batch that just
@@ -732,10 +788,10 @@ class CorpusCampaign:
         if store is None:
             return
         try:
-            chunks = [c for c in self._warm_set(lanes, width)
+            chunks = [c for c in self._warm_set(lanes, width, deploys)
                       if isinstance(c, int)]
             store.record(self._active_tier(),
-                         self._shape_key(lanes, width),
+                         self._shape_key(lanes, width, deploys),
                          self._store_cfh or self.semantic_hash(),
                          chunks=chunks)
         except Exception as e:  # noqa: BLE001 — recording is best-effort
@@ -767,31 +823,35 @@ class CorpusCampaign:
         (different max_steps / tx count) are skipped — their compiled
         functions could never be replayed here."""
         shape = [int(d) for d in bucket.get("shape") or ()]
-        if len(shape) != 4:
+        if len(shape) not in (4, 5):
             raise ValueError(f"prewarm bucket shape {shape!r}")
-        width, lanes, max_steps, txc = shape
+        width, lanes, max_steps, txc = shape[:4]
+        deploys = shape[4:] == [1]      # _shape_key's fifth field
         if max_steps != self.max_steps or txc != self.transaction_count:
             return
         chunks = [int(c) for c in bucket.get("chunks") or ()]
-        self._warm_set(lanes, width).update(chunks)
+        warm = self._warm_set(lanes, width, deploys)
+        warm.update(chunks)
         tier = self._tm.current if self._tm is not None else None
         if self._worker_enabled():
             sup = self._ensure_supervisor()
             val = sup.prewarm([{"lanes": lanes, "width": width,
-                                "tier": tier, "chunks": chunks}],
+                                "tier": tier, "chunks": chunks,
+                                "deploys": deploys}],
                               on_tier=tier)
             for wc in (val or {}).get("warm_chunks") or ():
-                self._warm_set(lanes, width).update(
-                    int(c) for c in wc or ())
-            self._warm_set(lanes, width).add(_WORKER_WARM)
+                warm.update(int(c) for c in wc or ())
+            warm.add(_WORKER_WARM)
         elif self._batch_runner is None:
             cm = self._tier_device(tier) if tier else None
             with (cm if cm is not None else contextlib.nullcontext()):
-                sym = self._explore_batch(-1, [], [], lanes, width)
+                sym = self._explore_batch(
+                    -1, [], [], lanes, width,
+                    creations=[] if deploys else None)
                 self._harvest_batch(-1, sym)
         self._event("prewarm_bucket", tier=tier or "",
                     width=width, lanes=lanes, chunks=len(chunks))
-        self._store_record(lanes, width)
+        self._store_record(lanes, width, deploys)
 
     def prewarm_from_store(self, limit: Optional[int] = None,
                            should_stop=None) -> Dict:
@@ -935,7 +995,9 @@ class CorpusCampaign:
 
     def _exec_batch(self, bi: int, names: List[str], codes: List[bytes],
                     lanes: Optional[int] = None,
-                    width: Optional[int] = None) -> Dict:
+                    width: Optional[int] = None,
+                    creations: Optional[List[Optional[bytes]]] = None
+                    ) -> Dict:
         """Analyze one (padded) batch; returns the batch's partial
         results. Serial composition of the device + host phases — the
         unit of work the watchdog guards and the bisection replays on
@@ -944,14 +1006,15 @@ class CorpusCampaign:
         (docs/observability.md "Per-stage latency")."""
         with obs_device.phase_timer("device_phase", bi=bi,
                                     n=len(names)) as dv:
-            sym = self._explore_batch(bi, names, codes, lanes, width)
+            sym = self._explore_batch(bi, names, codes, lanes, width,
+                                      creations)
         with obs_device.phase_timer("host_phase", bi=bi) as hp:
             out = self._harvest_batch(bi, sym)
         acc = getattr(self, "_phase_acc", None)
         if acc is not None:
             acc["device"] += dv.dur or 0.0
             acc["host"] += hp.dur or 0.0
-        self._store_record(lanes, width)
+        self._store_record(lanes, width, creations is not None)
         return out
 
     # --- supervised engine worker (docs/resilience.md) ------------------
@@ -1024,7 +1087,9 @@ class CorpusCampaign:
 
     def _worker_run(self, bi: int, names: List[str], codes: List[bytes],
                     lanes: Optional[int], width: Optional[int],
-                    on_tier: Optional[str]) -> Dict:
+                    on_tier: Optional[str],
+                    creations: Optional[List[Optional[bytes]]] = None
+                    ) -> Dict:
         """One batch through the supervisor (which enforces the
         per-batch deadline parent-side — no extra watchdog thread).
         Success marks the shape class worker-warm. The reply's
@@ -1037,7 +1102,7 @@ class CorpusCampaign:
         try:
             out = sup.run_batch(bi, names, codes, lanes=lanes,
                                 width=width, on_cpu=(on_tier == "cpu"),
-                                on_tier=on_tier)
+                                on_tier=on_tier, creations=creations)
         except BaseException:
             # a failed attempt (worker death, deadline) stalled the
             # pipeline slot too: charge its wall to the device stage so
@@ -1058,9 +1123,10 @@ class CorpusCampaign:
         # join the shape class's warm set and the registry bucket
         wc = out.pop("warm_chunks", None) if isinstance(out, dict) \
             else None
-        self._warm_set(lanes, width).update(int(c) for c in wc or ())
-        self._warm_set(lanes, width).add(_WORKER_WARM)
-        self._store_record(lanes, width)
+        warm = self._warm_set(lanes, width, creations is not None)
+        warm.update(int(c) for c in wc or ())
+        warm.add(_WORKER_WARM)
+        self._store_record(lanes, width, creations is not None)
         return out
 
     def worker_status(self) -> Optional[Dict]:
@@ -1092,7 +1158,8 @@ class CorpusCampaign:
     def run_external_batch(self, items: Sequence[tuple],
                            bi: Optional[int] = None) -> Dict:
         """Resident-mode entry: analyze one externally-fed batch of
-        ``(name, bytecode)`` pairs through the FULL resilient machinery
+        ``(name, bytecode)`` pairs (or records with creation code,
+        which deploy) through the FULL resilient machinery
         (watchdog / OOM ladder / retry / bisect-to-quarantine) and
         return its partial-result dict (``issues`` / ``paths`` /
         ``dropped`` / ``iprof`` / ``quarantined`` / ``retries`` /
@@ -1246,8 +1313,7 @@ class CorpusCampaign:
         falls through to the in-process path on the demoted tier, and
         the tier manager's prober climbs back when the better tier
         probes healthy again (no permanent pin)."""
-        names = [n for n, _ in items]
-        codes = [c for _, c in items]
+        names, codes, creations = _split_records(items)
 
         # batch boundaries are where tier transitions land: give a due
         # re-promotion its chance, then fold any transition (from here
@@ -1273,7 +1339,7 @@ class CorpusCampaign:
                 injected = True
             try:
                 return self._worker_run(bi, names, codes, lanes, width,
-                                        on_tier)
+                                        on_tier, creations)
             except WorkerCrashLoop as e:
                 tm = self._tier_manager()
                 on_tier = tm.demote(
@@ -1286,10 +1352,16 @@ class CorpusCampaign:
                 self._tier_sync()
 
         def call_runner():
-            runner = self._batch_runner or self._exec_batch
-            if self._batch_runner is not None and not self._runner_degradable:
-                return runner(bi, names, codes)
-            return runner(bi, names, codes, lanes=lanes, width=width)
+            # a stub runner has no engine to deploy with: it is handed
+            # names and runtime codes, as ever
+            if self._batch_runner is None:
+                kw = {} if creations is None else {"creations": creations}
+                return self._exec_batch(bi, names, codes, lanes=lanes,
+                                        width=width, **kw)
+            if not self._runner_degradable:
+                return self._batch_runner(bi, names, codes)
+            return self._batch_runner(bi, names, codes, lanes=lanes,
+                                      width=width)
 
         def work():
             if self.fault_injector is not None and not injected:
@@ -1320,8 +1392,7 @@ class CorpusCampaign:
         and the host phase passes the finished result through."""
         if self._worker_enabled():
             return ("out", self._guarded_batch(bi, items))
-        names = [n for n, _ in items]
-        codes = [c for _, c in items]
+        names, codes, creations = _split_records(items)
 
         def work():
             if self.fault_injector is not None:
@@ -1331,7 +1402,8 @@ class CorpusCampaign:
                     return ("out", self._batch_runner(bi, names, codes))
                 return ("out", self._batch_runner(bi, names, codes,
                                                   lanes=None, width=None))
-            return ("sym", self._explore_batch(bi, names, codes))
+            return ("sym", self._explore_batch(bi, names, codes,
+                                               creations=creations))
 
         return run_with_watchdog(work, self.batch_timeout,
                                  label=f"batch {bi} device")
@@ -1984,8 +2056,7 @@ class CorpusCampaign:
             items = None
             ucfg: Dict = {}
             if self.fleet_follow:
-                unames, codes, ucfg = ledger.read_unit(unit.uid)
-                items = list(zip(unames, codes))
+                items, ucfg = ledger.read_unit_items(unit.uid)
                 ucfg = ucfg if isinstance(ucfg, dict) else {}
             rec = self._run_unit(ledger, unit, deadline, items=items,
                                  trace=ucfg.get("trace"))

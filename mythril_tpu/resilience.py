@@ -984,8 +984,12 @@ class WorkerSupervisor:
                   lanes: Optional[int] = None,
                   width: Optional[int] = None,
                   on_cpu: bool = False,
-                  on_tier: Optional[str] = None) -> Dict:
-        """Run one batch in the worker under the parent-side deadline.
+                  on_tier: Optional[str] = None,
+                  creations: Optional[Sequence[Optional[bytes]]] = None
+                  ) -> Dict:
+        """Run one batch in the worker under the parent-side deadline
+        (``creations``: the contracts' creation codes when the batch
+        deploys, so that a replayed batch deploys again).
         Raises :class:`WorkerCrashLoop` (breaker open),
         :class:`BatchTimeout` (deadline; worker killed),
         :class:`WorkerDied` (crash mid-batch), or the rehydrated typed
@@ -1009,6 +1013,10 @@ class WorkerSupervisor:
                 self._send({"op": "batch", "bi": int(bi),
                             "names": [str(x) for x in names],
                             "codes": [bytes(c) for c in codes],
+                            "creations": (
+                                None if creations is None else
+                                [None if k is None else bytes(k)
+                                 for k in creations]),
                             "lanes": lanes, "width": width,
                             "on_cpu": bool(on_cpu or on_tier == "cpu"),
                             "on_tier": on_tier,

@@ -2348,7 +2348,7 @@ def sym_superstep(sf: SymFrontier, env: Env, corpus: Corpus,
 
 
 def between_txs(sf: SymFrontier, require_mutation: bool = True,
-                new_contract_id=None,
+                runtime_offset: int = 0,
                 dependency_prune: bool = True,
                 first_message_tx: int = 0) -> SymFrontier:
     """Advance surviving lanes to the next symbolic transaction.
@@ -2366,11 +2366,15 @@ def between_txs(sf: SymFrontier, require_mutation: bool = True,
     ``SymExecWrapper`` already preserved them for detection.
     tx-scoped leaves re-key via tx_id (TX_STRIDE encoding).
 
-    ``require_mutation=False`` + ``new_contract_id`` serve the
+    ``require_mutation=False`` + ``runtime_offset`` serve the
     creation→runtime handoff (reference: ``execute_contract_creation``
     then message calls ⚠unv): a constructor needn't write storage for its
     deploy to count, and the surviving lanes switch from the creation
-    image to the runtime image while keeping their storage.
+    image to the runtime image (the corpus holds it ``runtime_offset``
+    images further on) while keeping their storage. The switch follows
+    the lane's own home contract, not the lane's position: a constructor
+    that forks (solc's non-payable check does) puts the copy in any free
+    lane of the frontier, another contract's block included.
     """
     b = sf.base
     P = sf.n_lanes
@@ -2388,10 +2392,8 @@ def between_txs(sf: SymFrontier, require_mutation: bool = True,
         # when a creation tx ran: the constructor is different code, not
         # an equivalent ancestor).
         go = go & ((sf.tx_id <= first_message_tx) | sf.dep_read)
-    if new_contract_id is None:
-        new_home = b.home_contract
-    else:
-        new_home = jnp.asarray(new_contract_id, dtype=b.home_contract.dtype)
+    new_home = (b.home_contract + runtime_offset if runtime_offset
+                else b.home_contract)
     attacker = jnp.broadcast_to(
         jnp.asarray(u256.from_int(ATTACKER_ADDRESS)), (P, 8)
     ).astype(jnp.uint32)
@@ -2921,6 +2923,59 @@ def rebalance_parked(sf: SymFrontier, fork_block: int = 0,
         base=b.replace(op_resid=resid),
         fork_req=new.fork_req.at[src].set(False),
     ), len(src_idx)
+
+
+def relieve_starved(sf: SymFrontier, n_contracts: int,
+                    active, fork_req, running, home):
+    """Break the fixpoint of a full frontier for the contracts it starves.
+
+    With ``defer_starved`` a fork that finds no free lane parks its lane,
+    and nothing retires a lane inside a transaction. So once every lane
+    is taken and every running lane is parked, no superstep changes the
+    frontier again: the rest of the budget and the drain spin, and every
+    parked lane ends as a dropped fork. Who holds the lanes at that
+    point is decided by who forked first. A contract of five functions
+    beside neighbours of sixty is stopped a handful of lanes short of
+    all it needs, in a frontier of a thousand.
+
+    Each contract is therefore guaranteed a FLOOR of a quarter of its
+    share of the frontier (``P // n_contracts`` lanes). At such a
+    fixpoint, if a contract that holds less than its floor waits for a
+    lane, the contracts that hold more than their share give up their
+    parked lanes (lost at a fixpoint whatever happens next; they are
+    counted as dropped forks). The freed lanes go to whoever still
+    asks, the contracts under their share; where those fill the frontier
+    again the next seam repeats the step, so the contracts of least
+    demand finish first.
+
+    Host-planned at the chunk seam from the leaves the seam has fetched
+    anyway (``home``: ``base.home_contract``), device-applied as one
+    mask over ``active`` and ``fork_req``; the compiled superstep loop is
+    untouched. Returns ``(sf, n_evicted)``."""
+    import numpy as np
+
+    active = np.asarray(active)
+    parked = np.asarray(fork_req) & active
+    if (not parked.any() or not active.all()
+            or (np.asarray(running) & active & ~parked).any()):
+        return sf, 0    # a lane is free, or a lane still moves
+    P = active.shape[0]
+    share = P // n_contracts
+    contract = np.asarray(home) % n_contracts   # creation | runtime image
+    held = np.bincount(contract, minlength=n_contracts)
+    waits = np.bincount(contract[parked], minlength=n_contracts) > 0
+    if not (waits & (held < share // 4)).any():
+        return sf, 0
+    out = parked & (held > share)[contract]
+    if not out.any():
+        return sf, 0
+    # a mask, not an index list: one program whatever the count (a
+    # scatter would compile anew for every new number of lanes)
+    keep = jnp.asarray(~out)
+    return sf.replace(
+        base=sf.base.replace(active=sf.base.active & keep),
+        fork_req=sf.fork_req & keep,
+    ), int(out.sum())
 
 
 @jax.named_scope("migrate_parked_device")

@@ -1,0 +1,330 @@
+"""Campaigns that deploy: a corpus record may carry a contract's
+creation code, and ``CorpusCampaign`` then analyses it as single-contract
+``analyze --creation-code`` does (constructor from the creator account,
+message calls from the storage it left). The record survives every seam a
+batch can be replayed through: corpus directory, fingerprint and
+checkpoint resume, the isolation worker's IPC, a fed fleet unit.
+"""
+
+import dataclasses
+import hashlib
+import json
+
+import numpy as np
+import pytest
+
+import mythril_tpu  # noqa: F401
+from mythril_tpu import engine_worker
+from mythril_tpu.config import TEST_LIMITS
+from mythril_tpu.core.frontier import ATTACKER_ADDRESS, CREATOR_ADDRESS
+from mythril_tpu.disassembler.asm import assemble, selector_prologue
+from mythril_tpu.fleet import WorkLedger, corpus_fingerprint
+from mythril_tpu.mythril.campaign import CorpusCampaign, load_corpus_dir
+from mythril_tpu.mythril.orchestration import (MythrilAnalyzer,
+                                               MythrilConfig,
+                                               MythrilDisassembler)
+from mythril_tpu.resilience import (FaultInjector, FaultSpec, InjectedKill,
+                                    WorkerSupervisor)
+from mythril_tpu.symbolic import SymSpec
+
+CONCRETE = SymSpec(storage=False)
+MODULES = ["AccidentallyKillable", "EtherThief"]
+
+#: the wallet of parity_wallet_bug_2: ``init(address)`` sets the owner,
+#: whoever calls; ``kill()`` is for the owner
+WALLET = assemble(
+    *selector_prologue(),
+    "DUP1", 0x11111111, "EQ", ("ref", "init"), "JUMPI",
+    "DUP1", 0x22222222, "EQ", ("ref", "kill"), "JUMPI",
+    0, 0, "REVERT",
+    ("label", "init"), "POP", 4, "CALLDATALOAD", 0, "SSTORE", "STOP",
+    ("label", "kill"), "POP", "CALLER", 0, "SLOAD", "EQ",
+    ("ref", "ok"), "JUMPI", 0, 0, "REVERT",
+    ("label", "ok"), "CALLER", "SELFDESTRUCT",
+)
+#: ``kill()`` for the owner alone: safe once a constructor set the owner
+GUARDED = assemble(
+    "CALLER", 0, "SLOAD", "EQ", ("ref", "ok"), "JUMPI", 0, 0, "REVERT",
+    ("label", "ok"), "CALLER", "SELFDESTRUCT",
+)
+CTOR_OWNER = assemble("CALLER", 0, "SSTORE", 0, 0, "RETURN")
+#: a constructor that hands the contract to whoever calls it first
+CTOR_OPEN = assemble("CALLER", 0, "SSTORE", 0, 0, "SSTORE", 0, 0, "RETURN")
+assert ATTACKER_ADDRESS != 0
+
+
+def campaign(contracts, **kw):
+    kw.setdefault("transaction_count", 2)
+    return CorpusCampaign(
+        contracts, batch_size=1, lanes_per_contract=16, limits=TEST_LIMITS,
+        spec=CONCRETE, max_steps=128, modules=MODULES, **kw)
+
+
+def found(res):
+    return sorted((i["contract"], str(i["swc-id"])) for i in res.issues)
+
+
+def test_campaign_over_records_equals_analyze_with_creation_code():
+    res = campaign([("wallet", WALLET, CTOR_OWNER)]).run()
+    contract = MythrilDisassembler.load_from_bytecode(
+        WALLET.hex(), creation_code=CTOR_OWNER.hex(), name="wallet")
+    single = MythrilAnalyzer([contract], MythrilConfig(
+        limits=TEST_LIMITS, spec=CONCRETE, lanes_per_contract=16,
+        max_steps=128, transaction_count=2)).fire_lasers(MODULES)
+    want = sorted((i.contract, i.swc_id, i.address,
+                   json.dumps(i.transaction_sequence))
+                  for i in single.issues)
+    got = sorted((i["contract"], str(i["swc-id"]), i["address"],
+                  json.dumps(i["tx_sequence"])) for i in res.issues)
+    assert got == want and [g[:2] for g in got] == [("wallet", "106")]
+    # creation from the creator, init(attacker), kill(): three steps
+    seq = res.issues[0]["tx_sequence"]
+    assert len(seq) == 3
+    assert int(seq[0]["caller"], 16) == CREATOR_ADDRESS
+    assert seq[1]["input"].startswith("0x11111111")
+    assert int(seq[1]["input"][10:74], 16) == ATTACKER_ADDRESS
+    assert seq[2]["input"].startswith("0x22222222")
+    assert int(seq[2]["caller"], 16) == ATTACKER_ADDRESS
+
+
+@pytest.mark.parametrize("record, want", [
+    # the guard rests on the constructor's write
+    (("guarded", GUARDED, CTOR_OWNER), []),
+    # a constructor that leaves the owner open to the zero address
+    (("wallet", WALLET, CTOR_OPEN), [("wallet", "106")]),
+    # one transaction does not reach the flaw that needs two
+    (("wallet", WALLET, CTOR_OWNER, 1), []),
+])
+def test_verdicts_rest_on_the_constructor(record, want):
+    txs = record[3] if len(record) > 3 else 2
+    assert found(campaign([record[:3]], transaction_count=txs).run()) == want
+
+
+def test_mixed_batch_deploys_and_pairs_keep_their_shape_class():
+    camp = CorpusCampaign(
+        [("wallet", WALLET, CTOR_OWNER), ("plain", GUARDED)], batch_size=2,
+        lanes_per_contract=16, limits=TEST_LIMITS, spec=CONCRETE,
+        max_steps=128, transaction_count=2, modules=MODULES)
+    assert found(camp.run()) == [("wallet", "106")]
+    assert camp.shape_is_warm(deploys=True) and not camp.shape_is_warm()
+    assert camp._shape_key() == (2, 16, 128, 2)
+    assert camp._shape_key(deploys=True) == (2, 16, 128, 2, 1)
+
+
+def _hog(n: int, salt: int) -> bytes:
+    """``n`` functions that each write their own slot: every one of
+    them is a state the second transaction starts from, and each of
+    those walks the whole dispatcher again."""
+    toks = list(selector_prologue())
+    for i in range(n):
+        toks += ["DUP1", 0x30000000 + i, "EQ", ("ref", f"f{i}"), "JUMPI"]
+    toks += [0, 0, "REVERT"]
+    for i in range(n):
+        toks += [("label", f"f{i}"), "POP", 4, "CALLDATALOAD", i + 1,
+                 "SSTORE", "STOP"]
+    return assemble(*toks) + bytes([salt])
+
+
+@pytest.mark.parametrize("floor", [True, False])
+def test_small_contract_beside_hogs_is_served_at_the_pools_fixpoint(
+        floor, monkeypatch):
+    """Three 12-function neighbours fill the pool of 64 lanes at the
+    first fork of the second transaction, and every lane parks. The
+    wallet needs four lanes there and holds fewer: under its floor (a
+    quarter of its share of 16), so the seam's ``relieve_starved`` has
+    the neighbours give up their parked lanes. Without it the flaw that
+    needs the second call is not found."""
+    from mythril_tpu.analysis import symbolic
+    from mythril_tpu.obs import metrics
+
+    if not floor:
+        monkeypatch.setattr(symbolic, "relieve_starved",
+                            lambda sf, *a: (sf, 0))
+    def evicted():
+        return metrics.REGISTRY.snapshot()["counters"].get(
+            "evicted_lanes_total", 0)
+
+    before = evicted()
+    res = CorpusCampaign(
+        [(f"hog{i}", _hog(12, i), CTOR_OWNER) for i in range(3)]
+        + [("wallet", WALLET, CTOR_OWNER)],
+        batch_size=4, lanes_per_contract=16, limits=TEST_LIMITS,
+        spec=CONCRETE, max_steps=128, transaction_count=2,
+        modules=MODULES).run()
+    evicted = evicted() - before
+    assert found(res) == ([("wallet", "106")] if floor else [])
+    assert (evicted > 0) is floor
+    # the lanes given up are dropped forks, as those still parked are
+    assert res.dropped_forks >= evicted
+
+
+def test_deploy_epilogue_longer_than_memory_does_not_trap():
+    """solc's epilogue copies the whole runtime code to memory and
+    returns it; a runtime longer than the memory model must not cost the
+    deploy (the payload is the image the caller supplies)."""
+    limits = dataclasses.replace(TEST_LIMITS, mem_bytes=128)
+    runtime = GUARDED + bytes(200)
+    ctor = assemble("CALLER", 0, "SSTORE")
+    at = len(ctor) + 13
+    creation = (ctor + b"\x61" + len(runtime).to_bytes(2, "big") + b"\x80\x61"
+                + at.to_bytes(2, "big") + b"\x60\x00\x39\x60\x00\xf3"
+                + runtime)
+    from mythril_tpu.analysis import SymExecWrapper
+
+    sym = SymExecWrapper([runtime], creation_bytecodes=[creation],
+                         limits=limits, spec=CONCRETE, lanes_per_contract=4,
+                         max_steps=64, transaction_count=1)
+    assert not sym.tx_contexts[0].trap_counts
+    assert len(sym.tx_contexts) == 2, "the message call ran"
+    # beyond the payload the cap still traps: MSTORE at 4096
+    far = assemble(1, 4096, "MSTORE", 0, 0, "RETURN")
+    sym = SymExecWrapper([runtime], creation_bytecodes=[far], limits=limits,
+                         spec=CONCRETE, lanes_per_contract=4, max_steps=64,
+                         transaction_count=1)
+    assert sym.tx_contexts[0].trap_counts
+
+
+def test_corpus_dir_pairs_bin_with_bin_runtime(tmp_path):
+    (tmp_path / "Wallet.bin").write_text(CTOR_OWNER.hex())
+    (tmp_path / "Wallet.bin-runtime").write_text("0x" + WALLET.hex())
+    (tmp_path / "lone.hex").write_text(GUARDED.hex())
+    (tmp_path / "legacy.bin").write_text(GUARDED.hex())
+    (tmp_path / "empty.bin").write_text("")
+    (tmp_path / "empty.bin-runtime").write_text(WALLET.hex())
+    (tmp_path / "notes.txt").write_text("skipped")
+    got = load_corpus_dir(str(tmp_path))
+    assert got == [("Wallet", WALLET, CTOR_OWNER), ("empty", WALLET),
+                   ("legacy", GUARDED), ("lone", GUARDED)]
+    (tmp_path / "void").mkdir()
+    with pytest.raises(ValueError):
+        load_corpus_dir(str(tmp_path / "void"))
+
+
+def test_fingerprint_covers_the_constructor_and_pairs_hash_as_before():
+    pairs = [("a", WALLET), ("b", GUARDED)]
+    h = hashlib.sha256()
+    for name, code in pairs:
+        h.update(name.encode() + b"\0" + hashlib.sha256(code).digest())
+    assert corpus_fingerprint(pairs) == h.hexdigest()[:16]
+    assert corpus_fingerprint([("a", WALLET, None), ("b", GUARDED)]) \
+        == corpus_fingerprint(pairs)
+    owner = corpus_fingerprint([("a", WALLET, CTOR_OWNER), ("b", GUARDED)])
+    opened = corpus_fingerprint([("a", WALLET, CTOR_OPEN), ("b", GUARDED)])
+    assert len({owner, opened, corpus_fingerprint(pairs)}) == 3
+
+
+def test_checkpoint_resume_deploys_again_and_refuses_another_constructor(
+        tmp_path):
+    corpus = [("first", GUARDED, CTOR_OWNER), ("wallet", WALLET, CTOR_OWNER)]
+    ck = str(tmp_path / "ck")
+    with pytest.raises(InjectedKill):
+        campaign(corpus, checkpoint_dir=ck, fault_injector=FaultInjector(
+            [FaultSpec.parse("kill:batch=1")])).run()
+    res = campaign(corpus, checkpoint_dir=ck).run()
+    assert res.batches == 2 and found(res) == [("wallet", "106")]
+    assert len(res.batch_wall) == 2, "batch 0 came from the checkpoint"
+    # the same names and runtime codes under another constructor are
+    # another corpus: the cursor is not resumed over it
+    other = [corpus[0], ("wallet", WALLET, CTOR_OPEN)]
+    camp = campaign(other, checkpoint_dir=ck)
+    camp.run()
+    assert "checkpoint_reset" in camp._event_kinds
+
+
+class Spy:
+    """A supervisor that answers like the stub worker and keeps what it
+    was asked."""
+
+    on_event = None
+    device = None
+
+    def __init__(self):
+        self.asked = []
+
+    def run_batch(self, bi, names, codes, **kw):
+        self.asked.append((list(names), list(codes), kw))
+        return {"issues": [], "paths": len(names), "dropped": 0,
+                "iprof": {}}
+
+    def close(self):
+        pass
+
+
+def test_isolation_worker_is_handed_the_creation_code():
+    # parent side: the campaign gives the supervisor the record ...
+    spy = Spy()
+    campaign([("wallet", WALLET, CTOR_OWNER), ("plain", GUARDED)],
+             worker_isolation="on", worker_supervisor=spy).run()
+    assert [a[2]["creations"] for a in spy.asked] == [[CTOR_OWNER], None]
+    # ... the supervisor puts it on the wire ...
+    sup = WorkerSupervisor(stub=True, batch_timeout=30.0,
+                           spawn_timeout=60.0)
+    sent = []
+    send = sup._send
+    sup._send = lambda msg: (sent.append(msg), send(msg))[1]
+    try:
+        sup.run_batch(0, ["wallet"], [WALLET], creations=[CTOR_OWNER])
+        sup.run_batch(1, ["plain"], [GUARDED])
+    finally:
+        sup.close()
+    batches = [m for m in sent if m.get("op") == "batch"]
+    assert [m["creations"] for m in batches] == [[CTOR_OWNER], None]
+    # ... and the worker's batch handler deploys with it
+    camp = campaign([])
+    msg = {"bi": 0, "names": ["wallet"], "codes": [WALLET],
+           "creations": [CTOR_OPEN], "lanes": None, "width": None}
+    out = engine_worker._run_batch(camp, False, msg, None, 0)
+    assert [(i["contract"], str(i["swc-id"])) for i in out["issues"]] \
+        == [("wallet", "106")]
+    assert len(out["issues"][0]["tx_sequence"]) == 3
+
+
+def test_fed_fleet_unit_carries_the_record(tmp_path):
+    ledger = WorkLedger(str(tmp_path / "ledger"))
+    ledger.ensure_feed()
+    uid = ledger.feed_unit([("wallet", WALLET, CTOR_OWNER),
+                            ("plain", GUARDED)], config={"k": 1})
+    items, cfg = ledger.read_unit_items(uid)
+    assert items == [("wallet", WALLET, CTOR_OWNER), ("plain", GUARDED)]
+    assert cfg == {"k": 1}
+    assert ledger.read_unit(uid) == (["wallet", "plain"],
+                                     [WALLET, GUARDED], {"k": 1})
+    uid2 = ledger.feed_unit([("plain", GUARDED)])
+    with open(ledger._unit_desc_path(uid2)) as fh:
+        assert "creations" not in json.load(fh)
+
+
+def test_deployed_counter_and_spans_say_which_transaction(tmp_path):
+    from mythril_tpu.obs import metrics as obs_metrics
+    from mythril_tpu.obs import trace as obs_trace
+
+    reg = obs_metrics.REGISTRY
+    before = reg.snapshot()["counters"]
+    tracer = obs_trace.configure(buffer=True)
+    try:
+        campaign([("wallet", WALLET, CTOR_OWNER)]).run()
+        spans = [s for s in tracer.drain_buffer() if s.get("kind") == "span"]
+    finally:
+        obs_trace.close()
+    after = reg.snapshot()["counters"]
+
+    def delta(key):
+        return after.get(key, 0) - before.get(key, 0)
+
+    assert delta("campaign_contracts_deployed_total") == 1
+    kinds = {(s["name"], s.get("tx"), s.get("tx_kind")) for s in spans
+             if s["name"] in ("superstep", "drain", "harvest", "tx_seam")}
+    assert ("harvest", 0, "creation") in kinds
+    assert ("tx_seam", 0, "creation") in kinds
+    assert ("tx_seam", 1, "message") in kinds
+    assert ("harvest", 2, "message") in kinds
+    assert not any(k == "creation" for _, tx, k in kinds if tx)
+    seams = {s["tx"]: s for s in spans if s["name"] == "tx_seam"}
+    assert seams[0]["carried"] >= 1 and seams[1]["carried"] >= 1
+    # one path deployed; what survives each message call is counted
+    # under its own transaction index
+    assert delta('engine_paths_total{tx="0"}') == 1
+    assert delta('engine_paths_total{tx="1"}') >= 2
+    assert delta('engine_paths_total{tx="2"}') >= 1
+    assert delta('engine_dropped_forks_total{tx="2"}') == 0
+    assert np.isfinite(seams[0]["dur"])

@@ -395,7 +395,7 @@ def test_superstep_spans_end_with_the_device_and_count_what_ran(spill):
              if r["what"].startswith("visited,steps_total")]
     assert len(reads) == len(steps)
     assert reads[0] == ("visited,steps_total,base.active,fork_req,"
-                        "base.running" if spill
+                        "base.running,base.home_contract" if spill
                         else "visited,steps_total")
     import jax
 

@@ -9,6 +9,7 @@ on the virtual 8-device mesh (conftest).
 """
 
 import numpy as np
+import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
@@ -194,3 +195,53 @@ def test_sym_run_migration_unblocks_starved_forks():
     assert int(np.asarray(moved.dropped_total)) == 0
     # migrated run explores every path of the 2-branch fixture: 4 leaves
     assert done_moved >= 4
+
+
+# --- the floor of a full frontier (``relieve_starved``) ---------------------
+
+# 4 contracts x 8 lanes: a share of 8 lanes, a floor of 2
+_HOME = np.array([0] * 1 + [1] * 9 + [2] * 14 + [3] * 8, dtype=np.int32)
+
+
+def _relieve(active, parked, running, home, n=4):
+    from mythril_tpu.symbolic.engine import relieve_starved
+
+    sf = synth(active, parked)
+    out, k = relieve_starved(sf, n, active, parked, running, home)
+    return (k, np.asarray(out.base.active), np.asarray(out.fork_req),
+            np.asarray(out.base.pc))
+
+
+@pytest.mark.parametrize("case", ["starved", "creation_images",
+                                  "a_lane_is_free", "a_lane_still_moves",
+                                  "nobody_under_the_floor",
+                                  "the_starved_does_not_wait"])
+def test_full_frontier_fixpoint_is_broken_for_the_starved_only(case):
+    full = np.ones(P, dtype=bool)
+    # every contract has parked lanes; the rest have halted
+    parked = np.zeros(P, dtype=bool)
+    parked[[0, 3, 4, 12, 13, 14, 20, 25, 26]] = True
+    active, running, home = full.copy(), parked.copy(), _HOME.copy()
+    if case == "creation_images":
+        home = home + 4         # the lanes' homes are images 4..7 of 8
+    elif case == "a_lane_is_free":
+        active[31] = False
+    elif case == "a_lane_still_moves":
+        running[30] = True
+    elif case == "nobody_under_the_floor":
+        home[1] = 0             # contract 0 holds 2 lanes: its floor
+    elif case == "the_starved_does_not_wait":
+        parked[0] = running[0] = False
+    k, act, req, pc = _relieve(active, parked, running, home)
+    if case in ("starved", "creation_images"):
+        # contracts 1 (9 lanes) and 2 (14) hold more than their share
+        # of 8: their parked lanes go, and nothing else changes
+        gone = [3, 4, 12, 13, 14, 20]
+        assert k == len(gone)
+        assert not act[gone].any() and not req[gone].any()
+        keep = np.setdiff1d(np.arange(P), gone)
+        assert act[keep].all() and (req[keep] == parked[keep]).all()
+        assert (pc == np.arange(P)).all()      # no lane moved
+    else:
+        assert k == 0
+        assert (act == active).all() and (req == parked).all()

@@ -153,6 +153,40 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
     else:
         out.append("(no spans)")
 
+    # 1b. the transactions of a batch: which kind each was, what its
+    # sym_run calls cost, the paths that ended it and the forks it lost
+    by_tx: Dict[tuple, Dict] = {}
+    for s in spans:
+        a = s["args"]
+        if s["name"] not in ("superstep", "harvest", "tx_seam") \
+                or "tx_kind" not in a:
+            continue
+        row = by_tx.setdefault((a.get("tx"), a["tx_kind"]), {
+            "calls": 0, "sec": 0.0, "paths": 0, "dropped": 0,
+            "carried": 0, "seam": 0.0})
+        if s["name"] == "superstep":
+            row["calls"] += 1
+            row["sec"] += s["dur"]
+        elif s["name"] == "harvest":
+            row["paths"] += int(a.get("paths", 0))
+            row["dropped"] += int(a.get("dropped", 0))
+        else:
+            row["carried"] += int(a.get("carried", 0))
+            row["seam"] += s["dur"]
+    if by_tx:
+        out.append("")
+        out.append("== transactions (tx, tx_kind) ==")
+        out.append(f"{'tx':>3} {'kind':<9}{'calls':>6}{'sym_run':>10}"
+                   f"{'paths':>8}{'dropped':>9}{'admitted':>10}"
+                   f"{'carried':>9}{'seam':>10}")
+        for (tx, kind), r in sorted(by_tx.items(), key=lambda kv: str(kv[0])):
+            tot = r["paths"] + r["dropped"]
+            out.append(
+                f"{tx!s:>3} {kind:<9}{r['calls']:>6}{_fmt_s(r['sec']):>10}"
+                f"{r['paths']:>8}{r['dropped']:>9}"
+                f"{(100.0 * r['paths'] / tot if tot else 100.0):>9.1f}%"
+                f"{r['carried']:>9}{_fmt_s(r['seam']):>10}")
+
     # 2. batch stall table: slowest batches, with their outcome
     status_by_bi: Dict[int, str] = {}
     for e in instants:
