@@ -28,8 +28,8 @@ from ..smt.eval import Assignment
 from ..smt.solver import solve_tape
 from ..smt.tape import HostNode, HostTape, extract_tape, intern_node
 from ..symbolic import SymSpec, between_txs, make_sym_frontier, sym_run
-from ..symbolic.engine import (rebalance_parked, relieve_starved,
-                               sym_run_donated)
+from ..symbolic.engine import (pool_stuck, rebalance_parked,
+                               relieve_starved, sym_run_donated)
 
 log = logging.getLogger(__name__)
 
@@ -233,6 +233,46 @@ def coverage_summary(tx_contexts) -> dict:
     return out
 
 
+class _PoolWatch:
+    """Says when a transaction's lane pool is stuck for good.
+
+    A seam is *stuck* when ``pool_stuck`` holds (every lane taken, every
+    running lane parked on a fork it cannot place) and the seam's
+    scheduling step did nothing, so the next ``sym_run`` call starts
+    from the frontier the seam saw. From there no superstep changes
+    anything (a parked lane un-executes its branch and raises the same
+    request again); only a feasibility sweep that kills a lane frees a
+    slot. The sweep is incremental and walks every node added since the
+    last one, so once a call has held a whole sweep and ended at a
+    stuck seam with ``active``, ``fork_req``, ``running`` and the run
+    totals of kills and drops all as the stuck seam before it had them,
+    that call was a witness: every lane's sweep has caught up, and
+    every later call of the transaction would hand back this frontier
+    (but for its step counters). ``seam`` returns that as ``proven``."""
+
+    def __init__(self, sweep_every: int):
+        self._sweep = max(1, sweep_every)   # supersteps that hold a sweep
+        self._last = None       # what the last seam saw, if it was stuck
+        self._ran = 0           # supersteps run since
+
+    def ran(self, steps_run: int) -> None:
+        self._ran += steps_run
+
+    def seam(self, active, fork_req, running, killed: int, dropped: int,
+             idle: bool) -> tuple:
+        """One seam, after its scheduling step (``idle``: it moved and
+        evicted nothing): ``(stuck, proven)``."""
+        stuck = idle and pool_stuck(active, fork_req, running)
+        seen = (killed, dropped, active, fork_req, running) if stuck else None
+        proven = (stuck and self._last is not None
+                  and self._ran >= self._sweep
+                  and seen[:2] == self._last[:2]
+                  and all(np.array_equal(a, b)
+                          for a, b in zip(seen[2:], self._last[2:])))
+        self._last, self._ran = seen, 0
+        return stuck, proven
+
+
 class SymExecWrapper:
     """Build + run the symbolic exploration for a batch of contracts.
 
@@ -254,8 +294,14 @@ class SymExecWrapper:
     and ``engine_paths_total{tx}`` / ``engine_dropped_forks_total{tx}``
     count, per transaction index, the paths that survived it and the
     forks it lost to the budget (the ``harvest`` span carries the two
-    as ``paths`` / ``dropped``). All of it rides the reads the harvest
-    and the seam make anyway.
+    as ``paths`` / ``dropped``). Every ``superstep`` span says whether
+    its own seam found the lane pool ``stuck``, and a transaction's last
+    one what ``ended`` it (``fixpoint`` | ``budget`` | ``quiescent`` |
+    ``deadline``); ``engine_fixpoint_ends_total{tx}`` counts the
+    transactions ended at their pool's fixpoint (``_PoolWatch``) and
+    ``engine_calls_skipped_total{tx}`` the ``sym_run`` calls their budget
+    still allowed. All of it rides the reads the harvest and the seam
+    make anyway.
     """
 
     def __init__(
@@ -450,17 +496,73 @@ class SymExecWrapper:
         # tx so detection sees lanes that between_txs retires
         self.tx_contexts: List[AnalysisContext] = []
 
+        DRAIN_ROUNDS = 4
+
         def explore(sf):
             """One transaction's exploration, chunked when a wall-clock
             deadline is set (reference: --execution-timeout checked in the
             exec loop, SURVEY §5.3). Chunks re-enter the same compiled
             sym_run; between chunks the host checks the clock and may
-            checkpoint."""
+            checkpoint. With spill the transaction also ends at the seam
+            that proves its lane pool stuck for good (``_PoolWatch``)."""
+            try:
+                sf, ended = walk(sf)
+                if held:
+                    held[-1].attrs["ended"] = ended
+                return sf
+            finally:
+                seal()
+
+        # the last call's ``superstep`` span: timed as ever, emitted
+        # once its seam has said what it found (``stuck``) and, for a
+        # transaction's last call, what ended it
+        held: list = []
+
+        def seal():
+            while held:
+                held.pop().emit()
+
+        def walk(sf):
             import time as _time
 
             runner = sym_run_donated if self._donate else sym_run
             warm_shapes: set = getattr(self, "_warm_chunk_shapes", set())
             self._warm_chunk_shapes = warm_shapes
+            watch = _PoolWatch(limits.propagate_every)
+            q = max(1, self._chunk // 4)
+
+            def chunk_at(done):
+                # max_steps is a static jit arg: every distinct n is a
+                # full-engine XLA compile. Quantize tails to the small
+                # chunk so at most THREE shapes exist per run (chunk,
+                # chunk//4, and one sub-q remainder).
+                n = min(self._chunk, max_steps - done)
+                return q if q < n < self._chunk else n
+
+            def chunks_left(done):
+                """The chunk calls the step budget still allows."""
+                k = 0
+                while done < max_steps:
+                    done += chunk_at(done)
+                    k += 1
+                return k
+
+            def fixpoint_end(skipped):
+                reg = obs_metrics.REGISTRY
+                labels = {"tx": str(self._cur_tx)}
+                reg.counter(
+                    "engine_fixpoint_ends_total",
+                    help="transactions ended at the seam that proved "
+                         "their lane pool stuck for good",
+                    labels=labels).inc()
+                reg.counter(
+                    "engine_calls_skipped_total",
+                    help="sym_run calls (chunks and drain rounds) the "
+                         "budget of a transaction ended at its pool's "
+                         "fixpoint still allowed", labels=labels
+                ).inc(skipped)
+                if held:
+                    held[-1].attrs["skipped"] = skipped
 
             def superstep(sf, n, shape, also=(), run_kw=None, **attrs):
                 """One ``sym_run`` call of at most ``n`` supersteps,
@@ -475,9 +577,10 @@ class SymExecWrapper:
                 leaves."""
                 cold = shape not in warm_shapes
                 w0 = tally()[1]
+                seal()
                 with obs_trace.timer("superstep", tx=self._cur_tx,
-                                     tx_kind=self._tx_kind,
-                                     steps=n, cold=cold, **attrs) as sp:
+                                     tx_kind=self._tx_kind, steps=n,
+                                     cold=cold, stuck=False, **attrs) as sp:
                     sf, vis = runner(
                         sf, env, self.corpus, spec, limits,
                         max_steps=n, track_coverage=True,
@@ -495,6 +598,10 @@ class SymExecWrapper:
                         steps_run=steps_run,
                         enqueue_s=round(enqueue_s, 6),
                         device_wait_s=round(tally()[1] - w0, 6))
+                    # timed to here, emitted by ``seal`` once the
+                    # call's seam has said what it found
+                    held.append(sp.hold())
+                watch.ran(steps_run)
                 self._steps_seen = int(got[1])
                 self._visited |= got[0]
                 reg = obs_metrics.REGISTRY
@@ -516,14 +623,17 @@ class SymExecWrapper:
             # what a seam of the spill machinery reads of the frontier,
             # in the one transfer of its ``superstep`` call
             SEAM = ("base.active", "fork_req", "base.running",
-                    "base.home_contract")
+                    "base.home_contract", "killed_total", "dropped_total")
 
-            def rebalance(sf, act_h, freq_h, run_h, home_h):
+            def rebalance(sf, act_h, freq_h, run_h, home_h, killed_h,
+                          dropped_h):
                 """A seam's scheduling step: parked lanes move to blocks
                 with free lanes; where the whole frontier is full and
                 stuck, the contracts it starves are relieved (the
                 lanes given up are lost forks, counted with those still
-                parked at the end)."""
+                parked at the end). Where it does nothing, ``watch``
+                judges the seam: returns the frontier and whether the
+                pool is now proven stuck for good."""
                 with obs_trace.span("rebalance", tx=self._cur_tx):
                     sf, moved = rebalance_parked(sf, self.fork_block,
                                                  active=act_h,
@@ -543,30 +653,30 @@ class SymExecWrapper:
                     help="parked lanes given up at a full frontier's "
                          "fixpoint for a contract under its floor"
                 ).inc(evicted)
-                return sf
+                stuck, proven = watch.seam(
+                    act_h, freq_h, run_h, int(killed_h), int(dropped_h),
+                    idle=not (moved or evicted))
+                if held:
+                    held[-1].attrs["stuck"] = stuck
+                return sf, proven
 
             if (self._deadline_at is None and self.checkpoint_dir is None
                     and not self.spill):
                 # execute + fork fuse inside the jitted superstep loop;
                 # the host-visible unit (and the span) is the whole call
-                sf, *_ = superstep(sf, max_steps, ("whole", max_steps),
-                                   done=0)
-                return sf
+                sf, steps_run, *_ = superstep(
+                    sf, max_steps, ("whole", max_steps), done=0)
+                return sf, ("quiescent" if steps_run < max_steps
+                            else "budget")
             steps_done = 0
             sec_per_step = 0.0
-            q = max(1, self._chunk // 4)
+            ended, proven = "budget", False
             chunk_kw = dict(defer_starved=self.spill,
                             migrate_every=self.migrate_every)
             telemetry = (obs_metrics.REGISTRY.enabled
                          or obs_trace.active())
             while steps_done < max_steps:
-                n = min(self._chunk, max_steps - steps_done)
-                # max_steps is a static jit arg: every distinct n is a
-                # full-engine XLA compile. Quantize tails to the small
-                # chunk so at most THREE shapes exist per run (chunk,
-                # chunk//4, and one sub-q remainder).
-                if q < n < self._chunk:
-                    n = q
+                n = chunk_at(steps_done)
                 # deadline granularity: when the
                 # remaining budget would not cover a full chunk, fall to
                 # the small chunk instead of overshooting by seconds.
@@ -581,10 +691,13 @@ class SymExecWrapper:
                 # quiescence check ride the same fetch (each separate
                 # read is a blocking sync). A bare run with telemetry
                 # off and spill off reads only ``running`` beside the
-                # bitmap. (Reusing the pre-rebalance fetch for the
-                # quiescence check is exact: rebalance RELOCATES lanes,
-                # or retires parked ones for another that goes on
-                # waiting — never changing whether any lane is running.)
+                # bitmap. With spill the same fetch (and the two run
+                # totals beside the masks) also decides whether the
+                # pool is stuck for good, which ends the transaction.
+                # (Reusing the pre-rebalance fetch for the quiescence
+                # check is exact: rebalance RELOCATES lanes, or retires
+                # parked ones for another that goes on waiting — never
+                # changing whether any lane is running.)
                 seam = (SEAM if self.spill
                         else SEAM[:3] if telemetry else SEAM[2:3])
                 sf, steps_run, dur, cold, got = superstep(
@@ -599,16 +712,24 @@ class SymExecWrapper:
                     sec_per_step = max(sec_per_step, dur / steps_run)
                 steps_done += n
                 if self.spill:
-                    sf = rebalance(sf, *got)
+                    sf, proven = rebalance(sf, *got)
                 self._observe_frontier(sf, active=act_h, fork_req=freq_h)
                 self.plugin_loader.fire("on_chunk", sf, steps_done)
                 if self.checkpoint_dir is not None:
                     self._save_checkpoint(sf, steps_done)
+                if proven:
+                    # every further call of this transaction, chunk or
+                    # drain round, would hand back this frontier
+                    fixpoint_end(chunks_left(steps_done) + DRAIN_ROUNDS)
+                    self._parked_end += int((freq_h & act_h).sum())
+                    return sf, "fixpoint"
                 if not bool(run_h.any()):
+                    ended = "quiescent"
                     break
                 if (self._deadline_at is not None
                         and _time.monotonic() >= self._deadline_at):
                     self.timed_out = True
+                    ended = "deadline"
                     break
             if self.spill:
                 # drain phase: lanes still parked at budget end re-raise
@@ -623,24 +744,31 @@ class SymExecWrapper:
                     got = fetch(tuple(attrgetter(a)(sf) for a in SEAM),
                                 ",".join(SEAM))
                     parked = got[1] & got[0]
-                    for _ in range(4):
+                    for left in range(DRAIN_ROUNDS, 0, -1):
                         if not parked.any():
                             break
                         if self.timed_out or (
                                 self._deadline_at is not None
                                 and _time.monotonic() >= self._deadline_at):
+                            ended = "deadline"
                             break  # the drain respects the wall clock too
-                        sf = rebalance(sf, *got)
+                        sf, proven = rebalance(sf, *got)
+                        if proven:
+                            fixpoint_end(left)
+                            ended = "fixpoint"
+                            break
                         # the chunk loop's program (same static args)
                         sf, _, _, _, got = superstep(
                             sf, self._chunk, self._chunk, also=SEAM,
                             run_kw=chunk_kw, drain=True)
                         parked = got[1] & got[0]
+                        # no scheduling step follows the last round
+                        held[-1].attrs["stuck"] = pool_stuck(*got[:3])
                 # forks still parked after draining are lost coverage —
                 # count them in the drop channel for honesty (reusing
                 # the drain loop's final fetch — no extra sync)
                 self._parked_end += int(parked.sum())
-            return sf
+            return sf, ended
 
         def run_one_tx(sf, is_last: bool, handoff_kw=None):
             self.plugin_loader.fire("on_tx_start", self._cur_tx, sf)

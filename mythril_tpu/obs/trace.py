@@ -239,7 +239,7 @@ class Span:
     mid-flight and read the measurement afterwards."""
 
     __slots__ = ("_tracer", "name", "attrs", "t_wall", "_t0", "dur",
-                 "sid", "_ctx_token")
+                 "sid", "_ctx_token", "_held")
 
     def __init__(self, tracer: Optional["Tracer"], name: str,
                  attrs: Dict[str, Any]):
@@ -251,6 +251,7 @@ class Span:
         self.dur: Optional[float] = None
         self.sid: Optional[str] = None
         self._ctx_token = None
+        self._held = False
 
     def __enter__(self) -> "Span":
         self.t_wall = time.time()
@@ -286,9 +287,21 @@ class Span:
             except ValueError:
                 pass  # stopped from a different thread/context
             self._ctx_token = None
+        if not self._held or exc[0] is not None:
+            self.emit()
+        return False
+
+    def hold(self) -> "Span":
+        """Have the ``with`` block end the span without emitting it:
+        for a caller that learns an attribute only after the timed work
+        and calls ``emit`` then. A block that an exception leaves emits
+        as ever."""
+        self._held = True
+        return self
+
+    def emit(self) -> None:
         if self._tracer is not None:
             self._tracer._emit_span(self)
-        return False
 
     @property
     def elapsed(self) -> float:

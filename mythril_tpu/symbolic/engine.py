@@ -2925,6 +2925,20 @@ def rebalance_parked(sf: SymFrontier, fork_block: int = 0,
     ), len(src_idx)
 
 
+def pool_stuck(active, fork_req, running) -> bool:
+    """Is the lane pool at a full frontier's fixpoint? From host copies of
+    ``base.active``, ``fork_req`` and ``base.running``: some lane is
+    parked on a fork it could not place, every lane is taken, and no
+    running lane is anything but parked. No superstep moves such a
+    frontier; only a feasibility sweep that kills a lane can."""
+    import numpy as np
+
+    active = np.asarray(active)
+    parked = np.asarray(fork_req) & active
+    return bool(parked.any() and active.all()
+                and not (np.asarray(running) & active & ~parked).any())
+
+
 def relieve_starved(sf: SymFrontier, n_contracts: int,
                     active, fork_req, running, home):
     """Break the fixpoint of a full frontier for the contracts it starves.
@@ -2954,11 +2968,10 @@ def relieve_starved(sf: SymFrontier, n_contracts: int,
     untouched. Returns ``(sf, n_evicted)``."""
     import numpy as np
 
+    if not pool_stuck(active, fork_req, running):
+        return sf, 0    # a lane is free, or a lane still moves
     active = np.asarray(active)
     parked = np.asarray(fork_req) & active
-    if (not parked.any() or not active.all()
-            or (np.asarray(running) & active & ~parked).any()):
-        return sf, 0    # a lane is free, or a lane still moves
     P = active.shape[0]
     share = P // n_contracts
     contract = np.asarray(home) % n_contracts   # creation | runtime image

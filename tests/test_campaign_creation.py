@@ -158,6 +158,117 @@ def test_small_contract_beside_hogs_is_served_at_the_pools_fixpoint(
     assert res.dropped_forks >= evicted
 
 
+@pytest.fixture(scope="module")
+def pools():
+    """Two batches at the shape the test above compiles, each run with
+    the rule that ends a transaction at its pool's fixpoint and with
+    its predicate patched to ``False`` from here (the product has no
+    switch): ``hogs`` fills its pool in the second message call and
+    nobody is under the floor; in ``relieved`` the wallet is."""
+    from mythril_tpu.analysis import symbolic
+    from mythril_tpu.obs import metrics as obs_metrics
+    from mythril_tpu.obs import trace as obs_trace
+
+    hogs = [(f"hog{i}", _hog(12, i), CTOR_OWNER) for i in range(4)]
+    batches = {"hogs": hogs,
+               "relieved": hogs[:3] + [("wallet", WALLET, CTOR_OWNER)]}
+    ran = {}
+
+    def run(name, rule):
+        before = obs_metrics.REGISTRY.snapshot()["counters"]
+        tracer = obs_trace.configure(buffer=True)
+        with pytest.MonkeyPatch.context() as mp:
+            if not rule:
+                mp.setattr(symbolic, "pool_stuck", lambda *a: False)
+            try:
+                res = CorpusCampaign(
+                    batches[name], batch_size=4, lanes_per_contract=16,
+                    limits=TEST_LIMITS, spec=CONCRETE, max_steps=128,
+                    transaction_count=2, modules=MODULES).run()
+                spans = [s for s in tracer.drain_buffer()
+                         if s.get("kind") == "span"
+                         and s["name"] == "superstep"]
+            finally:
+                obs_trace.close()
+        after = obs_metrics.REGISTRY.snapshot()["counters"]
+        return {
+            "report": (found(res), res.paths_total, res.dropped_forks),
+            "counters": {k: v - before.get(k, 0) for k, v in after.items()
+                         if k.startswith(("engine_", "evicted_"))
+                         and v != before.get(k, 0)},
+            "calls": [(s["tx"], s["steps"], s["steps_run"],
+                       bool(s.get("drain"))) for s in spans],
+            "spans": spans}
+
+    def pool(name, rule):
+        if (name, rule) not in ran:     # a worker runs what its cases read
+            ran[name, rule] = run(name, rule)
+        return ran[name, rule]
+
+    return pool
+
+
+def _per_tx(counters, family):
+    return {k: v for k, v in counters.items() if k.startswith(family)}
+
+
+@pytest.mark.parametrize("case", [
+    "the_reports_are_the_rule_off_runs", "the_calls_that_go",
+    "counters_and_span_attributes", "a_transaction_never_stuck",
+    "a_pool_that_is_relieved"])
+def test_transaction_ends_where_its_pool_is_proven_stuck(pools, case):
+    """Three 12-function hogs and a fourth fill the pool of 64 lanes in
+    the first chunk of the second message call: every lane parks. The
+    second chunk is the witness, and the transaction ends there: the
+    four drain rounds, which hand the frontier back unchanged, do not
+    run. Nothing any report reads moves."""
+    name = "relieved" if case == "a_pool_that_is_relieved" else "hogs"
+    on, off = pools(name, True), pools(name, False)
+    if case == "the_reports_are_the_rule_off_runs":
+        assert on["report"] == off["report"]
+        assert on["report"][2] > 0      # forks were lost: a full pool
+        for family in ("engine_paths_total", "engine_dropped_forks_total"):
+            assert _per_tx(on["counters"], family) == _per_tx(
+                off["counters"], family) != {}
+    elif case == "the_calls_that_go":
+        last = [c for c in off["calls"] if c[0] == 2]
+        assert last == [(2, 64, 64, False)] * 2 + [(2, 64, 64, True)] * 4
+        assert [c for c in on["calls"] if c[0] == 2] == last[:2]
+        assert off["spans"][-1]["ended"] == "budget"
+        assert on["counters"]["engine_supersteps_total"] == (
+            off["counters"]["engine_supersteps_total"] - 4 * 64)
+    elif case == "counters_and_span_attributes":
+        assert on["counters"]['engine_fixpoint_ends_total{tx="2"}'] == 1
+        assert on["counters"]['engine_calls_skipped_total{tx="2"}'] == 4
+        assert not _per_tx(off["counters"], "engine_fixpoint_ends_total")
+        assert not _per_tx(off["counters"], "engine_calls_skipped_total")
+        last = [s for s in on["spans"] if s["tx"] == 2]
+        assert [s["stuck"] for s in last] == [True, True]
+        assert "ended" not in last[0] and "skipped" not in last[0]
+        assert last[1]["ended"] == "fixpoint" and last[1]["skipped"] == 4
+        assert not any(s["stuck"] for s in off["spans"])
+    elif case == "a_transaction_never_stuck":
+        # the constructor and the first message call end at quiescence
+        for run in (on, off):
+            early = [s for s in run["spans"] if s["tx"] < 2]
+            assert [c for c in run["calls"] if c[0] < 2] == [
+                (0, 64, 6, False), (1, 64, 64, False), (1, 64, 8, False)]
+            assert not any(s["stuck"] for s in early)
+            assert [s.get("ended") for s in early] == [
+                "quiescent", None, "quiescent"]
+    else:
+        # the wallet is under its floor at the first seam of the second
+        # call: ``relieve_starved`` evicts there, so that seam is not
+        # stuck, and every call runs that ran before
+        assert on["report"] == off["report"]
+        assert on["report"][0] == [("wallet", "106")]
+        assert on["calls"] == off["calls"]
+        assert on["counters"] == off["counters"]
+        assert on["counters"]["evicted_lanes_total"] > 0
+        assert not _per_tx(on["counters"], "engine_fixpoint_ends_total")
+        assert on["spans"][-1]["ended"] == "quiescent"
+
+
 def test_deploy_epilogue_longer_than_memory_does_not_trap():
     """solc's epilogue copies the whole runtime code to memory and
     returns it; a runtime longer than the memory model must not cost the

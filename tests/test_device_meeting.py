@@ -394,9 +394,15 @@ def test_superstep_spans_end_with_the_device_and_count_what_ran(spill):
     reads = [r["what"] for r in spans_of(recs, "device_fetch")
              if r["what"].startswith("visited,steps_total")]
     assert len(reads) == len(steps)
+    # with spill the seam's masks and the two run totals that prove a
+    # stuck pool (``_PoolWatch``) ride it too: no read of their own
     assert reads[0] == ("visited,steps_total,base.active,fork_req,"
-                        "base.running,base.home_contract" if spill
+                        "base.running,base.home_contract,killed_total,"
+                        "dropped_total" if spill
                         else "visited,steps_total")
+    assert [sp["stuck"] for sp in steps] == [False] * len(steps)
+    assert [sp.get("ended") for sp in steps] == (
+        [None] * (len(steps) - 1) + ["quiescent"])
     import jax
 
     assert ctr["gauges"]["frontier_bytes"] == sum(
@@ -536,6 +542,45 @@ def test_trace_report_counts_the_kernel_reads_of_each_host_phase(
     assert ("dispatches on the device" in bi0) == bool(kernel_reads)
     assert "1 fetches, 0 kernel: reads)" in bi1
     assert "dispatches on the device" not in bi1
+
+
+def test_trace_report_says_how_each_transaction_ended(tmp_path):
+    """Two batches: the second message call ends at its pool's fixpoint
+    after a witness call; the first runs its budget."""
+    import importlib.util
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "trace_report", os.path.join(root, "tools", "trace_report.py"))
+    report = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(report)
+
+    def call(mono, tx, stuck, **attrs):
+        return dict(schema=1, kind="span", name="superstep", t=0.0,
+                    mono=mono, dur=2.0, tid=9, tx=tx, tx_kind="message",
+                    steps=64, steps_run=64, cold=False, stuck=stuck,
+                    **attrs)
+
+    recs = []
+    for t0 in (0.0, 100.0):
+        recs += [call(t0 + 1, 0, False), call(t0 + 4, 0, False,
+                                              ended="budget"),
+                 call(t0 + 10, 1, True),
+                 call(t0 + 13, 1, True, ended="fixpoint", skipped=6)]
+    # emitted once their seams have spoken: not in time order
+    recs.reverse()
+    path = tmp_path / "t.jsonl"
+    path.write_text("".join(json.dumps(r) + "\n" for r in recs))
+    text = report.report(*report.load_trace(str(path))).splitlines()
+    head = text.index("== transactions (tx, tx_kind) ==")
+    cols = text[head + 1].split()
+    rows = [dict(zip(cols, ln.split(None, len(cols) - 1)))
+            for ln in text[head + 2:head + 4]]
+    assert [(r["tx"], r["calls"], r["skipped"], r["spun"], r["ended"])
+            for r in rows] == [("0", "4", "0", "0", "budget x2"),
+                               ("1", "4", "12", "2", "fixpoint x2")]
 
 
 # --- checkpoints written before the counter ----------------------------------

@@ -245,3 +245,104 @@ def test_full_frontier_fixpoint_is_broken_for_the_starved_only(case):
     else:
         assert k == 0
         assert (act == active).all() and (req == parked).all()
+
+
+# --- the proof that a full frontier is stuck for good (``_PoolWatch``) -------
+
+def _seams(case):
+    """Two seams of one transaction with a 64-superstep call between
+    them, as ``(active, fork_req, running, killed, dropped, idle)``."""
+    full = np.ones(P, dtype=bool)
+    parked = np.zeros(P, dtype=bool)
+    parked[[0, 3, 4, 12, 13, 14, 20, 25, 26]] = True
+    first = [full, parked, parked.copy(), 7, 2, True]
+    second = [full.copy(), parked.copy(), parked.copy(), 7, 2, True]
+    ran = 64
+    if case == "a_lane_is_free":
+        second[0][31] = False
+    elif case == "a_lane_still_moves":
+        second[2][30] = True
+    elif case == "the_seam_before_was_not_stuck":
+        first[2] = parked.copy()
+        first[2][30] = True
+    elif case == "the_first_seam_evicted":
+        first[5] = False        # relieve_starved gave lanes up there
+    elif case == "this_seam_moved_lanes":
+        second[5] = False
+    elif case == "a_sweep_killed":
+        second[3] = 8           # and a parked fork took the freed lane
+    elif case == "a_fork_was_dropped":
+        second[4] = 3
+    elif case == "another_lane_is_parked":
+        # a halted lane went and a parked fork took its slot, parked
+        # itself: ``active`` as before, ``fork_req`` not
+        second[1][5] = second[2][5] = True
+    elif case == "the_call_held_no_sweep":
+        ran = 4
+    return first, ran, second
+
+
+@pytest.mark.parametrize("case", [
+    "stuck_twice_and_equal", "a_lane_is_free", "a_lane_still_moves",
+    "the_seam_before_was_not_stuck", "the_first_seam_evicted",
+    "this_seam_moved_lanes", "a_sweep_killed", "a_fork_was_dropped",
+    "another_lane_is_parked", "the_call_held_no_sweep"])
+def test_pool_is_proven_stuck_only_by_stuck_stuck_and_equal(case):
+    from mythril_tpu.analysis.symbolic import _PoolWatch
+
+    first, ran, second = _seams(case)
+    watch = _PoolWatch(sweep_every=8)
+    stuck, proven = watch.seam(*first[:5], idle=first[5])
+    assert not proven           # one seam alone proves nothing
+    assert stuck is (case not in ("the_seam_before_was_not_stuck",
+                                  "the_first_seam_evicted"))
+    watch.ran(ran)
+    stuck, proven = watch.seam(*second[:5], idle=second[5])
+    assert proven is (case == "stuck_twice_and_equal")
+    assert stuck is (case not in ("a_lane_is_free", "a_lane_still_moves",
+                                  "this_seam_moved_lanes"))
+    # a second look at a seam with no call in between is no witness
+    # (the drain's first fetch after the last chunk's seam)
+    assert watch.seam(*second[:5], idle=second[5]) == (stuck, False)
+    # ... and the next whole call from it is
+    watch.ran(64)
+    assert watch.seam(*second[:5], idle=second[5]) == (stuck, stuck)
+
+
+def test_a_stuck_pools_next_chunk_hands_back_the_frontier():
+    """The proof itself, on the compiled engine (the program ``_run(0)``
+    compiles): every lane is taken and parks on the first branch; the
+    call after the first stuck seam is the witness; from there one more
+    chunk changes no leaf but the two step counters."""
+    from mythril_tpu.analysis.symbolic import _PoolWatch
+    from mythril_tpu.symbolic.engine import pool_stuck
+
+    img = ContractImage.from_bytecode(CODE, L.max_code)
+    corpus = Corpus.from_images([img])
+    sf = make_sym_frontier(P, L, active=np.ones(P, dtype=bool))
+    env = make_env(P)
+
+    def chunk(sf):
+        return sym_run(sf, env, corpus, SymSpec(), L, max_steps=64,
+                       fork_block=B, defer_starved=True, migrate_every=0)
+
+    def seam(sf):
+        return (np.asarray(sf.base.active), np.asarray(sf.fork_req),
+                np.asarray(sf.base.running), int(sf.killed_total),
+                int(sf.dropped_total))
+
+    watch = _PoolWatch(L.propagate_every)
+    sf = chunk(sf)
+    assert pool_stuck(*seam(sf)[:3])
+    assert watch.seam(*seam(sf), idle=True) == (True, False)
+    witness = chunk(sf)
+    watch.ran(64)
+    assert watch.seam(*seam(witness), idle=True) == (True, True)
+    after = chunk(witness)
+    moved = [jax.tree_util.keystr(path)
+             for (path, a), b in zip(
+                 jax.tree_util.tree_leaves_with_path(witness),
+                 jax.tree.leaves(after))
+             if not np.array_equal(np.asarray(a), np.asarray(b))]
+    assert sorted(moved) == [".base.n_steps", ".steps_total"]
+    assert int(after.steps_total) == int(witness.steps_total) + 64

@@ -154,19 +154,32 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
         out.append("(no spans)")
 
     # 1b. the transactions of a batch: which kind each was, what its
-    # sym_run calls cost, the paths that ended it and the forks it lost
+    # sym_run calls cost, the paths that ended it and the forks it lost;
+    # the calls the pool's fixpoint saved (``skipped``), those that
+    # still started from a stuck seam and could only hand their
+    # frontier back (``spun``: the witnesses), and what ended it
     by_tx: Dict[tuple, Dict] = {}
-    for s in spans:
+    before = None       # the superstep span before, if of this transaction
+    for s in sorted(spans, key=lambda s: s["mono"]):
         a = s["args"]
         if s["name"] not in ("superstep", "harvest", "tx_seam") \
                 or "tx_kind" not in a:
             continue
         row = by_tx.setdefault((a.get("tx"), a["tx_kind"]), {
             "calls": 0, "sec": 0.0, "paths": 0, "dropped": 0,
-            "carried": 0, "seam": 0.0})
+            "carried": 0, "seam": 0.0, "skipped": 0, "spun": 0,
+            "spun_sec": 0.0, "ended": {}})
         if s["name"] == "superstep":
             row["calls"] += 1
             row["sec"] += s["dur"]
+            row["skipped"] += int(a.get("skipped", 0))
+            if before is not None and before.get("stuck"):
+                row["spun"] += 1
+                row["spun_sec"] += s["dur"]
+            if "ended" in a:
+                row["ended"][a["ended"]] = row["ended"].get(
+                    a["ended"], 0) + 1
+            before = None if "ended" in a else a
         elif s["name"] == "harvest":
             row["paths"] += int(a.get("paths", 0))
             row["dropped"] += int(a.get("dropped", 0))
@@ -176,16 +189,21 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
     if by_tx:
         out.append("")
         out.append("== transactions (tx, tx_kind) ==")
-        out.append(f"{'tx':>3} {'kind':<9}{'calls':>6}{'sym_run':>10}"
+        out.append(f"{'tx':>3} {'kind':<9}{'calls':>6}{'skipped':>8}"
+                   f"{'spun':>5}{'spun_s':>10}{'sym_run':>10}"
                    f"{'paths':>8}{'dropped':>9}{'admitted':>10}"
-                   f"{'carried':>9}{'seam':>10}")
+                   f"{'carried':>9}{'seam':>10}  ended")
         for (tx, kind), r in sorted(by_tx.items(), key=lambda kv: str(kv[0])):
             tot = r["paths"] + r["dropped"]
+            ended = ", ".join(f"{k} x{n}" for k, n in sorted(
+                r["ended"].items(), key=lambda kv: -kv[1]))
             out.append(
-                f"{tx!s:>3} {kind:<9}{r['calls']:>6}{_fmt_s(r['sec']):>10}"
+                f"{tx!s:>3} {kind:<9}{r['calls']:>6}{r['skipped']:>8}"
+                f"{r['spun']:>5}{_fmt_s(r['spun_sec']):>10}"
+                f"{_fmt_s(r['sec']):>10}"
                 f"{r['paths']:>8}{r['dropped']:>9}"
                 f"{(100.0 * r['paths'] / tot if tot else 100.0):>9.1f}%"
-                f"{r['carried']:>9}{_fmt_s(r['seam']):>10}")
+                f"{r['carried']:>9}{_fmt_s(r['seam']):>10}  {ended}")
 
     # 2. batch stall table: slowest batches, with their outcome
     status_by_bi: Dict[int, str] = {}
