@@ -29,7 +29,7 @@ from ..smt.solver import solve_tape
 from ..smt.tape import HostNode, HostTape, extract_tape, intern_node
 from ..symbolic import SymSpec, between_txs, make_sym_frontier, sym_run
 from ..symbolic.engine import (pool_stuck, rebalance_parked,
-                               relieve_starved, sym_run_donated)
+                               relieve_starved)
 
 log = logging.getLogger(__name__)
 
@@ -330,10 +330,7 @@ class SymExecWrapper:
         dyn_loader=None,
         dynld_limit: int = 4,
         warm_shapes: Optional[set] = None,
-        fork_impl: Optional[str] = None,
-        unroll: Optional[int] = None,
     ):
-        import os as _os
         import time as _time
 
         import jax
@@ -382,26 +379,6 @@ class SymExecWrapper:
         # other blocks' free slots between chunks
         self.spill = spill
         self.fork_block = fork_block
-        # superstep restructure knobs (docs/performance.md "Scaling
-        # cliff"): fork slot-mapping machinery + supersteps rolled per
-        # while-loop body. Env overrides exist so campaigns / benches
-        # can A/B without plumbing a parameter through every layer.
-        self.fork_impl = (fork_impl
-                          or _os.environ.get("MYTHRIL_FORK_IMPL")
-                          or "packed")
-        self.unroll = int(unroll if unroll is not None
-                          else _os.environ.get("MYTHRIL_SYM_UNROLL")
-                          or 1)
-        # buffer donation on the chunk loop's sym_run calls: the loop
-        # consumes each input frontier, so the engine may alias input
-        # buffers into outputs (halves peak frontier memory on
-        # accelerators). OPT-IN (MYTHRIL_DONATE=1): between_txs and the
-        # plugin/checkpoint seams run EAGERLY, so an untouched leaf of a
-        # donated frontier can still be shared with a kept
-        # AnalysisContext — only enable when no plugin retains frontier
-        # references across chunks. CPU ignores donation entirely.
-        self._donate = (_os.environ.get("MYTHRIL_DONATE") == "1"
-                        and jax.default_backend() != "cpu")
         # in-jit cross-block migration (SURVEY §5.8 ICI tier): only
         # meaningful when fork compaction is blocked (fork_block > 0) and
         # spill parks starved lanes; a no-op otherwise (and inside
@@ -525,7 +502,6 @@ class SymExecWrapper:
         def walk(sf):
             import time as _time
 
-            runner = sym_run_donated if self._donate else sym_run
             warm_shapes: set = getattr(self, "_warm_chunk_shapes", set())
             self._warm_chunk_shapes = warm_shapes
             watch = _PoolWatch(limits.propagate_every)
@@ -581,12 +557,11 @@ class SymExecWrapper:
                 with obs_trace.timer("superstep", tx=self._cur_tx,
                                      tx_kind=self._tx_kind, steps=n,
                                      cold=cold, stuck=False, **attrs) as sp:
-                    sf, vis = runner(
+                    sf, vis = sym_run(
                         sf, env, self.corpus, spec, limits,
                         max_steps=n, track_coverage=True,
                         fork_policy=self.fork_policy,
                         fork_block=self.fork_block,
-                        fork_impl=self.fork_impl, unroll=self.unroll,
                         **(run_kw or {}))
                     enqueue_s = sp.elapsed
                     got = fetch(

@@ -4,9 +4,9 @@ demote-and-repromote failover ladder built on top of it.
 Before this module, platform knowledge was smeared across the tree as
 ``JAX_PLATFORMS=cpu`` literals: the startup probe's fallback pinned the
 process to CPU (resilience.py), the OOM ladder's terminal rung was the
-string ``"cpu"`` (config.py), bench re-ran itself under a hard-coded
-CPU env (bench.py) — and nothing ever *lifted* any of those pins, so a
-transient TPU wedge demoted the process for its whole lifetime.
+string ``"cpu"`` (config.py) — and nothing ever *lifted* any of those
+pins, so a transient TPU wedge demoted the process for its whole
+lifetime.
 
 This module replaces all of that with two pieces:
 

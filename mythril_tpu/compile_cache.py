@@ -2,8 +2,8 @@
 
 Every process that owns an engine calls :func:`enable` before its first
 compile: in-process ``analyze``, the engine worker, the cache-probe
-child, ``bench.py`` and the tests. Where ``JAX_COMPILATION_CACHE_DIR``
-is set JAX reads it itself and nothing here sets a directory; where it
+child and the tests. Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX
+reads it itself and nothing here sets a directory; where it
 is not, the cache is ``<checkout>/.jax_cache`` — a fixed path, because
 the path is part of the cache key and a directory that moves never
 hits. A supervisor never calls this: it has no engine to compile.

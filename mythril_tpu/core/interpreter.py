@@ -110,36 +110,15 @@ def _peek(f: Frontier, i) -> jnp.ndarray:
     return jnp.take_along_axis(f.stack, idx[:, None, None].astype(I32), axis=1)[:, 0]
 
 
-# tools/scaling_report.py forces a specific write strategy when TRACING
-# cost models on a backend that is not the deployment target (attributing the
-# TPU-path op counts must be possible from a CPU box). None = backend-adaptive (the only mode used at run
-# time); "scatter"/"dense" pin the strategy for the next trace. Set via
-# force_write_mode() around a jaxpr trace, never around real execution.
-_WRITE_MODE_OVERRIDE = None
-
-
-def force_write_mode(mode):
-    """Pin (``"scatter"``/``"dense"``) or restore (``None``) the slot-
-    write strategy :func:`_use_scatter` reports. Trace-time analysis
-    only — returns the previous value so callers can restore it."""
-    global _WRITE_MODE_OVERRIDE
-    prev = _WRITE_MODE_OVERRIDE
-    if mode not in (None, "scatter", "dense"):
-        raise ValueError(f"unknown write mode: {mode!r}")
-    _WRITE_MODE_OVERRIDE = mode
-    return prev
-
-
 def _use_scatter() -> bool:
-    """Slot-write strategy, resolved once at trace time (cf.
-    ``default_cond_classes``): XLA:CPU lowers per-lane dynamic scatters
-    well and the O(P) index write beats touching the whole array; TPU
-    lowers them as serialized updates — measured on the SAME chip, the
-    round-3 scatter rewrite took the concrete interpreter from 1.05M to
-    0.149M lane-steps/s (7x). Dense one-hot compare-selects keep every
-    write a fusable vector op on TPU."""
-    if _WRITE_MODE_OVERRIDE is not None:
-        return _WRITE_MODE_OVERRIDE == "scatter"
+    """Slot-write strategy, resolved once at trace time: XLA:CPU lowers
+    per-lane dynamic scatters well and the O(P) index write beats
+    touching the whole array; TPU lowers them as serialized updates —
+    measured on the SAME chip, the round-3 scatter rewrite took the
+    concrete interpreter from 1.05M to 0.149M lane-steps/s (7x). Dense
+    one-hot compare-selects keep every write a fusable vector op on TPU.
+    To trace the other backend's choice, patch this function (as
+    tests/test_write_paths.py and tools/scaling_report.py do)."""
     return jax.default_backend() == "cpu"
 
 
@@ -808,26 +787,11 @@ def prologue(f: Frontier, corpus: Corpus, berlin: bool = False):
     return f, op, run, f.pc
 
 
-# Dispatch granularity: which classes hide behind `lax.cond` so a
-# superstep only pays for classes actually present in the frontier.
-#
-# MEASURED on the real chip (tools/profile_superstep.py via bench.py,
-# P=4096, ERC-20 workload, round 4):
-#     all_cond   3.88 ms/superstep   <- every class gated
-#     split      23.06 ms/superstep  <- cheap classes unconditional
-#     none_cond  763 ms/superstep    <- everything unconditional
-# The earlier hypothesis that TPU conds act as fusion barriers worth
-# avoiding was WRONG on hardware — an un-taken cond skips its handler's
-# whole-frontier reads/writes, which dominates any fusion benefit; the
-# 256-step DIV/EXP fori_loops make ungated dispatch catastrophic. On
-# XLA:CPU gating everything also wins (5.3 vs 9.0 ms/superstep at
-# P=1024). So: gate EVERYTHING, on every backend. COND_CLASSES is kept
-# for the profiler's A/B variants.
-COND_CLASSES = (CLS_MUL, CLS_DIVMOD, CLS_MODARITH, CLS_EXP, CLS_SHA3, CLS_COPY)
-
-
-def default_cond_classes() -> tuple:
-    return tuple(range(N_CLASSES))
+# Dispatch granularity: EVERY class hides behind a `lax.cond`, so a
+# superstep pays only for the classes present in the frontier. Measured
+# on the chip (round 4, P=4096, ERC-20 mix): all gated 3.88 ms/superstep,
+# cheap classes ungated 23.06 ms, nothing gated 763 ms — an un-taken
+# cond skips its handler's whole-frontier reads and writes.
 
 
 # Fields each class handler may WRITE. A gated class's `lax.cond`
@@ -969,7 +933,7 @@ def narrow_cond(pred, fn, obj, declared, aux_defaults=None):
 
 
 def dispatch(f: Frontier, env: Env, corpus: Corpus, op, run, old_pc,
-             skip=None, cond_classes=None) -> Frontier:
+             skip=None) -> Frontier:
     """Run the per-class handlers over the frontier. ``skip`` masks lanes
     out of concrete handling (the symbolic engine claims them).
 
@@ -978,8 +942,6 @@ def dispatch(f: Frontier, env: Env, corpus: Corpus, op, run, old_pc,
     (narrow) cond boundary, and one shared ``_set_slot`` pass lands every
     class's result at ``sp - sin + sout - 1`` (plus the SWAP second
     port). ``sp`` advances centrally from the arity tables."""
-    if cond_classes is None:
-        cond_classes = default_cond_classes()
     cls = _J_CLASS[op]
     if skip is not None:
         run = run & ~skip
@@ -1011,48 +973,33 @@ def dispatch(f: Frontier, env: Env, corpus: Corpus, op, run, old_pc,
         mask = run & (cls == cid)
         names = WRITE_FIELDS[cid]
         akeys = AUX_KEYS[cid]
-        if cid in cond_classes:
 
-            def _run_handler(fr=f, h=handler, mk=mask, names=names,
-                             akeys=akeys):
-                fr2, aux = h(fr, env, corpus, op, mk, old_pc)
-                for fld in all_fields:
-                    if fld not in names and \
-                            getattr(fr2, fld) is not getattr(fr, fld):
-                        raise AssertionError(
-                            f"{h.__name__} wrote undeclared field {fld!r}; "
-                            f"add it to WRITE_FIELDS[{cid}]")
-                for k in aux:
-                    if k not in akeys:
-                        raise AssertionError(
-                            f"{h.__name__} returned undeclared aux {k!r}; "
-                            f"add it to AUX_KEYS[{cid}]")
-                return tuple(getattr(fr2, n) for n in names) + tuple(
-                    aux.get(k, aux_defaults[k]) for k in akeys)
-
-            outs = lax.cond(
-                present[cid],
-                _run_handler,
-                lambda fr=f, names=names, akeys=akeys: tuple(
-                    getattr(fr, n) for n in names) + tuple(
-                    aux_defaults[k] for k in akeys),
-            )
-            f = f.replace(**dict(zip(names, outs[:len(names)])))
-            aux = dict(zip(akeys, outs[len(names):]))
-        else:
-            f2, aux = handler(f, env, corpus, op, mask, old_pc)
+        def _run_handler(fr=f, h=handler, mk=mask, names=names,
+                         akeys=akeys):
+            fr2, aux = h(fr, env, corpus, op, mk, old_pc)
             for fld in all_fields:
                 if fld not in names and \
-                        getattr(f2, fld) is not getattr(f, fld):
+                        getattr(fr2, fld) is not getattr(fr, fld):
                     raise AssertionError(
-                        f"{handler.__name__} wrote undeclared field {fld!r}; "
+                        f"{h.__name__} wrote undeclared field {fld!r}; "
                         f"add it to WRITE_FIELDS[{cid}]")
             for k in aux:
                 if k not in akeys:
                     raise AssertionError(
-                        f"{handler.__name__} returned undeclared aux {k!r}; "
+                        f"{h.__name__} returned undeclared aux {k!r}; "
                         f"add it to AUX_KEYS[{cid}]")
-            f = f2
+            return tuple(getattr(fr2, n) for n in names) + tuple(
+                aux.get(k, aux_defaults[k]) for k in akeys)
+
+        outs = lax.cond(
+            present[cid],
+            _run_handler,
+            lambda fr=f, names=names, akeys=akeys: tuple(
+                getattr(fr, n) for n in names) + tuple(
+                aux_defaults[k] for k in akeys),
+        )
+        f = f.replace(**dict(zip(names, outs[:len(names)])))
+        aux = dict(zip(akeys, outs[len(names):]))
         if "r" in akeys:
             val = jnp.where(mask[:, None], aux.get("r", zero_word), val)
         if "ok" in akeys:

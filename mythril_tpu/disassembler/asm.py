@@ -3,7 +3,7 @@
 Counterpart of the reference's ``mythril/disassembler/asm.py`` (⚠unv,
 SURVEY.md §2 "Disassembler") going the other direction: we need to *author*
 representative bytecode in-repo because the image carries no ``solc``
-binary. Used by ``bench.py``, sample contracts, and tests.
+binary. Used by the corpus generators, sample contracts, and tests.
 
 Token forms accepted by :func:`assemble`:
 

@@ -29,12 +29,11 @@ per-process token so streams from resumed/merged sessions stay sortable
 
 ``timer()`` is the always-measuring variant: it returns a real
 :class:`Span` whose ``elapsed`` property works whether or not tracing is
-enabled (emitting only when it is). bench.py and the profilers use it in
-place of their former ad-hoc ``perf_counter``/``monotonic`` pairs, so
-one mechanism both measures and (when asked) records.
+enabled (emitting only when it is), so one mechanism both measures and
+(when asked) records.
 
 Import cost is stdlib-only — no jax, no engine — so backend-free
-front-ends (``campaign-merge``, bench's pre-probe phase) can load it.
+front-ends (``campaign-merge``, a supervisor) can load it.
 """
 
 from __future__ import annotations

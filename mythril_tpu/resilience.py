@@ -17,14 +17,13 @@ device faults):
 - :func:`run_with_watchdog` — run a callable under a hard wall-clock
   deadline in a worker thread; expiry raises :class:`BatchTimeout`
   instead of stalling the supervisor (the stuck thread is abandoned,
-  exactly like bench.py abandons an unkillable D-state probe child).
+  as an unkillable D-state probe child is).
 - :class:`FaultInjector` — deterministic, env/constructor-driven fault
   injection (hang / raise / device-lost / kill at a batch index or on a
   contract name) so every recovery path is testable on CPU.
 - :class:`BackendManager` — subprocess-isolated backend probe with a
   timeout, bounded re-init attempts with backoff, and an explicit CPU
   fallback, all recorded as structured events for the campaign report.
-  Generalizes ``bench.py``'s ad-hoc ``_probe_backend``.
 
 IMPORTANT: nothing in this module may touch a JAX backend at import or
 probe time — the whole point is to stay alive when the backend is the
@@ -436,8 +435,7 @@ class BackendManager:
                             "t": round(time.time(), 3)})
 
     def _subprocess_probe(self, timeout_s: float) -> Tuple[bool, str]:
-        """One isolated backend init (lifted from bench.py's round-3
-        hardening). Returns (ok, diagnosis)."""
+        """One isolated backend init. Returns (ok, diagnosis)."""
         import tempfile
 
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

@@ -2510,8 +2510,7 @@ def between_txs(sf: SymFrontier, require_mutation: bool = True,
 
 
 @jax.named_scope("plan_fork_map")
-def plan_fork_map(req2, free2, key, fork_policy: str = "fifo",
-                  fork_impl: str = "packed"):
+def plan_fork_map(req2, free2, key, fork_policy: str = "fifo"):
     """The fork source→destination mapping machinery, factored out of
     :func:`expand_forks` so tools/scaling_report.py can trace and cost
     it in isolation (the whole-frontier copy around it is linear in P
@@ -2521,23 +2520,25 @@ def plan_fork_map(req2, free2, key, fork_policy: str = "fifo",
     mask, and the policy key (ignored for fifo). Returns
     ``(src2 [G, B], is_copy [P], slot [P])`` — per-destination source
     index, the copy mask, and the per-source admission sentinel
-    (``slot == P`` ⇔ starved; intermediate values are only meaningful
-    on the legacy path where they are real slot ids).
+    (``slot == P`` ⇔ starved; any other value means admitted).
+
+    Scatter-free on every backend: one sort gives the admission order,
+    and the map is built destination-major (a cumsum over the free mask
+    plus one gather), so the cost is linear in P; tests/fork_map_ref.py
+    holds the source-major reference it is compared with.
     """
     G, B = req2.shape
     P = G * B
     loc = jnp.arange(B, dtype=I32)[None, :]
-    gidx = jnp.broadcast_to(jnp.arange(G, dtype=I32)[:, None], (G, B))
     n_free = jnp.sum(free2.astype(I32), axis=1, keepdims=True)
     if fork_policy == "fifo":
         rank = jnp.cumsum(req2.astype(I32), axis=1) - req2.astype(I32)
-        order = None
-    elif fork_impl == "packed":
+    else:
         # pack (key, lane) into ONE int32 composite: composites are
-        # unique (the lane index breaks key ties exactly like the
-        # legacy stable argsort), so a single sort gives the
-        # admission order and a searchsorted over the sorted
-        # composites gives each lane's rank — no second argsort.
+        # unique (the lane index breaks key ties exactly like a stable
+        # argsort), so a single sort gives the admission order and a
+        # searchsorted over the sorted composites gives each lane's
+        # rank — no second argsort.
         # The key budget shrinks when B is huge so the composite
         # stays inside int32; policy keys are ≤ 16 bits by
         # construction (weighted caps at 65535, random at 0x7FFF,
@@ -2550,66 +2551,30 @@ def plan_fork_map(req2, free2, key, fork_policy: str = "fifo",
         skey = jnp.sort(ukey, axis=1)
         order = (skey % B).astype(I32)
         rank = jax.vmap(jnp.searchsorted)(skey, ukey).astype(I32)
-    else:
-        key = jnp.where(req2, key, 1 << 20)  # non-requesters sort last
-        order = jnp.argsort(key, axis=1, stable=True).astype(I32)
-        # rank = inverse permutation of order; argsort(order) IS that
-        # inverse, and sorts lower on TPU than a [G, B] scatter
-        rank = jnp.argsort(order, axis=1).astype(I32)
     # beam: admit at most B//4 forks per block per superstep (shallowest
     # first via the key above) — the frontier analog of a beam width
     # (reference: beam.py ⚠unv); the rest defer/drop by mode
     n_adm = (jnp.minimum(n_free, max(1, B // 4))
              if fork_policy == "beam" else n_free)
-    if fork_impl == "packed":
-        # destination-major mapping (scatter-free, compare-free): the
-        # free slot with free-rank t receives the t-th admitted request
-        # — precisely the pairing the legacy source-major formulation
-        # produced via free_ids[rank] — so a cumsum over the free mask
-        # plus one gather of `order` replaces the [G, B] scatter (CPU
-        # legacy) / [G, B, B] one-hot compare (TPU legacy, the O(P²)
-        # superlinear term tools/scaling_report.py names).
-        if fork_policy == "fifo":
-            # requesters in lane order; B pads the tail (never gathered:
-            # free_rank < n_admit <= n_req keeps the index in-range)
-            order = jnp.sort(jnp.where(req2, loc, B), axis=1).astype(I32)
-        n_req = jnp.sum(req2.astype(I32), axis=1, keepdims=True)
-        n_admit = jnp.minimum(n_adm, n_req)
-        free_rank = jnp.cumsum(free2.astype(I32), axis=1) - free2.astype(I32)
-        is_copy2 = free2 & (free_rank < n_admit)
-        src_i = jnp.take_along_axis(
-            order, jnp.clip(free_rank, 0, B - 1), axis=1)
-        src2 = jnp.where(is_copy2, src_i, jnp.broadcast_to(loc, (G, B)))
-        is_copy = is_copy2.reshape(P)
-        # per-source admission bit (drop/defer accounting): admitted
-        # requests are exactly those ranked inside the admission window
-        slot = jnp.where(req2 & (rank < n_adm), 0, P).reshape(P)
-    elif fork_impl == "legacy":
-        free_ids = jnp.sort(jnp.where(free2, loc, B), axis=1)
-        slot2 = jnp.where(
-            req2 & (rank < n_adm),
-            jnp.take_along_axis(free_ids, jnp.clip(rank, 0, B - 1), axis=1),
-            B,
-        )  # local free-slot index per forking lane; B = dropped
-        if ci._use_scatter():
-            src2 = jnp.broadcast_to(loc, (G, B)).at[gidx, slot2].set(
-                jnp.broadcast_to(loc, (G, B)), mode="drop")
-            is_copy = jnp.zeros((G, B), dtype=bool).at[gidx, slot2].set(
-                True, mode="drop").reshape(P)
-        else:
-            # dense inverse-map: dst j is a copy iff some source i chose it
-            # (slot2 values are unique: distinct ranks -> distinct free ids),
-            # and its source is that i. [G, B, B] compare instead of scatter.
-            eq = slot2[:, :, None] == jnp.arange(B, dtype=I32)[None, None, :]
-            is_copy2 = jnp.any(eq, axis=1)
-            src_i = jnp.argmax(eq, axis=1).astype(I32)
-            src2 = jnp.where(is_copy2, src_i, jnp.broadcast_to(loc, (G, B)))
-            is_copy = is_copy2.reshape(P)
-        slot = jnp.where(slot2 < B,
-                         slot2 + jnp.arange(G, dtype=I32)[:, None] * B,
-                         P).reshape(P)
-    else:
-        raise ValueError(f"unknown fork_impl: {fork_impl}")
+    # destination-major mapping (scatter-free, compare-free): the free
+    # slot with free-rank t receives the t-th admitted request, so a
+    # cumsum over the free mask plus one gather of `order` builds the
+    # map — no [G, B] scatter and no [G, B, B] one-hot compare
+    if fork_policy == "fifo":
+        # requesters in lane order; B pads the tail (never gathered:
+        # free_rank < n_admit <= n_req keeps the index in-range)
+        order = jnp.sort(jnp.where(req2, loc, B), axis=1).astype(I32)
+    n_req = jnp.sum(req2.astype(I32), axis=1, keepdims=True)
+    n_admit = jnp.minimum(n_adm, n_req)
+    free_rank = jnp.cumsum(free2.astype(I32), axis=1) - free2.astype(I32)
+    is_copy2 = free2 & (free_rank < n_admit)
+    src_i = jnp.take_along_axis(
+        order, jnp.clip(free_rank, 0, B - 1), axis=1)
+    src2 = jnp.where(is_copy2, src_i, jnp.broadcast_to(loc, (G, B)))
+    is_copy = is_copy2.reshape(P)
+    # per-source admission bit (drop/defer accounting): admitted
+    # requests are exactly those ranked inside the admission window
+    slot = jnp.where(req2 & (rank < n_adm), 0, P).reshape(P)
     return src2, is_copy, slot
 
 
@@ -2618,8 +2583,7 @@ def expand_forks(sf: SymFrontier, loop_bound: int = 0,
                  fork_block: int = 0,
                  fork_policy: str = "fifo",
                  defer_starved: bool = False,
-                 visited=None,
-                 fork_impl: str = "packed") -> SymFrontier:
+                 visited=None) -> SymFrontier:
     """Materialize fork requests: copy each forking lane into a free lane
     (prefix-sum compaction), point the copy at the jump target, and flip
     its final path-condition sign to "taken". Forks beyond capacity are
@@ -2651,22 +2615,7 @@ def expand_forks(sf: SymFrontier, loop_bound: int = 0,
     SHORTEST path condition (breadth-flavored), "deep" the longest
     (depth-flavored).
 
-    ``fork_impl`` selects the source→slot mapping machinery (the scaling
-    cliff's named term — docs/performance.md "Scaling cliff"):
-
-    - ``"packed"`` (default): scatter-free on EVERY backend. One sort of
-      a packed (key, lane) composite yields the admission order; the
-      per-lane admission rank comes from a searchsorted over the unique
-      composites (no argsort-of-argsort); and the destination map is
-      built destination-major — free slot j with free-rank t copies from
-      ``order[t]`` — a cumsum + gather instead of the legacy [G, B, B]
-      one-hot compare (O(P²) when fork_block=0) or [G, B] scatter.
-    - ``"legacy"``: the pre-restructure path (double argsort + backend-
-      adaptive scatter/dense inverse map), kept as the byte-parity
-      baseline (tests/test_superstep_parity.py) and for
-      tools/scaling_report.py to attribute the old curve.
-
-    Both produce identical frontiers for identical inputs.
+    The source→slot map itself is :func:`plan_fork_map`'s.
     """
     P = sf.n_lanes
     if fork_block > 0 and P % fork_block != 0:
@@ -2723,8 +2672,7 @@ def expand_forks(sf: SymFrontier, loop_bound: int = 0,
                 key = seen.astype(I32).reshape(G, B)
         else:
             raise ValueError(f"unknown fork_policy: {fork_policy}")
-    src2, is_copy, slot = plan_fork_map(req2, free2, key,
-                                        fork_policy, fork_impl)
+    src2, is_copy, slot = plan_fork_map(req2, free2, key, fork_policy)
     req = req_live
 
     # the iprof residual sidecar is lane-independent: detach it so the
@@ -3128,10 +3076,10 @@ def _sym_run_impl(sf: SymFrontier, env: Env, corpus: Corpus,
                   track_coverage: bool = False,
                   fork_policy: str = "fifo",
                   defer_starved: bool = False,
-                  migrate_every: int = 0,
-                  fork_impl: str = "packed",
-                  unroll: int = 1):
-    """Run the symbolic engine until quiescence or max_steps supersteps.
+                  migrate_every: int = 0):
+    """Run the symbolic engine until quiescence or max_steps supersteps:
+    one while-loop, one superstep a trip, the quiescence check before
+    every trip.
     ``propagate_every`` > 0 interleaves feasibility sweeps that kill
     provably-unsat lanes (reference: lazy ``Solver.check()`` pruning);
     0 disables them; None uses ``limits.propagate_every``.
@@ -3145,26 +3093,11 @@ def _sym_run_impl(sf: SymFrontier, env: Env, corpus: Corpus,
     (``migrate_parked_device``) every that many supersteps — the ICI
     tier of SURVEY §5.8's rebalancing; the host-seam
     ``rebalance_parked`` remains the chunk-boundary tier.
-    ``fork_impl`` selects :func:`expand_forks`' slot-mapping machinery
-    ("packed" scatter-free default / "legacy" parity baseline).
-    ``unroll`` > 1 rolls that many supersteps into ONE while-loop body
-    (Python-unrolled at trace time), amortizing the loop's per-iteration
-    carry handling over K steps. Byte-parity with unroll=1 is preserved:
-    the quiescence check runs every K steps instead of every step, but a
-    quiesced frontier's supersteps are exact no-ops (every write is
-    masked by ``running``), and the cadence-gated passes (propagation
-    sweep, migration) gain an explicit any-running gate so a tail step
-    after mid-block quiescence cannot fire them where the per-step loop
-    would have exited. Cadences stay anchored to the absolute step index.
-    ``unroll`` values not dividing ``max_steps`` are lowered to the
-    largest divisor so the loop cannot overshoot the step budget."""
+    Both cadences are anchored to the call's own step index."""
     from .propagate import kill_infeasible
 
     if propagate_every is None:
         propagate_every = limits.propagate_every
-    unroll = max(1, int(unroll))
-    while unroll > 1 and max_steps % unroll:
-        unroll -= 1
 
     P_run = sf.n_lanes
     C, MC = corpus.code.shape
@@ -3174,15 +3107,8 @@ def _sym_run_impl(sf: SymFrontier, env: Env, corpus: Corpus,
         i, s, _ = state
         return (i < max_steps) & jnp.any(s.base.running)
 
-    def one_step(i, s, visited):
-        if unroll > 1:
-            # the per-step loop re-checks its cond BEFORE each body: a
-            # step that begins quiesced never runs — including its
-            # cadence passes. Unrolled tail steps replicate that exact
-            # gate with the ENTRY state (post-superstep running would
-            # over-suppress: a sweep whose step started live runs in the
-            # per-step path even when that step quiesced the frontier)
-            alive = jnp.any(s.base.running)
+    def body(state):
+        i, s, visited = state
         if track_coverage:
             # init-frame pcs index the per-lane init buffer, not the
             # contract image — they must not pollute its bitmap
@@ -3194,24 +3120,17 @@ def _sym_run_impl(sf: SymFrontier, env: Env, corpus: Corpus,
         # expand_forks tree-gathers EVERY leaf of the frontier; gate it so
         # supersteps with no pending fork request (the common case) skip
         # that full-frontier pass. Identity-valued when no live request.
-        pred = jnp.any(s.fork_req & s.base.active)
-        if unroll > 1:
-            pred = pred & alive
         s = lax.cond(
-            pred,
+            jnp.any(s.fork_req & s.base.active),
             lambda x: expand_forks(x, limits.loop_bound, fork_block,
                                    fork_policy, defer_starved,
-                                   visited if track_coverage else None,
-                                   fork_impl),
+                                   visited if track_coverage else None),
             lambda x: x,
             s,
         )
         if propagate_every:
-            gate = (i % propagate_every) == propagate_every - 1
-            if unroll > 1:
-                gate = gate & alive
             s = ci.narrow_cond(
-                gate,
+                (i % propagate_every) == propagate_every - 1,
                 kill_infeasible, s,
                 ("iv_lo", "iv_hi", "kb_m", "kb_v", "prop_len",
                  "base.active", "fork_req", "killed_infeasible",
@@ -3230,42 +3149,24 @@ def _sym_run_impl(sf: SymFrontier, env: Env, corpus: Corpus,
             # pay the full-leaf no-op migration pass every firing
             need = (jnp.any(jnp.any(stm, axis=1) & (occ == Bm))
                     & jnp.any(occ <= Bm - 2))
-            if unroll > 1:
-                need = need & alive
             s = lax.cond(
                 ((i % migrate_every) == migrate_every - 1) & need,
                 lambda x: migrate_parked_device(x, fork_block),
                 lambda x: x,
                 s,
             )
-        return s, visited
-
-    def body(state):
-        i, s, visited = state
-        for k in range(unroll):
-            s, visited = one_step(i + k, s, visited)
-        return i + unroll, s, visited
+        return i + 1, s, visited
 
     steps, sf, visited = lax.while_loop(cond, body,
                                         (jnp.int32(0), sf, visited0))
-    # the loop counter as the loop left it (with ``unroll`` > 1 it
-    # advances by ``unroll``): the only record of how many supersteps a
-    # call that ended on quiescence really ran
+    # the loop counter as the loop left it: the only record of how many
+    # supersteps a call that ended on quiescence really ran
     sf = sf.replace(steps_total=sf.steps_total + steps)
     return (sf, visited) if track_coverage else sf
 
 
 _SYM_RUN_STATIC = ("spec", "limits", "max_steps", "propagate_every",
                    "fork_block", "track_coverage", "fork_policy",
-                   "defer_starved", "migrate_every", "fork_impl", "unroll")
+                   "defer_starved", "migrate_every")
 
 sym_run = jax.jit(_sym_run_impl, static_argnames=_SYM_RUN_STATIC)
-
-# Donating entry for callers that consume their input frontier (the
-# analysis chunk loop rebinds ``sf`` on every call): XLA aliases the
-# input buffers into the outputs, so the superstep loop's carry never
-# holds two copies of a multi-GiB frontier. Never use this where the
-# input ``sf`` is reused afterwards (bench reps, parity tests). CPU
-# ignores donation — callers gate on backend to avoid warning spam.
-sym_run_donated = jax.jit(_sym_run_impl, static_argnames=_SYM_RUN_STATIC,
-                          donate_argnums=(0,))

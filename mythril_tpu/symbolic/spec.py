@@ -40,6 +40,6 @@ class SymSpec:
     # keys with fully-known bits to their value at SSTORE/SLOAD so
     # provably-equal keys connect. Trace-time static: False compiles the
     # probe out entirely (~0-15% cost on storage-heavy CPU workloads,
-    # noise-limited — see docs/perf-round5-cpu-ab.md; the soundness win
-    # is the default, the flag exists for perf runs and A/B measurement).
+    # noise-limited, never measured on the chip; the soundness win is
+    # the default).
     alias_probe: bool = True

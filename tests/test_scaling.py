@@ -11,8 +11,13 @@ they are exactly what the 16k-lane trace would fit.
 import os
 import sys
 
+import jax
+import numpy as np
+
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
 import scaling_report  # noqa: E402  (tools/ is not a package)
+
+from fork_map_ref import fork_map_ref  # noqa: E402
 
 P_SMOKE = (256, 1024)
 
@@ -21,8 +26,7 @@ def test_superstep_body_growth_within_budget():
     # the committed threshold (≈1.05 total ⇔ ≈0.05 per-lane): the whole
     # while-loop body — superstep, expand gate, pop seam, carry — must
     # cost O(P^1.05) or less with the packed fork map
-    rep = scaling_report.attribution(P_SMOKE, fork_impl="packed",
-                                     only=("sym_run_body",))
+    rep = scaling_report.attribution(P_SMOKE, only=("sym_run_body",))
     e = rep["superstep_body_exponent"]
     assert e is not None
     assert e <= scaling_report.PER_LANE_EXPONENT_BUDGET, (
@@ -32,21 +36,25 @@ def test_superstep_body_growth_within_budget():
     assert rep["dominant_superlinear"] is None
 
 
-def test_attribution_names_legacy_dense_term():
-    # the report must still SEE the old cliff: the legacy dense fork map
-    # ([G, B, B] one-hot) fits ~P² and is named as dominant
-    rep = scaling_report.attribution(P_SMOKE, fork_impl="legacy",
-                                     only=("fork_plan",))
-    b = rep["buckets"]["fork_plan"]
-    assert b["exponent"] > 1.5, (
-        f"legacy dense fork map fit P^{b['exponent']}; the attribution "
-        "lost sight of the [G,B,B] term it exists to name")
-    assert rep["dominant_superlinear"] == "fork_plan"
+def test_cost_counter_sees_a_dense_inverse_map():
+    # the counter must still SEE the old cliff: the reference map in its
+    # one-hot form ([G, B, B] compare) fits ~P², far above the product's
+    def elems(p):
+        mask = jax.ShapeDtypeStruct((1, p), bool)
+        key = jax.ShapeDtypeStruct((1, p), np.int32)
+        return scaling_report.jaxpr_cost(jax.make_jaxpr(
+            lambda r, f, k: fork_map_ref(r, f, k, "shallow", dense=True))(
+                mask, mask, key))["elems"]
+
+    e = scaling_report.fit_exponent(P_SMOKE, [elems(p) for p in P_SMOKE])
+    assert e > 1.5, (
+        f"the [G,B,B] one-hot map fit P^{e}: the cost counter lost sight "
+        "of the term it exists to name")
 
 
 def test_packed_fork_plan_is_linear():
-    rep = scaling_report.attribution(P_SMOKE, fork_impl="packed",
-                                     only=("fork_plan",))
+    rep = scaling_report.attribution(P_SMOKE, only=("fork_plan",))
     b = rep["buckets"]["fork_plan"]
     assert b["exponent"] <= 1.05, (
         f"packed fork map fit P^{b['exponent']}, expected linear")
+    assert rep["dominant_superlinear"] is None

@@ -2,8 +2,10 @@
 ``sym_run`` adds its loop counter where the loop ran, so a call that
 ended on quiescence says how many supersteps it took."""
 
+import pathlib
+import re
+
 import numpy as np
-import pytest
 
 import mythril_tpu  # noqa: F401
 from mythril_tpu.config import TEST_LIMITS
@@ -40,27 +42,22 @@ def executed(sf) -> int:
 BUDGET = 8
 
 
-def run(sf, env, corpus, unroll=1):
+def run(sf, env, corpus):
     return sym_run(sf, env, corpus, SymSpec(), TEST_LIMITS,
-                   max_steps=BUDGET, propagate_every=0, unroll=unroll)
+                   max_steps=BUDGET, propagate_every=0)
 
 
-@pytest.mark.parametrize("unroll", [1, 2])
-def test_counts_the_loop_of_a_program_that_quiesces_early(unroll):
+def test_counts_the_loop_of_a_program_that_quiesces_early():
     sf, env, corpus = build(QUIESCES)
     assert steps_of(sf) == 0
-    out = run(sf, env, corpus, unroll)
-    ran = executed(out)
-    assert 0 < ran < BUDGET
-    # the counter advances by ``unroll``: the last block may hold tail
-    # steps that found the frontier quiescent and did nothing
-    assert steps_of(out) == -(-ran // unroll) * unroll
+    out = run(sf, env, corpus)
+    assert 0 < executed(out) < BUDGET
+    assert steps_of(out) == executed(out)
 
 
-@pytest.mark.parametrize("unroll", [1, 2])
-def test_counts_the_budget_of_a_program_that_outlasts_it(unroll):
+def test_counts_the_budget_of_a_program_that_outlasts_it():
     sf, env, corpus = build(SPINS)
-    out = run(sf, env, corpus, unroll)
+    out = run(sf, env, corpus)
     assert bool(np.asarray(out.base.running).any())
     assert steps_of(out) == executed(out) == BUDGET
 
@@ -77,3 +74,21 @@ def test_adds_up_over_chunked_calls():
     # a call on a quiescent frontier runs nothing and adds nothing
     sf = run(sf, env, corpus)
     assert steps_of(sf) == total
+
+
+def test_sym_run_has_one_program():
+    """One jit entry, and no static argument or environment variable
+    that picks another compiled program: every static argument left has
+    two values in use somewhere in the product."""
+    from mythril_tpu.symbolic import engine
+
+    assert engine._SYM_RUN_STATIC == (
+        "spec", "limits", "max_steps", "propagate_every", "fork_block",
+        "track_coverage", "fork_policy", "defer_starved", "migrate_every")
+    assert not hasattr(engine, "sym_run_donated")
+    pkg = pathlib.Path(mythril_tpu.__file__).parent
+    readers = [str(f.relative_to(pkg))
+               for d in ("symbolic", "core", "analysis")
+               for f in sorted((pkg / d).rglob("*.py"))
+               if re.search(r"os\.environ|getenv", f.read_text())]
+    assert readers == []

@@ -165,6 +165,39 @@ def test_narrow_cond_undeclared_aux_raises():
         raise AssertionError("undeclared aux key must raise at trace time")
 
 
+def test_dispatch_rejects_undeclared_write(monkeypatch):
+    """A class handler that writes a field outside its WRITE_FIELDS entry
+    fails when ``dispatch`` is traced: the gated cond returns only the
+    declared leaves, so an undeclared write would otherwise be dropped
+    without a word."""
+    import jax
+    import pytest
+
+    from mythril_tpu.config import TEST_LIMITS
+    from mythril_tpu.core import Corpus, make_env, make_frontier
+    from mythril_tpu.disassembler import ContractImage
+    from mythril_tpu.disassembler.asm import assemble
+
+    img = ContractImage.from_bytecode(assemble(1, "POP", "STOP"),
+                                      TEST_LIMITS.max_code)
+    corpus = Corpus.from_images([img])
+    f, env = make_frontier(2, TEST_LIMITS), make_env(2)
+
+    def rogue(fr, env, corpus, op, mask, old_pc):
+        return fr.replace(gas_min=fr.gas_min + 1), {}
+
+    assert "gas_min" not in ci.WRITE_FIELDS[0]
+    monkeypatch.setattr(ci, "_HANDLERS", [rogue])
+
+    def step(fr):
+        fr, op, run, old_pc = ci.prologue(fr, corpus)
+        return ci.dispatch(fr, env, corpus, op, run, old_pc)
+
+    with pytest.raises(AssertionError,
+                       match="rogue wrote undeclared field 'gas_min'"):
+        jax.eval_shape(step, f)
+
+
 def test_shared_writeback_swap_and_veto_semantics():
     """SWAP16-at-depth and the ok-veto: the dispatch shared writeback must
     reproduce the per-handler writes the oracle suites pin, including the

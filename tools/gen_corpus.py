@@ -22,8 +22,8 @@ import sys
 # host-side tool: never let the imports below (asm → package __init__ →
 # u256 device tables) initialize a TPU backend — a wedged one
 # hangs the process before the first file is written. Only
-# when run AS the tool: bench.py imports MIX for the BENCH_E2E corpus
-# and must keep its own backend choice.
+# when run AS the tool: an importer (the tests take MIX from here)
+# keeps its own backend choice.
 if __name__ == "__main__":
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Compiled-cost attribution for the P-scaling cliff (ROADMAP #1).
+"""Compiled-cost attribution: how the engine's program grows with P.
 
 Traces the symbolic engine's jaxprs at several lane counts P — WITHOUT
 executing or allocating anything at those sizes (inputs are
@@ -7,33 +7,32 @@ executing or allocating anything at those sizes (inputs are
 output-byte counts by phase, and fits a log-log growth exponent per
 bucket. A bucket whose fitted exponent is ~1.0 scales linearly in P
 (flat per-lane cost); anything materially above 1 is a superlinear term,
-and the report names the dominant one. This is how the 4096→16384
-throughput cliff (1.08M → 771k lane-steps/s, BENCH r4) was attributed to
-``expand_forks``' dense ``[G, B, B]`` destination map from a CPU-only
-box: the op-count model needs no hardware, only traces.
+and the report names the dominant one. This is how a throughput cliff
+between 4096 and 16384 lanes was once attributed to a dense
+``[G, B, B]`` destination map in ``expand_forks`` from a CPU-only box:
+the op-count model needs no hardware, only traces.
 
 Phases bucketed:
 
 - ``superstep``      one :func:`sym_superstep` (dispatch + overlay +
                      claimed handlers + gas + pop seam)
-- ``expand_forks``   the fork compaction pass (see ``--impl``)
+- ``expand_forks``   the fork compaction pass
+- ``fork_plan``      its source→slot map alone (``plan_fork_map``)
 - ``rebalance``      the in-jit migration tier (``migrate_parked_device``)
 - ``sym_run_body``   one full while-loop body of :func:`sym_run` — the
                      unit the CI smoke (tests/test_scaling.py) holds to a
                      per-lane exponent budget
 - ``cond_carry``     analytic: elements carried across the superstep's
-                     cond boundaries per step (full-frontier legacy vs
-                     the narrow pop_frames write set)
+                     cond boundaries per step (the expand gate's full
+                     frontier plus the narrow pop_frames write set)
 - ``observe_fetch``  analytic: device→host bytes per chunk seam
 
 ``--write-mode dense`` pins the TPU-style slot-write lowering while
-tracing on CPU (``interpreter.force_write_mode``) so the accelerator
-cost curve is attributable from any box; ``--impl legacy`` traces the
-pre-restructure fork machinery for before/after comparison.
+tracing on CPU (it patches ``interpreter._use_scatter`` for the trace)
+so the accelerator cost curve is attributable from any box.
 
 Usage:
-  python tools/scaling_report.py                      # packed, dense
-  python tools/scaling_report.py --impl legacy        # the old curve
+  python tools/scaling_report.py                      # dense writes
   python tools/scaling_report.py --p 256,1024 --json  # CI-sized, JSON only
 
 One JSON document on stdout with ``--json``; human table otherwise.
@@ -62,7 +61,7 @@ DEFAULT_P = (1024, 4096, 16384)
 PER_LANE_EXPONENT_BUDGET = 1.05
 
 
-def _jaxpr_cost(jaxpr) -> dict:
+def jaxpr_cost(jaxpr) -> dict:
     """Recursive op/element/byte totals over a (Closed)Jaxpr. Sub-jaxprs
     (cond branches, while bodies, pjit calls, scans) count ONCE — the
     model measures program size per trip, not trip counts, which is the
@@ -97,7 +96,7 @@ def _jaxpr_cost(jaxpr) -> dict:
             nbytes += n * (dt.itemsize if dt is not None else 4)
         for val in eqn.params.values():
             for sub in _subjaxprs(val):
-                c = _jaxpr_cost(sub)
+                c = jaxpr_cost(sub)
                 ops += c["ops"]
                 elems += c["elems"]
                 nbytes += c["bytes"]
@@ -172,7 +171,7 @@ def _carry_elems(sf, declared=None) -> int:
     return total
 
 
-def _fit_exponent(ps, ys) -> float:
+def fit_exponent(ps, ys) -> float:
     """Least-squares slope of log(y) on log(P); 0.0 when degenerate."""
     pts = [(math.log(p), math.log(y)) for p, y in zip(ps, ys) if y > 0]
     if len(pts) < 2:
@@ -185,8 +184,7 @@ def _fit_exponent(ps, ys) -> float:
     return num / den if den else 0.0
 
 
-def attribution(p_list=DEFAULT_P, fork_impl: str = "packed",
-                write_mode: str = "dense",
+def attribution(p_list=DEFAULT_P, write_mode: str = "dense",
                 fork_policy: str = "shallow",
                 steps: int = 8,
                 only=None) -> dict:
@@ -220,7 +218,10 @@ def attribution(p_list=DEFAULT_P, fork_impl: str = "packed",
     buckets = {name: {"elems": {}, "bytes": {}, "ops": {}}
                for name in names}
 
-    prev = ci.force_write_mode(write_mode)
+    if write_mode not in ("dense", "scatter"):
+        raise ValueError(f"unknown write mode: {write_mode!r}")
+    real = ci._use_scatter
+    ci._use_scatter = lambda: write_mode == "scatter"
     try:
         for p in p_list:
             sf = _skeleton(sf0, p_base, p)
@@ -229,7 +230,7 @@ def attribution(p_list=DEFAULT_P, fork_impl: str = "packed",
             def rec(name, mk):
                 if name not in buckets:
                     return
-                c = _jaxpr_cost(mk())
+                c = jaxpr_cost(mk())
                 buckets[name]["elems"][p] = c["elems"]
                 buckets[name]["bytes"][p] = c["bytes"]
                 buckets[name]["ops"][p] = c["ops"]
@@ -238,56 +239,51 @@ def attribution(p_list=DEFAULT_P, fork_impl: str = "packed",
                 lambda s, e: sym_superstep(s, e, corpus, spec, L))(sf, env))
             rec("expand_forks", lambda: jax.make_jaxpr(
                 lambda s: expand_forks(s, L.loop_bound, 0, fork_policy,
-                                       True, None, fork_impl))(sf))
+                                       True, None))(sf))
             # the mapping machinery alone — inside the full expand_forks
             # trace the whole-frontier copy (linear, ~hundreds of kB per
-            # lane) drowns this term; isolated, the legacy dense path's
-            # [G, B, B] one-hot shows its P² directly
+            # lane) drowns this term; isolated, a [G, B, B] one-hot
+            # shows its P² directly
             import numpy as _np
             req2 = jax.ShapeDtypeStruct((1, p), bool)
             free2 = jax.ShapeDtypeStruct((1, p), bool)
             key2 = jax.ShapeDtypeStruct((1, p), _np.int32)
             if fork_policy == "fifo":
                 rec("fork_plan", lambda: jax.make_jaxpr(
-                    lambda r, f: plan_fork_map(r, f, None, fork_policy,
-                                               fork_impl))(req2, free2))
+                    lambda r, f: plan_fork_map(r, f, None, fork_policy))(
+                        req2, free2))
             else:
                 rec("fork_plan", lambda: jax.make_jaxpr(
-                    lambda r, f, k: plan_fork_map(r, f, k, fork_policy,
-                                                  fork_impl))(req2, free2,
-                                                              key2))
+                    lambda r, f, k: plan_fork_map(r, f, k, fork_policy))(
+                        req2, free2, key2))
             # the in-jit migration tier needs G > 1 blocks to exist
             rec("rebalance", lambda: jax.make_jaxpr(
                 lambda s: migrate_parked_device(s, max(1, p // 4)))(sf))
             rec("sym_run_body", lambda: jax.make_jaxpr(
                 lambda s, e: _sym_run_impl(
                     s, e, corpus, spec, L, max_steps=steps,
-                    fork_policy=fork_policy, defer_starved=True,
-                    fork_impl=fork_impl))(sf, env))
+                    fork_policy=fork_policy, defer_starved=True))(sf, env))
             # analytic buckets: cond-boundary carry (the expand gate
-            # carries the full frontier; the pop seam now carries only
-            # its write set — the legacy full carry is reported next to
-            # it for the before/after) and the chunk-seam host fetch
+            # carries the full frontier; the pop seam carries only its
+            # write set) and the chunk-seam host fetch
             if "cond_carry" in buckets:
                 full = _carry_elems(sf)
                 narrow = _carry_elems(sf, _POP_FRAME_WRITES)
                 buckets["cond_carry"]["elems"][p] = full + narrow
                 buckets["cond_carry"]["bytes"][p] = 0
                 buckets["cond_carry"]["ops"][p] = 2
-                buckets["cond_carry"].setdefault(
-                    "legacy_elems", {})[p] = 2 * full
             if "observe_fetch" in buckets:
                 # (active, fork_req, running) — one bool each per lane
                 buckets["observe_fetch"]["elems"][p] = 3 * p
                 buckets["observe_fetch"]["bytes"][p] = 3 * p
                 buckets["observe_fetch"]["ops"][p] = 1
     finally:
-        ci.force_write_mode(prev)
+        ci._use_scatter = real
 
     ps = list(p_list)
     for name, b in buckets.items():
         ys = [b["elems"][p] for p in ps]
-        b["exponent"] = round(_fit_exponent(ps, ys), 4)
+        b["exponent"] = round(fit_exponent(ps, ys), 4)
         b["per_lane_exponent"] = round(b["exponent"] - 1.0, 4)
 
     # dominant superlinear bucket: worst exponent, ties broken by size
@@ -301,7 +297,6 @@ def attribution(p_list=DEFAULT_P, fork_impl: str = "packed",
 
     return {
         "P": ps,
-        "fork_impl": fork_impl,
         "write_mode": write_mode,
         "fork_policy": fork_policy,
         "per_lane_exponent_budget": PER_LANE_EXPONENT_BUDGET,
@@ -314,8 +309,8 @@ def attribution(p_list=DEFAULT_P, fork_impl: str = "packed",
 
 def _table(rep: dict) -> str:
     ps = rep["P"]
-    lines = ["scaling attribution  impl=%s write_mode=%s policy=%s"
-             % (rep["fork_impl"], rep["write_mode"], rep["fork_policy"]),
+    lines = ["scaling attribution  write_mode=%s policy=%s"
+             % (rep["write_mode"], rep["fork_policy"]),
              "%-14s %s %10s" % ("bucket",
                                 " ".join("%14s" % ("elems@%d" % p)
                                          for p in ps), "exponent")]
@@ -334,8 +329,6 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--p", default=",".join(str(p) for p in DEFAULT_P),
                     help="comma-separated lane counts")
-    ap.add_argument("--impl", default="packed",
-                    choices=["packed", "legacy"], help="expand_forks path")
     ap.add_argument("--write-mode", default="dense",
                     choices=["dense", "scatter"],
                     help="slot-write lowering to attribute (dense = the "
@@ -346,7 +339,7 @@ def main() -> int:
                     help="one JSON document on stdout")
     args = ap.parse_args()
     ps = tuple(int(x) for x in args.p.split(",") if x.strip())
-    rep = attribution(ps, fork_impl=args.impl, write_mode=args.write_mode,
+    rep = attribution(ps, write_mode=args.write_mode,
                       fork_policy=args.policy)
     if args.json:
         print(json.dumps(rep))

@@ -103,11 +103,10 @@ suitable as a CI smoke or a manual post-change sanity run:
     JAX_PLATFORMS=cpu python tools/soak_campaign.py \
         --fault-inject oom:batch=0:times=2
 
-Env gates (PROF_INIT_TIMEOUT-style, all opt-in):
+Env gates (all opt-in):
 
   SOAK_INIT_TIMEOUT=<sec>   probe backend init in a subprocess first,
-                            falling back to CPU on failure (same gate
-                            tools/profile_superstep.py exposes)
+                            falling back to CPU on failure
   SOAK_BATCH_TIMEOUT=<sec>  per-batch watchdog budget (default 300)
 """
 
