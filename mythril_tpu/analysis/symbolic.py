@@ -233,46 +233,6 @@ def coverage_summary(tx_contexts) -> dict:
     return out
 
 
-class _PoolWatch:
-    """Says when a transaction's lane pool is stuck for good.
-
-    A seam is *stuck* when ``pool_stuck`` holds (every lane taken, every
-    running lane parked on a fork it cannot place) and the seam's
-    scheduling step did nothing, so the next ``sym_run`` call starts
-    from the frontier the seam saw. From there no superstep changes
-    anything (a parked lane un-executes its branch and raises the same
-    request again); only a feasibility sweep that kills a lane frees a
-    slot. The sweep is incremental and walks every node added since the
-    last one, so once a call has held a whole sweep and ended at a
-    stuck seam with ``active``, ``fork_req``, ``running`` and the run
-    totals of kills and drops all as the stuck seam before it had them,
-    that call was a witness: every lane's sweep has caught up, and
-    every later call of the transaction would hand back this frontier
-    (but for its step counters). ``seam`` returns that as ``proven``."""
-
-    def __init__(self, sweep_every: int):
-        self._sweep = max(1, sweep_every)   # supersteps that hold a sweep
-        self._last = None       # what the last seam saw, if it was stuck
-        self._ran = 0           # supersteps run since
-
-    def ran(self, steps_run: int) -> None:
-        self._ran += steps_run
-
-    def seam(self, active, fork_req, running, killed: int, dropped: int,
-             idle: bool) -> tuple:
-        """One seam, after its scheduling step (``idle``: it moved and
-        evicted nothing): ``(stuck, proven)``."""
-        stuck = idle and pool_stuck(active, fork_req, running)
-        seen = (killed, dropped, active, fork_req, running) if stuck else None
-        proven = (stuck and self._last is not None
-                  and self._ran >= self._sweep
-                  and seen[:2] == self._last[:2]
-                  and all(np.array_equal(a, b)
-                          for a, b in zip(seen[2:], self._last[2:])))
-        self._last, self._ran = seen, 0
-        return stuck, proven
-
-
 class SymExecWrapper:
     """Build + run the symbolic exploration for a batch of contracts.
 
@@ -297,11 +257,14 @@ class SymExecWrapper:
     as ``paths`` / ``dropped``). Every ``superstep`` span says whether
     its own seam found the lane pool ``stuck``, and a transaction's last
     one what ``ended`` it (``fixpoint`` | ``budget`` | ``quiescent`` |
-    ``deadline``); ``engine_fixpoint_ends_total{tx}`` counts the
-    transactions ended at their pool's fixpoint (``_PoolWatch``) and
-    ``engine_calls_skipped_total{tx}`` the ``sym_run`` calls their budget
-    still allowed. All of it rides the reads the harvest and the seam
-    make anyway.
+    ``deadline``); a call that left its loop at the pool's fixpoint
+    (``engine.pool_fixpoint``, the frontier's ``fixpoint`` scalar) says
+    ``ended_in="fixpoint"`` and counts in
+    ``engine_inloop_fixpoint_exits_total{tx}``;
+    ``engine_fixpoint_ends_total{tx}`` counts the transactions ended
+    there and ``engine_calls_skipped_total{tx}`` the ``sym_run`` calls
+    their budget still allowed. All of it rides the reads the harvest
+    and the seam make anyway.
     """
 
     def __init__(
@@ -458,6 +421,11 @@ class SymExecWrapper:
         self._iprof = np.zeros(256, dtype=np.int64)
         if enable_iprof:
             sf = sf.replace(base=sf.base.attach_iprof())
+        if spill:
+            # the scalar every ``defer_starved`` call writes, there from
+            # the first call on: one frontier structure, one program
+            import jax.numpy as jnp
+            sf = sf.replace(fixpoint=jnp.zeros((), dtype=bool))
         env = make_env(P)
         # host mirror of the frontier's run-total superstep counter (a
         # chunk's count is the difference across its sym_run call)
@@ -481,7 +449,9 @@ class SymExecWrapper:
             exec loop, SURVEY §5.3). Chunks re-enter the same compiled
             sym_run; between chunks the host checks the clock and may
             checkpoint. With spill the transaction also ends at the seam
-            that proves its lane pool stuck for good (``_PoolWatch``)."""
+            of a call that left its loop at the lane pool's fixpoint
+            (``engine.pool_fixpoint``), if the seam's scheduling step
+            leaves the frontier as that call handed it over."""
             try:
                 sf, ended = walk(sf)
                 if held:
@@ -504,7 +474,6 @@ class SymExecWrapper:
 
             warm_shapes: set = getattr(self, "_warm_chunk_shapes", set())
             self._warm_chunk_shapes = warm_shapes
-            watch = _PoolWatch(limits.propagate_every)
             q = max(1, self._chunk // 4)
 
             def chunk_at(done):
@@ -528,8 +497,8 @@ class SymExecWrapper:
                 labels = {"tx": str(self._cur_tx)}
                 reg.counter(
                     "engine_fixpoint_ends_total",
-                    help="transactions ended at the seam that proved "
-                         "their lane pool stuck for good",
+                    help="transactions ended at the seam of a call that "
+                         "left its loop at the lane pool's fixpoint",
                     labels=labels).inc()
                 reg.counter(
                     "engine_calls_skipped_total",
@@ -550,7 +519,9 @@ class SymExecWrapper:
                 compiled program in ``warm_shapes``. Returns the
                 frontier, the supersteps that ran, the span's seconds,
                 whether the call compiled, and the fetched ``also``
-                leaves."""
+                leaves. A call that left its loop at the pool's fixpoint
+                (the ``fixpoint`` leaf, where ``also`` reads it) says so
+                on its span and is counted."""
                 cold = shape not in warm_shapes
                 w0 = tally()[1]
                 seal()
@@ -569,6 +540,10 @@ class SymExecWrapper:
                         + tuple(attrgetter(a)(sf) for a in also),
                         ",".join(("visited", "steps_total", *also)))
                     steps_run = int(got[1]) - self._steps_seen
+                    left_inside = bool(
+                        dict(zip(also, got[2:])).get("fixpoint"))
+                    if left_inside:
+                        sp.attrs["ended_in"] = "fixpoint"
                     sp.attrs.update(
                         steps_run=steps_run,
                         enqueue_s=round(enqueue_s, 6),
@@ -576,10 +551,15 @@ class SymExecWrapper:
                     # timed to here, emitted by ``seal`` once the
                     # call's seam has said what it found
                     held.append(sp.hold())
-                watch.ran(steps_run)
                 self._steps_seen = int(got[1])
                 self._visited |= got[0]
                 reg = obs_metrics.REGISTRY
+                if left_inside:
+                    reg.counter(
+                        "engine_inloop_fixpoint_exits_total",
+                        help="sym_run calls that left their loop at the "
+                             "lane pool's fixpoint, with budget to spare",
+                        labels={"tx": str(self._cur_tx)}).inc()
                 if cold:
                     warm_shapes.add(shape)
                     reg.counter(
@@ -587,8 +567,9 @@ class SymExecWrapper:
                         help="distinct chunk shapes compiled").inc()
                 reg.counter(
                     "engine_supersteps_total",
-                    help="supersteps sym_run's loop ran (quiescence "
-                         "ends a call early)").inc(steps_run)
+                    help="supersteps sym_run's loop ran (quiescence or "
+                         "the pool's fixpoint ends a call early)"
+                ).inc(steps_run)
                 reg.counter(
                     "engine_supersteps_budget_total",
                     help="supersteps the sym_run calls were allowed "
@@ -598,17 +579,18 @@ class SymExecWrapper:
             # what a seam of the spill machinery reads of the frontier,
             # in the one transfer of its ``superstep`` call
             SEAM = ("base.active", "fork_req", "base.running",
-                    "base.home_contract", "killed_total", "dropped_total")
+                    "base.home_contract", "fixpoint")
 
-            def rebalance(sf, act_h, freq_h, run_h, home_h, killed_h,
-                          dropped_h):
+            def rebalance(sf, act_h, freq_h, run_h, home_h, fix_h):
                 """A seam's scheduling step: parked lanes move to blocks
                 with free lanes; where the whole frontier is full and
                 stuck, the contracts it starves are relieved (the
                 lanes given up are lost forks, counted with those still
-                parked at the end). Where it does nothing, ``watch``
-                judges the seam: returns the frontier and whether the
-                pool is now proven stuck for good."""
+                parked at the end). Returns the frontier and whether the
+                pool is stuck for good: the call left its loop at the
+                fixpoint (``fix_h``) and the step did nothing, so the
+                next call would start from the frontier that one handed
+                over."""
                 with obs_trace.span("rebalance", tx=self._cur_tx):
                     sf, moved = rebalance_parked(sf, self.fork_block,
                                                  active=act_h,
@@ -628,12 +610,11 @@ class SymExecWrapper:
                     help="parked lanes given up at a full frontier's "
                          "fixpoint for a contract under its floor"
                 ).inc(evicted)
-                stuck, proven = watch.seam(
-                    act_h, freq_h, run_h, int(killed_h), int(dropped_h),
-                    idle=not (moved or evicted))
+                idle = not (moved or evicted)
                 if held:
-                    held[-1].attrs["stuck"] = stuck
-                return sf, proven
+                    held[-1].attrs["stuck"] = idle and pool_stuck(
+                        act_h, freq_h, run_h)
+                return sf, idle and bool(fix_h)
 
             if (self._deadline_at is None and self.checkpoint_dir is None
                     and not self.spill):
@@ -666,9 +647,9 @@ class SymExecWrapper:
                 # quiescence check ride the same fetch (each separate
                 # read is a blocking sync). A bare run with telemetry
                 # off and spill off reads only ``running`` beside the
-                # bitmap. With spill the same fetch (and the two run
-                # totals beside the masks) also decides whether the
-                # pool is stuck for good, which ends the transaction.
+                # bitmap. With spill the same fetch (the frontier's
+                # ``fixpoint`` scalar beside the masks) also says whether
+                # the pool is stuck for good, which ends the transaction.
                 # (Reusing the pre-rebalance fetch for the quiescence
                 # check is exact: rebalance RELOCATES lanes, or retires
                 # parked ones for another that goes on waiting — never
@@ -716,8 +697,10 @@ class SymExecWrapper:
                                     tx_kind=self._tx_kind):
                     # one fetch per drain round, shared with the
                     # rebalance planner and the final parked count
-                    got = fetch(tuple(attrgetter(a)(sf) for a in SEAM),
-                                ",".join(SEAM))
+                    # (the seam before it has judged the last call's
+                    # ``fixpoint``: this frontier is no call's yet)
+                    got = (*fetch(tuple(attrgetter(a)(sf) for a in SEAM[:4]),
+                                  ",".join(SEAM[:4])), False)
                     parked = got[1] & got[0]
                     for left in range(DRAIN_ROUNDS, 0, -1):
                         if not parked.any():

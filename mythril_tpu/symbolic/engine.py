@@ -2873,6 +2873,14 @@ def rebalance_parked(sf: SymFrontier, fork_block: int = 0,
     ), len(src_idx)
 
 
+def _pool_stuck(xp, active, fork_req, running):
+    """The predicate of a stuck pool over the array module ``xp``: NumPy
+    on the host's copies at a seam, ``jnp`` inside the compiled loop."""
+    parked = fork_req & active
+    return (xp.any(parked) & xp.all(active)
+            & ~xp.any(running & active & ~parked))
+
+
 def pool_stuck(active, fork_req, running) -> bool:
     """Is the lane pool at a full frontier's fixpoint? From host copies of
     ``base.active``, ``fork_req`` and ``base.running``: some lane is
@@ -2881,10 +2889,43 @@ def pool_stuck(active, fork_req, running) -> bool:
     frontier; only a feasibility sweep that kills a lane can."""
     import numpy as np
 
-    active = np.asarray(active)
-    parked = np.asarray(fork_req) & active
-    return bool(parked.any() and active.all()
-                and not (np.asarray(running) & active & ~parked).any())
+    return bool(_pool_stuck(np, np.asarray(active), np.asarray(fork_req),
+                            np.asarray(running)))
+
+
+def empty_observation(n_lanes: int) -> tuple:
+    """What ``pool_fixpoint`` has seen before a call's first sweep:
+    nothing, and not stuck."""
+    mask = jnp.zeros(n_lanes, dtype=bool)
+    return (jnp.zeros((), dtype=bool), jnp.zeros((), dtype=I32),
+            jnp.zeros((), dtype=I32), mask, mask, mask)
+
+
+def pool_fixpoint(seen: tuple, swept, active, fork_req, running,
+                  killed_total, dropped_total) -> tuple:
+    """Has the lane pool reached its fixpoint? Called once a superstep
+    with the frontier as the trip leaves it and ``swept``, whether the
+    trip held a feasibility sweep; ``seen`` is the observation taken at
+    the sweep before. Returns ``(seen, fixpoint)``.
+
+    An observation is ``pool_stuck``'s predicate, the run totals of
+    kills and drops and the three masks, and only a trip with a sweep
+    takes one. No superstep moves a stuck frontier (a parked lane
+    un-executes its branch and raises the same request again); only a
+    sweep that kills a lane frees a slot, and a sweep walks every node
+    a lane has added since the last one. So when two observations in a
+    row are stuck and equal in every part, a whole sweep ran from a
+    stuck frontier, killed nothing and handed the frontier back: every
+    later superstep would too, whatever budget is left. An empty
+    ``seen`` (``empty_observation``: this is the call's first sweep)
+    proves nothing."""
+    stuck = _pool_stuck(jnp, active, fork_req, running)
+    now = (stuck, killed_total, dropped_total, active, fork_req, running)
+    fixpoint = swept & stuck & seen[0]
+    for before, after in zip(seen[1:], now[1:]):
+        fixpoint &= jnp.all(before == after)
+    return (jax.tree.map(lambda a, b: jnp.where(swept, a, b), now, seen),
+            fixpoint)
 
 
 def relieve_starved(sf: SymFrontier, n_contracts: int,
@@ -3102,13 +3143,18 @@ def _sym_run_impl(sf: SymFrontier, env: Env, corpus: Corpus,
     P_run = sf.n_lanes
     C, MC = corpus.code.shape
     visited0 = jnp.zeros((C, MC), dtype=bool)
+    # the pool's fixpoint ends the loop (``pool_fixpoint``): only where
+    # lanes can park, and only a sweep can prove it. Elsewhere the carry
+    # holds no observation and the program is the one without the rule
+    watch_pool = bool(defer_starved and propagate_every)
 
     def cond(state):
-        i, s, _ = state
-        return (i < max_steps) & jnp.any(s.base.running)
+        i, s, _, pool = state
+        go = (i < max_steps) & jnp.any(s.base.running)
+        return go & ~pool[1] if watch_pool else go
 
     def body(state):
-        i, s, visited = state
+        i, s, visited, pool = state
         if track_coverage:
             # init-frame pcs index the per-lane init buffer, not the
             # contract image — they must not pollute its bitmap
@@ -3129,8 +3175,9 @@ def _sym_run_impl(sf: SymFrontier, env: Env, corpus: Corpus,
             s,
         )
         if propagate_every:
+            swept = (i % propagate_every) == propagate_every - 1
             s = ci.narrow_cond(
-                (i % propagate_every) == propagate_every - 1,
+                swept,
                 kill_infeasible, s,
                 ("iv_lo", "iv_hi", "kb_m", "kb_v", "prop_len",
                  "base.active", "fork_req", "killed_infeasible",
@@ -3155,13 +3202,31 @@ def _sym_run_impl(sf: SymFrontier, env: Env, corpus: Corpus,
                 lambda x: x,
                 s,
             )
-        return i + 1, s, visited
+        if watch_pool:
+            # judged as the trip leaves the frontier, after everything it
+            # does. A migration needs a free lane, so it never fires
+            # between two stuck observations; one that fired before them
+            # moved a lane, which the masks show: the compare covers it
+            pool = pool_fixpoint(pool[0], swept, s.base.active, s.fork_req,
+                                 s.base.running, s.killed_total,
+                                 s.dropped_total)
+        return i + 1, s, visited, pool
 
-    steps, sf, visited = lax.while_loop(cond, body,
-                                        (jnp.int32(0), sf, visited0))
+    # the observation starts empty in every call: what the host's seam
+    # did to the frontier since the last one cannot be taken for
+    # standing still
+    pool0 = ((empty_observation(P_run), jnp.zeros((), dtype=bool))
+             if watch_pool else ())
+    steps, sf, visited, pool = lax.while_loop(
+        cond, body, (jnp.int32(0), sf, visited0, pool0))
     # the loop counter as the loop left it: the only record of how many
     # supersteps a call that ended on quiescence really ran
     sf = sf.replace(steps_total=sf.steps_total + steps)
+    if defer_starved:
+        # whether THIS call left on the rule (a call without sweeps
+        # never does)
+        sf = sf.replace(fixpoint=pool[1] if watch_pool
+                        else jnp.zeros((), dtype=bool))
     return (sf, visited) if track_coverage else sf
 
 

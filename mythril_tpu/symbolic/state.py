@@ -17,6 +17,8 @@ fresh unconstrained variables rather than wrong values):
 
 from __future__ import annotations
 
+from typing import Optional
+
 import numpy as np
 import jax.numpy as jnp
 from flax import struct
@@ -193,6 +195,13 @@ class SymFrontier:
     arith_r: jnp.ndarray     # i32[P, AL] result node id
     arith_pc: jnp.ndarray    # i32[P, AL]
     arith_cid: jnp.ndarray   # i32[P, AL]
+    # bool[]: the last ``sym_run`` call left its loop at the lane pool's
+    # fixpoint (``pool_fixpoint``), with budget to spare. Written by every
+    # ``defer_starved`` call and read at the host's seam beside
+    # ``steps_total``. A frontier that no such call has seen carries no
+    # leaf, so a bare run's program has no such argument; a caller that
+    # chains ``defer_starved`` calls starts from ``False`` (one program)
+    fixpoint: Optional[jnp.ndarray] = None
 
     @property
     def n_lanes(self) -> int:

@@ -296,10 +296,11 @@ def _load_frontier_inner(path: str, template) -> Tuple[Any, Dict]:
                 # harvested or lost with the old format's fold-in)
                 leaves.append(np.asarray(tmpl_leaf))
                 continue
-            if name.endswith("steps_total"):
+            if name.endswith(("steps_total", "fixpoint")):
                 # frontiers written before the superstep counter: the
                 # count of what ran before the checkpoint is not known,
-                # so it resumes at 0
+                # so it resumes at 0; before ``fixpoint``: no call has
+                # left on the rule yet
                 leaves.append(np.zeros_like(np.asarray(tmpl_leaf)))
                 continue
             raise CheckpointCorrupt(

@@ -162,9 +162,14 @@ def test_small_contract_beside_hogs_is_served_at_the_pools_fixpoint(
 def pools():
     """Two batches at the shape the test above compiles, each run with
     the rule that ends a transaction at its pool's fixpoint and with
-    its predicate patched to ``False`` from here (the product has no
-    switch): ``hogs`` fills its pool in the second message call and
-    nobody is under the floor; in ``relieved`` the wallet is."""
+    the host deaf to it (the product has no switch: ``sym_run`` is
+    wrapped from here so that every frontier comes back with its
+    ``fixpoint`` scalar cleared; the compiled program is the same, and
+    each call still leaves its loop where the rule says): ``hogs`` fills
+    its pool in the second message call and nobody is under the floor;
+    in ``relieved`` the wallet is."""
+    import jax.numpy as jnp
+
     from mythril_tpu.analysis import symbolic
     from mythril_tpu.obs import metrics as obs_metrics
     from mythril_tpu.obs import trace as obs_trace
@@ -174,12 +179,18 @@ def pools():
                "relieved": hogs[:3] + [("wallet", WALLET, CTOR_OWNER)]}
     ran = {}
 
+    def deaf(*a, **kw):
+        sf, vis = symbolic_sym_run(*a, **kw)
+        return sf.replace(fixpoint=jnp.zeros((), dtype=bool)), vis
+
+    symbolic_sym_run = symbolic.sym_run
+
     def run(name, rule):
         before = obs_metrics.REGISTRY.snapshot()["counters"]
         tracer = obs_trace.configure(buffer=True)
         with pytest.MonkeyPatch.context() as mp:
             if not rule:
-                mp.setattr(symbolic, "pool_stuck", lambda *a: False)
+                mp.setattr(symbolic, "sym_run", deaf)
             try:
                 res = CorpusCampaign(
                     batches[name], batch_size=4, lanes_per_contract=16,
@@ -215,15 +226,17 @@ def _per_tx(counters, family):
 @pytest.mark.parametrize("case", [
     "the_reports_are_the_rule_off_runs", "the_calls_that_go",
     "counters_and_span_attributes", "a_transaction_never_stuck",
-    "a_pool_that_is_relieved"])
+    "a_pool_that_is_relieved", "no_call_spins_a_chunk_at_a_stuck_pool"])
 def test_transaction_ends_where_its_pool_is_proven_stuck(pools, case):
     """Three 12-function hogs and a fourth fill the pool of 64 lanes in
-    the first chunk of the second message call: every lane parks. The
-    second chunk is the witness, and the transaction ends there: the
-    four drain rounds, which hand the frontier back unchanged, do not
-    run. Nothing any report reads moves."""
+    the first chunk of the second message call: every lane parks, the
+    call leaves its loop at the second sweep after that, and the
+    transaction ends at that call's seam: no witness call, no second
+    chunk, none of the four drain rounds. Nothing any report reads
+    moves."""
     name = "relieved" if case == "a_pool_that_is_relieved" else "hogs"
     on, off = pools(name, True), pools(name, False)
+    sweep = TEST_LIMITS.propagate_every
     if case == "the_reports_are_the_rule_off_runs":
         assert on["report"] == off["report"]
         assert on["report"][2] > 0      # forks were lost: a full pool
@@ -231,22 +244,35 @@ def test_transaction_ends_where_its_pool_is_proven_stuck(pools, case):
             assert _per_tx(on["counters"], family) == _per_tx(
                 off["counters"], family) != {}
     elif case == "the_calls_that_go":
+        # deaf to the flag the host starts every call the budget holds,
+        # and each leaves at its second sweep: the frontier stands still
         last = [c for c in off["calls"] if c[0] == 2]
-        assert last == [(2, 64, 64, False)] * 2 + [(2, 64, 64, True)] * 4
-        assert [c for c in on["calls"] if c[0] == 2] == last[:2]
+        filled = last[0][2]
+        assert 2 * sweep <= filled < 64 and filled % sweep == 0
+        assert last == ([(2, 64, filled, False),
+                         (2, 64, 2 * sweep, False)]
+                        + [(2, 64, 2 * sweep, True)] * 4)
+        assert [c for c in on["calls"] if c[0] == 2] == last[:1]
         assert off["spans"][-1]["ended"] == "budget"
         assert on["counters"]["engine_supersteps_total"] == (
-            off["counters"]["engine_supersteps_total"] - 4 * 64)
+            off["counters"]["engine_supersteps_total"] - 5 * 2 * sweep)
     elif case == "counters_and_span_attributes":
         assert on["counters"]['engine_fixpoint_ends_total{tx="2"}'] == 1
-        assert on["counters"]['engine_calls_skipped_total{tx="2"}'] == 4
+        assert on["counters"]['engine_calls_skipped_total{tx="2"}'] == 5
+        assert on["counters"][
+            'engine_inloop_fixpoint_exits_total{tx="2"}'] == 1
+        # (a deaf host counts none of the six calls that left early)
+        assert not _per_tx(off["counters"],
+                           "engine_inloop_fixpoint_exits_total")
         assert not _per_tx(off["counters"], "engine_fixpoint_ends_total")
         assert not _per_tx(off["counters"], "engine_calls_skipped_total")
-        last = [s for s in on["spans"] if s["tx"] == 2]
-        assert [s["stuck"] for s in last] == [True, True]
-        assert "ended" not in last[0] and "skipped" not in last[0]
-        assert last[1]["ended"] == "fixpoint" and last[1]["skipped"] == 4
-        assert not any(s["stuck"] for s in off["spans"])
+        (last,) = [s for s in on["spans"] if s["tx"] == 2]
+        assert last["stuck"] is True and last["ended_in"] == "fixpoint"
+        assert last["ended"] == "fixpoint" and last["skipped"] == 5
+        assert last["steps_run"] < last["steps"]
+        # the predicate of ``stuck`` is the host's own, deaf or not
+        assert all(s["stuck"] and "ended_in" not in s
+                   for s in off["spans"] if s["tx"] == 2)
     elif case == "a_transaction_never_stuck":
         # the constructor and the first message call end at quiescence
         for run in (on, off):
@@ -254,18 +280,39 @@ def test_transaction_ends_where_its_pool_is_proven_stuck(pools, case):
             assert [c for c in run["calls"] if c[0] < 2] == [
                 (0, 64, 6, False), (1, 64, 64, False), (1, 64, 8, False)]
             assert not any(s["stuck"] for s in early)
+            assert not any("ended_in" in s for s in early)
             assert [s.get("ended") for s in early] == [
                 "quiescent", None, "quiescent"]
+            assert not any(k.startswith(
+                ("engine_inloop_fixpoint_exits_total{tx=\"0\"",
+                 "engine_inloop_fixpoint_exits_total{tx=\"1\""))
+                for k in run["counters"])
+    elif case == "no_call_spins_a_chunk_at_a_stuck_pool":
+        # what the parent paid for its proof: a whole chunk from a
+        # stuck seam. No call does, with the host listening or deaf
+        for run in (on, off):
+            before = None
+            for s in run["spans"]:
+                if before is not None and before["tx"] == s["tx"] \
+                        and before["stuck"]:
+                    assert s["steps_run"] <= 2 * sweep < s["steps"]
+                before = s
     else:
         # the wallet is under its floor at the first seam of the second
-        # call: ``relieve_starved`` evicts there, so that seam is not
-        # stuck, and every call runs that ran before
+        # call: that call left its loop at the fixpoint too, but
+        # ``relieve_starved`` evicts at its seam, so the transaction goes
+        # on from a new frontier, and runs every call it ran
         assert on["report"] == off["report"]
         assert on["report"][0] == [("wallet", "106")]
         assert on["calls"] == off["calls"]
-        assert on["counters"] == off["counters"]
+        exits = _per_tx(on["counters"], "engine_inloop_fixpoint_exits_total")
+        assert {k: v for k, v in on["counters"].items()
+                if k not in exits} == off["counters"]
         assert on["counters"]["evicted_lanes_total"] > 0
         assert not _per_tx(on["counters"], "engine_fixpoint_ends_total")
+        assert exits == {'engine_inloop_fixpoint_exits_total{tx="2"}': 1}
+        first = [s for s in on["spans"] if s["tx"] == 2][0]
+        assert first["ended_in"] == "fixpoint" and not first["stuck"]
         assert on["spans"][-1]["ended"] == "quiescent"
 
 

@@ -394,12 +394,13 @@ def test_superstep_spans_end_with_the_device_and_count_what_ran(spill):
     reads = [r["what"] for r in spans_of(recs, "device_fetch")
              if r["what"].startswith("visited,steps_total")]
     assert len(reads) == len(steps)
-    # with spill the seam's masks and the two run totals that prove a
-    # stuck pool (``_PoolWatch``) ride it too: no read of their own
+    # with spill the seam's masks and the scalar that says the call left
+    # its loop at the pool's fixpoint ride it too: no read of their own
     assert reads[0] == ("visited,steps_total,base.active,fork_req,"
-                        "base.running,base.home_contract,killed_total,"
-                        "dropped_total" if spill
-                        else "visited,steps_total")
+                        "base.running,base.home_contract,fixpoint"
+                        if spill else "visited,steps_total")
+    assert not any("ended_in" in sp for sp in steps)
+    assert (sym.sf.fixpoint is None) is (not spill)
     assert [sp["stuck"] for sp in steps] == [False] * len(steps)
     assert [sp.get("ended") for sp in steps] == (
         [None] * (len(steps) - 1) + ["quiescent"])
@@ -461,6 +462,84 @@ def test_deadline_pacing_reads_the_devices_rate(monkeypatch):
     for sp in steps:
         assert sp["steps_run"] == sp["steps"]
         assert sp["dur"] >= 0.9 * per_step * sp["steps"] > sp["enqueue_s"]
+
+
+# --- the seam of a call that left its loop at the pool's fixpoint -----------
+
+@pytest.mark.parametrize("case", [
+    "an_idle_seam_ends_the_transaction", "a_seam_that_evicts_goes_on",
+    "a_stuck_pool_without_the_flag_runs_its_budget",
+    "the_drain_does_not_take_the_last_chunks_flag"])
+def test_the_host_ends_a_transaction_only_on_the_devices_word(
+        case, monkeypatch):
+    """What ``explore`` makes of the frontier's ``fixpoint`` scalar, on
+    a stand-in for ``sym_run`` that hands back a full pool, every lane
+    parked, with the flag as the case wants it: the transaction ends at
+    a seam only if the device said so AND the seam's scheduling step did
+    nothing; the host has no rule of its own."""
+    import jax.numpy as jnp
+
+    from mythril_tpu.analysis import SymExecWrapper
+    from mythril_tpu.analysis import symbolic as asym
+
+    flag = case != "a_stuck_pool_without_the_flag_runs_its_budget"
+    calls = []
+
+    def runner(sf, env, corpus, spec, limits, max_steps, **kw):
+        calls.append(max_steps)
+        C, MC = corpus.code.shape
+        P = sf.n_lanes
+        full = jnp.ones(P, dtype=bool)
+        return sf.replace(
+            base=sf.base.replace(active=full,
+                                 halted=jnp.zeros(P, dtype=bool)),
+            fork_req=full, fixpoint=jnp.asarray(flag),
+            steps_total=sf.steps_total + 2), np.zeros((C, MC), dtype=bool)
+
+    evictions = iter([1] if case in (
+        "a_seam_that_evicts_goes_on",
+        "the_drain_does_not_take_the_last_chunks_flag") else [])
+    monkeypatch.setattr(asym, "sym_run", runner)
+    monkeypatch.setattr(asym, "relieve_starved",
+                        lambda sf, *a: (sf, next(evictions, 0)))
+    budget = 8 if case.startswith("the_drain") else 32
+    tr = obs_trace.configure(buffer=True)
+    SymExecWrapper([SAFE], limits=TEST_LIMITS, lanes_per_contract=4,
+                   max_steps=budget, deadline_chunk_steps=8,
+                   warm_shapes={8, 2})
+    steps = spans_of(tr.drain_buffer(), "superstep")
+    ctr = obs_metrics.REGISTRY.snapshot()["counters"]
+    ends = ctr.get('engine_fixpoint_ends_total{tx="0"}', 0)
+    skipped = ctr.get('engine_calls_skipped_total{tx="0"}', 0)
+    exits = ctr.get('engine_inloop_fixpoint_exits_total{tx="0"}', 0)
+    assert len(steps) == len(calls) == exits + (0 if flag else len(calls))
+    assert [sp.get("ended_in") for sp in steps] == (
+        ["fixpoint" if flag else None] * len(steps))
+    if case == "an_idle_seam_ends_the_transaction":
+        assert calls == [8]
+        assert (ends, skipped) == (1, 3 + 4)     # 3 chunks, 4 drain rounds
+        assert steps[0]["stuck"] and steps[0]["ended"] == "fixpoint"
+        assert steps[0]["skipped"] == 7 and steps[0]["steps_run"] == 2
+    elif case == "a_seam_that_evicts_goes_on":
+        assert calls == [8, 8]
+        assert (ends, skipped) == (1, 2 + 4)
+        assert [sp["stuck"] for sp in steps] == [False, True]
+        assert "ended" not in steps[0] and steps[1]["ended"] == "fixpoint"
+    elif case == "a_stuck_pool_without_the_flag_runs_its_budget":
+        assert calls == [8] * 4 + [8] * 4        # chunks, then the drain
+        assert (ends, skipped) == (0, 0)
+        assert all(sp["stuck"] for sp in steps)
+        assert steps[-1]["ended"] == "budget"
+        assert [bool(sp.get("drain")) for sp in steps] == (
+            [False] * 4 + [True] * 4)
+    else:
+        # the budget's one chunk left on the rule, but its seam evicted:
+        # the drain starts from a frontier that is no call's yet, runs a
+        # round, and that round's word ends it
+        assert calls == [8, 8]
+        assert [bool(sp.get("drain")) for sp in steps] == [False, True]
+        assert (ends, skipped) == (1, 3)
+        assert steps[-1]["ended"] == "fixpoint"
 
 
 # --- phase spans of a pipelined campaign ------------------------------------
@@ -544,9 +623,12 @@ def test_trace_report_counts_the_kernel_reads_of_each_host_phase(
     assert "dispatches on the device" not in bi1
 
 
-def test_trace_report_says_how_each_transaction_ended(tmp_path):
-    """Two batches: the second message call ends at its pool's fixpoint
-    after a witness call; the first runs its budget."""
+@pytest.mark.parametrize("trace", ["calls_leave_inside", "witness_calls"])
+def test_trace_report_says_how_each_transaction_ended(tmp_path, trace):
+    """Two batches: the second message call ends at its pool's fixpoint,
+    in the call that filled it, which left its loop inside; the first
+    runs its budget. (``witness_calls``: a trace from before the loop
+    saw the fixpoint, whose transactions paid a whole call for it.)"""
     import importlib.util
     import json
     import os
@@ -557,18 +639,23 @@ def test_trace_report_says_how_each_transaction_ended(tmp_path):
     report = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(report)
 
-    def call(mono, tx, stuck, **attrs):
+    def call(mono, tx, stuck, steps_run=64, **attrs):
         return dict(schema=1, kind="span", name="superstep", t=0.0,
                     mono=mono, dur=2.0, tid=9, tx=tx, tx_kind="message",
-                    steps=64, steps_run=64, cold=False, stuck=stuck,
+                    steps=64, steps_run=steps_run, cold=False, stuck=stuck,
                     **attrs)
 
     recs = []
     for t0 in (0.0, 100.0):
-        recs += [call(t0 + 1, 0, False), call(t0 + 4, 0, False,
-                                              ended="budget"),
-                 call(t0 + 10, 1, True),
-                 call(t0 + 13, 1, True, ended="fixpoint", skipped=6)]
+        recs += [call(t0 + 1, 0, False),
+                 call(t0 + 4, 0, False, ended="budget")]
+        if trace == "witness_calls":
+            recs += [call(t0 + 10, 1, True),
+                     call(t0 + 13, 1, True, ended="fixpoint", skipped=6)]
+        else:
+            recs += [call(t0 + 10, 1, True, steps_run=40,
+                          ended_in="fixpoint", ended="fixpoint",
+                          skipped=7)]
     # emitted once their seams have spoken: not in time order
     recs.reverse()
     path = tmp_path / "t.jsonl"
@@ -578,9 +665,13 @@ def test_trace_report_says_how_each_transaction_ended(tmp_path):
     cols = text[head + 1].split()
     rows = [dict(zip(cols, ln.split(None, len(cols) - 1)))
             for ln in text[head + 2:head + 4]]
-    assert [(r["tx"], r["calls"], r["skipped"], r["spun"], r["ended"])
-            for r in rows] == [("0", "4", "0", "0", "budget x2"),
-                               ("1", "4", "12", "2", "fixpoint x2")]
+    got = [(r["tx"], r["calls"], r["early"], r["unrun"], r["skipped"],
+            r["spun"], r["ended"]) for r in rows]
+    assert got[0] == ("0", "4", "0", "0", "0", "0", "budget x2")
+    assert got[1] == (
+        ("1", "4", "0", "0", "12", "2", "fixpoint x2")
+        if trace == "witness_calls"
+        else ("1", "2", "2", "48", "14", "0", "fixpoint x2"))
 
 
 # --- checkpoints written before the counter ----------------------------------
@@ -604,3 +695,35 @@ def test_checkpoint_without_the_step_counter_resumes_at_zero(tmp_path):
     save_frontier(path, template, {"tx": 1})
     got, _ = load_frontier(path, sf)
     assert int(np.asarray(got.steps_total)) == 7
+
+
+@pytest.mark.parametrize("written", ["before_the_leaf", "with_the_leaf",
+                                     "by_a_bare_run"])
+def test_checkpoint_and_the_fixpoint_scalar(tmp_path, written):
+    """A spill run's frontier carries ``fixpoint``; a checkpoint written
+    before the leaf existed resumes with it clear (no call has left on
+    the rule yet), one written with it brings it back, and a bare run's
+    frontier has no such leaf on either side."""
+    import jax.numpy as jnp
+
+    from mythril_tpu.symbolic import make_sym_frontier
+    from mythril_tpu.utils.checkpoint import load_frontier, save_frontier
+
+    bare = make_sym_frontier(4, TEST_LIMITS)
+    assert bare.fixpoint is None
+    spill = bare.replace(fixpoint=jnp.ones((), dtype=bool))
+    path = str(tmp_path / "f.npz")
+    if written == "before_the_leaf":
+        save_frontier(path, bare, {})
+        got, _ = load_frontier(path, spill)
+        assert np.asarray(got.fixpoint).dtype == np.bool_
+        assert not bool(np.asarray(got.fixpoint))
+    elif written == "with_the_leaf":
+        save_frontier(path, spill, {})
+        got, _ = load_frontier(
+            path, bare.replace(fixpoint=jnp.zeros((), dtype=bool)))
+        assert bool(np.asarray(got.fixpoint))
+    else:
+        save_frontier(path, bare, {})
+        got, _ = load_frontier(path, bare)
+        assert got.fixpoint is None

@@ -247,28 +247,33 @@ def test_full_frontier_fixpoint_is_broken_for_the_starved_only(case):
         assert (act == active).all() and (req == parked).all()
 
 
-# --- the proof that a full frontier is stuck for good (``_PoolWatch``) -------
+# --- the rule that ends sym_run's loop at the pool's fixpoint ----------------
 
-def _seams(case):
-    """Two seams of one transaction with a 64-superstep call between
-    them, as ``(active, fork_req, running, killed, dropped, idle)``."""
+def _observations(case):
+    """Two trips of one call that each held a feasibility sweep, as
+    ``[active, fork_req, running, killed_total, dropped_total]``, and
+    what the first of them found in the loop's carry."""
+    from mythril_tpu.symbolic.engine import empty_observation
+
     full = np.ones(P, dtype=bool)
     parked = np.zeros(P, dtype=bool)
     parked[[0, 3, 4, 12, 13, 14, 20, 25, 26]] = True
-    first = [full, parked, parked.copy(), 7, 2, True]
-    second = [full.copy(), parked.copy(), parked.copy(), 7, 2, True]
-    ran = 64
+    first = [full, parked, parked.copy(), 7, 2]
+    second = [full.copy(), parked.copy(), parked.copy(), 7, 2]
+    seen = empty_observation(P)
     if case == "a_lane_is_free":
         second[0][31] = False
     elif case == "a_lane_still_moves":
         second[2][30] = True
-    elif case == "the_seam_before_was_not_stuck":
+    elif case == "the_sweep_before_was_not_stuck":
         first[2] = parked.copy()
         first[2][30] = True
-    elif case == "the_first_seam_evicted":
-        first[5] = False        # relieve_starved gave lanes up there
-    elif case == "this_seam_moved_lanes":
-        second[5] = False
+    elif case == "an_observation_from_before_the_call_does_not_count":
+        # the call before left stuck at this very frontier and the
+        # host's seam evicted: a new call starts from an empty carry
+        first = None
+    elif case == "a_parked_lane_halted":
+        second[2][3] = False    # both stuck, ``running`` not as it was
     elif case == "a_sweep_killed":
         second[3] = 8           # and a parked fork took the freed lane
     elif case == "a_fork_was_dropped":
@@ -277,72 +282,119 @@ def _seams(case):
         # a halted lane went and a parked fork took its slot, parked
         # itself: ``active`` as before, ``fork_req`` not
         second[1][5] = second[2][5] = True
-    elif case == "the_call_held_no_sweep":
-        ran = 4
-    return first, ran, second
+    return seen, first, second
 
 
 @pytest.mark.parametrize("case", [
     "stuck_twice_and_equal", "a_lane_is_free", "a_lane_still_moves",
-    "the_seam_before_was_not_stuck", "the_first_seam_evicted",
-    "this_seam_moved_lanes", "a_sweep_killed", "a_fork_was_dropped",
-    "another_lane_is_parked", "the_call_held_no_sweep"])
+    "the_sweep_before_was_not_stuck",
+    "an_observation_from_before_the_call_does_not_count",
+    "a_parked_lane_halted", "a_sweep_killed", "a_fork_was_dropped",
+    "another_lane_is_parked", "the_call_held_no_second_sweep"])
 def test_pool_is_proven_stuck_only_by_stuck_stuck_and_equal(case):
-    from mythril_tpu.analysis.symbolic import _PoolWatch
+    """``pool_fixpoint`` on tiny arrays, as the loop's body calls it."""
+    from mythril_tpu.symbolic.engine import pool_fixpoint
 
-    first, ran, second = _seams(case)
-    watch = _PoolWatch(sweep_every=8)
-    stuck, proven = watch.seam(*first[:5], idle=first[5])
-    assert not proven           # one seam alone proves nothing
-    assert stuck is (case not in ("the_seam_before_was_not_stuck",
-                                  "the_first_seam_evicted"))
-    watch.ran(ran)
-    stuck, proven = watch.seam(*second[:5], idle=second[5])
-    assert proven is (case == "stuck_twice_and_equal")
-    assert stuck is (case not in ("a_lane_is_free", "a_lane_still_moves",
-                                  "this_seam_moved_lanes"))
-    # a second look at a seam with no call in between is no witness
-    # (the drain's first fetch after the last chunk's seam)
-    assert watch.seam(*second[:5], idle=second[5]) == (stuck, False)
-    # ... and the next whole call from it is
-    watch.ran(64)
-    assert watch.seam(*second[:5], idle=second[5]) == (stuck, stuck)
+    def trip(seen, obs, swept=True):
+        seen, fixpoint = pool_fixpoint(seen, swept, *map(jnp.asarray, obs))
+        return seen, bool(fixpoint)
+
+    seen, first, second = _observations(case)
+    if first is not None:
+        seen, fixpoint = trip(seen, first)
+        assert not fixpoint     # one observation alone proves nothing
+        assert bool(seen[0]) is (case != "the_sweep_before_was_not_stuck")
+    if case == "the_call_held_no_second_sweep":
+        # the seven trips between two sweeps take no observation and
+        # prove nothing, however stuck the frontier is
+        for _ in range(7):
+            was = seen
+            seen, fixpoint = trip(seen, second, swept=False)
+            assert not fixpoint
+            assert all(np.array_equal(a, b) for a, b in zip(seen, was))
+        return
+    seen, fixpoint = trip(seen, second)
+    assert fixpoint is (case == "stuck_twice_and_equal")
+    stuck = case not in ("a_lane_is_free", "a_lane_still_moves")
+    assert bool(seen[0]) is stuck
+    # the observation is what this sweep saw: the next one like it is
+    # the proof, whatever came before
+    seen, fixpoint = trip(seen, second)
+    assert fixpoint is stuck
+
+
+@pytest.mark.parametrize("case", ["stuck_twice_and_equal", "a_sweep_killed",
+                                  "a_lane_is_free"])
+def test_sharded_pool_rule_matches_unsharded(case):
+    """The rule's reductions over a lane axis sharded on the virtual
+    8-device mesh (what ``cond`` already does with ``any(running)``):
+    same observation, same verdict."""
+    from mythril_tpu.symbolic.engine import pool_fixpoint
+
+    seen, first, second = _observations(case)
+    mesh = Mesh(np.array(jax.devices()[:8]), axis_names=("dp",))
+    lanes, whole = NamedSharding(mesh, PS("dp")), NamedSharding(mesh, PS())
+
+    def two_sweeps(seen, first, second):
+        seen, _ = pool_fixpoint(seen, True, *first)
+        return pool_fixpoint(seen, True, *second)
+
+    def put(obs, sharded):
+        return tuple(
+            jax.device_put(jnp.asarray(x), lanes if sharded
+                           and np.ndim(x) else whole) for x in obs)
+
+    ref = jax.jit(two_sweeps)(seen, put(first, False), put(second, False))
+    seen_sh = (*seen[:3], *(jax.device_put(m, lanes) for m in seen[3:]))
+    out = jax.jit(two_sweeps)(seen_sh, put(first, True), put(second, True))
+    assert bool(out[1]) is bool(ref[1]) is (case == "stuck_twice_and_equal")
+    for a, b in zip(jax.tree.leaves(ref), jax.tree.leaves(out)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _leaves_that_differ(a, b):
+    return sorted(
+        jax.tree_util.keystr(path)
+        for (path, x), y in zip(jax.tree_util.tree_leaves_with_path(a),
+                                jax.tree.leaves(b))
+        if not np.array_equal(np.asarray(x), np.asarray(y)))
 
 
 def test_a_stuck_pools_next_chunk_hands_back_the_frontier():
-    """The proof itself, on the compiled engine (the program ``_run(0)``
-    compiles): every lane is taken and parks on the first branch; the
-    call after the first stuck seam is the witness; from there one more
-    chunk changes no leaf but the two step counters."""
-    from mythril_tpu.analysis.symbolic import _PoolWatch
+    """The rule on the compiled engine (the program ``_run(0)``
+    compiles): every lane is taken and parks on the first branch, and
+    the call leaves its loop at the second sweep after that, flag set;
+    one more chunk from there runs two sweeps and changes no leaf but
+    the two step counters."""
     from mythril_tpu.symbolic.engine import pool_stuck
 
     img = ContractImage.from_bytecode(CODE, L.max_code)
     corpus = Corpus.from_images([img])
     sf = make_sym_frontier(P, L, active=np.ones(P, dtype=bool))
     env = make_env(P)
+    assert sf.fixpoint is None      # no ``defer_starved`` call has run
 
     def chunk(sf):
-        return sym_run(sf, env, corpus, SymSpec(), L, max_steps=64,
-                       fork_block=B, defer_starved=True, migrate_every=0)
+        # (the flag is an output only: handed in without it, every call
+        # is the one program ``_run(0)`` compiled)
+        return sym_run(sf.replace(fixpoint=None), env, corpus, SymSpec(),
+                       L, max_steps=64, fork_block=B, defer_starved=True,
+                       migrate_every=0)
 
-    def seam(sf):
-        return (np.asarray(sf.base.active), np.asarray(sf.fork_req),
-                np.asarray(sf.base.running), int(sf.killed_total),
-                int(sf.dropped_total))
-
-    watch = _PoolWatch(L.propagate_every)
-    sf = chunk(sf)
-    assert pool_stuck(*seam(sf)[:3])
-    assert watch.seam(*seam(sf), idle=True) == (True, False)
-    witness = chunk(sf)
-    watch.ran(64)
-    assert watch.seam(*seam(witness), idle=True) == (True, True)
-    after = chunk(witness)
-    moved = [jax.tree_util.keystr(path)
-             for (path, a), b in zip(
-                 jax.tree_util.tree_leaves_with_path(witness),
-                 jax.tree.leaves(after))
-             if not np.array_equal(np.asarray(a), np.asarray(b))]
-    assert sorted(moved) == [".base.n_steps", ".steps_total"]
-    assert int(after.steps_total) == int(witness.steps_total) + 64
+    first = chunk(sf)
+    assert pool_stuck(np.asarray(first.base.active),
+                      np.asarray(first.fork_req),
+                      np.asarray(first.base.running))
+    assert bool(first.fixpoint)
+    ran = int(first.steps_total)
+    assert ran < 64 and ran % L.propagate_every == 0
+    after = chunk(first)
+    assert bool(after.fixpoint)
+    assert int(after.steps_total) == ran + 2 * L.propagate_every <= ran + 16
+    assert _leaves_that_differ(first, after) == [".base.n_steps",
+                                                 ".steps_total"]
+    # a call that ends another way clears the flag: nothing runs here
+    idle = chunk(after.replace(base=after.base.replace(
+        halted=jnp.ones(P, dtype=bool))))
+    assert not bool(idle.fixpoint)
+    assert int(idle.steps_total) == int(after.steps_total)

@@ -6,6 +6,7 @@ import pathlib
 import re
 
 import numpy as np
+import pytest
 
 import mythril_tpu  # noqa: F401
 from mythril_tpu.config import TEST_LIMITS
@@ -92,3 +93,57 @@ def test_sym_run_has_one_program():
                for f in sorted((pkg / d).rglob("*.py"))
                if re.search(r"os\.environ|getenv", f.read_text())]
     assert readers == []
+
+
+def _loop_of(monkeypatch, rule_raises=False, **static):
+    """``_sym_run_impl`` traced (nothing compiles): the carry of its
+    superstep loop and the frontier it returns."""
+    import jax
+
+    from mythril_tpu.symbolic import engine
+
+    if rule_raises:
+        def never(*a, **kw):
+            raise AssertionError("pool_fixpoint traced")
+
+        monkeypatch.setattr(engine, "pool_fixpoint", never)
+    sf, env, corpus = build(SPINS)
+    closed, out = jax.make_jaxpr(
+        lambda sf, env, corpus: engine._sym_run_impl(
+            sf, env, corpus, SymSpec(), TEST_LIMITS, max_steps=BUDGET,
+            **static),
+        return_shape=True)(sf, env, corpus)
+    (loop,) = [e for e in closed.jaxpr.eqns if e.primitive.name == "while"]
+    return len(loop.outvars), len(jax.tree.leaves(sf)), out
+
+
+@pytest.mark.parametrize("case", ["bare", "spill", "spill_without_sweeps"])
+def test_the_pools_rule_is_compiled_in_only_where_lanes_park(
+        case, monkeypatch):
+    """Without ``defer_starved`` nothing parks: the rule is not even
+    traced, the loop carries what it always did and the frontier comes
+    back without a ``fixpoint`` leaf, so the program is the one it was
+    before the rule (``tools/sym_run_digest.py``'s ``bare`` is the
+    byte-for-byte check against another checkout). With it the carry
+    also holds the observation of the last sweep (six parts) and the
+    flag; without sweeps nothing can be proven, and only the flag comes
+    back, clear."""
+    if case == "bare":
+        carry, leaves, out = _loop_of(monkeypatch, rule_raises=True)
+        # (JAX keeps what the body hands through unchanged out of the
+        # carry, so it holds fewer than every leaf)
+        assert carry <= 1 + leaves + 1
+        assert out.fixpoint is None
+        return
+    plain, _, out = _loop_of(monkeypatch, rule_raises=True,
+                             defer_starved=True, propagate_every=0)
+    assert out.fixpoint.shape == () and out.fixpoint.dtype == bool
+    if case == "spill":
+        with pytest.raises(AssertionError, match="pool_fixpoint traced"):
+            _loop_of(monkeypatch, rule_raises=True, defer_starved=True)
+        monkeypatch.undo()
+        carry, _, out = _loop_of(monkeypatch, defer_starved=True)
+        # the observation's six parts and the flag (and what a sweep
+        # writes, which the run without sweeps handed through)
+        assert carry >= plain + 6 + 1
+        assert out.fixpoint.shape == () and out.fixpoint.dtype == bool

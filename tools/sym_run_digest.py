@@ -5,10 +5,13 @@ Lowers (never compiles, never runs) ``sym_run`` with exactly the
 arguments ``SymExecWrapper.explore`` passes in a benchmark cell, 8
 contracts x 128 lanes at ``DEFAULT_LIMITS``, once over a corpus of
 pairs and once over a deploying one (16 images, ``--concrete-storage``),
-and prints the SHA-256 of each StableHLO text. Two checkouts whose
-digests agree hand XLA the same module, so they share one executable in
-the compile cache. A PR that says it left the engine alone shows it by
-running this on its parent and on itself:
+and prints the SHA-256 of each StableHLO text; ``bare`` is the pair
+corpus without spill (``defer_starved=False``: one whole call, the
+program in which no lane parks and no rule of the lane pool is
+compiled). Two checkouts whose digests agree hand XLA the same module,
+so they share one executable in the compile cache. A PR that says it
+left the engine alone shows it by running this on its parent and on
+itself:
 
     python tools/sym_run_digest.py                # this checkout
     python tools/sym_run_digest.py ../parent DIR  # another one; texts kept in DIR
@@ -68,6 +71,7 @@ def main() -> int:
     out = {"root": root, "backend": jax.default_backend()}
     for cell, kw in (
             ("pairs", dict(spec=SymSpec())),
+            ("bare", dict(spec=SymSpec(), spill=False)),
             ("deploys", dict(spec=SymSpec(storage=False),
                              creation_bytecodes=[creation_of(c)
                                                  for c in codes]))):

@@ -155,9 +155,12 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
 
     # 1b. the transactions of a batch: which kind each was, what its
     # sym_run calls cost, the paths that ended it and the forks it lost;
-    # the calls the pool's fixpoint saved (``skipped``), those that
-    # still started from a stuck seam and could only hand their
-    # frontier back (``spun``: the witnesses), and what ended it
+    # the calls that left their loop at the pool's fixpoint (``early``,
+    # with the supersteps of their budget they did not run: ``unrun``),
+    # the calls the fixpoint saved whole (``skipped``), those that still
+    # started from a stuck seam and could only hand their frontier back
+    # (``spun``: none since the loop itself sees the fixpoint), and what
+    # ended it
     by_tx: Dict[tuple, Dict] = {}
     before = None       # the superstep span before, if of this transaction
     for s in sorted(spans, key=lambda s: s["mono"]):
@@ -168,11 +171,15 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
         row = by_tx.setdefault((a.get("tx"), a["tx_kind"]), {
             "calls": 0, "sec": 0.0, "paths": 0, "dropped": 0,
             "carried": 0, "seam": 0.0, "skipped": 0, "spun": 0,
-            "spun_sec": 0.0, "ended": {}})
+            "spun_sec": 0.0, "early": 0, "unrun": 0, "ended": {}})
         if s["name"] == "superstep":
             row["calls"] += 1
             row["sec"] += s["dur"]
             row["skipped"] += int(a.get("skipped", 0))
+            if a.get("ended_in") == "fixpoint":
+                row["early"] += 1
+                row["unrun"] += int(a.get("steps", 0)) - int(
+                    a.get("steps_run", 0))
             if before is not None and before.get("stuck"):
                 row["spun"] += 1
                 row["spun_sec"] += s["dur"]
@@ -189,7 +196,8 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
     if by_tx:
         out.append("")
         out.append("== transactions (tx, tx_kind) ==")
-        out.append(f"{'tx':>3} {'kind':<9}{'calls':>6}{'skipped':>8}"
+        out.append(f"{'tx':>3} {'kind':<9}{'calls':>6}{'early':>6}"
+                   f"{'unrun':>7}{'skipped':>8}"
                    f"{'spun':>5}{'spun_s':>10}{'sym_run':>10}"
                    f"{'paths':>8}{'dropped':>9}{'admitted':>10}"
                    f"{'carried':>9}{'seam':>10}  ended")
@@ -198,7 +206,8 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
             ended = ", ".join(f"{k} x{n}" for k, n in sorted(
                 r["ended"].items(), key=lambda kv: -kv[1]))
             out.append(
-                f"{tx!s:>3} {kind:<9}{r['calls']:>6}{r['skipped']:>8}"
+                f"{tx!s:>3} {kind:<9}{r['calls']:>6}{r['early']:>6}"
+                f"{r['unrun']:>7}{r['skipped']:>8}"
                 f"{r['spun']:>5}{_fmt_s(r['spun_sec']):>10}"
                 f"{_fmt_s(r['sec']):>10}"
                 f"{r['paths']:>8}{r['dropped']:>9}"
