@@ -115,9 +115,12 @@ class IntegerArithmetics(DetectionModule):
                 nodes = list(base.nodes)
                 idx = dict(ctx.tape_index(lane))
                 cons = list(base.constraints)
-                # predicate nodes are INTERNED onto the path tape: a
-                # SafeMath guard asserts the very same LT node, and the
-                # shared id lets the refuter prove guarded ops UNSAT
+                # predicate nodes are INTERNED onto the path tape, so
+                # they share the guard's operand ids. A SafeMath guard
+                # asserts the same predicate, rarely the same node
+                # (solc tests the other operand, GT for LT, EQ the other
+                # way round): the refuter proves guarded ops UNSAT by a
+                # shared id or by its normal form (smt/refute.py)
                 if op == 0x01:  # ADD
                     cons.append((intern_node(
                         nodes, HostNode(int(SymOp.LT), r, a, 0), idx), True))

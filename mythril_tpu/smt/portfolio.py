@@ -11,9 +11,12 @@ path) from the default into the rare last resort:
                    repeats from cloned bytecode hit; entries hold
                    canonical-coordinate witnesses that are rehydrated
                    and re-verified per hit;
-  2. **refute**  — structural unsat proof (``smt/refute.py``'s
-                   forced-value propagation over the tape the device
-                   produced): proven UNSAT without any search;
+  2. **refute**  — structural unsat proof (``smt/refute.py``: one
+                   predicate asserted both true and false, compared
+                   modulo its equivalent forms, then forced-value
+                   propagation over the tape the device produced):
+                   proven UNSAT without any search, counted by rule in
+                   ``solver_refute_total{rule}``;
   3. **probe**   — model probe via exact tape evaluation
                    (``smt/eval.py``, the native evaluator): if the
                    seed assignment already satisfies every constraint
@@ -63,7 +66,7 @@ from . import solver as _sv
 from .canon import (CanonicalQuery, canonical_query, witness_from_doc,
                     witness_ok, witness_to_doc)
 from .eval import Assignment, evaluate
-from .refute import refute_tape
+from .refute import RULES as REFUTE_RULES, refute_tape
 from .tape import HostTape
 from .vstore import VerdictStore
 
@@ -198,9 +201,18 @@ def register_metrics() -> None:
                     help=f"queries that reached the {s} stage")
         reg.counter(f"solver_hits_stage_{s}_total",
                     help=f"queries resolved by the {s} stage")
+    for rule in REFUTE_RULES:
+        _refute_counter(rule)
 
 
 # --- internals ---------------------------------------------------------
+
+def _refute_counter(rule: str):
+    return obs_metrics.REGISTRY.counter(
+        "solver_refute_total", labels={"rule": rule},
+        help="queries the refute stage proved unsat, by the rule "
+             "that found the conflict")
+
 
 def _stage_begin(stage: str) -> float:
     PORTFOLIO_STATS.attempt(stage)
@@ -210,7 +222,7 @@ def _stage_begin(stage: str) -> float:
 
 
 def _stage_end(stage: str, t0: float,
-               verdict: Optional[str] = None) -> None:
+               verdict: Optional[str] = None, **attrs) -> None:
     dt = time.perf_counter() - t0
     PORTFOLIO_STATS.add_time(stage, dt)
     obs_metrics.REGISTRY.histogram(
@@ -226,7 +238,7 @@ def _stage_end(stage: str, t0: float,
         # volume-bounded by queries, not stages
         if obs_trace.active():
             obs_trace.event("solver_stage", stage=stage,
-                            dur=round(dt, 6), verdict=verdict)
+                            dur=round(dt, 6), verdict=verdict, **attrs)
 
 
 def _lru_get(key):
@@ -308,8 +320,10 @@ def solve_query(tape: HostTape, seed: int = 0, max_iters: int = 400,
 
     # --- stage 2: structural refutation (proven unsat, no search) -----
     t0 = _stage_begin("refute")
-    if refute_tape(tape) is not None:
-        _stage_end("refute", t0, "unsat")
+    proof = refute_tape(tape)
+    if proof is not None:
+        _refute_counter(proof.rule).inc()
+        _stage_end("refute", t0, "unsat", rule=proof.rule)
         verdict, out, decided_by = "unsat", None, "refute"
     else:
         _stage_end("refute", t0)

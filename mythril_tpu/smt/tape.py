@@ -46,11 +46,15 @@ def intern_node(nodes: List[HostNode], node: HostNode, index=None) -> int:
     analog of the device tape's hash-consing. Detection modules MUST
     build attack predicates through this: a predicate that re-creates a
     node the path already asserts (e.g. the LT(a,b) a SafeMath guard
-    branched on) then shares its id, so the refuter sees the polarity
-    conflict and proves UNSAT instead of burning witness-search budget
-    into an `unknown` (round 4: this was every second solver query on
-    the ERC-20 workload). Pass the tape's :func:`node_index` when
-    interning repeatedly; it is kept in sync with appends."""
+    branched on) then shares its id and its operands' ids. A shared id
+    is one way the refuter sees the polarity conflict and proves UNSAT
+    instead of burning witness-search budget into an `unknown`; the
+    other is its normal form (``smt/refute.py``), which finds the
+    guard that asserts an EQUIVALENT node, as solc's do
+    (``ISZERO(GT(x, s))`` for the module's ``LT(s, x)``): until PR 35
+    only the shared id counted, and 98% of the benchmark corpora's
+    queries ran the whole search. Pass the tape's :func:`node_index`
+    when interning repeatedly; it is kept in sync with appends."""
     if index is not None:
         hit = index.get(node)
         if hit is None:

@@ -20,7 +20,9 @@ Reads either output of the span tracer — the Chrome-trace JSON
      rate — the silent-false-negative channel, docs/solver.md),
   8. a solver portfolio ladder (per-stage attempts / hits / hit rate /
      time across lru -> refute -> probe -> store -> search, plus the
-     Z3-avoided headline — docs/solver.md),
+     Z3-avoided headline, and one row a refute rule: what the stage
+     proved under each, from the ``solver_stage`` events —
+     docs/solver.md),
   9. a serve admission summary (docs/serving.md "Overload &
      multi-replica serving"): the shed/quota timeline (every
      shed_enter / shed_exit / quota_rejected, in order) and a
@@ -420,6 +422,14 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
                 f"{int(st.get('sat', 0) or 0):>7}"
                 f"{int(st.get('unsat', 0) or 0):>7}"
                 f"{_fmt_s(float(st.get('time_sec', 0.0) or 0.0)):>10}")
+        rules: Dict[str, int] = {}
+        for e in instants:
+            a = e["args"]
+            if e["kind"] == "solver_stage" and a.get("stage") == "refute":
+                rule = str(a.get("rule", "?"))
+                rules[rule] = rules.get(rule, 0) + 1
+        for rule in sorted(rules, key=lambda r: (-rules[r], r)):
+            out.append(f"  refute by {rule:<12}{rules[rule]:>8}")
         mm = int(last.get("witness_mismatch", 0) or 0)
         if mm:
             out.append(f"witness re-verification misses: {mm} "
