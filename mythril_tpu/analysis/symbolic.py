@@ -28,10 +28,44 @@ from ..smt.eval import Assignment
 from ..smt.solver import solve_tape
 from ..smt.tape import HostNode, HostTape, extract_tape, intern_node
 from ..symbolic import SymSpec, between_txs, make_sym_frontier, sym_run
-from ..symbolic.engine import (pool_stuck, rebalance_parked,
-                               relieve_starved)
+from ..symbolic.engine import (SEAM_STORAGE, hold_carried,
+                               plan_seam_admission, plan_waiting,
+                               pool_stuck, rebalance_parked,
+                               relieve_starved, starved_lanes)
 
 log = logging.getLogger(__name__)
+
+
+def guard_slots(image: ContractImage, pc: int) -> frozenset:
+    """The fixed storage slots of the guard that a path failed at.
+    ``pc`` is where the path ended (a ``REVERT`` or ``INVALID``, or just
+    past it); the guard is the ``JUMPI`` it fell through, as solc lays a
+    ``require`` out (the branch, then the revert with its reason), and
+    its slots are the ``PUSH k; SLOAD`` pairs of the block that ends in
+    that ``JUMPI``. Empty where the code has another shape: such a path
+    tested no slot that the seam's admission step can name."""
+    code = image.code[:image.code_len].tobytes()
+    starts = np.flatnonzero(image.is_code[:image.code_len])
+    at = int(np.searchsorted(starts, pc, side="right")) - 1
+    # the path's last instruction, then back over the revert's own
+    # block (a reason string's stores) to the JUMPI it fell through
+    if at > 0 and code[starts[at]] not in (0xFD, 0xFE):
+        at -= 1
+    if at < 0 or code[starts[at]] not in (0xFD, 0xFE):
+        return frozenset()      # it ended elsewhere: a trap, not a guard
+    while at >= 0 and code[starts[at]] != 0x57:
+        if code[starts[at]] in (0x5B, 0x56, 0x00, 0xF3, 0xFF):
+            return frozenset()
+        at -= 1
+    slots = set()
+    for j in range(at - 1, 0, -1):
+        op, before = code[starts[j]], code[starts[j - 1]]
+        if op in (0x5B, 0x56, 0x57, 0x00, 0xF3, 0xFD, 0xFE, 0xFF):
+            break
+        if op == 0x54 and 0x5F <= before <= 0x7F:
+            slots.add(int.from_bytes(
+                code[starts[j - 1] + 1:starts[j]], "big"))
+    return frozenset(slots)
 
 
 @dataclass
@@ -265,6 +299,16 @@ class SymExecWrapper:
     there and ``engine_calls_skipped_total{tx}`` the ``sym_run`` calls
     their budget still allowed. All of it rides the reads the harvest
     and the seam make anyway.
+
+    The seam between two calls also decides which of the end states
+    that passed the pruners start the next one (``engine.
+    plan_seam_admission``: inert unless a contract's carried states
+    exceed its share of the lane pool and one of them overwrote what a
+    failed path had read). Its ``tx_seam`` span says ``passed`` =
+    ``admitted`` + ``merged`` + ``deferred`` + ``dropped``,
+    ``engine_seam_states_total{tx,fate}`` counts the last fates, a
+    ``superstep`` span after waiting lanes started carries ``round``,
+    and the ``harvest`` span has ``paths`` / ``dropped`` per contract.
     """
 
     def __init__(
@@ -456,7 +500,7 @@ class SymExecWrapper:
                 sf, ended = walk(sf)
                 if held:
                     held[-1].attrs["ended"] = ended
-                return sf
+                return self._close_waiting(sf)
             finally:
                 seal()
 
@@ -525,6 +569,8 @@ class SymExecWrapper:
                 cold = shape not in warm_shapes
                 w0 = tally()[1]
                 seal()
+                if self._round:
+                    attrs["round"] = self._round
                 with obs_trace.timer("superstep", tx=self._cur_tx,
                                      tx_kind=self._tx_kind, steps=n,
                                      cold=cold, stuck=False, **attrs) as sp:
@@ -595,12 +641,20 @@ class SymExecWrapper:
                     sf, moved = rebalance_parked(sf, self.fork_block,
                                                  active=act_h,
                                                  fork_req=freq_h)
-                    evicted = 0
-                    if not moved:
+                    evicted = served = 0
+                    if not moved and self._seam_plan is not None:
+                        # the lanes the last seam left waiting go first:
+                        # they start where there is room and are given
+                        # up before any fork of a state that runs
+                        sf, served = self._serve_waiting(
+                            sf, C, act_h, freq_h, run_h)
+                    if not (moved or served):
                         sf, evicted = relieve_starved(
                             sf, C, act_h, freq_h, run_h, home_h)
+                        if evicted:
+                            self._count_lost(evicted, starved_lanes(
+                                C, act_h, freq_h, run_h, home_h), home_h)
                 self._rebalanced += moved
-                self._parked_end += evicted
                 reg = obs_metrics.REGISTRY
                 reg.counter(
                     "rebalanced_lanes_total",
@@ -610,7 +664,7 @@ class SymExecWrapper:
                     help="parked lanes given up at a full frontier's "
                          "fixpoint for a contract under its floor"
                 ).inc(evicted)
-                idle = not (moved or evicted)
+                idle = not (moved or evicted or served)
                 if held:
                     held[-1].attrs["stuck"] = idle and pool_stuck(
                         act_h, freq_h, run_h)
@@ -677,7 +731,8 @@ class SymExecWrapper:
                     # every further call of this transaction, chunk or
                     # drain round, would hand back this frontier
                     fixpoint_end(chunks_left(steps_done) + DRAIN_ROUNDS)
-                    self._parked_end += int((freq_h & act_h).sum())
+                    self._count_lost(int((freq_h & act_h).sum()),
+                                     freq_h & act_h, got[3])
                     return sf, "fixpoint"
                 if not bool(run_h.any()):
                     ended = "quiescent"
@@ -693,8 +748,8 @@ class SymExecWrapper:
                 # admitted late through no fault of their path, so they
                 # get bounded extra chunks (reference analog: the work
                 # list drains until empty or timeout)
-                with obs_trace.span("drain", tx=self._cur_tx,
-                                    tx_kind=self._tx_kind):
+                with obs_trace.timer("drain", tx=self._cur_tx,
+                                     tx_kind=self._tx_kind) as drain:
                     # one fetch per drain round, shared with the
                     # rebalance planner and the final parked count
                     # (the seam before it has judged the last call's
@@ -703,7 +758,11 @@ class SymExecWrapper:
                                   ",".join(SEAM[:4])), False)
                     parked = got[1] & got[0]
                     for left in range(DRAIN_ROUNDS, 0, -1):
-                        if not parked.any():
+                        # a round runs for a parked lane, or for a lane
+                        # the last seam left waiting that now has room
+                        if not (parked.any() or (
+                                self._seam_plan is not None and plan_waiting(
+                                    C, self._seam_plan, *got[:3])[1])):
                             break
                         if self.timed_out or (
                                 self._deadline_at is not None
@@ -722,10 +781,12 @@ class SymExecWrapper:
                         parked = got[1] & got[0]
                         # no scheduling step follows the last round
                         held[-1].attrs["stuck"] = pool_stuck(*got[:3])
+                    if self._round:
+                        drain.attrs["round"] = self._round
                 # forks still parked after draining are lost coverage —
                 # count them in the drop channel for honesty (reusing
                 # the drain loop's final fetch — no extra sync)
-                self._parked_end += int(parked.sum())
+                self._count_lost(int(parked.sum()), parked, got[3])
             return sf, ended
 
         def run_one_tx(sf, is_last: bool, handoff_kw=None):
@@ -739,14 +800,27 @@ class SymExecWrapper:
                 # code here is a loss from THIS transaction. The
                 # per-transaction path and dropped-fork counts ride
                 # the same transfer
-                err_h, act_h, bad_h, dropped_h = fetch(
+                # and per contract, with what the seam's admission
+                # step needs of the frontier as the transaction left it
+                (err_h, act_h, bad_h, dropped_h, rev_h, home_h,
+                 forks_h) = fetch(
                     (sf.base.err_code, sf.base.active, sf.base.error,
-                     sf.dropped_total),
-                    "base.err_code,base.active,base.error,dropped_total")
+                     sf.dropped_total, sf.base.reverted,
+                     sf.base.home_contract, sf.dropped_forks),
+                    "base.err_code,base.active,base.error,dropped_total,"
+                    "base.reverted,base.home_contract,dropped_forks")
                 trap_counts = _count_traps(err_h)
                 paths, lost = self._count_tx(
                     int((act_h & ~bad_h).sum()), int(dropped_h))
-                harvest.attrs.update(paths=paths, dropped=lost)
+                of = home_h % C     # creation | runtime image
+                harvest.attrs.update(
+                    paths=paths, dropped=lost,
+                    paths_by_contract=np.bincount(
+                        of[act_h & ~bad_h], minlength=C).tolist(),
+                    dropped_by_contract=(self._lost_by + np.bincount(
+                        of, weights=forks_h, minlength=C).astype(
+                            np.int64)).tolist())
+                self._lost_by[:] = 0
                 ctx = AnalysisContext(
                     sf=sf, corpus=self.corpus, limits=limits,
                     contract_names=names, solver_iters=solver_iters,
@@ -781,18 +855,31 @@ class SymExecWrapper:
                 kw.setdefault("first_message_tx", 1 if with_creation else 0)
                 with obs_trace.timer("tx_seam", tx=self._cur_tx,
                                      tx_kind=self._tx_kind) as seam:
+                    ended = sf
                     sf = between_txs(sf, **kw)
                     # the read the next transaction starts with (is
                     # anything left to extend?), made here so that the
                     # span ends when the handoff has run on the device
-                    self._carried = fetch(sf.base.active, "base.active")
-                    seam.attrs["carried"] = int(self._carried.sum())
+                    passed = fetch(sf.base.active, "base.active")
+                    sf, self._carried, fates = self._admit_carried(
+                        ended, sf, C, passed, act_h,
+                        act_h & (rev_h | bad_h), home_h)
+                    seam.attrs.update(carried=int(self._carried.sum()),
+                                      passed=int(passed.sum()), **fates)
             return sf
 
         self._cur_tx = 0
         self._tx_kind = "creation" if with_creation else "message"
         self._dropped_seen = 0
         self._carried = None    # ``active`` as the last seam left it
+        # the seam's admission step (``engine.plan_seam_admission``):
+        # the plan whose queue still waits, the states each contract's
+        # call began with, the times waiting lanes were started since,
+        # and this transaction's lost forks per contract
+        self._seam_plan = None
+        self._started = np.ones(C, dtype=np.int64)
+        self._round = 0
+        self._lost_by = np.zeros(C, dtype=np.int64)
         self.plugin_loader.fire("initialize", self)
         if with_creation:
             # --create-timeout (reference: a separate wall-clock budget
@@ -827,6 +914,98 @@ class SymExecWrapper:
         self.sf = sf
         self.ctx = self.tx_contexts[-1]
         self.plugin_loader.fire("on_run_end", self)
+
+    def _count_lost(self, n: int, lanes=None, home=None) -> None:
+        """``n`` forks given up at a host seam go into the drop channel
+        the coverage and ``engine_dropped_forks_total{tx}`` read
+        (``_parked_end``); ``lanes``, their mask, and ``home``, the
+        ``home_contract`` of every lane, let the harvest say whose they
+        were."""
+        self._parked_end += n
+        if lanes is not None:
+            C = len(self._lost_by)
+            self._lost_by += np.bincount(np.asarray(home)[lanes] % C,
+                                         minlength=C)
+
+    def _seam_fate(self, fate: str, n: int) -> None:
+        obs_metrics.REGISTRY.counter(
+            "engine_seam_states_total",
+            help="end states that passed the pruners at a transaction's "
+                 "seam, by what became of them: passed = admitted + "
+                 "merged + dropped once the next call has ended; "
+                 "deferred counts those made to wait first",
+            labels={"tx": str(self._seam_tx), "fate": fate}).inc(n)
+
+    def _admit_carried(self, ended, sf, C, passed, ended_active, failed,
+                       home):
+        """The seam's admission step: which of the states that
+        ``passed`` the pruners start the next call (``engine.
+        plan_seam_admission``), applied as one mask. ``ended`` is the
+        frontier the transaction left, the other arrays host copies of
+        its leaves. Returns the frontier, the mask of the lanes that
+        go on and the seam's fates."""
+        self._seam_tx, self._round = self._cur_tx, 0
+        plan = plan_seam_admission(
+            C, passed, home, ended_active, failed, self._started,
+            lambda: fetch(tuple(attrgetter(a)(ended) for a in SEAM_STORAGE),
+                          ",".join(SEAM_STORAGE)),
+            lambda image, pc: guard_slots(self.images[image], pc))
+        merged = dropped = wait = np.zeros_like(passed)
+        if plan is not None:
+            merged, dropped = plan["merged"], plan["dropped"]
+            wait = np.zeros_like(passed)
+            wait[plan["queue"]] = True
+            sf = hold_carried(sf, merged | dropped, wait)
+            self._count_lost(int(dropped.sum()), dropped, home)
+        start = passed & ~(merged | dropped | wait)
+        fates = dict(admitted=int(start.sum()), merged=int(merged.sum()),
+                     deferred=int(wait.sum()), dropped=int(dropped.sum()))
+        self._seam_plan = plan if wait.any() else None
+        self._started = np.bincount((home % C)[start], minlength=C)
+        self._seam_fate("passed", int(passed.sum()))
+        for fate, n in fates.items():
+            self._seam_fate(fate, n)
+        return sf, passed & ~(merged | dropped), fates
+
+    def _serve_waiting(self, sf, C, active, fork_req, running):
+        """A chunk seam's turn for the lanes the last transaction seam
+        left waiting (``engine.plan_waiting``), applied as one mask;
+        returns the frontier and how many lanes it started or gave
+        up."""
+        plan = self._seam_plan
+        plan["queue"], start, drop = plan_waiting(
+            C, plan, active, fork_req, running)
+        if start:
+            self._round += 1
+            self._started += np.bincount(plan["contract"][start],
+                                         minlength=C)
+            self._seam_fate("admitted", len(start))
+        if start or drop:
+            go = np.zeros_like(active)
+            go[start] = True
+            sf = hold_carried(sf, self._drop_waiting(plan, drop), start=go)
+        if not plan["queue"]:
+            self._seam_plan = None
+        return sf, len(start) + len(drop)
+
+    def _drop_waiting(self, plan, lanes):
+        """Waiting lanes given up: lost forks of their contracts. Returns
+        their mask."""
+        mask = np.zeros(len(plan["contract"]), dtype=bool)
+        mask[lanes] = True
+        if lanes:
+            self._count_lost(len(lanes), mask, plan["contract"])
+            self._seam_fate("dropped", len(lanes))
+        return mask
+
+    def _close_waiting(self, sf):
+        """A transaction's end: the lanes that still wait never started.
+        They leave the frontier before the harvest reads it, and count
+        as lost."""
+        plan, self._seam_plan = self._seam_plan, None
+        if plan is None:
+            return sf
+        return hold_carried(sf, self._drop_waiting(plan, plan["queue"]))
 
     def _count_tx(self, paths: int, dropped_total: int) -> tuple:
         """One transaction's ``engine_paths_total{tx}`` and

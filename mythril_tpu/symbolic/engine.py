@@ -2928,6 +2928,26 @@ def pool_fixpoint(seen: tuple, swept, active, fork_req, running,
             fixpoint)
 
 
+def starved_lanes(n_contracts: int, active, fork_req, running, home):
+    """The lanes ``relieve_starved`` gives up, as a host mask; None where
+    it gives up none."""
+    import numpy as np
+
+    if not pool_stuck(active, fork_req, running):
+        return None     # a lane is free, or a lane still moves
+    active = np.asarray(active)
+    parked = np.asarray(fork_req) & active
+    P = active.shape[0]
+    share = P // n_contracts
+    contract = np.asarray(home) % n_contracts   # creation | runtime image
+    held = np.bincount(contract, minlength=n_contracts)
+    waits = np.bincount(contract[parked], minlength=n_contracts) > 0
+    if not (waits & (held < share // 4)).any():
+        return None
+    out = parked & (held > share)[contract]
+    return out if out.any() else None
+
+
 def relieve_starved(sf: SymFrontier, n_contracts: int,
                     active, fork_req, running, home):
     """Break the fixpoint of a full frontier for the contracts it starves.
@@ -2952,24 +2972,12 @@ def relieve_starved(sf: SymFrontier, n_contracts: int,
     demand finish first.
 
     Host-planned at the chunk seam from the leaves the seam has fetched
-    anyway (``home``: ``base.home_contract``), device-applied as one
-    mask over ``active`` and ``fork_req``; the compiled superstep loop is
-    untouched. Returns ``(sf, n_evicted)``."""
-    import numpy as np
-
-    if not pool_stuck(active, fork_req, running):
-        return sf, 0    # a lane is free, or a lane still moves
-    active = np.asarray(active)
-    parked = np.asarray(fork_req) & active
-    P = active.shape[0]
-    share = P // n_contracts
-    contract = np.asarray(home) % n_contracts   # creation | runtime image
-    held = np.bincount(contract, minlength=n_contracts)
-    waits = np.bincount(contract[parked], minlength=n_contracts) > 0
-    if not (waits & (held < share // 4)).any():
-        return sf, 0
-    out = parked & (held > share)[contract]
-    if not out.any():
+    anyway (``home``: ``base.home_contract``; ``starved_lanes`` is the
+    plan), device-applied as one mask over ``active`` and ``fork_req``;
+    the compiled superstep loop is untouched. Returns
+    ``(sf, n_evicted)``."""
+    out = starved_lanes(n_contracts, active, fork_req, running, home)
+    if out is None:
         return sf, 0
     # a mask, not an index list: one program whatever the count (a
     # scatter would compile anew for every new number of lanes)
@@ -2978,6 +2986,178 @@ def relieve_starved(sf: SymFrontier, n_contracts: int,
         base=sf.base.replace(active=sf.base.active & keep),
         fork_req=sf.fork_req & keep,
     ), int(out.sum())
+
+
+#: what ``plan_seam_admission`` reads of the frontier a transaction ended
+#: with, fetched only where a contract's carried states exceed its share
+SEAM_STORAGE = ("base.st_used", "base.st_written", "base.st_keys",
+                "base.st_vals", "st_key_sym", "st_val_sym", "st_seq",
+                "base.contract_id", "base.pc")
+
+
+def plan_seam_admission(n_contracts: int, carried, home, ended, failed,
+                        started, storage, guard_slots):
+    """Which of the end states ``between_txs`` carried start the next
+    call, per contract. Host-planned from the seam's reads; returns None
+    where every carried state starts (the step is inert), else a dict:
+    ``merged`` and ``dropped`` (masks of lanes to retire), ``queue``
+    (the lanes that wait, most novel first within their contract),
+    ``contract`` (of every lane) and ``fanout`` (lanes one state of each
+    contract takes).
+
+    Every carried state re-enters at ``pc=0`` and forks through the
+    dispatcher again, and nothing retires a lane inside a transaction.
+    What one state's call takes was just observed: ``fanout``, the lanes
+    a contract held when the transaction ended (``ended``) over the
+    states it ``started`` with. A contract whose carried states times
+    that exceed its share of the pool (``P // n_contracts``) cannot run
+    them all, and which of them gets anywhere is decided by who forks
+    first. The step chooses instead, where there is something to choose
+    by: **a carried state that overwrote the slot a failed guard of the
+    same contract tested** can take the next call past that guard; the
+    states that did not can only repeat what this transaction explored,
+    from other balances. A failed guard is a path that ended reverted
+    or in error (``failed``) on a branch whose block loads a fixed slot
+    (``guard_slots(image, pc)``: read off the code at the path's last
+    ``pc``) that its storage cache holds under a concrete key with a
+    concrete value left by an earlier transaction (the constructor's
+    ``owner`` and ``initialized``): only a write can change what that
+    guard sees. The number of such paths over the concrete slots a
+    state wrote in this transaction is its novelty. Where no earlier
+    transaction left a value (the first call from unconstrained
+    storage: a guard on a symbolic leaf forks both ways in one call,
+    and no write unlocks anything) nothing is novel, and the step is
+    inert wherever no contract over its share has a novel state.
+
+    Where it acts, it holds EVERY contract over its share to it (the
+    pool is one, and a neighbour's carried states are what starved the
+    novel one): in order of novelty, then of lane, a state whose whole
+    written storage is concrete and equal to an earlier one's is
+    ``merged`` into it (the same storage: the next call cannot tell them
+    apart); the first ``share // fanout`` (at least one) start; as many
+    of the next as the share has lanes beside those states' fan-out go
+    into ``queue`` and wait in their lanes (``hold_carried``) until a
+    later seam starts them or gives them up (``plan_waiting``); the rest are
+    ``dropped``, the budget's cut, counted with the dropped forks.
+
+    ``storage`` is called only where some contract is over its share,
+    and returns host copies of ``SEAM_STORAGE`` of the frontier the
+    transaction ended with (``between_txs`` clears ``st_written``)."""
+    import numpy as np
+
+    carried = np.asarray(carried)
+    P = carried.shape[0]
+    share = P // n_contracts
+    contract = np.asarray(home) % n_contracts   # creation | runtime image
+    n_carried = np.bincount(contract[carried], minlength=n_contracts)
+    held = np.bincount(contract[np.asarray(ended)], minlength=n_contracts)
+    fanout = np.maximum(1, -(-held // np.maximum(1, started)))
+    over = (n_carried > 1) & (n_carried * fanout > share)
+    if not over.any():
+        return None
+    used, written, keys, vals, key_sym, val_sym, seq, image, pc = storage()
+    named = used & (key_sym == 0)       # entries under a concrete key
+    # 32 bytes an entry, compared whole
+    kb = np.ascontiguousarray(keys).view("V32")[..., 0]
+    vb = np.ascontiguousarray(vals).view("V32")[..., 0]
+    readers: dict = {}
+    guards: dict = {}       # (image, pc) -> the slots tested there
+    left = named & (val_sym == 0) & (seq > 0) & ~written
+    for lane in np.nonzero(np.asarray(failed) & over[contract])[0]:
+        site = int(image[lane]), int(pc[lane])
+        if site not in guards:
+            guards[site] = [int(k).to_bytes(32, "little")
+                            for k in guard_slots(*site)]
+        had = {k.tobytes() for k in kb[lane, left[lane]]}
+        for k in guards[site]:
+            if k in had:
+                readers[contract[lane], k] = readers.get(
+                    (contract[lane], k), 0) + 1
+    novelty = np.zeros(P, dtype=np.int64)
+    lanes, slots = np.nonzero(
+        named & written & (carried & over[contract])[:, None])
+    for lane, k in zip(lanes, kb[lanes, slots]):
+        novelty[lane] += readers.get((contract[lane], k.tobytes()), 0)
+    if not novelty.any():
+        return None
+    concrete = ~(used & (seq > 0) & ((key_sym != 0) | (val_sym != 0))
+                 ).any(axis=1)
+    merged, dropped = np.zeros(P, dtype=bool), np.zeros(P, dtype=bool)
+    queue = []
+    for c in np.nonzero(over)[0]:
+        mine = np.nonzero(carried & (contract == c))[0]
+        seen, room, rank = set(), max(1, share // fanout[c]), 0
+        hold = max(0, share - room * fanout[c])
+        for lane in mine[np.argsort(-novelty[mine], kind="stable")]:
+            if concrete[lane]:
+                w = used[lane] & (seq[lane] > 0)
+                sig = tuple(sorted(zip(kb[lane, w].tolist(),
+                                       vb[lane, w].tolist())))
+                if sig in seen:
+                    merged[lane] = True
+                    continue
+                seen.add(sig)
+            if room:
+                room -= 1
+            elif rank < hold:
+                # the contracts take turns: every one's next state
+                # before any one's second, the novel ones before both
+                queue.append((novelty[lane] == 0, rank, int(lane)))
+                rank += 1
+            else:
+                dropped[lane] = True
+    return {"merged": merged, "dropped": dropped,
+            "queue": [q[2] for q in sorted(queue)],
+            "contract": contract, "fanout": fanout}
+
+
+def hold_carried(sf: SymFrontier, retire, wait=None, start=None):
+    """Apply a seam's plan for the carried states as one mask, as
+    ``relieve_starved`` does: the lanes of ``retire`` leave the frontier,
+    those of ``wait`` stay in their lanes, halted, so that no superstep
+    moves them and no fork takes their place, and those of ``start``
+    waited and now run."""
+    b = sf.base
+    halted = b.halted
+    if wait is not None:
+        halted = halted | jnp.asarray(wait)
+    if start is not None:
+        halted = halted & jnp.asarray(~start)
+    return sf.replace(base=b.replace(
+        active=b.active & jnp.asarray(~retire), halted=halted))
+
+
+def plan_waiting(n_contracts: int, plan: dict, active, fork_req, running):
+    """What a seam does for the lanes a plan of ``plan_seam_admission``
+    left waiting, from host copies of the seam's leaves: ``(queue,
+    start, drop)``, the lanes that go on waiting, those that start and
+    those given up. At a full frontier's fixpoint all are given up
+    (nothing retires a lane inside a transaction, so the room one of
+    them needs will not come, and they have explored nothing yet; the
+    lanes go to the forks that are parked); until then, where a
+    contract has kept under its share by a whole ``fanout``, its next
+    ones start. Waiting lanes that a feasibility sweep killed leave the
+    queue."""
+    import numpy as np
+
+    active = np.asarray(active)
+    queue = [lane for lane in plan["queue"] if active[lane]]
+    if queue and pool_stuck(active, fork_req, running):
+        return [], [], queue
+    contract, fanout = plan["contract"], plan["fanout"]
+    share, free = active.shape[0] // n_contracts, int((~active).sum())
+    used = (np.bincount(contract[active], minlength=n_contracts)
+            - np.bincount(contract[queue], minlength=n_contracts))
+    start, wait = [], []
+    for lane in queue:
+        c = contract[lane]
+        if min(free, share - used[c]) >= fanout[c]:
+            used[c] += fanout[c]
+            free -= fanout[c]
+            start.append(lane)
+        else:
+            wait.append(lane)
+    return wait, start, []
 
 
 @jax.named_scope("migrate_parked_device")

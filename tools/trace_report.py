@@ -5,6 +5,8 @@ Reads either output of the span tracer — the Chrome-trace JSON
 (``--trace t.json``) or the JSONL event log (``t.jsonl``) — and prints:
 
   1. top spans by total wall time (count / total / mean / max per name),
+     then a row a transaction (tx, tx_kind), a row a fate of the seam's
+     admission step (tx, fate) and the contracts' paths and lost forks,
   2. a batch stall table (slowest campaign batches with their status),
   3. the degrade timeline (every ladder step, in order),
   4. a checkpoint summary (saves/loads, total and worst latency),
@@ -162,8 +164,13 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
     # the calls the fixpoint saved whole (``skipped``), those that still
     # started from a stuck seam and could only hand their frontier back
     # (``spun``: none since the loop itself sees the fixpoint), and what
-    # ended it
+    # ended it. Then what the seam's admission step made of the end
+    # states that passed the pruners, one row a (tx, fate), and the
+    # contracts' shares of the paths and the lost forks
     by_tx: Dict[tuple, Dict] = {}
+    fates: Dict[tuple, int] = {}
+    rounds: Dict[object, int] = {}
+    by_contract: Dict[tuple, List[int]] = {}
     before = None       # the superstep span before, if of this transaction
     for s in sorted(spans, key=lambda s: s["mono"]):
         a = s["args"]
@@ -175,6 +182,8 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
             "carried": 0, "seam": 0.0, "skipped": 0, "spun": 0,
             "spun_sec": 0.0, "early": 0, "unrun": 0, "ended": {}})
         if s["name"] == "superstep":
+            rounds[a.get("tx")] = max(rounds.get(a.get("tx"), 0),
+                                      int(a.get("round", 0)))
             row["calls"] += 1
             row["sec"] += s["dur"]
             row["skipped"] += int(a.get("skipped", 0))
@@ -192,9 +201,22 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
         elif s["name"] == "harvest":
             row["paths"] += int(a.get("paths", 0))
             row["dropped"] += int(a.get("dropped", 0))
+            for what in ("paths", "dropped"):
+                got = a.get(what + "_by_contract")
+                if not got:
+                    continue
+                tot = by_contract.setdefault((a.get("tx"), what),
+                                             [0] * len(got))
+                for i, n in enumerate(got[:len(tot)]):
+                    tot[i] += int(n)
         else:
             row["carried"] += int(a.get("carried", 0))
             row["seam"] += s["dur"]
+            for fate in ("passed", "admitted", "merged", "deferred",
+                         "dropped"):
+                if fate in a:
+                    fates[a.get("tx"), fate] = fates.get(
+                        (a.get("tx"), fate), 0) + int(a[fate])
     if by_tx:
         out.append("")
         out.append("== transactions (tx, tx_kind) ==")
@@ -215,6 +237,28 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
                 f"{r['paths']:>8}{r['dropped']:>9}"
                 f"{(100.0 * r['paths'] / tot if tot else 100.0):>9.1f}%"
                 f"{r['carried']:>9}{_fmt_s(r['seam']):>10}  {ended}")
+    if fates:
+        out.append("")
+        out.append("== seam admission (tx, fate): the end states that "
+                   "passed the pruners ==")
+        out.append(f"{'tx':>3} {'fate':<10}{'states':>8}{'share':>8}")
+        for (tx, fate), n in sorted(fates.items(), key=lambda kv: (
+                str(kv[0][0]), kv[0][1] != "passed", kv[0][1])):
+            passed = fates.get((tx, "passed"), 0)
+            out.append(f"{tx!s:>3} {fate:<10}{n:>8}"
+                       f"{(100.0 * n / passed if passed else 0.0):>7.1f}%"
+                       + (f"  (waiting lanes started in "
+                          f"{rounds[tx + 1]} later round(s) of tx {tx + 1})"
+                          if fate == "deferred" and isinstance(tx, int)
+                          and rounds.get(tx + 1) else ""))
+    if by_contract:
+        out.append("")
+        out.append("== paths and lost forks per contract of a batch "
+                   "(tx, what) ==")
+        for (tx, what), tot in sorted(by_contract.items(),
+                                      key=lambda kv: str(kv[0])):
+            out.append(f"{tx!s:>3} {what:<8}" + "".join(
+                f"{n:>7}" for n in tot))
 
     # 2. batch stall table: slowest batches, with their outcome
     status_by_bi: Dict[int, str] = {}
