@@ -19,6 +19,21 @@ Soundness direction: both domains only ever over-approximate, so a kill
 is always correct; undecided lanes stay alive (the reference keeps unsat
 paths alive until a solver call too). The expensive exact residue goes to
 the host model search only when a detection module needs a witness.
+
+Access pattern. The four domain arrays are ``[P, T, 8]`` and every
+indexed access to them is per lane. A lane reads an operand as one row
+of 8 limbs (a row gather, like the tape reads of ``engine.append_node``)
+and writes its node through ``interpreter._write_slot``: a per-lane
+scatter on XLA:CPU, one dense compare-select pass over the array on the
+TPU, which runs per-lane scatters and single-element gathers as
+serialized updates (``interpreter._use_scatter`` decides when the sweep
+is traced; the four 1,048,576-element gathers the constraint check used
+to make were 36-41% of the device's busy time in every benchmark cell,
+PERF.md PR 37). The constraint check computes both verdicts of every
+node in one dense pass and reads them at the constraints' nodes.
+``tests/test_write_paths.py`` holds the two write modes to one oracle,
+``tests/test_scaling.py`` the TPU's trace to no scatter into, and no
+element gather from, a domain array.
 """
 
 from __future__ import annotations
@@ -27,6 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..core import interpreter as ci
 from ..ops import u256
 from .ops import SymOp, FreeKind
 from .state import SymFrontier
@@ -74,7 +90,11 @@ def propagate_feasibility(sf: SymFrontier):
     km, kv = sf.kb_m, sf.kb_v
 
     def gather(arr, ids):
-        return jnp.take_along_axis(arr, jnp.clip(ids, 0, T - 1)[:, None, None].astype(I32).repeat(8, 2), axis=1)[:, 0]
+        # one row of 8 limbs a lane (the [P, 1, 1] index broadcasts over
+        # the limb axis), not 8 single elements
+        return jnp.take_along_axis(
+            arr, jnp.clip(ids, 0, T - 1)[:, None, None].astype(I32),
+            axis=1)[:, 0]
 
     def body(idx, carry):
         # idx is PER-LANE (i32[P]): lane p processes its own node
@@ -82,12 +102,11 @@ def propagate_feasibility(sf: SymFrontier):
         # NEW-node count, not the global tape span (SSA order guarantees
         # operands were processed in an earlier sweep or iteration)
         lo, hi, km, kv = carry
-        ci = jnp.clip(idx, 0, T - 1)[:, None]
-        op = jnp.take_along_axis(sf.tape_op, ci, axis=1)[:, 0]
-        a_id = jnp.take_along_axis(sf.tape_a, ci, axis=1)[:, 0]
-        b_id = jnp.take_along_axis(sf.tape_b, ci, axis=1)[:, 0]
-        imm = jnp.take_along_axis(sf.tape_imm, ci[:, :, None].repeat(8, 2),
-                                  axis=1)[:, 0]
+        at = jnp.clip(idx, 0, T - 1)[:, None]
+        op = jnp.take_along_axis(sf.tape_op, at, axis=1)[:, 0]
+        a_id = jnp.take_along_axis(sf.tape_a, at, axis=1)[:, 0]
+        b_id = jnp.take_along_axis(sf.tape_b, at, axis=1)[:, 0]
+        imm = gather(sf.tape_imm, idx)
         la, ha = gather(lo, a_id), gather(hi, a_id)
         lb, hb = gather(lo, b_id), gather(hi, b_id)
         ka, va = gather(km, a_id), gather(kv, a_id)
@@ -301,13 +320,9 @@ def propagate_feasibility(sf: SymFrontier):
         rv = jnp.where(dec_one[:, None], t_one, rv)
 
         live = (idx >= 1) & (idx < sf.tape_len) & (op != int(SymOp.NULL))
-        lanes = jnp.arange(idx.shape[0])
-        widx = jnp.where(live, jnp.clip(idx, 0, T - 1), T)
-        lo = lo.at[lanes, widx].set(r_lo, mode="drop")
-        hi = hi.at[lanes, widx].set(r_hi, mode="drop")
-        km = km.at[lanes, widx].set(rm, mode="drop")
-        kv = kv.at[lanes, widx].set(rv, mode="drop")
-        return lo, hi, km, kv
+        widx = jnp.where(live, jnp.clip(idx, 0, T - 1), T)  # T: nowhere
+        return (ci._write_slot(lo, widx, r_lo), ci._write_slot(hi, widx, r_hi),
+                ci._write_slot(km, widx, rm), ci._write_slot(kv, widx, rv))
 
     # per-lane resume: lane p walks nodes [prop_len[p], tape_len[p]);
     # trip count = the largest new-node count over lanes
@@ -328,18 +343,16 @@ def propagate_feasibility(sf: SymFrontier):
     # constraint check (either domain may contradict)
     C = sf.con_node.shape[1]
     con_live = jnp.arange(C)[None, :] < sf.con_len[:, None]
+    # the two verdicts of every node, one dense pass over the four
+    # arrays; a constraint then reads one bool at its node
     node = jnp.clip(sf.con_node, 0, T - 1)
-    idx = node[:, :, None].repeat(8, 2)
-    n_lo = jnp.take_along_axis(lo, idx, axis=1)
-    n_hi = jnp.take_along_axis(hi, idx, axis=1)
-    n_km = jnp.take_along_axis(km, idx, axis=1)
-    n_kv = jnp.take_along_axis(kv, idx, axis=1)
-    cant_be_nonzero = jnp.all(n_hi == 0, axis=-1) | (
-        jnp.all(n_km == 0xFFFFFFFF, axis=-1) & jnp.all(n_kv == 0, axis=-1)
-    )
-    cant_be_zero = ~jnp.all(n_lo == 0, axis=-1) | jnp.any(
-        (n_kv & n_km) != 0, axis=-1
-    )
+    cant_be_nonzero = jnp.take_along_axis(
+        jnp.all(hi == 0, axis=-1) | (
+            jnp.all(km == 0xFFFFFFFF, axis=-1) & jnp.all(kv == 0, axis=-1)),
+        node, axis=1)
+    cant_be_zero = jnp.take_along_axis(
+        ~jnp.all(lo == 0, axis=-1) | jnp.any((kv & km) != 0, axis=-1),
+        node, axis=1)
     contradicted = con_live & (sf.con_node != 0) & jnp.where(
         sf.con_sign, cant_be_nonzero, cant_be_zero
     )

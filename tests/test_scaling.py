@@ -58,3 +58,39 @@ def test_packed_fork_plan_is_linear():
     assert b["exponent"] <= 1.05, (
         f"packed fork map fit P^{b['exponent']}, expected linear")
     assert rep["dominant_superlinear"] is None
+
+
+def test_feasibility_sweep_is_dense_on_the_tpu_write_mode(monkeypatch):
+    # what the TPU traces (interpreter._use_scatter False) must hold no
+    # per-lane scatter into, and no single-element gather from, a
+    # [P, T, 8] domain array: the chip serializes both (four such gathers
+    # were 36-41% of its busy time in every cell until PR 37)
+    from mythril_tpu.config import TEST_LIMITS
+    from mythril_tpu.core import interpreter as ci
+    from mythril_tpu.symbolic import kill_infeasible, make_sym_frontier
+
+    P = 16
+    sf = make_sym_frontier(P, TEST_LIMITS)
+    wide = sf.iv_lo.shape
+    assert wide == (P, TEST_LIMITS.tape_len, 8)
+
+    def faults(scatter):
+        monkeypatch.setattr(ci, "_use_scatter", lambda: scatter)
+        found = []
+        # a new function a trace: make_jaxpr keeps what it traced before
+        for eqn in scaling_report.all_eqns(
+                jax.make_jaxpr(lambda s: kill_infeasible(s))(sf)):
+            name = eqn.primitive.name
+            operand = tuple(eqn.invars[0].aval.shape) if eqn.invars else ()
+            if operand != wide:
+                continue
+            if name.startswith("scatter"):
+                found.append(name)
+            elif (name == "gather"
+                  and tuple(eqn.params["slice_sizes"]) == (1, 1, 1)):
+                found.append(name)
+        return found
+
+    assert faults(scatter=False) == []
+    # the walker sees the CPU's form: four row scatters, no element gather
+    assert faults(scatter=True) == ["scatter"] * 4

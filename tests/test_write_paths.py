@@ -237,3 +237,461 @@ def test_shared_writeback_swap_and_veto_semantics():
     # offset, not a zero-fill gather result
     top = np.asarray(out2.stack)[0, 0]
     assert int(top[0]) == 0x7FFFFFFF and int(top[1:].sum()) == 0
+
+
+# ---------------------------------------------------------------------------
+# The feasibility sweep (symbolic/propagate.py) under both write modes,
+# against an oracle in Python integers: one lane at a time, one node at a
+# time, ``out[p, widx[p]] = r[p]`` where ``widx[p] < T``.
+# ---------------------------------------------------------------------------
+
+import functools  # noqa: E402
+
+import pytest  # noqa: E402
+
+from mythril_tpu.config import TEST_LIMITS  # noqa: E402
+from mythril_tpu.ops import u256  # noqa: E402
+from mythril_tpu.symbolic.ops import (  # noqa: E402
+    FreeKind, SymOp, WK_CALLDATA0 as _WK_CD0, WK_CALLDATASIZE as _WK_CDSIZE,
+    WK_CALLER as _WK_CALLER, WK_CALLVALUE as _WK_CALLVALUE)
+
+M256 = (1 << 256) - 1
+SWEEP_P = 8
+_ADDR_KINDS = (int(FreeKind.CALLER), int(FreeKind.ORIGIN))
+_SMALL_KINDS = (int(FreeKind.CALLDATASIZE), int(FreeKind.TIMESTAMP),
+                int(FreeKind.NUMBER))
+
+
+def _ints(arr):
+    """u32[P, T, 8] limbs -> [P][T] Python ints."""
+    P, T = arr.shape[:2]
+    flat = u256.to_ints(arr)
+    return [flat[p * T:(p + 1) * T] for p in range(P)]
+
+
+def _shr(s, a):
+    return a >> s if s < 256 else 0
+
+
+def _shl(s, a):
+    return (a << s) & M256 if s < 256 else 0
+
+
+def _oracle_node(op, kind, imm, A, B):
+    """One node's ``(lo, hi, km, kv)`` from its operands' (the transfer
+    functions of ``propagate_feasibility``'s body, in plain integers)."""
+    O = SymOp
+    (la, ha, ka, va), (lb, hb, kb, vb) = A, B
+    sing_a, sing_b = la == ha, lb == hb
+    lo, hi, km, kv = 0, M256, 0, 0
+    if op == O.CONST:
+        lo = hi = kv = imm
+        km = M256
+    elif op == O.FREE:
+        hi = ((1 << 160) - 1 if kind in _ADDR_KINDS
+              else (1 << 64) - 1 if kind in _SMALL_KINDS else M256)
+        km = M256 ^ hi if hi != M256 else 0
+    elif op == O.ADD:
+        if ha + hb <= M256:
+            lo, hi = (la + lb) & M256, ha + hb
+    elif op == O.SUB:
+        if la >= hb:
+            lo, hi = la - hb, (ha - lb) & M256
+    elif op == O.MUL:
+        if ha * hb <= M256:
+            lo, hi = (la * lb) & M256, ha * hb
+    elif op == O.DIV:
+        hi = ha
+    elif op == O.MOD:
+        hi = 0 if hb == 0 else min(ha, hb - 1)
+    elif op == O.AND:
+        hi = min(ha, hb)
+        km = (ka & kb) | (ka & ~va) | (kb & ~vb)
+        kv = va & vb & km
+    elif op == O.OR:
+        lo = max(la, lb)
+        km = (ka & kb) | (ka & va) | (kb & vb)
+        kv = (va | vb) & km
+    elif op == O.XOR:
+        km = ka & kb
+        kv = (va ^ vb) & km
+    elif op == O.NOT:
+        lo, hi = M256 ^ ha, M256 ^ la
+        km, kv = ka, ~va & ka
+    elif op == O.BYTE:
+        hi = 255
+    elif op == O.SHR:
+        lo, hi = (_shr(la, lb), _shr(la, hb)) if sing_a else (0, hb)
+        if sing_a and la < 256:
+            km = _shr(la, kb) | (M256 ^ _shr(la, M256))
+            kv = _shr(la, vb)
+    elif op == O.SHL:
+        top = _shl(la, hb)
+        if sing_a and la < 256 and _shr(la, top) == hb:
+            lo, hi = _shl(la, lb), top
+        if sing_a and la < 256:
+            km = _shl(la, kb) | (M256 ^ _shl(la, M256))
+            kv = _shl(la, vb)
+    elif op in (O.LT, O.GT, O.EQ, O.ISZERO, O.SLT, O.SGT):
+        t = f = False
+        if op == O.LT:
+            t, f = ha < lb, la >= hb
+        elif op == O.GT:
+            t, f = la > hb, ha <= lb
+        elif op == O.EQ:
+            t = sing_a and sing_b and la == lb
+            f = ha < lb or hb < la
+        elif op == O.ISZERO:
+            t, f = ha == 0, la != 0
+        lo, hi = int(t), int(not f)
+        km, kv = M256 ^ 1, 0
+        a_full, b_full = ka == M256, kb == M256
+        if op == O.EQ:
+            surely = a_full and b_full and va == vb
+            if surely or (va ^ vb) & ka & kb:
+                km, kv = M256, int(surely)
+        elif op == O.ISZERO:
+            surely = a_full and va == 0
+            if surely or va & ka:
+                km, kv = M256, int(surely)
+    return lo, hi & M256, km & M256, kv & M256
+
+
+def oracle_sweep(sf):
+    """``kill_infeasible`` on numpy copies of ``sf``'s leaves: returns the
+    four domain arrays, ``prop_len`` and the lanes killed."""
+    g = lambda x: np.asarray(x)  # noqa: E731
+    t_op, t_a, t_b = g(sf.tape_op), g(sf.tape_a), g(sf.tape_b)
+    t_imm = _ints(sf.tape_imm)
+    tape_len, prop_len = g(sf.tape_len), g(sf.prop_len)
+    dom = [_ints(x) for x in (sf.iv_lo, sf.iv_hi, sf.kb_m, sf.kb_v)]
+    P, T = t_op.shape
+    base = np.maximum(prop_len, 1)
+    trips = max(int((tape_len - base).max()), 0)
+    at = lambda i: min(max(int(i), 0), T - 1)  # noqa: E731
+    for p in range(P):
+        for idx in range(int(base[p]), int(base[p]) + trips):
+            n = at(idx)
+            op = int(t_op[p, n])
+            if not (idx < tape_len[p] and op != SymOp.NULL):
+                continue  # widx == T: written nowhere
+            a, b = at(t_a[p, n]), at(t_b[p, n])
+            r = _oracle_node(op, int(t_a[p, n]), t_imm[p][n],
+                             tuple(d[p][a] for d in dom),
+                             tuple(d[p][b] for d in dom))
+            for d, v in zip(dom, r):
+                d[p][idx] = v
+    lo, hi, km, kv = dom
+    con_node, con_sign = g(sf.con_node), g(sf.con_sign)
+    inf = np.zeros(P, dtype=bool)
+    for p in range(P):
+        for c in range(min(int(g(sf.con_len)[p]), con_node.shape[1])):
+            if con_node[p, c] == 0:
+                continue
+            n = at(con_node[p, c])
+            if con_sign[p, c]:   # asserted nonzero
+                bad = hi[p][n] == 0 or (km[p][n] == M256 and kv[p][n] == 0)
+            else:                # asserted zero
+                bad = lo[p][n] != 0 or (kv[p][n] & km[p][n]) != 0
+            inf[p] |= bad
+    inf &= g(sf.base.active) & ~g(sf.base.error)
+    arrs = [np.stack([u256.from_ints(row) for row in d]) for d in dom]
+    return arrs, np.maximum(prop_len, tape_len), inf
+
+
+@functools.lru_cache(maxsize=None)
+def _sweep_fn(scatter):
+    import jax
+    from mythril_tpu.symbolic import kill_infeasible
+
+    def traced(sf):  # _use_scatter is read while this traces
+        real, ci._use_scatter = ci._use_scatter, lambda: scatter
+        try:
+            return kill_infeasible(sf)
+        finally:
+            ci._use_scatter = real
+    return jax.jit(traced)
+
+
+def _frontier(lanes):
+    """A ``SWEEP_P``-lane frontier at TEST_LIMITS. ``lanes[p]`` is a dict:
+    ``nodes`` [(op, a, b, imm)] appended after the well-known leaves (a
+    negative a / b counts back from the node itself), ``fresh`` how many
+    of the tape's last nodes no sweep has seen (default: all of it),
+    ``cons`` [(node, sign)] (node < 0: from the tape's end), ``active``,
+    ``error``. Rows past a lane's ``prop_len`` hold garbage, so a write
+    that lands where it must not, or is lost, shows."""
+    from mythril_tpu.symbolic import make_sym_frontier
+
+    sf = make_sym_frontier(SWEEP_P, TEST_LIMITS)
+    P, T = sf.tape_op.shape
+    C = sf.con_node.shape[1]
+    n_wk = int(sf.tape_len[0])
+    t_op, t_a, t_b = (np.array(x) for x in (sf.tape_op, sf.tape_a, sf.tape_b))
+    t_imm = np.zeros((P, T, 8), dtype=np.uint32)
+    tape_len = np.full(P, n_wk, dtype=np.int32)
+    fresh = tape_len.copy()
+    con_node = np.zeros((P, C), dtype=np.int32)
+    con_sign = np.zeros((P, C), dtype=bool)
+    con_len = np.zeros(P, dtype=np.int32)
+    active = np.ones(P, dtype=bool)
+    error = np.zeros(P, dtype=bool)
+    for p, lane in enumerate(lanes):
+        n = n_wk
+        for op, a, b, imm in lane.get("nodes", ()):
+            t_op[p, n] = int(op)
+            t_a[p, n] = n + a if a < 0 else a
+            t_b[p, n] = n + b if b < 0 else b
+            t_imm[p, n] = u256.from_int(imm)
+            n += 1
+        assert n <= T
+        tape_len[p] = n
+        fresh[p] = lane.get("fresh", n)
+        for c, (node, sign) in enumerate(lane.get("cons", ())):
+            con_node[p, c] = n + node if node < 0 else node
+            con_sign[p, c] = sign
+            con_len[p] = c + 1
+        con_len[p] = lane.get("con_len", con_len[p])
+        active[p] = lane.get("active", True)
+        error[p] = lane.get("error", False)
+    sf = sf.replace(
+        tape_op=jnp.asarray(t_op), tape_a=jnp.asarray(t_a),
+        tape_b=jnp.asarray(t_b), tape_imm=jnp.asarray(t_imm),
+        tape_len=jnp.asarray(tape_len), con_node=jnp.asarray(con_node),
+        con_sign=jnp.asarray(con_sign), con_len=jnp.asarray(con_len),
+        base=sf.base.replace(active=jnp.asarray(active),
+                             error=jnp.asarray(error)))
+    # everything a lane calls fresh is one sweep away: sweep the rest first
+    seen = np.maximum(tape_len - fresh, 1)
+    if (seen > 1).any():
+        pre = sf.replace(tape_len=jnp.asarray(seen),
+                         con_len=jnp.zeros(P, dtype=jnp.int32))
+        done = _sweep_fn(True)(pre)
+        sf = sf.replace(iv_lo=done.iv_lo, iv_hi=done.iv_hi, kb_m=done.kb_m,
+                        kb_v=done.kb_v, prop_len=done.prop_len)
+    g = np.random.default_rng(11)
+    keep = (np.arange(T)[None, :] < np.asarray(sf.prop_len)[:, None])
+
+    def fill(x):
+        return jnp.where(keep[:, :, None], x, jnp.asarray(
+            g.integers(0, 2**32, (P, T, 8), dtype=np.uint32)))
+    return sf.replace(iv_lo=fill(sf.iv_lo), iv_hi=fill(sf.iv_hi),
+                      kb_m=fill(sf.kb_m), kb_v=fill(sf.kb_v))
+
+
+def _check_sweep(sf):
+    (lo, hi, km, kv), prop_len, inf = oracle_sweep(sf)
+    outs = {}
+    for scatter in (True, False):
+        out = _sweep_fn(scatter)(sf)
+        outs[scatter] = out
+        mode = "scatter" if scatter else "dense"
+        for name, want in (("iv_lo", lo), ("iv_hi", hi), ("kb_m", km),
+                           ("kb_v", kv), ("prop_len", prop_len)):
+            got = np.asarray(getattr(out, name))
+            bad = np.argwhere(got != want)
+            assert not len(bad), (mode, name, bad[:4].tolist())
+        was = np.asarray(sf.base.active)
+        assert (np.asarray(out.base.active) == (was & ~inf)).all(), mode
+        assert (np.asarray(out.killed_infeasible) == inf).all(), mode
+        assert int(out.killed_total) == int(inf.sum()), mode
+    return outs[False], inf
+
+
+def _C(v):
+    return (SymOp.CONST, 0, 0, v)
+
+
+
+# every arm of the body, each on concrete operands, on leaves and on a
+# mix (a: node -2, b: node -1 unless the arm says otherwise)
+_BIN = [SymOp.ADD, SymOp.SUB, SymOp.MUL, SymOp.DIV, SymOp.SDIV, SymOp.MOD,
+        SymOp.SMOD, SymOp.EXP, SymOp.SIGNEXTEND, SymOp.LT, SymOp.GT,
+        SymOp.SLT, SymOp.SGT, SymOp.EQ, SymOp.AND, SymOp.OR, SymOp.XOR,
+        SymOp.BYTE, SymOp.SHL, SymOp.SHR, SymOp.SAR, SymOp.KECCAK_ABS]
+_UN = [SymOp.ISZERO, SymOp.NOT, SymOp.KECCAK, SymOp.KECCAK_SEED]
+_PAIRS = [(7, 3), (3, 7), (5, 5), (0, 9), (9, 0), (M256, 2), (2, M256),
+          (1 << 255, 1 << 255), (255, 0xFF00), (256, 0xFF00), (8, 0xFF00),
+          (4, M256 >> 4), (4, M256), ((1 << 128) - 1, (1 << 128) + 1),
+          ((1 << 128), (1 << 128)), (0xF0, 0x0F)]
+
+
+def _arm_lanes(ops, operands):
+    """One lane an operand pattern: every op of ``ops`` on it."""
+    lanes = []
+    for a, b in operands:
+        nodes = []
+        for op in ops:
+            nodes += [a, b, (op, -2, -1, 0)]
+        lanes.append({"nodes": nodes})
+    return lanes
+
+
+def _consts(pairs):
+    return [(_C(a), _C(b)) for a, b in pairs]
+
+
+def _LEAF(wk):
+    return (SymOp.ADD, wk, 0, 0)   # leaf + node 0: the leaf's domains
+
+
+def _MASKED(wk, m):
+    return [_C(m), (SymOp.AND, wk, -1, 0)]
+
+
+def _n_well_known():
+    from mythril_tpu.symbolic.ops import N_WELL_KNOWN
+    return N_WELL_KNOWN(TEST_LIMITS.calldata_bytes)
+
+
+def _brimful(op):
+    """A constant and ``op`` on it until the tape is full."""
+    return [_C(1)] + [(op, -1, -1, 0)] * (
+        TEST_LIMITS.tape_len - _n_well_known() - 1)
+
+
+SWEEP_CASES = {
+    # all 26 ops on 16 concrete operand pairs, 8 lanes at a time
+    "every_op_concrete_0": lambda: _arm_lanes(_BIN + _UN, _consts(_PAIRS[:8])),
+    "every_op_concrete_1": lambda: _arm_lanes(_BIN + _UN, _consts(_PAIRS[8:])),
+    # the same ops where one or both operands are bounded leaves: the
+    # undecided and the interval-only arms
+    "every_op_on_leaves": lambda: _arm_lanes(_BIN + _UN, [
+        (_LEAF(_WK_CALLER), _C(5)), (_C(5), _LEAF(_WK_CALLER)),
+        (_LEAF(_WK_CDSIZE), _LEAF(_WK_CALLER)),
+        (_LEAF(_WK_CALLVALUE), _C(0)), (_C(300), _LEAF(_WK_CD0)),
+        (_LEAF(_WK_CDSIZE), _LEAF(_WK_CDSIZE)),
+        (_C(1 << 200), _LEAF(_WK_CALLER)), (_C(3), _LEAF(_WK_CDSIZE))]),
+    # a FREE leaf of every kind (160-bit, 64-bit and unbounded), used
+    "free_leaf_kinds": lambda: [
+        {"nodes": [n for k in kinds for n in (
+            (SymOp.FREE, int(k), 0, 0), (SymOp.ADD, -1, -1, 0),
+            (SymOp.NOT, -2, 0, 0))], **fresh}
+        for kinds, fresh in ((list(FreeKind)[:9], {}),
+                             (list(FreeKind)[9:], {"fresh": 10}))],
+    # known bits decide what intervals cannot: (x | 1) == 2, x & 0xF0 == 1,
+    # iszero(x | 4), shifts of a masked word
+    "known_bits_decide": lambda: [
+        {"nodes": [_C(1), (SymOp.OR, _WK_CD0, -1, 0), _C(2),
+                   (SymOp.EQ, -2, -1, 0)], "cons": [(-1, True)]},
+        {"nodes": _MASKED(_WK_CD0, 0xF0) + [_C(1), (SymOp.EQ, -2, -1, 0)],
+         "cons": [(-1, True)]},
+        {"nodes": [_C(4), (SymOp.OR, _WK_CD0, -1, 0),
+                   (SymOp.ISZERO, -1, 0, 0)], "cons": [(-1, True)]},
+        {"nodes": _MASKED(_WK_CD0, 0xFF00) + [_C(8), (SymOp.SHR, -1, -2, 0),
+                                              _C(4), (SymOp.SHL, -1, -3, 0),
+                                              (SymOp.XOR, -1, -3, 0),
+                                              (SymOp.NOT, -1, 0, 0)]},
+        {"nodes": [_C(7), _C(7), (SymOp.EQ, -2, -1, 0),
+                   (SymOp.ISZERO, -1, 0, 0)], "cons": [(-1, True)]},
+        {"nodes": [_C(0), (SymOp.ISZERO, -1, 0, 0)], "cons": [(-1, False)]},
+        {"nodes": _MASKED(_WK_CALLER, M256) + [(SymOp.NOT, -1, 0, 0)]},
+        {"nodes": [_C(300), (SymOp.SHR, -1, _WK_CD0, 0),
+                   (SymOp.SHL, -2, _WK_CD0, 0)]},
+    ],
+    # 0, 1 and many new nodes in one sweep (the trip count is the longest
+    # lane's), the earlier part of each tape swept before
+    "new_nodes_0_1_many": lambda: [
+        {"nodes": [_C(3), _C(4), (SymOp.ADD, -2, -1, 0)], "fresh": 0},
+        {"nodes": [_C(3), _C(4), (SymOp.ADD, -2, -1, 0)], "fresh": 1},
+        {"nodes": [_C(3)] + [(SymOp.ADD, -1, -1, 0)] * 40, "fresh": 30},
+        {"nodes": [], "fresh": 0},
+        {"nodes": [_C(9), (SymOp.LT, -1, _WK_CDSIZE, 0)], "fresh": 2},
+        {"nodes": [_C(2)] + [(SymOp.MUL, -1, -1, 0)] * 12, "fresh": 13},
+        {"nodes": [_C(1), (SymOp.SUB, _WK_CDSIZE, -1, 0)], "fresh": 1},
+        {"nodes": [_C(5)], "fresh": 1},
+    ],
+    # an operand computed an earlier trip of the SAME sweep, chains of it
+    "operand_from_same_sweep": lambda: [
+        {"nodes": [_C(2), (SymOp.ADD, -1, -1, 0), (SymOp.MUL, -1, -2, 0),
+                   (SymOp.SUB, -1, -3, 0), (SymOp.EQ, -1, -1, 0),
+                   (SymOp.ISZERO, -1, 0, 0)], "fresh": 6,
+         "cons": [(-1, True)]},
+        {"nodes": [_C(1)] + [(SymOp.SHL, -1, -1, 0)] * 9, "fresh": 10},
+        {"nodes": [(SymOp.AND, _WK_CALLER, _WK_CDSIZE, 0),
+                   (SymOp.OR, -1, _WK_CD0, 0), (SymOp.GT, -1, -2, 0)],
+         "fresh": 3},
+    ],
+    # a NULL node between live ones: its row keeps what it held
+    "null_node": lambda: [
+        {"nodes": [_C(6), (SymOp.NULL, 0, 0, 0), (SymOp.ADD, -2, -2, 0)],
+         "fresh": 3},
+        {"nodes": [(SymOp.NULL, 0, 0, 77), (SymOp.NULL, -1, -1, 0),
+                   (SymOp.NOT, -1, 0, 0)], "fresh": 3},
+        {"nodes": [_C(6), (SymOp.NULL, 0, 0, 0)], "fresh": 1},
+    ],
+    # tape_len == T: the last row is written, nothing past it; beside a
+    # lane with one new node (it idles for the rest of the trips)
+    "tape_full": lambda: [
+        {"nodes": _brimful(SymOp.ADD)},
+        {"nodes": [_C(1)], "fresh": 1},
+        {"nodes": _brimful(SymOp.XOR), "fresh": 5},
+    ],
+    # who is killed: either domain, either sign; node 0, slots past
+    # con_len, inactive and errored lanes are not
+    "kills": lambda: [
+        {"nodes": [_C(0)], "cons": [(-1, True)]},              # 0 != 0
+        {"nodes": [_C(5)], "cons": [(-1, False)]},             # 5 == 0
+        {"nodes": [_C(5)], "cons": [(-1, True), (0, True)]},   # fine
+        {"nodes": [_C(0)], "cons": [(-1, True)], "con_len": 0},
+        {"nodes": [_C(0)], "cons": [(-1, True)], "active": False},
+        {"nodes": [_C(0)], "cons": [(-1, True)], "error": True},
+        {"nodes": [_C(1 << 70), (SymOp.LT, _WK_CDSIZE, -1, 0)],
+         "cons": [(_WK_CD0, True), (-1, False)]},    # !(size < 2^70)
+        {"nodes": [(SymOp.LT, _WK_CALLER, _WK_CDSIZE, 0)],
+         "cons": [(-1, True), (_WK_CD0, False)]},              # undecided
+    ],
+}
+
+
+@pytest.mark.parametrize("case", sorted(SWEEP_CASES))
+def test_sweep_paths_match_oracle(case):
+    out, inf = _check_sweep(_frontier(SWEEP_CASES[case]()))
+    if case == "tape_full":
+        assert out.prop_len[:3].tolist() == [
+            TEST_LIMITS.tape_len, _n_well_known() + 1, TEST_LIMITS.tape_len]
+    if case == "kills":
+        assert inf.tolist() == [True, True, False, False, False, False,
+                                True, False]
+    if case == "known_bits_decide":
+        assert inf[:6].tolist() == [True, True, True, False, True, True]
+
+
+def test_sweep_cases_reach_every_op():
+    seen = set()
+    for case in SWEEP_CASES.values():
+        for lane in case():
+            seen |= {int(n[0]) for n in lane.get("nodes", ())}
+    assert seen == {int(op) for op in SymOp}
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_sweep_paths_match_oracle_random_tapes(seed):
+    """Random tapes over every op, operands anywhere earlier on the tape,
+    a second sweep resuming where the first stopped."""
+    g = np.random.default_rng(seed)
+    n_wk = _n_well_known()
+    words = [0, 1, 2, 255, 256, 1 << 160, M256, M256 - 1, 1 << 255]
+
+    def nodes(k, start):
+        out = []
+        for j in range(k):
+            op = int(g.integers(0, len(SymOp)))
+            if op == SymOp.FREE:   # a is the kind there
+                a, b = int(g.integers(0, 17)), 0
+            else:
+                a, b = (int(x) for x in g.integers(0, start + j, 2))
+            imm = (words[int(g.integers(len(words)))] if g.random() < 0.5
+                   else int.from_bytes(g.bytes(32), "big"))
+            out.append((op, a, b, imm))
+        return out
+
+    lanes = []
+    for p in range(SWEEP_P):
+        k = int(g.integers(0, 60))
+        cons = [(int(g.integers(0, n_wk + max(k, 1))), bool(g.random() < 0.5))
+                for _ in range(int(g.integers(0, 12)))]
+        lanes.append({"nodes": nodes(k, n_wk), "cons": cons})
+        if p % 2:
+            lanes[-1]["fresh"] = int(g.integers(0, k + 1))
+    _check_sweep(_frontier(lanes))

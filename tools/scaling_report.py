@@ -61,27 +61,30 @@ DEFAULT_P = (1024, 4096, 16384)
 PER_LANE_EXPONENT_BUDGET = 1.05
 
 
+def all_eqns(jaxpr):
+    """Every equation of a (Closed)Jaxpr, sub-jaxprs included (cond
+    branches, while bodies, pjit calls, scans: each once)."""
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        yield eqn
+        # params hold sub-jaxprs under many names (branches, jaxpr,
+        # body_jaxpr, ...) and inside tuples — duck-type on .eqns
+        stack = list(eqn.params.values())
+        while stack:
+            val = stack.pop()
+            if isinstance(val, (tuple, list)):
+                stack.extend(val)
+            elif hasattr(getattr(val, "jaxpr", val), "eqns"):
+                yield from all_eqns(val)
+
+
 def jaxpr_cost(jaxpr) -> dict:
-    """Recursive op/element/byte totals over a (Closed)Jaxpr. Sub-jaxprs
-    (cond branches, while bodies, pjit calls, scans) count ONCE — the
-    model measures program size per trip, not trip counts, which is the
-    right units for a growth-in-P fit."""
-    inner = getattr(jaxpr, "jaxpr", jaxpr)
+    """Op/element/byte totals over a (Closed)Jaxpr. Sub-jaxprs count
+    ONCE — the model measures program size per trip, not trip counts,
+    which is the right units for a growth-in-P fit."""
     ops = 0
     elems = 0
     nbytes = 0
-
-    def _subjaxprs(val):
-        # params hold sub-jaxprs under many names (branches, jaxpr,
-        # body_jaxpr, ...) and inside tuples — duck-type on .eqns
-        if hasattr(val, "eqns") or hasattr(getattr(val, "jaxpr", None),
-                                           "eqns"):
-            yield val
-        elif isinstance(val, (tuple, list)):
-            for v in val:
-                yield from _subjaxprs(v)
-
-    for eqn in inner.eqns:
+    for eqn in all_eqns(jaxpr):
         ops += 1
         for ov in eqn.outvars:
             aval = getattr(ov, "aval", None)
@@ -94,12 +97,6 @@ def jaxpr_cost(jaxpr) -> dict:
             elems += n
             dt = getattr(aval, "dtype", None)
             nbytes += n * (dt.itemsize if dt is not None else 4)
-        for val in eqn.params.values():
-            for sub in _subjaxprs(val):
-                c = jaxpr_cost(sub)
-                ops += c["ops"]
-                elems += c["elems"]
-                nbytes += c["bytes"]
     return {"ops": ops, "elems": elems, "bytes": nbytes}
 
 
