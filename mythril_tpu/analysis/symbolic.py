@@ -13,7 +13,7 @@ import hashlib
 import logging
 from operator import attrgetter
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -22,7 +22,7 @@ from ..core import Corpus, make_env
 from ..core.frontier import ATTACKER_ADDRESS, CAP_TRAPS, TRAP_NAMES
 from ..disassembler import ContractImage
 from ..obs import metrics as obs_metrics
-from ..obs.device import HostLeaves, fetch, tally
+from ..obs.device import HostLeaves, fetch, phase_timer, tally
 from ..obs import trace as obs_trace
 from ..smt.eval import Assignment
 from ..smt.solver import solve_tape
@@ -399,25 +399,37 @@ class SymExecWrapper:
             None if execution_timeout is None
             else _time.monotonic() + execution_timeout
         )
-        runtime_imgs = [ContractImage.from_bytecode(c, limits.max_code)
-                        for c in bytecodes]
-        C = len(runtime_imgs)
-        names = list(contract_names or [f"contract_{i}" for i in range(C)])
-        with_creation = creation_bytecodes is not None
-        if with_creation:
-            assert len(creation_bytecodes) == C
-            creation_imgs = [ContractImage.from_bytecode(c, limits.max_code)
-                             for c in creation_bytecodes]
-            # corpus layout: creation images [0, C), runtime images [C, 2C)
-            images = creation_imgs + runtime_imgs
-            runtime_base = C
-            names = [f"{n} (constructor)" for n in names] + names
-        else:
-            images = runtime_imgs
-            runtime_base = 0
+        # the lead-in: what this thread does before its first
+        # ``sym_run`` call, while the device waits, as four
+        # ``batch_build`` spans (docs/observability.md)
+        with phase_timer("batch_build", stage="images") as build:
+            runtime_imgs = [ContractImage.from_bytecode(c, limits.max_code)
+                            for c in bytecodes]
+            C = len(runtime_imgs)
+            names = list(contract_names
+                         or [f"contract_{i}" for i in range(C)])
+            with_creation = creation_bytecodes is not None
+            if with_creation:
+                assert len(creation_bytecodes) == C
+                creation_imgs = [
+                    ContractImage.from_bytecode(c, limits.max_code)
+                    for c in creation_bytecodes]
+                # corpus layout: creation images [0, C), runtime
+                # images [C, 2C)
+                images = creation_imgs + runtime_imgs
+                runtime_base = C
+                names = [f"{n} (constructor)" for n in names] + names
+            else:
+                images = runtime_imgs
+                runtime_base = 0
+            build.attrs.update(
+                images=len(images),
+                code_bytes=sum(map(len, bytecodes)) + sum(
+                    map(len, creation_bytecodes or ())))
         self.images = images
         self._n_creation = C if with_creation else 0
-        self.corpus = Corpus.from_images(images, self._n_creation)
+        with phase_timer("batch_build", stage="corpus"):
+            self.corpus = Corpus.from_images(images, self._n_creation)
         self._visited = np.zeros(
             (len(images), limits.max_code), dtype=bool)
         # mid-execution dynamic loading (reference: DynLoader.dynld
@@ -439,46 +451,60 @@ class SymExecWrapper:
         self._dynld_fails: Dict[int, int] = {}  # transient-failure counts
         self.dynld_loaded: List[int] = []  # addresses loaded mid-run
         self._dynld_sha: List[str] = []    # sha256 of each loaded image
-        P = C * lanes_per_contract
-        cid0 = np.repeat(np.arange(C, dtype=np.int32), lanes_per_contract)
-        active = np.zeros(P, dtype=bool)
-        active[::lanes_per_contract] = True  # one seed lane per contract
-        sf = make_sym_frontier(
-            P, limits, contract_id=cid0, active=active, n_contracts=C,
-            contract_addrs=(list(contract_addrs) if contract_addrs is not None
-                            else None),
-            caller=CREATOR_ADDRESS if with_creation else ATTACKER_ADDRESS,
-        )
-        if with_creation:
-            # account table resolves calls/extcode against RUNTIME images
-            b = sf.base
-            import jax.numpy as jnp
-            sf = sf.replace(base=b.replace(
-                acct_code=jnp.where(b.acct_code >= 0, b.acct_code + C,
-                                    b.acct_code),
-            ))
-        # instruction profiler (reference: --enable-iprof ⚠unv, SURVEY
-        # §5.1): per-lane opcode histograms ride the frontier; the host
-        # harvests + zeroes them at each tx boundary so slot recycling
-        # can't lose or double-count a retired lane's rows
-        self.enable_iprof = enable_iprof
-        self._iprof = np.zeros(256, dtype=np.int64)
-        if enable_iprof:
-            sf = sf.replace(base=sf.base.attach_iprof())
-        if spill:
-            # the scalar every ``defer_starved`` call writes, there from
-            # the first call on: one frontier structure, one program
-            import jax.numpy as jnp
-            sf = sf.replace(fixpoint=jnp.zeros((), dtype=bool))
-        env = make_env(P)
-        # host mirror of the frontier's run-total superstep counter (a
-        # chunk's count is the difference across its sym_run call)
-        self._steps_seen = 0
-        # from shapes and dtypes alone: no device sync
-        obs_metrics.REGISTRY.gauge(
-            "frontier_bytes",
-            help="bytes of the symbolic frontier's device leaves").set(
-            sum(x.nbytes for x in jax.tree.leaves(sf)))
+        with phase_timer("batch_build", stage="frontier") as build:
+            P = C * lanes_per_contract
+            cid0 = np.repeat(np.arange(C, dtype=np.int32),
+                             lanes_per_contract)
+            active = np.zeros(P, dtype=bool)
+            active[::lanes_per_contract] = True  # one seed lane a contract
+            sf = make_sym_frontier(
+                P, limits, contract_id=cid0, active=active, n_contracts=C,
+                contract_addrs=(list(contract_addrs)
+                                if contract_addrs is not None else None),
+                caller=(CREATOR_ADDRESS if with_creation
+                        else ATTACKER_ADDRESS),
+            )
+            if with_creation:
+                # account table resolves calls/extcode against RUNTIME
+                # images
+                b = sf.base
+                import jax.numpy as jnp
+                sf = sf.replace(base=b.replace(
+                    acct_code=jnp.where(b.acct_code >= 0, b.acct_code + C,
+                                        b.acct_code),
+                ))
+            # instruction profiler (reference: --enable-iprof ⚠unv,
+            # SURVEY §5.1): per-lane opcode histograms ride the frontier;
+            # the host harvests + zeroes them at each tx boundary so slot
+            # recycling can't lose or double-count a retired lane's rows
+            self.enable_iprof = enable_iprof
+            self._iprof = np.zeros(256, dtype=np.int64)
+            if enable_iprof:
+                sf = sf.replace(base=sf.base.attach_iprof())
+            if spill:
+                # the scalar every ``defer_starved`` call writes, there
+                # from the first call on: one frontier structure, one
+                # program
+                import jax.numpy as jnp
+                sf = sf.replace(fixpoint=jnp.zeros((), dtype=bool))
+            env = make_env(P)
+            # host mirror of the frontier's run-total superstep counter
+            # (a chunk's count is the difference across its sym_run call)
+            self._steps_seen = 0
+            # from shapes and dtypes alone: no device sync
+            frontier_bytes = sum(x.nbytes for x in jax.tree.leaves(sf))
+            obs_metrics.REGISTRY.gauge(
+                "frontier_bytes",
+                help="bytes of the symbolic frontier's device "
+                     "leaves").set(frontier_bytes)
+            build.attrs["frontier_bytes"] = int(frontier_bytes)
+        # from here to the first ``sym_run`` call: the plugins, the
+        # read of ``base.active``, ``on_tx_start``
+        self._lead_in = phase_timer("batch_build", stage="start").start()
+        # (mono start, mono end) of every ``sym_run`` call, by the
+        # ``superstep`` timer's own two clock reads: what the campaign
+        # measures a host phase's hidden seconds against
+        self.sym_run_calls: List[Tuple[float, float]] = []
 
         # multi-tx outer loop (reference: execute_transactions iterating
         # open_states ⚠unv SURVEY.md §3.2): snapshot a context after each
@@ -571,6 +597,7 @@ class SymExecWrapper:
                 seal()
                 if self._round:
                     attrs["round"] = self._round
+                self._end_lead_in()
                 with obs_trace.timer("superstep", tx=self._cur_tx,
                                      tx_kind=self._tx_kind, steps=n,
                                      cold=cold, stuck=False, **attrs) as sp:
@@ -597,6 +624,7 @@ class SymExecWrapper:
                     # timed to here, emitted by ``seal`` once the
                     # call's seam has said what it found
                     held.append(sp.hold())
+                self.sym_run_calls.append((sp.t_mono, sp.t_mono + sp.dur))
                 self._steps_seen = int(got[1])
                 self._visited |= got[0]
                 reg = obs_metrics.REGISTRY
@@ -880,40 +908,53 @@ class SymExecWrapper:
         self._started = np.ones(C, dtype=np.int64)
         self._round = 0
         self._lost_by = np.zeros(C, dtype=np.int64)
-        self.plugin_loader.fire("initialize", self)
-        if with_creation:
-            # --create-timeout (reference: a separate wall-clock budget
-            # for the creation transaction ⚠unv): narrow the deadline for
-            # the constructor run only, then restore — hitting the
-            # CREATION budget must not cancel the message-call phase
-            outer_deadline = self._deadline_at
-            if create_timeout is not None:
-                cd = _time.monotonic() + create_timeout
-                self._deadline_at = (cd if outer_deadline is None
-                                     else min(outer_deadline, cd))
-            # a constructor needn't mutate storage for the deploy to count
-            sf = run_one_tx(sf, is_last=False, handoff_kw=dict(
-                require_mutation=False, runtime_offset=runtime_base))
-            self._cur_tx += 1
-            self._tx_kind = "message"
-            if create_timeout is not None:
-                self._deadline_at = outer_deadline
-                if self.timed_out and (outer_deadline is None
-                                       or _time.monotonic() < outer_deadline):
-                    self.timed_out = False
-        for t in range(transaction_count):
-            if self.timed_out:
-                break  # deadline: report what was explored so far
-            alive, self._carried = self._carried, None
-            if alive is None:
-                alive = fetch(sf.base.active, "base.active")
-            if not bool(alive.any()):
-                break  # nothing survived: no state left to extend
-            sf = run_one_tx(sf, is_last=(t == transaction_count - 1))
-            self._cur_tx += 1
+        try:
+            self.plugin_loader.fire("initialize", self)
+            if with_creation:
+                # --create-timeout (reference: a separate wall-clock
+                # budget for the creation transaction ⚠unv): narrow the
+                # deadline for the constructor run only, then restore —
+                # hitting the CREATION budget must not cancel the
+                # message-call phase
+                outer_deadline = self._deadline_at
+                if create_timeout is not None:
+                    cd = _time.monotonic() + create_timeout
+                    self._deadline_at = (cd if outer_deadline is None
+                                         else min(outer_deadline, cd))
+                # a constructor needn't mutate storage for the deploy to
+                # count
+                sf = run_one_tx(sf, is_last=False, handoff_kw=dict(
+                    require_mutation=False, runtime_offset=runtime_base))
+                self._cur_tx += 1
+                self._tx_kind = "message"
+                if create_timeout is not None:
+                    self._deadline_at = outer_deadline
+                    if self.timed_out and (
+                            outer_deadline is None
+                            or _time.monotonic() < outer_deadline):
+                        self.timed_out = False
+            for t in range(transaction_count):
+                if self.timed_out:
+                    break  # deadline: report what was explored so far
+                alive, self._carried = self._carried, None
+                if alive is None:
+                    alive = fetch(sf.base.active, "base.active")
+                if not bool(alive.any()):
+                    break  # nothing survived: no state left to extend
+                sf = run_one_tx(sf, is_last=(t == transaction_count - 1))
+                self._cur_tx += 1
+        finally:
+            self._end_lead_in()
         self.sf = sf
         self.ctx = self.tx_contexts[-1]
         self.plugin_loader.fire("on_run_end", self)
+
+    def _end_lead_in(self) -> None:
+        """End the lead-in's last ``batch_build`` span: at the first
+        ``sym_run`` call, or where the run ends without one."""
+        sp, self._lead_in = self._lead_in, None
+        if sp is not None:
+            sp.stop()
 
     def _count_lost(self, n: int, lanes=None, home=None) -> None:
         """``n`` forks given up at a host seam go into the drop channel

@@ -1750,7 +1750,11 @@ class CorpusCampaign:
         phases; the attr is ``wait``, not ``kind`` — ``kind`` is the
         JSONL schema's reserved record-type field and a colliding span
         attr is dropped), plus a ``pipeline_occupancy`` gauge = fraction of
-        host-phase seconds hidden behind device execution. The per-batch
+        host-phase seconds hidden behind device execution: those that
+        passed while a ``sym_run`` call of the device phase beside the
+        host phase was in flight (``SymExecWrapper.sym_run_calls``). A
+        host second spent while that phase was still building its batch
+        is not hidden: the device waited through it. The per-batch
         ``batch`` span/wall is ``device_dur + commit_stall`` — the
         batch's contribution to campaign wall-clock — so the trace
         report's batch stall table sums to (about) the campaign wall,
@@ -1764,20 +1768,30 @@ class CorpusCampaign:
         inflight: Optional[Dict] = None
         host_idle_since: Optional[float] = None
 
-        def account_overlap(host_dur: float, stall: float) -> None:
-            hidden = max(0.0, host_dur - stall)
+        def account_overlap(host_dur: float, done_mono: float,
+                            beside) -> float:
+            """``hidden``: the overlap of the host phase that ended at
+            ``done_mono`` with the ``sym_run`` calls of ``beside``, the
+            handle of the device phase that ran meanwhile (None: there
+            was none; a handle without a wrapper made no call)."""
+            calls = (beside[1].sym_run_calls
+                     if beside is not None and beside[0] == "sym" else ())
+            start = done_mono - host_dur
+            hidden = sum(max(0.0, min(done_mono, c1) - max(start, c0))
+                         for c0, c1 in calls)
             self._pipe_host_sec += host_dur
             self._pipe_hidden_sec += hidden
             reg.counter(
                 "pipeline_host_hidden_seconds_total",
-                help="host-phase seconds overlapped with device "
-                     "execution").inc(hidden)
+                help="host-phase seconds that passed while a sym_run "
+                     "call was in flight").inc(hidden)
             reg.gauge(
                 "pipeline_occupancy",
-                help="fraction of host-phase seconds hidden behind "
-                     "device execution").set(
+                help="fraction of host-phase seconds that passed while "
+                     "a sym_run call was in flight").set(
                 self._pipe_hidden_sec / self._pipe_host_sec
                 if self._pipe_host_sec else 0.0)
+            return hidden
 
         def drain_serial(bi: int, items: Sequence[tuple], err,
                          dev_dur: float, t_wall: float, t_mono: float,
@@ -1793,7 +1807,7 @@ class CorpusCampaign:
                                drained=True)
             commit(bi, out, dt)
 
-        def commit_inflight(fl: Dict) -> None:
+        def commit_inflight(fl: Dict, beside=None) -> None:
             nonlocal host_idle_since
             bi = fl["bi"]
             wait_sp = obs_trace.timer("pipeline_stall",
@@ -1821,14 +1835,15 @@ class CorpusCampaign:
                 "pipeline_device_waits_host_seconds_total",
                 help="device idle: loop blocked on an unfinished host "
                      "phase").inc(stall)
-            account_overlap(host_dur, stall)
+            hidden = account_overlap(host_dur, done_mono, beside)
             dt = fl["dev_dur"] + stall
             obs_trace.complete("batch", dt, t_wall=fl["t_wall"],
                                mono=fl["mono"], bi=bi, n=fl["n"],
                                pipelined=True,
                                device_dur=round(fl["dev_dur"], 6),
                                host_dur=round(host_dur, 6),
-                               stall=round(stall, 6))
+                               stall=round(stall, 6),
+                               hidden=round(hidden, 6))
             commit(bi, out, dt)
 
         try:
@@ -1850,7 +1865,7 @@ class CorpusCampaign:
                 # commit the PREVIOUS batch only now: its host phase ran
                 # concurrently with the device phase that just finished
                 if inflight is not None:
-                    commit_inflight(inflight)
+                    commit_inflight(inflight, handle)
                     inflight = None
                 if first_err is not None:
                     drain_serial(bi, items, first_err, dev_dur,

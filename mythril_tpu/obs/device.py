@@ -22,9 +22,19 @@ always times the blocking call (clock reads only, like
   ``host_phase``) gain ``device_fetches``, ``device_wait_s`` and
   ``cpu_s`` (``time.thread_time()`` outside the reads) at their end,
   so that ``dur = cpu_s + device_wait_s + rest`` and ``rest`` is
-  waiting for a lock, a pool or another thread;
+  waiting for a lock, a pool or another thread; and ``proc_cpu_s``
+  (``time.process_time()`` differenced: every thread of the process),
+  which says what the rest of the process did meanwhile:
+  ``proc_cpu_s / dur`` near 1 while two threads had work is one lock
+  letting one of them run at a time, near 2 they ran side by side
+  (the runtime's own threads count too: read it beside the two
+  threads' ``cpu_s``, which add up to the wall clock under one lock);
 - only when ``trace.active()``, each read is a ``device_fetch`` span
   with ``what`` (the leaf's name), ``bytes`` and ``mono`` at its start.
+
+What ``device_wait_s`` really is: the blocking read *and* the wait to
+get the interpreter lock back once the array is there. Beside a busy
+thread it over-reads by that wait (PERF.md §5).
 
 What the tally misses: work the phase hands to other threads (the
 module pool of ``--solver-workers`` > 1, the watchdog thread of
@@ -134,20 +144,23 @@ class PhaseSpan(obs_trace.Span):
     carries what its own thread did meanwhile: ``device_fetches``,
     ``device_wait_s`` (the tally's differences) and ``cpu_s`` (the
     thread's CPU seconds outside the reads), so that
-    ``cpu_s + device_wait_s <= dur``."""
+    ``cpu_s + device_wait_s <= dur``; and ``proc_cpu_s``, the CPU
+    seconds of the whole process over the span."""
 
-    __slots__ = ("_n0", "_w0", "_c0")
+    __slots__ = ("_n0", "_w0", "_c0", "_p0")
 
     def __enter__(self) -> "PhaseSpan":
         super().__enter__()
         self._n0, self._w0, in_reads = tally()
         self._c0 = time.thread_time() - in_reads
+        self._p0 = time.process_time()
         return self
 
     start = __enter__
 
     def __exit__(self, *exc) -> bool:
         n1, w1, in_reads = tally()
+        proc = time.process_time() - self._p0
         cpu = time.thread_time() - in_reads - self._c0
         wait = round(w1 - self._w0, 6)
         # both clocks were read inside the span, so the sum cannot pass
@@ -157,7 +170,8 @@ class PhaseSpan(obs_trace.Span):
         if cpu + wait > so_far:
             cpu = max(0.0, round(cpu - 1e-6, 6))
         self.attrs.update(device_fetches=n1 - self._n0,
-                          device_wait_s=wait, cpu_s=cpu)
+                          device_wait_s=wait, cpu_s=cpu,
+                          proc_cpu_s=round(proc, 6))
         return super().__exit__(*exc)
 
 

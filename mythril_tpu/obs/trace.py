@@ -308,6 +308,11 @@ class Span:
             return self.dur
         return time.monotonic() - self._t0
 
+    @property
+    def t_mono(self) -> float:
+        """``time.monotonic()`` at the span's start: its ``mono``."""
+        return self._t0
+
 
 class _NullSpan:
     """The disabled-tracer singleton: zero state, zero clock reads.

@@ -111,6 +111,44 @@ def test_timer_stopwatch_start_stop():
     assert dur >= live and sw.elapsed == dur       # frozen after stop
 
 
+@pytest.mark.parametrize("beside", ["nobody", "a_busy_thread"])
+def test_phase_span_says_what_the_process_did_meanwhile(beside):
+    """``proc_cpu_s`` is the CPU clock of every thread: a phase that
+    sleeps beside a busy thread reads it well above its own
+    ``cpu_s``."""
+    import threading
+
+    from mythril_tpu.obs import device as obs_device
+
+    def burn(stop):
+        while not stop.is_set():
+            sum(range(2000))
+
+    stop = threading.Event()
+    helper = threading.Thread(target=burn, args=(stop,))
+    tr = obs_trace.configure(buffer=True)
+    if beside == "a_busy_thread":
+        helper.start()
+    try:
+        with obs_device.phase_timer("batch_build", stage="start") as sp:
+            time.sleep(0.3)
+            mono_in = time.monotonic()
+    finally:
+        stop.set()
+        if helper.ident is not None:
+            helper.join()
+    assert sp.t_mono <= mono_in <= sp.t_mono + sp.dur
+    (rec,) = [r for r in tr.drain_buffer() if r["name"] == "batch_build"]
+    assert rec["stage"] == "start" and rec["mono"] == round(sp.t_mono, 6)
+    assert rec["cpu_s"] + rec["device_wait_s"] <= rec["dur"]
+    assert rec["proc_cpu_s"] >= 0.0 and rec["cpu_s"] <= 0.05
+    # the process's clock holds this thread's (to the clocks' tick)
+    assert rec["proc_cpu_s"] >= rec["cpu_s"] - 0.02
+    if beside == "a_busy_thread":
+        # (a loaded machine gives the helper a share of a CPU, not one)
+        assert rec["proc_cpu_s"] - rec["cpu_s"] >= 0.03
+
+
 def test_jsonl_path_derivation():
     assert obs_trace.jsonl_path_for("t.json") == "t.jsonl"
     assert obs_trace.jsonl_path_for("out/trace") == "out/trace.jsonl"

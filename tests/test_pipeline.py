@@ -158,10 +158,210 @@ def test_pipeline_emits_overlap_telemetry(tmp_path):
     assert "host time hidden behind device execution" in text
 
 
+# --- the lead-in's spans, and what "hidden" means ----------------------
+
+PHASES = ("device_phase", "host_phase", "batch_build")
+STAGES = ["images", "corpus", "frontier", "start"]
+
+
+def _named(recs, name):
+    return sorted((r for r in recs if r.get("kind") == "span"
+                   and r["name"] == name), key=lambda r: r["mono"])
+
+
+def _hidden_by_the_spans(recs):
+    """Seconds of the ``host_phase`` spans that passed while a
+    ``superstep`` span of the feeder thread (the ``device_phase``
+    spans' ``tid``) was open, and the sum of their durations."""
+    feeder = {d["tid"] for d in _named(recs, "device_phase")}
+    calls = [(c["mono"], c["mono"] + c["dur"])
+             for c in _named(recs, "superstep") if c["tid"] in feeder]
+    hosts = _named(recs, "host_phase")
+    hidden = sum(max(0.0, min(h["mono"] + h["dur"], c1) - max(h["mono"], c0))
+                 for h in hosts for c0, c1 in calls)
+    return hidden, sum(h["dur"] for h in hosts)
+
+
+@pytest.fixture(scope="module")
+def lead_in_runs(tmp_path_factory):
+    """Three runs of one two-batch pipelined campaign: untraced, traced,
+    and traced with a host phase slowed until it reaches into the next
+    batch's first ``sym_run`` call. For each its signature, its records
+    and the registry's pipeline series over it."""
+    import time
+
+    from mythril_tpu.obs import metrics as obs_metrics
+    from mythril_tpu.obs import trace as obs_trace
+
+    corpus = write_corpus(tmp_path_factory.mktemp("lead_in"))
+    make_campaign(corpus, pipeline=True).run()      # compiles, if cold
+    runs = {}
+    for case in ("untraced", "traced", "slow_host"):
+        camp = make_campaign(corpus, pipeline=True)
+        if case == "slow_host":
+            harvest = camp._harvest_batch
+
+            def slow(bi, sym, harvest=harvest):
+                time.sleep(0.4)
+                return harvest(bi, sym)
+
+            camp._harvest_batch = slow
+        obs_trace.close()
+        tracer = (None if case == "untraced"
+                  else obs_trace.configure(buffer=True))
+        before = obs_metrics.REGISTRY.snapshot()
+        try:
+            res = camp.run()
+        finally:
+            after = obs_metrics.REGISTRY.snapshot()
+            if tracer is None:
+                # whatever a span emitted late would land here
+                tracer = obs_trace.configure(buffer=True)
+            recs = tracer.drain_buffer()
+            obs_trace.close()
+        runs[case] = {
+            "sig": _sig(res), "recs": recs,
+            "occupancy": after["gauges"]["pipeline_occupancy"],
+            "hidden": (
+                after["counters"]["pipeline_host_hidden_seconds_total"]
+                - before["counters"].get(
+                    "pipeline_host_hidden_seconds_total", 0.0))}
+    return runs
+
+
+def test_batch_build_stages_once_a_batch_on_the_feeder_in_order(
+        lead_in_runs):
+    recs = lead_in_runs["traced"]["recs"]
+    devs = _named(recs, "device_phase")
+    builds = _named(recs, "batch_build")
+    assert len(devs) == 2 and len(builds) == 2 * len(STAGES)
+    for d in devs:
+        end = d["mono"] + d["dur"]
+        mine = [b for b in builds if b["tid"] == d["tid"]
+                and d["mono"] <= b["mono"]
+                and b["mono"] + b["dur"] <= end + 1e-5]
+        assert [b["stage"] for b in mine] == STAGES
+        images, _, frontier, _ = mine
+        assert images["images"] == 4 and images["code_bytes"] > 0
+        assert frontier["frontier_bytes"] > 0
+
+
+def test_batch_build_stages_cover_the_lead_in_without_overlap(
+        lead_in_runs):
+    recs = lead_in_runs["traced"]["recs"]
+    builds = _named(recs, "batch_build")
+    for d in _named(recs, "device_phase"):
+        end = d["mono"] + d["dur"]
+        mine = [b for b in builds if d["mono"] <= b["mono"] <= end]
+        first = next(c for c in _named(recs, "superstep")
+                     if c["tid"] == d["tid"]
+                     and d["mono"] <= c["mono"] <= end)
+        # one after the other, the last ends where the call starts
+        for a, b in zip(mine, mine[1:]):
+            assert a["mono"] + a["dur"] <= b["mono"] + 2e-6
+        last = mine[-1]
+        assert last["stage"] == "start"
+        assert last["mono"] + last["dur"] <= first["mono"] + 2e-6
+        # what no stage holds is the hand-overs between them (a loaded
+        # machine may take the thread off the CPU in one: 0.1 s of room)
+        lead_in = first["mono"] - d["mono"]
+        assert lead_in - sum(b["dur"] for b in mine) <= 0.05 * lead_in + 0.1
+
+
+@pytest.mark.parametrize("name", PHASES)
+def test_phase_spans_split_their_wall_clock(lead_in_runs, name):
+    got = _named(lead_in_runs["traced"]["recs"], name)
+    assert got
+    for sp in got:
+        assert sp["cpu_s"] >= 0.0 and sp["device_wait_s"] >= 0.0
+        assert sp["cpu_s"] + sp["device_wait_s"] <= sp["dur"]
+        assert sp["proc_cpu_s"] >= 0.0
+
+
+def test_tracing_off_leaves_no_record_and_the_same_results(lead_in_runs):
+    assert lead_in_runs["untraced"]["recs"] == []
+    assert lead_in_runs["untraced"]["sig"] == lead_in_runs["traced"]["sig"]
+    assert lead_in_runs["untraced"]["sig"]["issues"]
+    # what is counted does not wait for a tracer
+    assert 0.0 <= lead_in_runs["untraced"]["occupancy"] <= 1.0
+
+
+@pytest.mark.parametrize("case", ["traced", "slow_host"])
+def test_occupancy_is_the_overlap_with_sym_run_calls(lead_in_runs, case):
+    """``hidden`` means "while a ``sym_run`` call was in flight": the
+    gauge, the counter and the ``batch`` spans' ``hidden`` agree with
+    the overlap of the run's own ``host_phase`` and ``superstep``
+    spans. A host phase that ends inside the next batch's lead-in hid
+    nothing, however short the stall it caused."""
+    run = lead_in_runs[case]
+    hidden, host = _hidden_by_the_spans(run["recs"])
+    assert host > 0.0
+    assert 0.0 <= run["occupancy"] <= 1.0
+    assert abs(run["occupancy"] - hidden / host) <= 0.02
+    assert abs(run["hidden"] - hidden) <= 0.02 * host
+    batches = [b for b in _named(run["recs"], "batch")
+               if b.get("pipelined") and not b.get("drained")]
+    assert len(batches) == 2
+    assert abs(sum(b["hidden"] for b in batches) - run["hidden"]) <= 1e-4
+    assert all(0.0 <= b["hidden"] <= b["host_dur"] + 1e-6
+               for b in batches)
+    # the window's last host phase has no device phase beside it
+    assert batches[-1]["hidden"] == 0.0
+    if case == "slow_host":
+        # it reached past the lead-in, over the whole first call
+        first = _named(run["recs"], "superstep")[-1]
+        assert hidden >= 0.9 * first["dur"] > 0.0
+        assert run["sig"] == lead_in_runs["traced"]["sig"]
+
+
+@pytest.mark.parametrize("case", ["traced", "slow_host"])
+def test_trace_report_reads_hidden_and_the_lead_in_off_the_spans(
+        lead_in_runs, case, tmp_path):
+    """The operator's reading: the report's hidden seconds are the
+    gauge's (not host work less the stalls), and every device phase has
+    a row for its lead-in with a line a ``batch_build`` stage."""
+    import importlib.util
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "trace_report", os.path.join(root, "tools", "trace_report.py"))
+    tr = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tr)
+    run = lead_in_runs[case]
+    path = tmp_path / "t.jsonl"
+    path.write_text("".join(json.dumps(r) + "\n" for r in run["recs"]))
+    text = tr.report(*tr.load_trace(str(path)))
+    hidden, host = _hidden_by_the_spans(run["recs"])
+    (line,) = [ln for ln in text.splitlines() if ln.startswith(
+        "host time hidden behind device execution:")]
+    assert line == ("host time hidden behind device execution: "
+                    f"{tr._fmt_s(hidden).strip()} "
+                    f"({100.0 * hidden / host:.0f}% of host work)")
+    assert (hidden > 0.0) == (case == "slow_host")
+    at = text.splitlines().index(
+        "lead-in of each device phase (start to first sym_run call), "
+        "then its batch_build stages:")
+    rows = text.splitlines()[at + 2:at + 2 + 2 * (1 + len(STAGES))]
+    assert [r.split()[0] for r in rows] == ["0", *STAGES, "1", *STAGES]
+    # batch 1's lead-in had batch 0's host phase beside it, batch 0's
+    # nobody: the row's last column
+    assert rows[0].split()[-1] == "0.00ms"
+    assert rows[1 + len(STAGES)].split()[-1] != "0.00ms"
+
+
 def test_pipeline_with_stub_runner_falls_through(tmp_path):
     """A custom batch_runner has no device/host seam: the handle
     carries its finished result and the pipeline degenerates to the
-    serial order (runner called once per batch, in order)."""
+    serial order (runner called once per batch, in order). It has no
+    host phase to hide either: 0 hidden seconds."""
+    from mythril_tpu.obs import metrics as obs_metrics
+
+    def hidden_total():
+        return obs_metrics.REGISTRY.snapshot()["counters"].get(
+            "pipeline_host_hidden_seconds_total", 0.0)
+
+    hidden0 = hidden_total()
     calls = []
 
     def runner(bi, names, codes, lanes=None, width=None):
@@ -176,6 +376,7 @@ def test_pipeline_with_stub_runner_falls_through(tmp_path):
     assert calls == [0, 1, 2, 3]
     assert r.batches == 4 and r.paths_total == 8
     assert r.batch_status == ["ok"] * 4
+    assert hidden_total() == hidden0
 
 
 # --- the background checkpoint writer ---------------------------------

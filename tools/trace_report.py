@@ -13,9 +13,13 @@ Reads either output of the span tracer — the Chrome-trace JSON
   5. a pipeline overlap summary (device/host phase totals, each host
      phase's split into CPU and device reads with its count of
      ``kernel:`` reads — reads that dispatched a program, marked when
-     not 0 — stall time by direction, and how much host-phase time the
-     pipelined campaign hid behind device execution —
-     docs/performance.md),
+     not 0 — stall time by direction, how much host-phase time passed
+     while a ``sym_run`` call was in flight (the overlap of the
+     ``host_phase`` spans with the feeder thread's ``superstep`` spans:
+     what the ``pipeline_occupancy`` gauge counts), and one row a
+     device phase for its lead-in: the ``batch_build`` stages' split,
+     the first call's ``enqueue_s`` and the host phase that ran beside
+     it — docs/performance.md),
   6. a fleet summary (unit leases claimed/committed/reclaimed/lost and
      the reclaim/lost timeline — docs/fleet.md),
   7. solver totals (attempts / sat / unsat / unknown and the unknown
@@ -115,6 +119,64 @@ def _from_jsonl(lines: List[Dict]) -> Tuple[List[Dict], List[Dict]]:
                              "mono": mono,
                              "args": args})
     return spans, instants
+
+
+def _overlap(lo: float, hi: float, intervals) -> float:
+    """Seconds of ``[lo, hi]`` that the union of ``intervals`` covers."""
+    total, at = 0.0, lo
+    for a, b in sorted(intervals):
+        a, b = max(a, at), min(b, hi)
+        if b > a:
+            total += b - a
+            at = b
+    return total
+
+
+def _lead_in_rows(dev: List[Dict], host: List[Dict],
+                  spans: List[Dict]) -> List[str]:
+    """One row a device phase: its lead-in (start to first ``sym_run``
+    call), how much of it the ``batch_build`` stages hold and their
+    split, the first call's enqueue, and the host phase that ran beside
+    the lead-in; under it a line a stage."""
+    builds = [s for s in spans if s["name"] == "batch_build"]
+    calls = sorted((s for s in spans if s["name"] == "superstep"),
+                   key=lambda s: s["mono"])
+    rows: List[str] = []
+    for d in sorted(dev, key=lambda s: s["mono"]):
+        lo, hi = d["mono"], d["mono"] + d["dur"]
+
+        def mine(group):
+            return [s for s in group if s.get("tid") == d.get("tid")
+                    and lo <= s["mono"] < hi]
+
+        first = next(iter(mine(calls)), None)
+        if first is None:
+            continue
+        lead_in = first["mono"] - lo
+        stages = sorted(mine(builds), key=lambda s: s["mono"])
+        held = sum(s["dur"] for s in stages)
+        beside = _overlap(lo, first["mono"], [
+            (h["mono"], h["mono"] + h["dur"]) for h in host])
+        cpu = sum(s["args"].get("cpu_s", 0.0) for s in stages)
+        wait = sum(s["args"].get("device_wait_s", 0.0) for s in stages)
+        proc = sum(s["args"].get("proc_cpu_s", 0.0) for s in stages)
+        enq = first["args"].get("enqueue_s")
+        rows.append(
+            f"{d['args'].get('bi', '?')!s:>6}{_fmt_s(lead_in):>10}"
+            f"{_fmt_s(held):>10}{_fmt_s(cpu):>10}{_fmt_s(wait):>10}"
+            f"{_fmt_s(held - cpu - wait):>10}{_fmt_s(proc):>10}"
+            f"{(proc / held if held else 0.0):>7.2f}"
+            f"{(_fmt_s(enq) if enq is not None else '-'):>10}"
+            f"{_fmt_s(beside):>10}")
+        for s in stages:
+            a = s["args"]
+            c, w = a.get("cpu_s", 0.0), a.get("device_wait_s", 0.0)
+            rows.append(
+                f"{'':>6}{a.get('stage', '?'):>10}{_fmt_s(s['dur']):>10}"
+                f"{_fmt_s(c):>10}{_fmt_s(w):>10}"
+                f"{_fmt_s(s['dur'] - c - w):>10}"
+                f"{_fmt_s(a.get('proc_cpu_s', 0.0)):>10}")
+    return rows
 
 
 def _fmt_s(v: float) -> str:
@@ -324,7 +386,10 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
         out.append("(no checkpoint spans)")
 
     # 5. pipeline overlap: how much host-phase (modules + solver) time
-    # the pipelined campaign hid behind device execution
+    # the pipelined campaign hid behind device execution: the seconds
+    # of the host phases that passed while a ``sym_run`` call of the
+    # thread that feeds the device was in flight (a host phase beside
+    # the next batch's lead-in hides nothing: the device waits)
     dev = [s for s in spans if s["name"] == "device_phase"]
     host = [s for s in spans if s["name"] == "host_phase"]
     stalls = [s for s in spans if s["name"] == "pipeline_stall"]
@@ -339,7 +404,11 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
             by_dir[k] = by_dir.get(k, 0.0) + s["dur"]
         dwh = by_dir.get("device-waits-host", 0.0)
         hwd = by_dir.get("host-waits-device", 0.0)
-        hidden = max(0.0, host_tot - dwh)
+        feeder = {s.get("tid") for s in dev}
+        calls = [(s["mono"], s["mono"] + s["dur"]) for s in spans
+                 if s["name"] == "superstep" and s.get("tid") in feeder]
+        hidden = sum(_overlap(s["mono"], s["mono"] + s["dur"], calls)
+                     for s in host)
         out.append(f"device phases: {len(dev):>4}  total "
                    f"{_fmt_s(dev_tot).strip()}")
         out.append(f"host phases:   {len(host):>4}  total "
@@ -381,6 +450,15 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
                       and s["args"].get("drained"))
         if drained:
             out.append(f"batches drained to the serial path: {drained}")
+        rows = _lead_in_rows(dev, host, spans)
+        if rows:
+            out.append("lead-in of each device phase (start to first "
+                       "sym_run call), then its batch_build stages:")
+            out.append(f"{'bi':>6}{'lead-in':>10}{'in stages':>10}"
+                       f"{'cpu':>10}{'dev reads':>10}{'rest':>10}"
+                       f"{'proc cpu':>10}{'/dur':>7}{'enqueue':>10}"
+                       f"{'host by':>10}")
+            out.extend(rows)
     else:
         out.append("(no pipeline spans — serial run or --no-pipeline)")
 
