@@ -13,7 +13,7 @@ import hashlib
 import logging
 from operator import attrgetter
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -337,6 +337,7 @@ class SymExecWrapper:
         dyn_loader=None,
         dynld_limit: int = 4,
         warm_shapes: Optional[set] = None,
+        on_first_call: Optional[Callable[[], None]] = None,
     ):
         import time as _time
 
@@ -501,6 +502,11 @@ class SymExecWrapper:
         # from here to the first ``sym_run`` call: the plugins, the
         # read of ``base.active``, ``on_tx_start``
         self._lead_in = phase_timer("batch_build", stage="start").start()
+        # fired once, when the first ``sym_run`` call is enqueued: from
+        # there on this thread blocks in reads with the interpreter lock
+        # released (the pipelined campaign starts the previous batch's
+        # host phase here)
+        self._on_first_call = on_first_call
         # (mono start, mono end) of every ``sym_run`` call, by the
         # ``superstep`` timer's own two clock reads: what the campaign
         # measures a host phase's hidden seconds against
@@ -608,6 +614,7 @@ class SymExecWrapper:
                         fork_block=self.fork_block,
                         **(run_kw or {}))
                     enqueue_s = sp.elapsed
+                    self._first_call_enqueued()
                     got = fetch(
                         (vis, sf.steps_total)
                         + tuple(attrgetter(a)(sf) for a in also),
@@ -955,6 +962,16 @@ class SymExecWrapper:
         sp, self._lead_in = self._lead_in, None
         if sp is not None:
             sp.stop()
+
+    def _first_call_enqueued(self) -> None:
+        """Fire ``on_first_call`` once; what it raises stays out of the
+        exploration."""
+        hook, self._on_first_call = self._on_first_call, None
+        if hook is not None:
+            try:
+                hook()
+            except Exception:  # noqa: BLE001 — a hook, not the run
+                log.exception("on_first_call hook failed")
 
     def _count_lost(self, n: int, lanes=None, home=None) -> None:
         """``n`` forks given up at a host seam go into the drop channel

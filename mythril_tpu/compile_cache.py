@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import os
 import threading
-from typing import Dict, Optional
+from typing import Dict
 
 ENV = "JAX_COMPILATION_CACHE_DIR"
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -29,15 +29,10 @@ _enabled = False
 _stats = {"xla_compiles": 0, "xla_compile_sec": 0.0, "cache_hits": 0}
 
 
-def cache_dir(sub: Optional[str] = None) -> str:
+def cache_dir() -> str:
     """Where the persistent cache lives: ``$JAX_COMPILATION_CACHE_DIR``
-    if set, else ``<checkout>/.jax_cache`` (``/<sub>`` for the tests'
-    per-xdist-worker split)."""
-    env = os.environ.get(ENV)
-    if env:
-        return env
-    root = os.path.join(_ROOT, ".jax_cache")
-    return os.path.join(root, sub) if sub else root
+    if set, else ``<checkout>/.jax_cache``."""
+    return os.environ.get(ENV) or os.path.join(_ROOT, ".jax_cache")
 
 
 def _on_duration(event: str, secs: float, **_kw) -> None:
@@ -53,7 +48,7 @@ def _on_event(event: str, **_kw) -> None:
             _stats["cache_hits"] += 1
 
 
-def enable(sub: Optional[str] = None) -> str:
+def enable() -> str:
     """Turn the persistent cache on for this process (first call wins)
     and start counting its compiles. Returns the directory in force."""
     global _enabled
@@ -63,10 +58,10 @@ def enable(sub: Optional[str] = None) -> str:
         import jax
 
         if not os.environ.get(ENV):
-            jax.config.update("jax_compilation_cache_dir", cache_dir(sub))
+            jax.config.update("jax_compilation_cache_dir", cache_dir())
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
         jax.monitoring.register_event_listener(_on_event)
-    return cache_dir(sub)
+    return cache_dir()
 
 
 def stats() -> Dict:

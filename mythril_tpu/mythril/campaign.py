@@ -51,6 +51,7 @@ import contextlib
 import logging
 import os
 import sys
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
@@ -136,6 +137,28 @@ def _split_records(items: Sequence[tuple]):
     creations = [k for _, _, k in recs]
     return ([n for n, _, _ in recs], [c for _, c, _ in recs],
             creations if any(k is not None for k in creations) else None)
+
+
+class _HostPhaseStart:
+    """When one pipelined host phase starts. ``release(after)`` opens
+    it, once, for whichever comes first (``_run_pipelined`` says what
+    can); ``wait()`` blocks the worker until then and returns that
+    first ``after`` (None: the run is going down, do no work)."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._open = threading.Event()
+        self._after: Optional[str] = None
+
+    def release(self, after: Optional[str]) -> None:
+        with self._lock:
+            if not self._open.is_set():
+                self._after = after
+                self._open.set()
+
+    def wait(self) -> Optional[str]:
+        self._open.wait()
+        return self._after
 
 
 @dataclass
@@ -626,7 +649,8 @@ class CorpusCampaign:
                        codes: List[bytes],
                        lanes: Optional[int] = None,
                        width: Optional[int] = None,
-                       creations: Optional[List[Optional[bytes]]] = None):
+                       creations: Optional[List[Optional[bytes]]] = None,
+                       on_first_call=None):
         """DEVICE phase of one batch: pad to the compiled width and run
         the exploration (SymExecWrapper packs the corpus and drives the
         ``sym_run`` chunks — the dispatches are async under JAX; only
@@ -643,7 +667,9 @@ class CorpusCampaign:
         message calls start from the storage it left; the corpus then
         holds ``2 x width`` images, an engine shape class of its own,
         explored as every other batch is (one lane pool, chunks, spill,
-        drain). Returns the finished wrapper for :meth:`_harvest_batch`."""
+        drain). ``on_first_call`` is the wrapper's hook of that name: it
+        fires once, when the first ``sym_run`` call is enqueued. Returns
+        the finished wrapper for :meth:`_harvest_batch`."""
         from ..analysis import SymExecWrapper
 
         width = self.batch_size if width is None else width
@@ -676,6 +702,7 @@ class CorpusCampaign:
             enable_iprof=self.enable_iprof,
             warm_shapes=self._warm_set(lanes, width,
                                        creations is not None),
+            on_first_call=on_first_call,
         )
         # compile counters as of the END of this device phase: device
         # phases never overlap each other, so a batch that compiled no
@@ -1377,11 +1404,15 @@ class CorpusCampaign:
                                  label=f"batch {bi}")
 
     # --- pipelined phases (docs/performance.md) ------------------------
-    def _device_phase(self, bi: int, items: Sequence[tuple]):
+    def _device_phase(self, bi: int, items: Sequence[tuple],
+                      on_first_call=None):
         """Pipelined attempt, first half: fault-injection check + corpus
         packing + exploration, under the watchdog (a hung compile
         surfaces as BatchTimeout instead of stalling BOTH pipeline
         stages). Returns an opaque handle for :meth:`_host_phase_work`.
+        ``on_first_call`` fires when the exploration has enqueued its
+        first ``sym_run`` call; a phase that makes none (below, or one
+        that fails first) never fires it.
         A custom ``batch_runner`` has no device/host seam — the runner
         IS the whole attempt, so its finished result rides the handle
         and the host phase degenerates to a pass-through (same code
@@ -1402,8 +1433,9 @@ class CorpusCampaign:
                     return ("out", self._batch_runner(bi, names, codes))
                 return ("out", self._batch_runner(bi, names, codes,
                                                   lanes=None, width=None))
-            return ("sym", self._explore_batch(bi, names, codes,
-                                               creations=creations))
+            return ("sym", self._explore_batch(
+                bi, names, codes, creations=creations,
+                on_first_call=on_first_call))
 
         return run_with_watchdog(work, self.batch_timeout,
                                  label=f"batch {bi} device")
@@ -1419,14 +1451,38 @@ class CorpusCampaign:
                                  self.batch_timeout,
                                  label=f"batch {bi} host")
 
-    def _host_phase_job(self, bi: int, handle, tctx=None):
-        """Worker-thread entry: run the host phase inside a span and
-        return ``(out, host_dur, done_mono)`` so the commit side can
-        account overlap (hidden host seconds) and worker idle. ``tctx``
+    def _host_phase_job(self, bi: int, handle, tctx,
+                        start: _HostPhaseStart,
+                        idle_since: Optional[float]):
+        """Worker-thread entry: wait for ``start``, then run the
+        host phase inside a span and return ``(out, host_dur,
+        done_mono)`` so the commit side can account overlap (hidden
+        host seconds) and worker idle. The wait is outside the span: it
+        is the worker's idle time since ``idle_since`` (the end of the
+        host phase before), the ``host-waits-device`` stall. A start
+        that was abandoned returns None with no work done. ``tctx``
         re-enters the submitting thread's trace scope (contextvars
         don't cross the pool boundary on their own)."""
+        after = start.wait()
+        if after is None:
+            return None
+        reg = obs_metrics.REGISTRY
         with obs_trace.apply_context(tctx):
-            sp = obs_device.phase_timer("host_phase", bi=bi).start()
+            if idle_since is not None:
+                idle = max(0.0, time.monotonic() - idle_since)
+                obs_trace.complete("pipeline_stall", idle,
+                                   wait="host-waits-device", bi=bi)
+                reg.counter(
+                    "pipeline_host_waits_device_seconds_total",
+                    help="worker idle between host phases").inc(idle)
+            reg.counter(
+                "pipeline_host_phase_starts_total",
+                help="pipelined host phases by what released their "
+                     "start: the next batch's first sym_run call, the "
+                     "end of its device phase, or no next phase",
+                labels={"after": after}).inc()
+            sp = obs_device.phase_timer("host_phase", bi=bi,
+                                        after=after).start()
             try:
                 out = self._host_phase_work(bi, handle)
             finally:
@@ -1727,6 +1783,23 @@ class CorpusCampaign:
         """Depth-1 batch pipeline: batch *i*'s host phase (worker
         thread) overlaps batch *i+1*'s device phase (this thread).
 
+        Batch *i*'s host phase is submitted when its device phase has
+        ended and STARTS when batch *i+1*'s device phase has enqueued
+        its first ``sym_run`` call (``SymExecWrapper``'s
+        ``on_first_call``): until then that phase builds its batch on
+        the interpreter lock the host phase would share, with the
+        device waiting; from then on it blocks in reads with the lock
+        released. Each start is released exactly once
+        (:class:`_HostPhaseStart`), by whichever comes first: that hook
+        (``after="first_call"``); the end of device phase *i+1* however
+        it ends, a handle that made no call included
+        (``"phase_end"``); the loop leaving with no further device
+        phase (``"no_next_phase"``); this function going down with an
+        exception the loop does not drain (the start is abandoned: the
+        worker returns without working and no thread is left waiting).
+        The ``host_phase`` span carries ``after`` and
+        ``pipeline_host_phase_starts_total{after}`` counts them.
+
         Invariants that keep results byte-identical to the serial loop:
 
         - at most ONE host phase is in flight, and ``commit`` runs
@@ -1747,20 +1820,23 @@ class CorpusCampaign:
         with ``wait=device-waits-host`` (this loop blocked on an
         unfinished host phase — the device sat idle) and
         ``wait=host-waits-device`` (the worker sat idle between host
-        phases; the attr is ``wait``, not ``kind`` — ``kind`` is the
-        JSONL schema's reserved record-type field and a colliding span
-        attr is dropped), plus a ``pipeline_occupancy`` gauge = fraction of
+        phases, up to the release of the next one's start; the attr is
+        ``wait``, not ``kind`` — ``kind`` is the JSONL schema's reserved
+        record-type field and a colliding span attr is dropped), plus a
+        ``pipeline_occupancy`` gauge = fraction of
         host-phase seconds hidden behind device execution: those that
         passed while a ``sym_run`` call of the device phase beside the
         host phase was in flight (``SymExecWrapper.sym_run_calls``). A
-        host second spent while that phase was still building its batch
-        is not hidden: the device waited through it. The per-batch
+        host second spent while no call was in flight (between two
+        calls, or after a start released at the phase's end) is not
+        hidden: the device waited through it. The per-batch
         ``batch`` span/wall is ``device_dur + commit_stall`` — the
         batch's contribution to campaign wall-clock — so the trace
         report's batch stall table sums to (about) the campaign wall,
         and a pipelined run's total reads strictly below a serial run's
         whenever any host time was hidden."""
         from concurrent.futures import ThreadPoolExecutor
+        from functools import partial
 
         reg = obs_metrics.REGISTRY
         pool = ThreadPoolExecutor(max_workers=1,
@@ -1858,40 +1934,45 @@ class CorpusCampaign:
                 handle = None
                 first_err: Optional[BaseException] = None
                 try:
-                    handle = self._device_phase(bi, items)
+                    # the PREVIOUS batch's host phase starts at this
+                    # phase's first ``sym_run`` call
+                    handle = self._device_phase(
+                        bi, items, on_first_call=(
+                            None if inflight is None else partial(
+                                inflight["start"].release, "first_call")))
                 except Exception as e:  # noqa: BLE001 — drained below
                     first_err = e
                 dev_dur = dev_sp.stop()
                 # commit the PREVIOUS batch only now: its host phase ran
                 # concurrently with the device phase that just finished
+                # (all of it after that phase, if it made no call)
                 if inflight is not None:
+                    inflight["start"].release("phase_end")
                     commit_inflight(inflight, handle)
                     inflight = None
                 if first_err is not None:
                     drain_serial(bi, items, first_err, dev_dur,
                                  t_wall, t_mono)
                     continue
-                now = time.monotonic()
-                if host_idle_since is not None:
-                    idle = max(0.0, now - host_idle_since)
-                    obs_trace.complete("pipeline_stall", idle,
-                                       wait="host-waits-device", bi=bi)
-                    reg.counter(
-                        "pipeline_host_waits_device_seconds_total",
-                        help="worker idle between host phases").inc(idle)
+                start = _HostPhaseStart()
                 inflight = {"bi": bi, "items": items, "n": len(items),
                             "dev_dur": dev_dur, "t_wall": t_wall,
-                            "mono": t_mono,
+                            "mono": t_mono, "start": start,
                             "future": pool.submit(
                                 self._host_phase_job, bi, handle,
-                                obs_trace.context_snapshot())}
+                                obs_trace.context_snapshot(), start,
+                                host_idle_since)}
             if inflight is not None:
+                inflight["start"].release("no_next_phase")
                 commit_inflight(inflight)
                 inflight = None
         finally:
-            # no blocking wait: on the kill path a future may still be
-            # running its (now-moot) host phase; the worker finishes
-            # harmlessly and the pool reaps it
+            # no blocking wait: on the kill path a host phase that was
+            # running finishes harmlessly, one that was still waiting
+            # for its start is told to do nothing, and the pool reaps
+            # its thread either way
+            if inflight is not None:
+                inflight["start"].release(None)
             pool.shutdown(wait=False)
 
     # --- elastic fleet mode (docs/fleet.md) -----------------------------

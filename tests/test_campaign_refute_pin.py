@@ -62,33 +62,13 @@ def batch_report(kind: str):
             SOLVER_STATS.delta(before))
 
 
-@pytest.fixture(scope="module")
-def no_persistent_cache():
-    """These are the shapes of ``tests/benchmark``, whose drivers
-    install ``benchmark/hostcb_cache.py``: that shim writes executables
-    WITH host callbacks into the compile cache (one directory for all
-    xdist workers), and a process without the shim that loads one
-    segfaults (verify skill, Gotchas). Compile here, read and write
-    nothing."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache
-
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
-
-
 # one engine compile a case (~60 s each on the CPU): the tier-1 gate
 # (`-m 'not slow'`, 12 s under its time limit at PR 35) runs the
 # deploying batch, which holds every refuted shape, three-step
 # sequences and the constructor; `-m slow` adds the other
 @pytest.mark.parametrize("kind", [
     "deployed", pytest.param("wild", marks=pytest.mark.slow)])
-def test_batch_gives_the_parents_issues_and_decides_its_queries(
-        kind, no_persistent_cache):
+def test_batch_gives_the_parents_issues_and_decides_its_queries(kind):
     issues, solver = batch_report(kind)
     with open(os.path.join(GOLDEN, kind + ".json")) as fh:
         assert issues == fh.read()

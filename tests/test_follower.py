@@ -120,6 +120,7 @@ def node():
 class StubCampaign:
     def __init__(self, gate=None):
         self.gate = gate
+        self.entered = threading.Event()
         self.calls = 0
         self.batches = []
 
@@ -127,6 +128,7 @@ class StubCampaign:
         return self.calls > 0
 
     def run_external_batch(self, items, bi=None):
+        self.entered.set()
         if self.gate is not None:
             assert self.gate.wait(30.0), "test gate never released"
         self.calls += 1
@@ -182,10 +184,14 @@ def test_follower_ingests_new_contracts_and_persists_cursor(tmp_path,
         assert health["follower"]["lag"] == 0
         assert health["follower"]["cursor"] == 6
         assert health["tenants"]["follower"]["admitted"] == 1
-        # durable cursor on disk
-        cur = json.load(open(os.path.join(dm.data_dir,
-                                          "follower_cursor.json")))
-        assert cur["block"] == 6
+        # durable cursor on disk (written right after the attribute
+        # moves: a reader that saw `cursor == 6` may be ahead of it)
+        def durable():
+            with open(os.path.join(dm.data_dir,
+                                   "follower_cursor.json")) as fh:
+                return json.load(fh)["block"]
+
+        assert _wait(lambda: durable() == 6), durable()
         # the verdict is in the store: a user asking later gets a
         # dedupe hit — the precomputed-answer story
         assert _wait(lambda: dm.store.count() == 1)
@@ -259,6 +265,9 @@ def test_follower_is_shed_first_under_overload(tmp_path, node):
                                   ("busy2", b"\x01b2")],
                             tenant="fg", priority=5)
         assert _wait(lambda: dm.queue.shed_state == "shedding")
+        # ...which it is from the moment both are queued: read the depth
+        # once the first has left the queue for the gate
+        assert stub.entered.wait(10.0)
         depth_before = dm.queue.depth()
         miss0 = obs_metrics.REGISTRY.counter(
             "serve_shed_total", labels={"reason": "store-miss"}).value

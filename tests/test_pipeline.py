@@ -1,6 +1,7 @@
 """Pipelined campaign (docs/performance.md): batch i's host phase
-overlaps batch i+1's device phase, checkpoints move to a background
-writer — and NONE of it may change results. The contract under test:
+overlaps batch i+1's device phase from that phase's first ``sym_run``
+call on, checkpoints move to a background writer — and NONE of it may
+change results. The contract under test:
 
 - pipelined == serial, byte-for-byte, on issues / paths / iprof /
   quarantine / batch_status (the acceptance bar for the overlap layer);
@@ -11,6 +12,7 @@ writer — and NONE of it may change results. The contract under test:
 """
 
 import os
+import threading
 
 import pytest
 
@@ -182,21 +184,77 @@ def _hidden_by_the_spans(recs):
     return hidden, sum(h["dur"] for h in hosts)
 
 
-@pytest.fixture(scope="module")
-def lead_in_runs(tmp_path_factory):
-    """Three runs of one two-batch pipelined campaign: untraced, traced,
-    and traced with a host phase slowed until it reaches into the next
-    batch's first ``sym_run`` call. For each its signature, its records
-    and the registry's pipeline series over it."""
-    import time
+AFTER = ("first_call", "phase_end", "no_next_phase")
 
+
+def _delta(before, after, key):
+    return (after["counters"].get(key, 0.0)
+            - before["counters"].get(key, 0.0))
+
+
+def _starts(before, after):
+    """``pipeline_host_phase_starts_total{after}`` over a run, without
+    the labels that stayed at 0."""
+    got = {k: _delta(before, after,
+                     f'pipeline_host_phase_starts_total{{after="{k}"}}')
+           for k in AFTER}
+    return {k: int(v) for k, v in got.items() if v}
+
+
+def _first_call(recs, dev):
+    """The first ``superstep`` span of ``dev``'s thread inside it."""
+    return next(c for c in _named(recs, "superstep")
+                if c["tid"] == dev["tid"]
+                and dev["mono"] <= c["mono"] <= dev["mono"] + dev["dur"])
+
+
+def _traced_run(camp, traced=True):
+    """``camp.run()`` under a buffering tracer (``traced=False``: under
+    none, and the buffer holds whatever a span emitted late): its result
+    (or the kill it raised), its records and the registry before and
+    after."""
     from mythril_tpu.obs import metrics as obs_metrics
     from mythril_tpu.obs import trace as obs_trace
+
+    obs_trace.close()
+    tracer = obs_trace.configure(buffer=True) if traced else None
+    before = obs_metrics.REGISTRY.snapshot()
+    try:
+        res = camp.run()
+    except InjectedKill as e:
+        res = e
+    finally:
+        after = obs_metrics.REGISTRY.snapshot()
+        if tracer is None:
+            tracer = obs_trace.configure(buffer=True)
+        recs = tracer.drain_buffer()
+        obs_trace.close()
+    return res, recs, before, after
+
+
+@pytest.fixture(scope="module")
+def lead_in_runs(tmp_path_factory):
+    """Four runs of one two-batch pipelined campaign: untraced, traced,
+    traced with a host phase slowed until it outlives the next batch's
+    device phase, and traced with every ``sym_run`` call's read slowed
+    (the lock released, as while the chip runs the call: on the CPU a
+    call of this size may run inside its enqueue). For each its signature,
+    its records and the registry's pipeline series over it."""
+    import time
+
+    from mythril_tpu.analysis import symbolic
+
+    fetch = symbolic.fetch
+
+    def slow_fetch(x, what):
+        if what.startswith("visited"):
+            time.sleep(0.3)
+        return fetch(x, what)
 
     corpus = write_corpus(tmp_path_factory.mktemp("lead_in"))
     make_campaign(corpus, pipeline=True).run()      # compiles, if cold
     runs = {}
-    for case in ("untraced", "traced", "slow_host"):
+    for case in ("untraced", "traced", "slow_host", "slow_call"):
         camp = make_campaign(corpus, pipeline=True)
         if case == "slow_host":
             harvest = camp._harvest_batch
@@ -206,26 +264,19 @@ def lead_in_runs(tmp_path_factory):
                 return harvest(bi, sym)
 
             camp._harvest_batch = slow
-        obs_trace.close()
-        tracer = (None if case == "untraced"
-                  else obs_trace.configure(buffer=True))
-        before = obs_metrics.REGISTRY.snapshot()
         try:
-            res = camp.run()
+            if case == "slow_call":
+                symbolic.fetch = slow_fetch
+            res, recs, before, after = _traced_run(
+                camp, traced=case != "untraced")
         finally:
-            after = obs_metrics.REGISTRY.snapshot()
-            if tracer is None:
-                # whatever a span emitted late would land here
-                tracer = obs_trace.configure(buffer=True)
-            recs = tracer.drain_buffer()
-            obs_trace.close()
+            symbolic.fetch = fetch
         runs[case] = {
             "sig": _sig(res), "recs": recs,
             "occupancy": after["gauges"]["pipeline_occupancy"],
-            "hidden": (
-                after["counters"]["pipeline_host_hidden_seconds_total"]
-                - before["counters"].get(
-                    "pipeline_host_hidden_seconds_total", 0.0))}
+            "hidden": _delta(before, after,
+                             "pipeline_host_hidden_seconds_total"),
+            "starts": _starts(before, after)}
     return runs
 
 
@@ -286,13 +337,46 @@ def test_tracing_off_leaves_no_record_and_the_same_results(lead_in_runs):
     assert 0.0 <= lead_in_runs["untraced"]["occupancy"] <= 1.0
 
 
-@pytest.mark.parametrize("case", ["traced", "slow_host"])
+@pytest.mark.parametrize("case", ["untraced", "traced", "slow_host",
+                                  "slow_call"])
+def test_host_phase_starts_at_the_next_batchs_first_call(lead_in_runs,
+                                                         case):
+    """Batch k's host phase starts once batch k+1's device phase has
+    enqueued its first ``sym_run`` call, not beside its lead-in; the
+    window's last starts at once. The span and the counter say what
+    released each start, and the worker's idle time runs up to it."""
+    run = lead_in_runs[case]
+    assert run["starts"] == {"first_call": 1, "no_next_phase": 1}
+    assert run["sig"] == lead_in_runs["traced"]["sig"]
+    if case == "untraced":
+        return
+    recs = run["recs"]
+    devs, hosts = _named(recs, "device_phase"), _named(recs, "host_phase")
+    assert [h["bi"] for h in hosts] == [d["bi"] for d in devs] == [0, 1]
+    assert [h["after"] for h in hosts] == ["first_call", "no_next_phase"]
+    first = _first_call(recs, devs[1])
+    # after the enqueue (the span's ``enqueue_s`` is rounded), so beside
+    # no stage of the lead-in
+    assert hosts[0]["mono"] >= first["mono"] + first["enqueue_s"] - 1e-5
+    assert all(b["mono"] + b["dur"] <= hosts[0]["mono"]
+               for b in _named(recs, "batch_build"))
+    assert hosts[1]["mono"] >= devs[1]["mono"] + devs[1]["dur"]
+    # the worker idle between the two host phases, up to the release
+    (idle,) = [s for s in _named(recs, "pipeline_stall")
+               if s["wait"] == "host-waits-device"]
+    assert idle["bi"] == 1 and idle["tid"] == hosts[1]["tid"]
+    assert abs(idle["mono"] - (hosts[0]["mono"] + hosts[0]["dur"])) <= 0.02
+    assert idle["mono"] + idle["dur"] <= hosts[1]["mono"] + 1e-5
+
+
+@pytest.mark.parametrize("case", ["traced", "slow_host", "slow_call"])
 def test_occupancy_is_the_overlap_with_sym_run_calls(lead_in_runs, case):
     """``hidden`` means "while a ``sym_run`` call was in flight": the
     gauge, the counter and the ``batch`` spans' ``hidden`` agree with
     the overlap of the run's own ``host_phase`` and ``superstep``
-    spans. A host phase that ends inside the next batch's lead-in hid
-    nothing, however short the stall it caused."""
+    spans. A host phase starts when the next batch's first call is
+    enqueued, so it hides what that call still has to run: nothing of
+    its enqueue, and on the CPU a call of this size may be all enqueue."""
     run = lead_in_runs[case]
     hidden, host = _hidden_by_the_spans(run["recs"])
     assert host > 0.0
@@ -307,19 +391,27 @@ def test_occupancy_is_the_overlap_with_sym_run_calls(lead_in_runs, case):
                for b in batches)
     # the window's last host phase has no device phase beside it
     assert batches[-1]["hidden"] == 0.0
+    first = _named(run["recs"], "superstep")[-1]
+    assert hidden <= first["dur"] - first["enqueue_s"] + 1e-4
+    if case == "slow_call":
+        # the call's read waits with the lock released, as on the chip:
+        # the whole host phase fits under it
+        assert batches[0]["hidden"] >= 0.9 * batches[0]["host_dur"] > 0.0
+        assert batches[0]["stall"] <= 0.05
     if case == "slow_host":
-        # it reached past the lead-in, over the whole first call
-        first = _named(run["recs"], "superstep")[-1]
-        assert hidden >= 0.9 * first["dur"] > 0.0
-        assert run["sig"] == lead_in_runs["traced"]["sig"]
+        # it started inside the call and outlived the device phase: the
+        # loop waited for it
+        assert batches[0]["stall"] >= 0.3
+    assert run["sig"] == lead_in_runs["traced"]["sig"]
 
 
-@pytest.mark.parametrize("case", ["traced", "slow_host"])
+@pytest.mark.parametrize("case", ["traced", "slow_host", "slow_call"])
 def test_trace_report_reads_hidden_and_the_lead_in_off_the_spans(
         lead_in_runs, case, tmp_path):
     """The operator's reading: the report's hidden seconds are the
-    gauge's (not host work less the stalls), and every device phase has
-    a row for its lead-in with a line a ``batch_build`` stage."""
+    gauge's (not host work less the stalls), beside them what released
+    the host phases' starts, and every device phase has a row for its
+    lead-in with a line a ``batch_build`` stage."""
     import importlib.util
     import json
 
@@ -338,16 +430,124 @@ def test_trace_report_reads_hidden_and_the_lead_in_off_the_spans(
     assert line == ("host time hidden behind device execution: "
                     f"{tr._fmt_s(hidden).strip()} "
                     f"({100.0 * hidden / host:.0f}% of host work)")
-    assert (hidden > 0.0) == (case == "slow_host")
+    assert text.splitlines()[text.splitlines().index(line) + 1] == (
+        "host phases started after: first_call 1, phase_end 0, "
+        "no_next_phase 1")
+    if case == "slow_call":
+        assert hidden > 0.25 * host
     at = text.splitlines().index(
         "lead-in of each device phase (start to first sym_run call), "
         "then its batch_build stages:")
     rows = text.splitlines()[at + 2:at + 2 + 2 * (1 + len(STAGES))]
     assert [r.split()[0] for r in rows] == ["0", *STAGES, "1", *STAGES]
-    # batch 1's lead-in had batch 0's host phase beside it, batch 0's
-    # nobody: the row's last column
+    # no lead-in has a host phase beside it, batch 1's either (batch
+    # 0's host phase waits for batch 1's first call): the last column
     assert rows[0].split()[-1] == "0.00ms"
-    assert rows[1 + len(STAGES)].split()[-1] != "0.00ms"
+    assert rows[1 + len(STAGES)].split()[-1] == "0.00ms"
+
+
+def test_device_phase_that_fails_before_its_first_call_releases_at_its_end(
+        tmp_path):
+    """Batch 1's device phase raises before any ``sym_run`` call (the
+    injector fires first): batch 0's host phase starts when that phase
+    has ended, commits before batch 1 drains, and the run is the serial
+    loop's."""
+    corpus = write_corpus(tmp_path)
+    fault = "raise:batch=1:times=1"
+    serial = make_campaign(corpus, fault=fault, pipeline=False).run()
+    piped, recs, before, after = _traced_run(
+        make_campaign(corpus, fault=fault, pipeline=True))
+    assert _sig(piped) == _sig(serial)
+    assert piped.batch_status == ["ok", "ok-retry"]
+    assert _starts(before, after) == {"phase_end": 1}
+    # batch 1 drained to the serial loop, whose spans have no ``after``
+    (host,) = [h for h in _named(recs, "host_phase") if "after" in h]
+    failed = _named(recs, "device_phase")[1]
+    assert host["bi"] == 0 and host["after"] == "phase_end"
+    assert host["mono"] >= failed["mono"] + failed["dur"]
+    assert not [c for c in _named(recs, "superstep")
+                if failed["mono"] <= c["mono"] <= failed["mono"]
+                + failed["dur"]]
+
+
+def test_stub_runner_handles_release_at_the_phase_end(tmp_path):
+    """An ``"out"`` handle made no ``sym_run`` call: the host phase
+    before it (a pass-through) starts when the runner has returned, the
+    last at once, and the run is the serial loop's."""
+    def runner(bi, names, codes, lanes=None, width=None):
+        return {"issues": [], "paths": len(names), "dropped": 0,
+                "iprof": {}}
+
+    def camp(pipeline):
+        return CorpusCampaign(
+            [(f"c{i:03d}", b"\x00") for i in range(8)], batch_size=2,
+            batch_runner=runner, pipeline=pipeline, fault_injector=None)
+
+    serial = camp(False).run()
+    piped, recs, before, after = _traced_run(camp(True))
+    assert _sig(piped) == _sig(serial)
+    assert _starts(before, after) == {"phase_end": 3, "no_next_phase": 1}
+    hosts, devs = _named(recs, "host_phase"), _named(recs, "device_phase")
+    assert [h["after"] for h in hosts] == ["phase_end"] * 3 + [
+        "no_next_phase"]
+    for h, nxt in zip(hosts, devs[1:]):
+        assert h["mono"] >= nxt["mono"] + nxt["dur"]
+
+
+def test_kill_leaves_no_host_phase_thread_waiting(tmp_path):
+    """An ``InjectedKill`` in batch 1's device phase blows through while
+    batch 0's host phase still waits for its start: the start is given
+    up, the worker does no work and ends (the interpreter joins pool
+    threads at exit: one blocked on an event would hang it)."""
+    corpus = write_corpus(tmp_path)
+    err, recs, before, after = _traced_run(
+        make_campaign(corpus, fault="kill:batch=1", pipeline=True))
+    assert isinstance(err, InjectedKill)
+    workers = [t for t in threading.enumerate()
+               if t.name.startswith("host-phase")]
+    for t in workers:
+        t.join(timeout=20.0)
+    assert not [t.name for t in workers if t.is_alive()]
+    assert _starts(before, after) == {}
+    assert not _named(recs, "host_phase")
+    assert [d["bi"] for d in _named(recs, "device_phase")] == [0]
+
+
+def test_a_start_is_released_once_whoever_races_for_it():
+    """The first ``release`` wins and every waiter reads that one, with
+    more racing threads than cores and the interpreter switching between
+    them as often as it can."""
+    import sys
+
+    from mythril_tpu.mythril.campaign import _HostPhaseStart
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for _ in range(50):
+            start = _HostPhaseStart()
+            seen, won = [], []
+
+            def race(after, start=start, won=won):
+                start.release(after)
+                won.append(start.wait())
+
+            threads = [threading.Thread(
+                target=lambda: seen.append(start.wait()))
+                for _ in range(4)] + [
+                threading.Thread(target=race, args=(after,))
+                for after in AFTER * 4]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=20.0)
+            assert not any(t.is_alive() for t in threads)
+            assert len(set(seen + won)) == 1 and seen[0] in AFTER
+            assert len(seen) == 4 and len(won) == 12
+            start.release(None)             # too late to give it up
+            assert start.wait() == seen[0]
+    finally:
+        sys.setswitchinterval(was)
 
 
 def test_pipeline_with_stub_runner_falls_through(tmp_path):

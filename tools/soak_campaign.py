@@ -342,9 +342,11 @@ def main() -> int:
             # leg 7: pipelined campaign + background checkpoint writer
             # under kill + torn-write. batch_size=2 -> 3 batches; the
             # kill fires in batch 2's DEVICE phase, i.e. while batch 1's
-            # host phase and the background write of batch 0's durable
-            # state are in flight — exactly the window the pipeline
-            # opened. The newest checkpoint is then truncated mid-file
+            # host phase waits for its start (batch 2's first sym_run
+            # call: the kill gives it up) and the background write of
+            # batch 0's durable state is in flight — exactly the window
+            # the pipeline opened. The newest checkpoint is then
+            # truncated mid-file
             # (a kill -9 landing during the background write itself);
             # the resume must see the tear, start from the last durable
             # point (here: nothing — first-ever write torn), replay,

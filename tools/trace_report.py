@@ -16,7 +16,9 @@ Reads either output of the span tracer — the Chrome-trace JSON
      not 0 — stall time by direction, how much host-phase time passed
      while a ``sym_run`` call was in flight (the overlap of the
      ``host_phase`` spans with the feeder thread's ``superstep`` spans:
-     what the ``pipeline_occupancy`` gauge counts), and one row a
+     what the ``pipeline_occupancy`` gauge counts), what released the
+     host phases' starts (``after``: the next batch's first call, the
+     end of its device phase, no next phase), and one row a
      device phase for its lead-in: the ``batch_build`` stages' split,
      the first call's ``enqueue_s`` and the host phase that ran beside
      it — docs/performance.md),
@@ -388,8 +390,9 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
     # 5. pipeline overlap: how much host-phase (modules + solver) time
     # the pipelined campaign hid behind device execution: the seconds
     # of the host phases that passed while a ``sym_run`` call of the
-    # thread that feeds the device was in flight (a host phase beside
-    # the next batch's lead-in hides nothing: the device waits)
+    # thread that feeds the device was in flight (a host phase starts
+    # when the next batch's first call is enqueued; one that started
+    # later, or outlived the calls, hides less: the device waits)
     dev = [s for s in spans if s["name"] == "device_phase"]
     host = [s for s in spans if s["name"] == "host_phase"]
     stalls = [s for s in spans if s["name"] == "pipeline_stall"]
@@ -446,6 +449,13 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
             out.append(f"host time hidden behind device execution: "
                        f"{_fmt_s(hidden).strip()} "
                        f"({100.0 * hidden / host_tot:.0f}% of host work)")
+            # what released each host phase's start: the next batch's
+            # first sym_run call (the one that can hide it), the end of
+            # its device phase (it made no call), or no next phase
+            started = [s["args"].get("after") for s in host]
+            out.append("host phases started after: " + ", ".join(
+                f"{k} {started.count(k)}"
+                for k in ("first_call", "phase_end", "no_next_phase")))
         drained = sum(1 for s in spans if s["name"] == "batch"
                       and s["args"].get("drained"))
         if drained:
