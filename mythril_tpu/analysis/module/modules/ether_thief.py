@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from typing import List
 
+from ....core.frontier import ATTACKER_ADDRESS
 from ....symbolic.ops import SymOp
 from ....smt.tape import HostNode, attacker_controlled
 from ...report import Issue
@@ -44,11 +45,22 @@ class EtherThief(DetectionModule):
                 if ev.value_sym:
                     # value must be able to exceed what the attacker paid in:
                     # nonzero is the v1 proxy (the reference compares against
-                    # the attacker's net balance delta)
+                    # the attacker's net balance delta). The witness asked
+                    # for first pays the sender itself, as upstream's does
+                    # (it constrains the target to the attacker ⚠unv): a
+                    # target the caller passes as an argument is otherwise
+                    # whatever the search left there
+                    n = len(tape.nodes)
                     nz = HostNode(int(SymOp.ISZERO), ev.value_sym, 0, 0)
+                    me = HostNode(int(SymOp.CONST), 0, 0, ATTACKER_ADDRESS)
+                    to_me = HostNode(int(SymOp.EQ), ev.to_sym, n + 1, 0)
                     asn = ctx.solve(
                         lane,
-                        extra_constraints=[(len(tape.nodes), False)],
+                        extra_constraints=[(n, False), (n + 2, True)],
+                        extra_nodes=[nz, me, to_me],
+                    ) or ctx.solve(
+                        lane,
+                        extra_constraints=[(n, False)],
                         extra_nodes=[nz],
                     )
                 elif ev.value > 0:

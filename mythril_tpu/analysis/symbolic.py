@@ -21,6 +21,7 @@ from ..config import DEFAULT_LIMITS, LimitsConfig
 from ..core import Corpus, make_env
 from ..core.frontier import ATTACKER_ADDRESS, CAP_TRAPS, TRAP_NAMES
 from ..disassembler import ContractImage
+from ..disassembler.opcodes import STACK_IN, STACK_OUT
 from ..obs import metrics as obs_metrics
 from ..obs.device import HostLeaves, fetch, phase_timer, tally
 from ..obs import trace as obs_trace
@@ -28,6 +29,8 @@ from ..smt.eval import Assignment
 from ..smt.solver import solve_tape
 from ..smt.tape import HostNode, HostTape, extract_tape, intern_node
 from ..symbolic import SymSpec, between_txs, make_sym_frontier, sym_run
+from ..ops.keccak import keccak256_host_int
+from ..symbolic.state import MEM_EXACT
 from ..symbolic.engine import (SEAM_STORAGE, hold_carried,
                                plan_seam_admission, plan_waiting,
                                pool_stuck, rebalance_parked,
@@ -35,15 +38,23 @@ from ..symbolic.engine import (SEAM_STORAGE, hold_carried,
 
 log = logging.getLogger(__name__)
 
+#: what ends a straight-line block: JUMPDEST, JUMP, JUMPI and the halts
+_BLOCK_ENDS = (0x5B, 0x56, 0x57, 0x00, 0xF3, 0xFD, 0xFE, 0xFF)
 
-def guard_slots(image: ContractImage, pc: int) -> frozenset:
-    """The fixed storage slots of the guard that a path failed at.
-    ``pc`` is where the path ended (a ``REVERT`` or ``INVALID``, or just
-    past it); the guard is the ``JUMPI`` it fell through, as solc lays a
-    ``require`` out (the branch, then the revert with its reason), and
-    its slots are the ``PUSH k; SLOAD`` pairs of the block that ends in
-    that ``JUMPI``. Empty where the code has another shape: such a path
-    tested no slot that the seam's admission step can name."""
+
+def guard_slots(image: ContractImage, pc: int,
+                caller: Optional[int] = None) -> frozenset:
+    """The storage slots of the guard that a path failed at, as far as
+    the code names them. ``pc`` is where the path ended (a ``REVERT`` or
+    ``INVALID``, or just past it); the guard is the ``JUMPI`` it fell
+    through, as solc lays a ``require`` out (the branch, then the revert
+    with its reason), and its slots are the ``PUSH k; SLOAD`` pairs of
+    the block that ends in that ``JUMPI``, and, where the sender's
+    address is concrete (``caller``), the ``mapping[msg.sender]`` reads
+    there: ``CALLER .. MSTORE; PUSH k .. MSTORE; .. SHA3; SLOAD`` is the
+    slot ``keccak(caller . k)`` (:func:`_hashed_slots`). Empty where the
+    code has another shape: such a path tested no slot that the seam's
+    admission step can name."""
     code = image.code[:image.code_len].tobytes()
     starts = np.flatnonzero(image.is_code[:image.code_len])
     at = int(np.searchsorted(starts, pc, side="right")) - 1
@@ -60,12 +71,73 @@ def guard_slots(image: ContractImage, pc: int) -> frozenset:
     slots = set()
     for j in range(at - 1, 0, -1):
         op, before = code[starts[j]], code[starts[j - 1]]
-        if op in (0x5B, 0x56, 0x57, 0x00, 0xF3, 0xFD, 0xFE, 0xFF):
+        if op in _BLOCK_ENDS:
             break
         if op == 0x54 and 0x5F <= before <= 0x7F:
             slots.add(int.from_bytes(
                 code[starts[j - 1] + 1:starts[j]], "big"))
+    if caller is not None:
+        first = at
+        while first > 0 and code[starts[first - 1]] not in _BLOCK_ENDS:
+            first -= 1
+        slots |= _hashed_slots(code, starts[first:at + 1], caller)
     return frozenset(slots)
+
+
+def _hashed_slots(code: bytes, starts, caller: int) -> set:
+    """The ``SLOAD`` keys of one straight-line block that are a hash of
+    constants and the sender: the block run over a stack and a memory of
+    known words (``PUSH``, ``CALLER``, ``DUP``, ``SWAP``, ``AND``,
+    ``MSTORE``, ``SHA3``; what any other instruction leaves is
+    unknown). ``starts``: its instructions, and the one that ends it."""
+    stack: list = []
+    mem: dict = {}
+    hashed, slots = set(), set()
+
+    def pop():
+        return stack.pop() if stack else None
+
+    for pos, end in zip(starts[:-1], starts[1:]):
+        op = code[pos]
+        if 0x5F <= op <= 0x7F:
+            stack.append(int.from_bytes(code[pos + 1:end], "big"))
+        elif op == 0x33:
+            stack.append(caller)
+        elif 0x80 <= op <= 0x9F:
+            n = op - 0x7F if op < 0x90 else op - 0x8E
+            stack[:0] = [None] * (n - len(stack))
+            if op < 0x90:
+                stack.append(stack[-n])
+            else:
+                stack[-1], stack[-n] = stack[-n], stack[-1]
+        elif op == 0x52:
+            off, word = pop(), pop()
+            if off is None:
+                mem.clear()
+            else:
+                mem[off] = word
+        elif op == 0x16:
+            a, b = pop(), pop()
+            stack.append(None if a is None or b is None else a & b)
+        elif op == 0x20:
+            off, size = pop(), pop()
+            words = ([mem.get(off + k) for k in range(0, size, 32)]
+                     if off is not None and size in (32, 64) else [None])
+            if None in words:
+                stack.append(None)
+            else:
+                stack.append(keccak256_host_int(b"".join(
+                    w.to_bytes(32, "big") for w in words)))
+                hashed.add(stack[-1])
+        elif op == 0x54:
+            key = pop()
+            if key in hashed:
+                slots.add(key)
+            stack.append(None)
+        else:
+            del stack[len(stack) - min(len(stack), int(STACK_IN[op])):]
+            stack.extend([None] * int(STACK_OUT[op]))
+    return slots
 
 
 @dataclass
@@ -838,17 +910,22 @@ class SymExecWrapper:
                 # and per contract, with what the seam's admission
                 # step needs of the frontier as the transaction left it
                 (err_h, act_h, bad_h, dropped_h, rev_h, home_h,
-                 forks_h) = fetch(
+                 forks_h, floor_h, cd_h) = fetch(
                     (sf.base.err_code, sf.base.active, sf.base.error,
                      sf.dropped_total, sf.base.reverted,
-                     sf.base.home_contract, sf.dropped_forks),
+                     sf.base.home_contract, sf.dropped_forks,
+                     sf.mem_floor, sf.cd_reads),
                     "base.err_code,base.active,base.error,dropped_total,"
-                    "base.reverted,base.home_contract,dropped_forks")
+                    "base.reverted,base.home_contract,dropped_forks,"
+                    "mem_floor,cd_reads")
                 trap_counts = _count_traps(err_h)
                 paths, lost = self._count_tx(
                     int((act_h & ~bad_h).sum()), int(dropped_h))
                 of = home_h % C     # creation | runtime image
+                kept = act_h & ~bad_h & ~rev_h
                 harvest.attrs.update(
+                    **self._count_dynamic(floor_h[kept], cd_h[kept],
+                                          trap_counts.get("loop_bound", 0)),
                     paths=paths, dropped=lost,
                     paths_by_contract=np.bincount(
                         of[act_h & ~bad_h], minlength=C).tolist(),
@@ -1007,7 +1084,10 @@ class SymExecWrapper:
             C, passed, home, ended_active, failed, self._started,
             lambda: fetch(tuple(attrgetter(a)(ended) for a in SEAM_STORAGE),
                           ",".join(SEAM_STORAGE)),
-            lambda image, pc: guard_slots(self.images[image], pc))
+            lambda image, pc: guard_slots(
+                self.images[image], pc,
+                None if self.spec.caller else ATTACKER_ADDRESS),
+            concrete_storage=not self.spec.storage)
         merged = dropped = wait = np.zeros_like(passed)
         if plan is not None:
             merged, dropped = plan["merged"], plan["dropped"]
@@ -1083,6 +1163,43 @@ class SymExecWrapper:
                     help="forks a transaction lost to the lane budget",
                     labels=labels).inc(lost)
         return paths, lost
+
+    def _count_dynamic(self, floor, cd_reads, loop_trapped: int) -> dict:
+        """What decoding dynamic arguments did to the paths that ended
+        one transaction without error or revert (``floor`` and
+        ``cd_reads`` are theirs; where a reverted path's memory ended
+        says nothing, and a reason string's stores lower its floor):
+        the state their memory ended in (``engine_paths_memory_total{tx,state}``:
+        ``exact``, nothing invalidated; ``floored``, from a word above
+        the scratch words and the free pointer, words 0-2; ``havoc``,
+        from one of those down), their calldata reads at a symbolic
+        offset by what answered them
+        (``engine_calldata_symreads_total{how}``), and the lanes the
+        loop bound retired (``engine_loop_bound_traps_total{tx}``).
+        Returns the ``harvest`` span's share of it."""
+        reg = obs_metrics.REGISTRY
+        tx = str(self._cur_tx)
+        states = {"exact": int((floor == MEM_EXACT).sum()),
+                  "havoc": int((floor <= 2).sum())}
+        states["floored"] = len(floor) - states["exact"] - states["havoc"]
+        for state, n in states.items():
+            reg.counter("engine_paths_memory_total",
+                        help="paths at the end of a transaction, by how "
+                             "much of their memory was still exact",
+                        labels={"tx": tx, "state": state}).inc(n)
+        reads = cd_reads.sum(axis=0)
+        for how, n in zip(("select", "havoc"), reads):
+            reg.counter("engine_calldata_symreads_total",
+                        help="CALLDATALOADs at a symbolic offset on the "
+                             "paths that ended a transaction, by what "
+                             "answered them",
+                        labels={"how": how}).inc(int(n))
+        reg.counter("engine_loop_bound_traps_total",
+                    help="lanes the loop bound retired in a transaction",
+                    labels={"tx": tx}).inc(loop_trapped)
+        return dict(mem_floored_paths=states["floored"],
+                    mem_havoc_paths=states["havoc"],
+                    cd_selects=int(reads[0]), loop_trapped=loop_trapped)
 
     def _dynld_between_txs(self, sf, names):
         """Fetch code for this tx's concrete-but-unknown call targets.

@@ -32,6 +32,7 @@ enum {
     OP_ISZERO = 17, OP_AND = 18, OP_OR = 19, OP_XOR = 20, OP_NOT = 21,
     OP_BYTE = 22, OP_SHL = 23, OP_SHR = 24, OP_SAR = 25,
     OP_KECCAK_SEED = 26, OP_KECCAK_ABS = 27, OP_KECCAK = 28,
+    OP_CD_SELECT = 29,
 };
 
 typedef struct { uint64_t w[4]; } u256; /* w[0] = least significant */
@@ -256,6 +257,8 @@ int tape_eval(int n, const int32_t *op, const int32_t *a, const int32_t *b,
         switch (o) {
         case OP_NULL:
         case OP_FREE: /* pre-seeded by the caller; a/b are (kind, index) */
+        case OP_CD_SELECT: /* the caller reads calldata at vals[a] between
+                            * passes (smt/eval.py _evaluate_native) */
             continue;
         case OP_CONST:
             memcpy(vals + (size_t)i * 32, imm + (size_t)i * 32, 32);

@@ -155,6 +155,8 @@ def _node_hashes(tape: HostTape, reach: List[int],
             # b == 0 means the absorbed word is the concrete imm
             w = ch(nd.b) if nd.b else _h("c", nd.imm & M256)
             hs[i] = _h("ka", ch(nd.a), w)
+        elif op == int(SymOp.CD_SELECT):
+            hs[i] = _h(op, ch(nd.a), nd.imm)    # imm: the transaction
         elif op in _UNARY:
             hs[i] = _h(op, ch(nd.a))
         elif op in _COMMUTATIVE:

@@ -192,6 +192,7 @@ def _evaluate_native(tape, asn: Assignment, lib) -> Optional[List[int]]:
     import ctypes
 
     n, op, a, b, imm, leaves = _packed_tape(tape)
+    selects = [i for i in range(n) if op[i] == int(SymOp.CD_SELECT)]
     vals = bytearray(n * 32)
     for i in leaves:
         nd = tape.nodes[i]
@@ -199,12 +200,29 @@ def _evaluate_native(tape, asn: Assignment, lib) -> Optional[List[int]]:
         if v:
             vals[i * 32:(i + 1) * 32] = v.to_bytes(32, "big")
     buf = (ctypes.c_uint8 * len(vals)).from_buffer(vals)
-    rc = lib.tape_eval(n, op, a, b, imm,
-                       ctypes.cast(buf, ctypes.POINTER(ctypes.c_uint8)))
-    if rc != 0:
-        return None
+    ptr = ctypes.cast(buf, ctypes.POINTER(ctypes.c_uint8))
     mv = memoryview(vals)
-    return [int.from_bytes(mv[i * 32:(i + 1) * 32], "big") for i in range(n)]
+
+    def word(i):
+        return int.from_bytes(mv[i * 32:(i + 1) * 32], "big")
+
+    # the C evaluator takes a select's value as given, like a leaf's: a
+    # pass evaluates the offsets, the selects are read at them, and the
+    # next pass sees their words. One more pass for each select whose
+    # offset hangs on another select (an array inside an array)
+    for _ in range(len(selects) + 1):
+        if lib.tape_eval(n, op, a, b, imm, ptr) != 0:
+            return None
+        moved = False
+        for i in selects:
+            nd = tape.nodes[i]
+            v = asn.tx(nd.imm).read_word(word(nd.a))
+            if v != word(i):
+                vals[i * 32:(i + 1) * 32] = v.to_bytes(32, "big")
+                moved = True
+        if not moved:
+            break
+    return [word(i) for i in range(n)]
 
 
 def evaluate(tape, asn: Assignment) -> List[int]:
@@ -257,6 +275,9 @@ def _evaluate_py(tape, asn: Assignment) -> List[int]:
             vals[i] = keccak256_host_int(data[r : r + ln])
             continue
 
+        if op == int(SymOp.CD_SELECT):
+            vals[i] = asn.tx(nd.imm).read_word(vals[nd.a])
+            continue
         a = vals[nd.a]
         b = vals[nd.b]
         if op == int(SymOp.ADD):

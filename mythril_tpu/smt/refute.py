@@ -135,7 +135,9 @@ def _free_reach(tape: HostTape):
     for i, nd in enumerate(tape.nodes):
         if i == 0 or nd.op == int(SymOp.NULL):
             continue
-        if nd.op == int(SymOp.FREE):
+        if nd.op in (int(SymOp.FREE), int(SymOp.CD_SELECT)):
+            # a select is free whatever its offset is, and opaque: no
+            # rule reduces through it, so no fact is derived about it
             hf[i] = True
         elif nd.op != int(SymOp.CONST):
             hf[i] = (nd.a and nd.a < i and hf[nd.a]) or \

@@ -149,11 +149,12 @@ class _Inverter:
         for i, nd in enumerate(tape.nodes):
             if i == 0 or nd.op == int(SymOp.NULL):
                 continue
-            if nd.op == int(SymOp.FREE):
-                hf[i] = True
+            if nd.op in (int(SymOp.FREE), int(SymOp.CD_SELECT)):
+                hf[i] = True    # a select reads bytes the search chooses
             elif nd.op not in (int(SymOp.CONST),):
                 hf[i] = (nd.a and nd.a < i and hf[nd.a]) or (nd.b and nd.b < i and hf[nd.b])
         self._has_free = hf
+        self._heads: Dict[int, List[int]] = {}  # _head's, by transaction
 
     def has_free(self, i: int) -> bool:
         return bool(self._has_free[i]) if 0 <= i < len(self._has_free) else False
@@ -165,6 +166,8 @@ class _Inverter:
         op = nd.op
         if op == int(SymOp.FREE):
             return self._set_leaf(i, nd, target, asn)
+        if op == int(SymOp.CD_SELECT):
+            return self._set_select(nd, target, asn)
         a, b = nd.a, nd.b
         av, bv = self.vals[a] if a else 0, self.vals[b] if b else 0
         a_free, b_free = (a and self.has_free(a)), (b and self.has_free(b))
@@ -244,6 +247,49 @@ class _Inverter:
     def _set_leaf(self, node_id: int, nd, target: int, asn: Assignment) -> bool:
         return _assign_leaf(node_id, nd, target, asn)
 
+    def _misplaced(self, nd, asn: Assignment) -> Optional[int]:
+        """None where a ``CD_SELECT`` reads inside the calldata and off
+        the call's head under the assignment; else the offset at which
+        the ABI puts the data: behind the head, 32 bytes a word of it.
+        The head is the calldata words that the constraints and the
+        select's own offset are made of."""
+        if nd.imm not in self._heads:
+            self._heads[nd.imm] = self._head(
+                nd.imm, [root for root, _ in self.tape.constraints])
+        head = self._heads[nd.imm] + self._head(nd.imm, [nd.a])
+        off = self.vals[nd.a]
+        if (off + 32 <= len(asn.tx(nd.imm).calldata)
+                and not any(h < off + 32 and off < h + 32 for h in head)):
+            return None
+        return max(head, default=-28) + 32
+
+    def _head(self, tx: int, roots) -> List[int]:
+        """Byte offsets of transaction ``tx``'s calldata words under
+        ``roots``."""
+        from .eval import TX_STRIDE
+
+        nodes = self.tape.nodes
+        return [nodes[i].b % TX_STRIDE
+                for root in roots for i in _leaf_support(self.tape, root)
+                if nodes[i].op == int(SymOp.FREE)
+                and nodes[i].a == int(FreeKind.CALLDATA_WORD)
+                and nodes[i].b // TX_STRIDE == tx]
+
+    def _set_select(self, nd, target: int, asn: Assignment) -> bool:
+        """Make the word a ``CD_SELECT`` reads ``target``: write it where
+        its offset points under the assignment, a misplaced offset
+        (all-zero calldata puts an array's length at byte 4, on the word
+        that says where the array is) moved behind the head first."""
+        tx = asn.tx(nd.imm)
+        off, moved = self.vals[nd.a], self._misplaced(nd, asn)
+        if moved is not None:
+            if (moved + 32 > len(tx.calldata)
+                    or not self.apply(nd.a, moved, asn)):
+                return False
+            off = moved
+        tx.write_word(off, target)
+        return True
+
 
 def _assign_leaf(node_id: int, nd, target: int, asn: Assignment) -> bool:
     from .eval import TX_STRIDE
@@ -281,6 +327,8 @@ def _leaf_support(tape: HostTape, root: int) -> List[int]:
         if nd.op == int(SymOp.FREE):
             out.append(i)
         else:
+            if nd.op == int(SymOp.CD_SELECT):
+                out.append(i)   # a variable too: the bytes it reads
             stack.extend((nd.a, nd.b))
     return out
 
@@ -295,25 +343,34 @@ def _leaf_support(tape: HostTape, root: int) -> List[int]:
 # underlying tx bytes, so they must share a cluster even though their
 # node ids differ.
 
-def _leaf_keys(tape: HostTape, leaves: List[int], cds_txs: frozenset) -> set:
+def _leaf_keys(tape: HostTape, leaves: List[int], cds_txs: frozenset,
+               sel_txs: frozenset = frozenset()) -> set:
     """Assignment-granular variable keys touched by `leaves`. Calldata
     words expand to their byte windows; when tx ``t``'s CALLDATASIZE is
     constrained somewhere (``t in cds_txs``), every calldata read of tx
     ``t`` couples to it (reads zero-pad past the chosen size, see
-    ``TxInput.read_word``). ORIGIN aliases CALLER(tx0) — the evaluator
-    defaults an unassigned origin to ``asn.caller`` — so ORIGIN leaves
-    carry the caller key too."""
+    ``TxInput.read_word``). A ``CD_SELECT`` reads wherever its offset
+    points, so where tx ``t`` has one (``t in sel_txs``) it and every
+    calldata word of ``t`` share a key. ORIGIN aliases CALLER(tx0) — the
+    evaluator defaults an unassigned origin to ``asn.caller`` — so ORIGIN
+    leaves carry the caller key too."""
     from .eval import BY_NODE_KINDS, TX_STRIDE
 
     keys = set()
     for i in leaves:
         nd = tape.nodes[i]
         kind, b = nd.a, nd.b
-        if kind == int(FreeKind.CALLDATA_WORD):
+        if nd.op == int(SymOp.CD_SELECT):
+            keys.add(("cd", nd.imm))
+            if nd.imm in cds_txs:
+                keys.add((int(FreeKind.CALLDATASIZE), nd.imm))
+        elif kind == int(FreeKind.CALLDATA_WORD):
             tx, off = divmod(b, TX_STRIDE)
             keys.update(("cd", tx, off + k) for k in range(32))
             if tx in cds_txs:
                 keys.add((int(FreeKind.CALLDATASIZE), tx))
+            if tx in sel_txs:
+                keys.add(("cd", tx))
         elif kind in BY_NODE_KINDS:
             keys.add(("n", i))  # keyed by node id in Assignment.by_node
         elif kind == int(FreeKind.ORIGIN):
@@ -340,7 +397,12 @@ def partition_constraints(tape: HostTape) -> List[List[int]]:
     cds_txs = frozenset(
         tape.nodes[i].b
         for sup in supports for i in sup
-        if tape.nodes[i].a == int(FreeKind.CALLDATASIZE))
+        if tape.nodes[i].op == int(SymOp.FREE)
+        and tape.nodes[i].a == int(FreeKind.CALLDATASIZE))
+    sel_txs = frozenset(
+        tape.nodes[i].imm
+        for sup in supports for i in sup
+        if tape.nodes[i].op == int(SymOp.CD_SELECT))
     parent = list(range(n))
 
     def find(x: int) -> int:
@@ -351,7 +413,7 @@ def partition_constraints(tape: HostTape) -> List[List[int]]:
 
     owner: Dict[tuple, int] = {}
     for j in range(n):
-        for k in _leaf_keys(tape, supports[j], cds_txs):
+        for k in _leaf_keys(tape, supports[j], cds_txs, sel_txs):
             if k in owner:
                 ra, rb = find(j), find(owner[k])
                 if ra != rb:
@@ -401,9 +463,16 @@ def _solve_partitioned(tape: HostTape, seed: int, max_iters: int,
     return ("sat" if out is not None else "unknown"), out
 
 
-def _mutate_leaf(tape: HostTape, leaf: int, asn: Assignment, rng: random.Random):
+def _mutate_leaf(tape: HostTape, leaf: int, asn: Assignment,
+                 rng: random.Random, vals: List[int]):
     nd = tape.nodes[leaf]
     v = rng.choice(_INTERESTING) if rng.random() < 0.6 else rng.getrandbits(256)
+    if nd.op == int(SymOp.CD_SELECT):
+        # the word it reads under ``vals``, wherever that is
+        tx = asn.tx(nd.imm)
+        if vals[nd.a] + 32 <= len(tx.calldata):
+            tx.write_word(vals[nd.a], v)
+        return
     _assign_leaf(leaf, nd, v, asn)
 
 
@@ -478,8 +547,34 @@ def solve_tape_ex(tape: HostTape, seed: int = 0, max_iters: int = 400,
     and is never cached."""
     from .portfolio import solve_query
 
-    return solve_query(tape, seed=seed, max_iters=max_iters, base=base,
-                       max_time=max_time)
+    verdict, asn = solve_query(tape, seed=seed, max_iters=max_iters,
+                               base=base, max_time=max_time)
+    return verdict, (asn if asn is None else _settle_selects(tape, asn))
+
+
+def _settle_selects(tape: HostTape, asn: Assignment) -> Assignment:
+    """A witness whose every ``CD_SELECT`` reads where the ABI would put
+    it. A dynamic argument that no constraint mentions (a ``bytes`` that
+    is decoded and dropped) is left where all-zero calldata points: its
+    length is read off the head, an address say, and the transaction
+    would copy 2**160 bytes, which no chain has the gas for. Such a
+    select is moved behind the head with a length of zero, if the
+    constraints still hold."""
+    selects = [i for i, nd in enumerate(tape.nodes)
+               if nd.op == int(SymOp.CD_SELECT)]
+    if not selects:
+        return asn
+    inv = _Inverter(tape, evaluate(tape, asn))
+    for i in selects:
+        nd = tape.nodes[i]
+        if inv._misplaced(nd, asn) is None:
+            continue
+        cand = asn.copy()
+        if inv._set_select(nd, 0, cand):
+            vals = evaluate(tape, cand)
+            if all(_sat_vector(tape, vals)):
+                asn, inv.vals = cand, vals
+    return asn
 
 
 def solve_tape(tape: HostTape, seed: int = 0, max_iters: int = 400,
@@ -532,7 +627,7 @@ def _solve_tape_inner(tape: HostTape, seed: int = 0, max_iters: int = 400,
             inv.vals = vals
             inv.apply(node, 1 if sign else 0, cand)
         else:
-            _mutate_leaf(tape, rng.choice(support), cand, rng)
+            _mutate_leaf(tape, rng.choice(support), cand, rng, vals)
         cvals = evaluate(tape, cand)
         csat = _sat_vector(tape, cvals)
         if sum(csat) >= sum(sat):

@@ -81,6 +81,9 @@ def support(tape: HostTape, root: int):
             ids.append(i)
             kinds.add(nd.a)
         elif nd.op not in (int(SymOp.CONST), int(SymOp.NULL)):
+            if nd.op == int(SymOp.CD_SELECT):
+                # the caller's bytes, wherever the offset points
+                kinds.add(int(FreeKind.CALLDATA_WORD))
             stack.extend((nd.a, nd.b))
     return ids, kinds
 
@@ -102,10 +105,15 @@ def cone(tape: HostTape, roots, storage_key_div: int = 0) -> set:
     caller wants FREE(STORAGE) leaves traversed into their symbolic key
     node (the engine packs ``b = key_sym * A + account_slot``,
     ``symbolic/engine.py`` SLOAD-miss leaf) — which slot a storage read
-    hits observably depends on the key, so taint flows through it."""
+    hits observably depends on the key, so taint flows through it. A
+    ``CD_SELECT`` is a leaf here: the word is the caller's bytes wherever
+    its offset points, and an ABI decode's own ``4 + offset`` is no value
+    that reaches the effect (upstream's ``Select`` drops its index's
+    annotations the same way ⚠unv)."""
     nodes = tape.nodes
     n = len(nodes)
-    leafish = (int(SymOp.CONST), int(SymOp.NULL), int(SymOp.FREE))
+    leafish = (int(SymOp.CONST), int(SymOp.NULL), int(SymOp.FREE),
+               int(SymOp.CD_SELECT))
     storage = int(FreeKind.STORAGE)
     seen: set = set()
     stack = [int(r) for r in roots]

@@ -15,9 +15,25 @@ frontier-first:
   (the reference's ``work_list.append`` of forked GlobalStates).
 
 Over-approximation policy: wherever byte-exact symbolic tracking is not
-worth the shapes (unaligned accesses, symbolic offsets, ADDMOD), the
-result is a fresh unconstrained HAVOC leaf — never a wrong value, so the
-engine may explore infeasible paths but never misses feasible ones.
+worth the shapes, the result is a fresh unconstrained HAVOC leaf, never
+a wrong value. Exact: aligned words at concrete offsets; a word below
+the lane's ``mem_floor`` after a store or a copy at a symbolic offset
+invalidated memory from its destination's concrete base up (solc's free
+pointer after it decoded a dynamic argument: the scratch words that hash
+every mapping slot stay exact); a ``CALLDATALOAD`` at a symbolic offset
+in the top frame (a ``CD_SELECT`` node over the transaction's bytes).
+Still a havoc leaf: an unaligned access that meets a symbolic word;
+``ADDMOD`` / ``MULMOD`` over symbols; a calldata read beyond the
+modelled window, or at a symbolic offset inside a sub-frame; any word at
+or above the floor, those a ``CALLDATACOPY`` filled among them; all of
+memory after a store or a copy whose destination has no concrete base.
+A havoc leaf is tied to nothing, so the engine may explore infeasible
+paths; it misses no feasible one AS LONG AS what the leaf stands for is
+not read back through concrete state: from concrete storage a mapping
+slot hashed out of havoc memory is a key that no earlier write matches,
+and a flaw behind such a guard was a false negative until the floor
+(``tests/test_dynamic_args.py``). A destination below its concrete base
+(a sum that wraps) is not modelled: ``_concrete_base``.
 """
 
 from __future__ import annotations
@@ -36,7 +52,7 @@ from ..core.frontier import (Frontier, Env, Corpus, Trap, CAP_TRAPS,
                              CODE_UNKNOWN)
 from ..ops import u256
 from .ops import SymOp, FreeKind, TX_STRIDE, BAL_STRIDE
-from .state import SymFrontier, SymSpec
+from .state import MEM_EXACT, SymFrontier, SymSpec
 # imported here, outside any trace, for its module-level jnp constants:
 # ``_sym_run_impl`` imports from it while it is being traced, and a
 # first import there would build them as tracers of that trace
@@ -144,6 +160,67 @@ def _havoc(sf: SymFrontier, mask):
         sf, mask, int(SymOp.FREE), int(FreeKind.HAVOC), sf.havoc_cnt
     )
     return sf2.replace(havoc_cnt=sf2.havoc_cnt + mask.astype(I32)), ids
+
+
+def _floor_word(off64):
+    """The memory word of a byte offset, as ``mem_floor`` counts them."""
+    return jnp.clip(off64 // 32, 0, MEM_EXACT).astype(I32)
+
+
+def _reaches_floor(sf: SymFrontier, off64, ln64):
+    """Does the window ``[off, off + ln)`` hold a word at or above the
+    lane's ``mem_floor``, i.e. one that may be unknown?"""
+    return (ln64 > 0) & (_floor_word(off64 + ln64 + 31) > sf.mem_floor)
+
+
+def _lower_floor(floor, mask, word):
+    return jnp.where(mask, jnp.minimum(floor, word), floor)
+
+
+_BASE_DEPTH = 3
+
+
+def _concrete_base(sf: SymFrontier, node):
+    """A byte offset that the value of ``node`` does not lie below, read
+    off the tape: the concrete parts of a sum ``ADD(CONST c, x)``, nested
+    up to ``_BASE_DEPTH`` deep (solc's free pointer after a decode is
+    ``ADD(0xa0, MUL(32, len))``, an allocation after it ``ADD(that,
+    CONST)``); 0 for any other node. The sum is taken not to wrap: a
+    destination below its base needs a term within ``base`` of 2**256,
+    which no length that solc computes reaches under the gas limit."""
+    T = sf.tape_op.shape[1]
+
+    def row(arr, i):
+        return jnp.take_along_axis(
+            arr, jnp.clip(i, 0, T - 1)[:, None], axis=1)[:, 0]
+
+    def small_const(i):
+        imm = jnp.take_along_axis(
+            sf.tape_imm, jnp.clip(i, 0, T - 1)[:, None, None], axis=1)[:, 0]
+        ok = ((row(sf.tape_op, i) == int(SymOp.CONST))
+              & jnp.all(imm[:, 1:] == 0, axis=1) & (imm[:, 0] < 2**31))
+        return ok, imm[:, 0].astype(I64)
+
+    base = jnp.zeros(node.shape, dtype=I64)
+    cur, live = node, node != 0
+    for _ in range(_BASE_DEPTH):
+        is_add = live & (row(sf.tape_op, cur) == int(SymOp.ADD))
+        a, b = row(sf.tape_a, cur), row(sf.tape_b, cur)
+        (a_ok, a_val), (b_ok, b_val) = small_const(a), small_const(b)
+        take_a = is_add & a_ok
+        take_b = is_add & ~a_ok & b_ok
+        base = base + jnp.where(take_a, a_val, 0) + jnp.where(take_b, b_val, 0)
+        cur = jnp.where(take_a, b, jnp.where(take_b, a, cur))
+        live = take_a | take_b
+    return base
+
+
+def _dest_floor_word(sf: SymFrontier, off64, off_sym):
+    """The word a write at ``off`` lowers ``mem_floor`` to: the offset's
+    own where it is concrete, else its concrete base's (word 0 without
+    one)."""
+    return _floor_word(jnp.where(off_sym == 0, off64,
+                                 _concrete_base(sf, off_sym)))
 
 
 def _event_slot(counter, mask, length: int):
@@ -781,7 +858,8 @@ def _h_sym_call(sf: SymFrontier, corpus: Corpus, op, m, old_pc,
     )
     tail_partial = (a_len % 32) != 0
     tail_sym = tail_partial & (_take_word_sym(sf.mem_sym, w0 + (a_len // 32).astype(I32)) != 0)
-    cd_havoc_new = sf.mem_havoc | (~aligned_a & any_sym_window) | (aligned_a & tail_sym)
+    cd_havoc_new = (_reaches_floor(sf, a_off, a_len)
+                    | (~aligned_a & any_sym_window) | (aligned_a & tail_sym))
     cd_sym_new = jnp.zeros_like(sf.cd_sym)
     for w in range(CDW):
         full_cover = aligned_a & ((32 * (w + 1)) <= a_len)
@@ -830,7 +908,8 @@ def _h_sym_call(sf: SymFrontier, corpus: Corpus, op, m, old_pc,
         base=f2,
         stack_sym=stack_sym,
         mem_sym=jnp.where(mi[:, None], 0, sf.mem_sym),
-        mem_havoc=jnp.where(mi, False, sf.mem_havoc | havoc_mem),
+        mem_floor=jnp.where(mi, MEM_EXACT, _lower_floor(
+            sf.mem_floor, havoc_mem, _dest_floor_word(sf, r_off, r_off_s))),
         retdata_sym=jnp.where(mi | eoa_ok | fail0, False,
                               sf.retdata_sym | external),
         cd_from_mem=sf.cd_from_mem | mi,
@@ -840,7 +919,7 @@ def _h_sym_call(sf: SymFrontier, corpus: Corpus, op, m, old_pc,
         caller_sym=jnp.where(mi, new_caller_sym, sf.caller_sym),
         fr_caller_sym=_fr_set(sf.fr_caller_sym, d, sf.caller_sym, mi),
         fr_mem_sym=_fr_set(sf.fr_mem_sym, d, sf.mem_sym, mi),
-        fr_mem_havoc=_fr_set(sf.fr_mem_havoc, d, sf.mem_havoc, mi),
+        fr_mem_floor=_fr_set(sf.fr_mem_floor, d, sf.mem_floor, mi),
         fr_cd_from_mem=_fr_set(sf.fr_cd_from_mem, d, sf.cd_from_mem, mi),
         fr_cd_havoc=_fr_set(sf.fr_cd_havoc, d, sf.cd_havoc, mi),
         fr_cd_sym=_fr_set(sf.fr_cd_sym, d, sf.cd_sym, mi),
@@ -913,7 +992,7 @@ def _apply_precompiles(sf: SymFrontier, pre, pid, a_off, a_len, r_off,
     wids = jnp.arange(W)[None, :]
     win_lo = (a_off // 32)[:, None]
     win_hi = ((a_off + a_len + 31) // 32)[:, None]
-    sym_in = (sf.mem_havoc | jnp.any(
+    sym_in = (_reaches_floor(sf, a_off, a_len) | jnp.any(
         (wids >= win_lo) & (wids < win_hi) & (sf.mem_sym != 0), axis=1
     )) & (a_len > 0)
 
@@ -1141,9 +1220,10 @@ def _apply_precompiles(sf: SymFrontier, pre, pid, a_off, a_len, r_off,
     edge_dirty = jnp.any(edge & (sf.mem_sym != 0), axis=1)
     leaf_word_ok = m_leaf & ((r_off % 32) == 0) & (r_len >= 32) & (out_len == 32)
     mem_sym = _set_word_sym(mem_sym, (r_off // 32).astype(I32), leaf, leaf_word_ok)
-    mem_havoc = sf.mem_havoc | (conc_res & edge_dirty) | (
-        m_leaf & (r_len > 0) & ~leaf_word_ok
-    )
+    mem_floor = _lower_floor(
+        sf.mem_floor,
+        (conc_res & edge_dirty) | (m_leaf & (r_len > 0) & ~leaf_word_ok),
+        _floor_word(r_off))
 
     # a malformed input FAILS the call: the success word the caller
     # pushed (top of stack after the sp update) is rewritten to 0
@@ -1154,7 +1234,7 @@ def _apply_precompiles(sf: SymFrontier, pre, pid, a_off, a_len, r_off,
         base=f.replace(memory=memory, returndata=returndata, stack=stack,
                        returndata_len=jnp.where(pre, n_out, f.returndata_len)),
         mem_sym=mem_sym,
-        mem_havoc=mem_havoc,
+        mem_floor=mem_floor,
         retdata_sym=jnp.where(pre, m_leaf, sf.retdata_sym),
     )
 
@@ -1258,7 +1338,7 @@ def _h_sym_create(sf: SymFrontier, op, m, old_pc) -> SymFrontier:
     D = f.fr_ret_pc.shape[1]
     W = sf.mem_sym.shape[1]
     wids = jnp.arange(W)[None, :]
-    win_sym = (sf.mem_havoc | jnp.any(
+    win_sym = (_reaches_floor(sf, off, ln) | jnp.any(
         (wids >= (off // 32)[:, None])
         & (wids < ((off + ln + 31) // 32)[:, None])
         & (sf.mem_sym != 0), axis=1
@@ -1386,7 +1466,7 @@ def _push_create_frame(sf: SymFrontier, mi, is_c2, slot, sin, off, ln, salt,
     return sf.replace(
         base=f2,
         mem_sym=jnp.where(mi[:, None], 0, sf.mem_sym),
-        mem_havoc=jnp.where(mi, False, sf.mem_havoc),
+        mem_floor=jnp.where(mi, MEM_EXACT, sf.mem_floor),
         cd_from_mem=sf.cd_from_mem | mi,
         cd_havoc=jnp.where(mi, False, sf.cd_havoc),
         cd_sym=jnp.where(mi[:, None], 0, sf.cd_sym),
@@ -1394,7 +1474,7 @@ def _push_create_frame(sf: SymFrontier, mi, is_c2, slot, sin, off, ln, salt,
         caller_sym=jnp.where(mi, 0, sf.caller_sym),
         fr_caller_sym=_fr_set(sf.fr_caller_sym, d, sf.caller_sym, mi),
         fr_mem_sym=_fr_set(sf.fr_mem_sym, d, sf.mem_sym, mi),
-        fr_mem_havoc=_fr_set(sf.fr_mem_havoc, d, sf.mem_havoc, mi),
+        fr_mem_floor=_fr_set(sf.fr_mem_floor, d, sf.mem_floor, mi),
         fr_cd_from_mem=_fr_set(sf.fr_cd_from_mem, d, sf.cd_from_mem, mi),
         fr_cd_havoc=_fr_set(sf.fr_cd_havoc, d, sf.cd_havoc, mi),
         fr_cd_sym=_fr_set(sf.fr_cd_sym, d, sf.cd_sym, mi),
@@ -1449,7 +1529,7 @@ def pop_frames(sf: SymFrontier, corpus: Corpus) -> SymFrontier:
 
     # sym overlay: restore caller's, then map the returndata words
     mem_sym = jnp.where(mp[:, None], _fr_get(sf.fr_mem_sym, d), sf.mem_sym)
-    mem_havoc = jnp.where(mp, _fr_get(sf.fr_mem_havoc, d), sf.mem_havoc)
+    mem_floor = jnp.where(mp, _fr_get(sf.fr_mem_floor, d), sf.mem_floor)
     roff_al = (r_off % 32) == 0
     RDW = sf.rv_sym.shape[1]
     rv_words_sym = jnp.any(
@@ -1468,10 +1548,10 @@ def pop_frames(sf: SymFrontier, corpus: Corpus) -> SymFrontier:
         (jnp.arange(RDW)[None, :] == (n_rd // 32)[:, None]) & (sf.rv_sym != 0),
         axis=1,
     )
-    mem_havoc = mem_havoc | (has_rd & (
+    mem_floor = _lower_floor(mem_floor, has_rd & (
         (sf.rv_havoc & (r_len > 0)) | (~roff_al & rv_words_sym)
         | (roff_al & tail_sym_rd)
-    ))
+    ), _floor_word(r_off))
 
     # storage + balance rollback on failure
     def roll(cur, snap):
@@ -1601,7 +1681,7 @@ def pop_frames(sf: SymFrontier, corpus: Corpus) -> SymFrontier:
         base=base,
         stack_sym=stack_sym,
         mem_sym=mem_sym,
-        mem_havoc=mem_havoc,
+        mem_floor=mem_floor,
         retdata_sym=jnp.where(mp, has_rd & rv_unknown, sf.retdata_sym),
         rv_sym=jnp.where(mp[:, None], 0, sf.rv_sym),
         rv_havoc=jnp.where(mp, False, sf.rv_havoc),
@@ -1631,9 +1711,16 @@ def _h_sym_claimed_misc(sf: SymFrontier, op, m_memoff, m_sha3off, m_copyoff,
                         m_haltoff, m_logoff) -> SymFrontier:
     """Symbolic-offset memory/copy/sha3/halt/log ops: stack bookkeeping +
     havoc over-approximation (no byte-accurate modeling at symbolic
-    addresses under static shapes)."""
+    addresses under static shapes). A store or a copy invalidates memory
+    from its destination's word up (``mem_floor``), not below it."""
     f = sf.base
     is_load = op == 0x51
+    # the destination: operand 0, EXTCODECOPY's operand 1
+    is_ext = op == 0x3C
+    dst64 = u256.to_u64_saturating(jnp.where(
+        is_ext[:, None], ci._peek(f, 1), ci._peek(f, 0))).astype(I64)
+    dst_word = _dest_floor_word(
+        sf, dst64, jnp.where(is_ext, _peek_sym(sf, 1), _peek_sym(sf, 0)))
     # LOG is a state modification: a symbolic-offset LOG inside a
     # STATICCALL frame must trap exactly like the concrete handler's
     static_viol = m_logoff & f.static
@@ -1683,8 +1770,8 @@ def _h_sym_claimed_misc(sf: SymFrontier, op, m_memoff, m_sha3off, m_copyoff,
             jnp.where(n_topics >= 1, _peek_sym(sf, 2), 0)),
         log_data0_sym=ci._write_slot(sf.log_data0_sym, wl, -1),
         stack_sym=stack_sym,
-        # symbolic-offset stores / copies invalidate the whole memory overlay
-        mem_havoc=sf.mem_havoc | (m_memoff & ~is_load) | m_copyoff,
+        mem_floor=_lower_floor(
+            sf.mem_floor, (m_memoff & ~is_load) | m_copyoff, dst_word),
         # a symbolic-window RETURN/REVERT leaves the payload unknown — the
         # caller's returndata havocs when this frame pops
         rv_havoc=sf.rv_havoc | m_haltoff,
@@ -1825,12 +1912,26 @@ def _overlay(sf: SymFrontier, env: Env, spec: SymSpec, op, m, cls, pre_sp,
     leaf(True, (op == 0x3D) & sf.retdata_sym, int(FreeKind.RETDATASIZE),
          jnp.maximum(sf.n_calls - 1, 0))
 
-    need_leaf = m_env & (kind >= 0)
-    sf, env_leaf = append_node(sf, need_leaf, int(SymOp.FREE), kind, bsel)
+    # a CALLDATALOAD at a symbolic offset, top frame: a select over the
+    # transaction's bytes (solc's decode of a dynamic argument reads its
+    # length so). It rides the leaves' append: one hash scan for both
+    cd_symoff = m_env & is_cdload & (s[0] != 0)
+    cd_select = cd_symoff & at_top & bool(spec.calldata)
+    need_leaf = (m_env & (kind >= 0)) | cd_select
+    sel_imm = jnp.zeros((f.pc.shape[0], 8), dtype=U32).at[:, 0].set(
+        jnp.where(cd_select, txb, 0).astype(U32))
+    sf, env_leaf = append_node(
+        sf, need_leaf,
+        jnp.where(cd_select, int(SymOp.CD_SELECT), int(SymOp.FREE)),
+        jnp.where(cd_select, s[0], kind), jnp.where(cd_select, 0, bsel),
+        sel_imm)
+    sf = sf.replace(cd_reads=sf.cd_reads + jnp.stack(
+        [cd_select, cd_symoff & ~cd_select], axis=1).astype(I32))
 
     # havoc cases: unknowable values must never collapse to a wrong
     # concrete 0 (EXTCODESIZE/EXTCODEHASH of unknown addresses, BALANCE of
-    # unknown addresses, BLOCKHASH, symbolic-offset CALLDATALOAD).
+    # unknown addresses, BLOCKHASH, a symbolic-offset CALLDATALOAD inside
+    # a sub-frame).
     # EXTCODESIZE/EXTCODEHASH of a table account are answered concretely
     # by the concrete handler (corpus image hashes precomputed).
     unknown_addr = (s[0] != 0) | ~known_acct
@@ -1844,7 +1945,7 @@ def _overlay(sf: SymFrontier, env: Env, spec: SymSpec, op, m, cls, pre_sp,
     # havoc instead (the engine's own policy: never a wrong value)
     cd_beyond_window = bool(spec.calldata) & is_cdload & (s[0] == 0) & beyond & at_top
     env_hv_need = m_env & (
-        (is_cdload & (s[0] != 0))
+        (cd_symoff & ~cd_select)
         | cd_beyond_window
         | (is_balance & unknown_addr)
         | (op == 0x40)  # BLOCKHASH
@@ -1904,8 +2005,9 @@ def _overlay(sf: SymFrontier, env: Env, spec: SymSpec, op, m, cls, pre_sp,
     # hashed data and yield a WRONG digest downstream — havoc instead
     # (over-approximation policy: never a wrong value)
     fits_chain = (off64 % 32 + ln64) <= 32 * NCW
-    m_hvsha = m_sha & (ln64 > 0) & (sf.mem_havoc | (any_w_sym & ~fits_chain))
-    m_chain = m_sha & any_w_sym & ~sf.mem_havoc & fits_chain
+    sha_unknown = _reaches_floor(sf, off64, ln64)
+    m_hvsha = m_sha & (ln64 > 0) & (sha_unknown | (any_w_sym & ~fits_chain))
+    m_chain = m_sha & any_w_sym & ~sha_unknown & fits_chain
     sf, sha_hv = _havoc(sf, m_hvsha)
     seed_imm = jnp.zeros((f.pc.shape[0], 8), dtype=U32)
     seed_imm = seed_imm.at[:, 0].set(jnp.clip(ln64, 0, 2**31).astype(U32))
@@ -1932,20 +2034,23 @@ def _overlay(sf: SymFrontier, env: Env, spec: SymSpec, op, m, cls, pre_sp,
     wm = (off64 // 32).astype(I32)
     wsym_a = _take_word_sym(sf.mem_sym, wm)
     wsym_b = _take_word_sym(sf.mem_sym, wm + 1)
+    # words at or above the lane's floor are unknown whatever they hold
+    unk_a = wm >= sf.mem_floor
+    unk_ab = wm + jnp.where(aligned, 0, 1) >= sf.mem_floor
     # MLOAD
     load_sym_needed = m_mem & is_load & (
-        (aligned & ((wsym_a != 0) | sf.mem_havoc))
-        | (~aligned & ((wsym_a != 0) | (wsym_b != 0) | sf.mem_havoc))
+        (aligned & (wsym_a != 0))
+        | (~aligned & ((wsym_a != 0) | (wsym_b != 0))) | unk_ab
     )
-    hv_load_need = load_sym_needed & (~aligned | sf.mem_havoc)
+    hv_load_need = load_sym_needed & (~aligned | unk_ab)
     # unaligned MSTORE: havoc both covered words if anything symbolic
     st_mask = m_mem & ~is_load
     un_any = st_mask & ~is_store8 & ~aligned & (
-        (s[1] != 0) | (wsym_a != 0) | (wsym_b != 0) | sf.mem_havoc
+        (s[1] != 0) | (wsym_a != 0) | (wsym_b != 0) | unk_ab
     )
     sf, hv_a = _havoc(sf, hv_load_need | un_any)
     r_mload = jnp.where(
-        load_sym_needed, jnp.where(aligned & ~sf.mem_havoc, wsym_a, hv_a), 0
+        load_sym_needed, jnp.where(aligned & ~unk_ab, wsym_a, hv_a), 0
     )
     mstore_aligned = st_mask & ~is_store8 & aligned
     mem_sym = _set_word_sym(sf.mem_sym, wm, s[1], mstore_aligned)
@@ -1953,7 +2058,7 @@ def _overlay(sf: SymFrontier, env: Env, spec: SymSpec, op, m, cls, pre_sp,
     mem_sym = _set_word_sym(mem_sym, wm, hv_a, un_any)
     mem_sym = _set_word_sym(mem_sym, wm + 1, hv_b, un_any)
     # MSTORE8: havoc the word if value or word symbolic
-    m8_any = st_mask & is_store8 & ((s[1] != 0) | (wsym_a != 0) | sf.mem_havoc)
+    m8_any = st_mask & is_store8 & ((s[1] != 0) | (wsym_a != 0) | unk_a)
     sf, hv_c = _havoc(sf, m8_any)
     mem_sym = _set_word_sym(mem_sym, wm, hv_c, m8_any)
     sf = sf.replace(mem_sym=mem_sym)
@@ -1966,8 +2071,9 @@ def _overlay(sf: SymFrontier, env: Env, spec: SymSpec, op, m, cls, pre_sp,
     is_cdcopy = op == 0x37
     is_rdcopy = op == 0x3E
     # calldatacopy of symbolic calldata / returndatacopy after a symbolic
-    # call: coarse whole-memory havoc (v1). Sub-frame calldata is only
-    # symbolic where the caller's memory window was.
+    # call: memory is unknown from the destination's word up (the words
+    # filled stay havoc leaves). Sub-frame calldata is only symbolic
+    # where the caller's memory window was.
     cd_symbolic = jnp.where(
         at_top,
         jnp.full_like(sf.cd_havoc, spec.calldata),
@@ -1996,15 +2102,17 @@ def _overlay(sf: SymFrontier, env: Env, spec: SymSpec, op, m, cls, pre_sp,
     edge_dirty = jnp.any(edge & (sf.mem_sym != 0), axis=1)
     sf = sf.replace(
         mem_sym=mem_sym2,
-        mem_havoc=sf.mem_havoc | cd_havoc | (conc_src & edge_dirty)
-        | (m_cp & ext_unknown & (cln64 > 0)),
+        mem_floor=_lower_floor(
+            sf.mem_floor,
+            cd_havoc | (conc_src & edge_dirty)
+            | (m_cp & ext_unknown & (cln64 > 0)), _floor_word(dst64)),
     )
 
     # ---- CLS_HALT: capture return-payload syms; SELFDESTRUCT beneficiary ----
     m_halt = m & (cls == ci.CLS_HALT)
     has_data = (op == 0xF3) | (op == 0xFD)
     rv_words = sf.rv_sym.shape[1]
-    cap_ok = m_halt & has_data & aligned & ~sf.mem_havoc
+    cap_ok = m_halt & has_data & aligned & ~_reaches_floor(sf, off64, ln64)
     rv_sym = sf.rv_sym
     for k in range(rv_words):
         in_rv = (jnp.int32(k) * 32) < ln64
@@ -2054,7 +2162,7 @@ def _overlay(sf: SymFrontier, env: Env, spec: SymSpec, op, m, cls, pre_sp,
     log_idx = sf.base.n_logs - 1  # concrete handler already bumped it
     wl = jnp.where(m_log & (log_idx >= 0) & (log_idx < LS), log_idx, LS)
     lanes_all = jnp.arange(f.pc.shape[0])
-    d0_sym = jnp.where(aligned & ~sf.mem_havoc, wsym_a, -1)
+    d0_sym = jnp.where(aligned & ~unk_a, wsym_a, -1)
     d0_sym = jnp.where(u256.to_u64_saturating(a[1]) == 0, 0, d0_sym)
     log_nt = op - 0xA0  # LOG0 has no topic: s[2] is an unrelated slot
     sf = sf.replace(
@@ -2205,7 +2313,7 @@ _MISC_WRITES = (
     "base.n_logs", "base.log_pc", "base.log_cid", "base.log_ntopics",
     "base.log_topic0", "base.error", "base.err_code",
     "havoc_cnt", "log_topic0_sym", "log_data0_sym", "stack_sym",
-    "mem_havoc", "rv_havoc",
+    "mem_floor", "rv_havoc",
 ) + _TAPE_WRITES
 
 # pop_frames' declared write set: everything the caller-restore touches —
@@ -2226,7 +2334,7 @@ _POP_FRAME_WRITES = (
     "base.st_acct", "base.acct_bal", "base.warm_acct", "base.st_warm",
     "base.gas_min", "base.gas_max", "base.gas_limit",
     "base.halted", "base.reverted", "base.error", "base.err_code",
-    "stack_sym", "mem_sym", "mem_havoc", "retdata_sym", "rv_sym",
+    "stack_sym", "mem_sym", "mem_floor", "retdata_sym", "rv_sym",
     "rv_havoc", "cd_from_mem", "cd_havoc", "cd_sym", "callvalue_sym",
     "caller_sym", "bal_epoch", "st_val_sym", "st_key_sym", "st_seq",
     "sub_revert_pc", "sub_revert_cid",
@@ -2442,10 +2550,11 @@ def between_txs(sf: SymFrontier, require_mutation: bool = True,
         ),
         stack_sym=jnp.where(go[:, None], 0, sf.stack_sym),
         mem_sym=jnp.where(go[:, None], 0, sf.mem_sym),
-        mem_havoc=jnp.where(go, False, sf.mem_havoc),
+        mem_floor=jnp.where(go, MEM_EXACT, sf.mem_floor),
         retdata_sym=jnp.where(go, False, sf.retdata_sym),
         rv_sym=jnp.where(go[:, None], 0, sf.rv_sym),
         rv_havoc=jnp.where(go, False, sf.rv_havoc),
+        cd_reads=jnp.zeros_like(sf.cd_reads),
         cd_from_mem=jnp.where(go, False, sf.cd_from_mem),
         cd_havoc=jnp.where(go, False, sf.cd_havoc),
         cd_sym=jnp.where(go[:, None], 0, sf.cd_sym),
@@ -2996,7 +3105,8 @@ SEAM_STORAGE = ("base.st_used", "base.st_written", "base.st_keys",
 
 
 def plan_seam_admission(n_contracts: int, carried, home, ended, failed,
-                        started, storage, guard_slots):
+                        started, storage, guard_slots,
+                        concrete_storage: bool = False):
     """Which of the end states ``between_txs`` carried start the next
     call, per contract. Host-planned from the seam's reads; returns None
     where every carried state starts (the step is inert), else a dict:
@@ -3027,7 +3137,10 @@ def plan_seam_admission(n_contracts: int, carried, home, ended, failed,
     transaction left a value (the first call from unconstrained
     storage: a guard on a symbolic leaf forks both ways in one call,
     and no write unlocks anything) nothing is novel, and the step is
-    inert wherever no contract over its share has a novel state.
+    inert wherever no contract over its share has a novel state. From
+    ``concrete_storage`` a slot that no transaction wrote reads as zero,
+    a concrete value like the constructor's: a guard on it
+    (``require(members[msg.sender])`` before anyone joined) counts too.
 
     Where it acts, it holds EVERY contract over its share to it (the
     pool is one, and a neighbour's carried states are what starved the
@@ -3069,8 +3182,9 @@ def plan_seam_admission(n_contracts: int, carried, home, ended, failed,
             guards[site] = [int(k).to_bytes(32, "little")
                             for k in guard_slots(*site)]
         had = {k.tobytes() for k in kb[lane, left[lane]]}
+        held = {k.tobytes() for k in kb[lane, named[lane] & (seq[lane] > 0)]}
         for k in guards[site]:
-            if k in had:
+            if k in had or (concrete_storage and k not in held):
                 readers[contract[lane], k] = readers.get(
                     (contract[lane], k), 0) + 1
     novelty = np.zeros(P, dtype=np.int64)

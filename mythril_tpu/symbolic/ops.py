@@ -47,6 +47,12 @@ class SymOp(IntEnum):
     KECCAK_SEED = 26  # imm = byte length
     KECCAK_ABS = 27   # a = chain, b = absorbed word id (imm = concrete word)
     KECCAK = 28       # a = final chain node -> 256-bit digest
+    # a CALLDATALOAD at a symbolic offset, top frame: the 32-byte word of
+    # transaction imm's calldata at the byte offset node a evaluates to
+    # (zero-padded past its end, as the EVM reads). b = 0: the transaction
+    # index is payload, not an operand, so every walker that follows a/b
+    # as node ids stays right
+    CD_SELECT = 29
 
 
 class FreeKind(IntEnum):

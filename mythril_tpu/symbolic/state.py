@@ -11,8 +11,9 @@ Granularity choices (documented over-approximations; each introduces
 fresh unconstrained variables rather than wrong values):
 - memory symbolics are tracked per 32-byte word (``mem_sym``);
 - unaligned symbolic stores/loads produce HAVOC leaves;
-- an unaligned CALLDATACOPY (or symbolic-offset store) sets ``mem_havoc``:
-  every later MLOAD of that lane returns a fresh HAVOC leaf.
+- a copy of symbolic calldata, or a store or copy at a symbolic offset,
+  lowers ``mem_floor``, the lowest memory word that may be unknown: an
+  MLOAD at or above it returns a fresh HAVOC leaf, one below it is exact.
 """
 
 from __future__ import annotations
@@ -30,6 +31,8 @@ from .spec import SymSpec  # noqa: F401  (re-exported: its historical home)
 
 I32 = jnp.int32
 U32 = jnp.uint32
+#: ``SymFrontier.mem_floor`` of a lane whose memory is exact throughout
+MEM_EXACT = 2**31 - 1
 
 
 def tape_row_hash(op, a, b, imm):
@@ -62,7 +65,10 @@ class SymFrontier:
     # --- overlay: sym ids (0 = concrete) ---
     stack_sym: jnp.ndarray   # i32[P, S]
     mem_sym: jnp.ndarray     # i32[P, M/32]
-    mem_havoc: jnp.ndarray   # bool[P] whole-memory havoc (coarse escape hatch)
+    mem_floor: jnp.ndarray   # i32[P] lowest memory word that may be unknown
+    # (MEM_EXACT: none). A write or a copy at a symbolic offset lowers it
+    # to the word of its concrete base (0 without one); reads, hashes and
+    # call windows below it stay exact, those reaching it are havoc
     retdata_sym: jnp.ndarray  # bool[P] returndata of last call is symbolic
     st_val_sym: jnp.ndarray  # i32[P, K]
     st_key_sym: jnp.ndarray  # i32[P, K] sym id of the key stored in the slot
@@ -89,7 +95,7 @@ class SymFrontier:
     # BALANCE reads across the change get fresh leaves instead of being
     # forced equal (advisor r2 low)
     fr_mem_sym: jnp.ndarray  # i32[P, D, M/32] saved caller memory overlay
-    fr_mem_havoc: jnp.ndarray  # bool[P, D]
+    fr_mem_floor: jnp.ndarray  # i32[P, D]
     fr_cd_from_mem: jnp.ndarray  # bool[P, D]
     fr_cd_havoc: jnp.ndarray  # bool[P, D]
     fr_cd_sym: jnp.ndarray   # i32[P, D, CD/32]
@@ -110,6 +116,9 @@ class SymFrontier:
     # the hash-cons scan's fast path; must stay in sync with every write
     tape_len: jnp.ndarray    # i32[P]
     havoc_cnt: jnp.ndarray   # i32[P] fresh-variable counter (HAVOC uniqueness)
+    cd_reads: jnp.ndarray    # i32[P, 2] this transaction's CALLDATALOADs at a
+    # symbolic offset on the lane's path: [answered by a CD_SELECT node,
+    # answered by a HAVOC leaf] (the harvest's ``engine_calldata_symreads_total``)
     create_cnt: jnp.ndarray  # i32[P] CREATE/CREATE2 counter (fresh addresses)
     # --- persistent abstract domains (incremental propagation) ---
     # the tape is SSA append-only, so a node's interval/known-bits never
@@ -256,7 +265,7 @@ def make_sym_frontier(
         base=base,
         stack_sym=z(P, S),
         mem_sym=z(P, L.mem_bytes // 32),
-        mem_havoc=jnp.zeros(P, dtype=bool),
+        mem_floor=jnp.full(P, MEM_EXACT, dtype=I32),
         retdata_sym=jnp.zeros(P, dtype=bool),
         st_val_sym=z(P, K),
         st_key_sym=z(P, K),
@@ -271,7 +280,7 @@ def make_sym_frontier(
         caller_sym=z(P),
         bal_epoch=z(P),
         fr_mem_sym=z(P, D, L.mem_bytes // 32),
-        fr_mem_havoc=jnp.zeros((P, D), dtype=bool),
+        fr_mem_floor=jnp.full((P, D), MEM_EXACT, dtype=I32),
         fr_cd_from_mem=jnp.zeros((P, D), dtype=bool),
         fr_cd_havoc=jnp.zeros((P, D), dtype=bool),
         fr_cd_sym=z(P, D, CDW),
@@ -291,6 +300,7 @@ def make_sym_frontier(
                                 jnp.zeros((P, T, 8), dtype=U32)),
         tape_len=jnp.full(P, n_wk, dtype=I32),
         havoc_cnt=z(P),
+        cd_reads=z(P, 2),
         create_cnt=z(P),
         iv_lo=jnp.zeros((P, T, 8), dtype=U32),
         iv_hi=jnp.zeros((P, T, 8), dtype=U32),

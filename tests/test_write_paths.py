@@ -509,7 +509,8 @@ _BIN = [SymOp.ADD, SymOp.SUB, SymOp.MUL, SymOp.DIV, SymOp.SDIV, SymOp.MOD,
         SymOp.SMOD, SymOp.EXP, SymOp.SIGNEXTEND, SymOp.LT, SymOp.GT,
         SymOp.SLT, SymOp.SGT, SymOp.EQ, SymOp.AND, SymOp.OR, SymOp.XOR,
         SymOp.BYTE, SymOp.SHL, SymOp.SHR, SymOp.SAR, SymOp.KECCAK_ABS]
-_UN = [SymOp.ISZERO, SymOp.NOT, SymOp.KECCAK, SymOp.KECCAK_SEED]
+_UN = [SymOp.ISZERO, SymOp.NOT, SymOp.KECCAK, SymOp.KECCAK_SEED,
+       SymOp.CD_SELECT]     # a select is the top of both domains
 _PAIRS = [(7, 3), (3, 7), (5, 5), (0, 9), (9, 0), (M256, 2), (2, M256),
           (1 << 255, 1 << 255), (255, 0xFF00), (256, 0xFF00), (8, 0xFF00),
           (4, M256 >> 4), (4, M256), ((1 << 128) - 1, (1 << 128) + 1),
@@ -551,7 +552,7 @@ def _brimful(op):
 
 
 SWEEP_CASES = {
-    # all 26 ops on 16 concrete operand pairs, 8 lanes at a time
+    # all 27 ops on 16 concrete operand pairs, 8 lanes at a time
     "every_op_concrete_0": lambda: _arm_lanes(_BIN + _UN, _consts(_PAIRS[:8])),
     "every_op_concrete_1": lambda: _arm_lanes(_BIN + _UN, _consts(_PAIRS[8:])),
     # the same ops where one or both operands are bounded leaves: the

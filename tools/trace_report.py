@@ -229,9 +229,12 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
     # started from a stuck seam and could only hand their frontier back
     # (``spun``: none since the loop itself sees the fixpoint), and what
     # ended it. Then what the seam's admission step made of the end
-    # states that passed the pruners, one row a (tx, fate), and the
-    # contracts' shares of the paths and the lost forks
+    # states that passed the pruners, one row a (tx, fate), the
+    # contracts' shares of the paths and the lost forks, and what
+    # decoding dynamic arguments did to a transaction's paths, one row a
+    # transaction (``harvest``: ``mem_floored_paths`` ..)
     by_tx: Dict[tuple, Dict] = {}
+    dynamic: Dict[object, Dict[str, int]] = {}
     fates: Dict[tuple, int] = {}
     rounds: Dict[object, int] = {}
     by_contract: Dict[tuple, List[int]] = {}
@@ -265,6 +268,11 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
         elif s["name"] == "harvest":
             row["paths"] += int(a.get("paths", 0))
             row["dropped"] += int(a.get("dropped", 0))
+            if "mem_floored_paths" in a:
+                got = dynamic.setdefault(a.get("tx"), {})
+                for key in ("paths", "mem_floored_paths", "mem_havoc_paths",
+                            "cd_selects", "loop_trapped"):
+                    got[key] = got.get(key, 0) + int(a.get(key, 0))
             for what in ("paths", "dropped"):
                 got = a.get(what + "_by_contract")
                 if not got:
@@ -315,6 +323,16 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
                           f"{rounds[tx + 1]} later round(s) of tx {tx + 1})"
                           if fate == "deferred" and isinstance(tx, int)
                           and rounds.get(tx + 1) else ""))
+    if dynamic:
+        out.append("")
+        out.append("== dynamic arguments (tx): memory invalidated from a "
+                   "floor up, calldata selects, the loop bound ==")
+        out.append(f"{'tx':>3}{'paths':>8}{'floored':>9}{'havoc':>7}"
+                   f"{'selects':>9}{'loop_trapped':>14}")
+        for tx, r in sorted(dynamic.items(), key=lambda kv: str(kv[0])):
+            out.append(f"{tx!s:>3}{r['paths']:>8}{r['mem_floored_paths']:>9}"
+                       f"{r['mem_havoc_paths']:>7}{r['cd_selects']:>9}"
+                       f"{r['loop_trapped']:>14}")
     if by_contract:
         out.append("")
         out.append("== paths and lost forks per contract of a batch "
