@@ -117,6 +117,10 @@ def _use_scatter() -> bool:
     measured on the SAME chip, the round-3 scatter rewrite took the
     concrete interpreter from 1.05M to 0.149M lane-steps/s (7x). Dense
     one-hot compare-selects keep every write a fusable vector op on TPU.
+    The same holds for reads of single elements (the TPU serializes an
+    element gather as it does a scatter: PR 37's feasibility sweep, PR
+    41's :func:`_gather_bytes`), so this one test also picks between the
+    CPU's element gather and the TPU's row reads there.
     To trace the other backend's choice, patch this function (as
     tests/test_write_paths.py and tools/scaling_report.py do)."""
     return jax.default_backend() == "cpu"
@@ -181,15 +185,52 @@ def _be_bytes_to_word(b) -> jnp.ndarray:
     return jnp.sum(bb * w, axis=-1).astype(U32)
 
 
+_ROW_BYTES = 128  # one lane tile of u8: the row `_gather_bytes` reads on the TPU
+
+
 def _gather_bytes(buf, start, n_static: int, limit):
     """buf[P, L] bytes; read n_static bytes from per-lane offset start,
-    zero-filled past `limit` (per-lane logical length). Returns u8[P, n]."""
-    idx = start[:, None].astype(I64) + jnp.arange(n_static, dtype=I64)[None, :]
-    L = buf.shape[1]
-    safe = jnp.clip(idx, 0, L - 1).astype(I32)
-    vals = jnp.take_along_axis(buf, safe, axis=1)
-    ok = (idx >= 0) & (idx < limit[:, None].astype(I64)) & (idx < L)
-    return jnp.where(ok, vals, 0)
+    zero-filled past `limit` (per-lane logical length), before 0, past L
+    and where ``start + k`` wraps in int64. Returns u8[P, n].
+
+    Backend-adaptive like :func:`_set_slot` (one result, two lowerings).
+    The CPU gathers the P x n single bytes. The TPU serializes an element
+    gather (~12 ns a byte: seven of these reads were 22-23% of its busy
+    time in every cell until PR 41) and reads a row for about the price
+    of an element, so there the window is read as the ``ceil(n / 128) + 1``
+    rows of 128 bytes that hold it and shifted left by ``start % 128`` in
+    seven compare-select stages."""
+    n = n_static
+    P, L = buf.shape
+    W = _ROW_BYTES
+    with jax.named_scope("gather_bytes"):
+        if _use_scatter():
+            idx = start[:, None].astype(I64) + jnp.arange(n, dtype=I64)[None, :]
+            safe = jnp.clip(idx, 0, L - 1).astype(I32)
+            vals = jnp.take_along_axis(buf, safe, axis=1)
+            ok = (idx >= 0) & (idx < limit[:, None].astype(I64)) & (idx < L)
+            return jnp.where(ok, vals, 0)
+        R = -(-L // W)
+        if R * W != L:
+            buf = jnp.pad(buf, ((0, 0), (0, R * W - L)))
+        K = -(-n // W) + 1
+        # a window that starts before -n or past L holds no byte of the
+        # buffer (nor does one that wraps): clipping moves no byte of it
+        s = jnp.clip(start.astype(I64), -n, L).astype(I32)
+        row0 = s // W  # floor: a negative start reads from row -1 (masked)
+        rows = jnp.clip(row0[:, None] + jnp.arange(K, dtype=I32)[None, :],
+                        0, R - 1)
+        x = jnp.take_along_axis(buf.reshape(P, R, W), rows[:, :, None],
+                                axis=1, mode="promise_in_bounds")
+        x = x.reshape(P, K * W)
+        shift = s - row0 * W  # in [0, W)
+        b = W >> 1
+        while b:  # x[:, j] <- x[:, j + shift], a bit of `shift` a stage
+            x = jnp.where(((shift & b) != 0)[:, None], x[:, b:], x[:, :-b])
+            b >>= 1
+        idx = s[:, None] + jnp.arange(n, dtype=I32)[None, :]
+        end = jnp.clip(limit.astype(I64), 0, L).astype(I32)
+        return jnp.where((idx >= 0) & (idx < end[:, None]), x[:, :n], 0)
 
 
 def _scatter_bytes(memory, start, vals, n_static: int, mask):

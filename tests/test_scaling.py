@@ -94,3 +94,51 @@ def test_feasibility_sweep_is_dense_on_the_tpu_write_mode(monkeypatch):
     assert faults(scatter=False) == []
     # the walker sees the CPU's form: four row scatters, no element gather
     assert faults(scatter=True) == ["scatter"] * 4
+
+
+def _byte_gathers_of(jaxpr, lanes):
+    """Gathers of single bytes (all-ones ``slice_sizes``) from a
+    ``u8[lanes, L]`` operand, made inside ``_gather_bytes``."""
+    found = []
+    for eqn in scaling_report.all_eqns(jaxpr):
+        if eqn.primitive.name != "gather":
+            continue
+        aval = eqn.invars[0].aval
+        if (aval.dtype != np.uint8 or len(aval.shape) != 2
+                or aval.shape[0] != lanes
+                or set(eqn.params["slice_sizes"]) != {1}):
+            continue
+        if any(fr.function_name == "_gather_bytes"
+               for fr in eqn.source_info.traceback.frames):
+            found.append(tuple(aval.shape))
+    return found
+
+
+def test_gather_bytes_reads_rows_on_the_tpu_write_mode(monkeypatch):
+    # what the TPU traces must gather no single byte in _gather_bytes:
+    # the chip serializes an element gather (~12 ns a byte; seven such
+    # fusions were 22-23% of its busy time in every cell until PR 41).
+    # A lane's window is read as whole rows and shifted into place.
+    from mythril_tpu.core import interpreter as ci
+    from mythril_tpu.symbolic import SymSpec
+    from mythril_tpu.symbolic.engine import sym_superstep
+
+    P = 16
+    sf0, env0, corpus, L = scaling_report._build_inputs(P)
+
+    def faults(scatter):
+        monkeypatch.setattr(ci, "_use_scatter", lambda: scatter)
+        # a new function a trace: make_jaxpr keeps what it traced before
+        step = jax.make_jaxpr(
+            lambda f, e: ci.superstep(f, e, corpus))(sf0.base, env0)
+        sym = jax.make_jaxpr(
+            lambda s, e: sym_superstep(s, e, corpus, SymSpec(), L))(sf0, env0)
+        return _byte_gathers_of(step, P), _byte_gathers_of(sym, P)
+
+    step, sym = faults(scatter=False)
+    assert step == [] and sym == [], (
+        f"{len(step)} single-byte gathers in the interpreter's step and "
+        f"{len(sym)} in sym_superstep come from _gather_bytes: {step + sym}")
+    # the walker sees the CPU's form: one element gather a call site
+    step, sym = faults(scatter=True)
+    assert len(step) >= 7 and len(sym) > len(step)

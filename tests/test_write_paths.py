@@ -696,3 +696,80 @@ def test_sweep_paths_match_oracle_random_tapes(seed):
         if p % 2:
             lanes[-1]["fresh"] = int(g.integers(0, k + 1))
     _check_sweep(_frontier(lanes))
+
+
+# ---------------------------------------------------------------------------
+# _gather_bytes: the TPU reads a lane's window as rows, the CPU as bytes
+# ---------------------------------------------------------------------------
+
+
+def _gather_ref(buf, start, n, limit):
+    """out[p, k] = buf[p, start[p] + k] where that index (wrapped to
+    int64, as the device adds it) lies in [0, min(limit[p], L)), else 0."""
+    P, L = buf.shape
+    out = np.zeros((P, n), np.uint8)
+    for p in range(P):
+        for k in range(n):
+            idx = (int(start[p]) + k + 2**63) % 2**64 - 2**63
+            if 0 <= idx < min(int(limit[p]), L):
+                out[p, k] = buf[p, idx]
+    return out
+
+
+def _gather_starts(L, n):
+    from mythril_tpu.ops import u256
+    big = np.zeros((1, 8), np.uint32)
+    big[0, 5] = 1   # an offset past 2**64: saturates, then wraps to -1
+    saturated = int(np.asarray(
+        u256.to_u64_saturating(jnp.asarray(big)).astype(jnp.int64))[0])
+    assert saturated == -1
+    return [-(2**63), -n - 1, -n, -n + 1, -33, -1, saturated, 0, 1, 5, 31,
+            32, 33, 127, 129, L // 2 + 3, L - n - 1, L - n, L - n + 1,
+            L - 33, L - 1, L, L + 1, L + n, 2**31 - 1, 2**31 + 7,
+            2**63 - n, 2**63 - 1]
+
+
+GATHER_LIMITS = ("zero", "inside_window", "equal_L", "beyond_L")
+
+
+@pytest.mark.parametrize("limit_kind", GATHER_LIMITS)
+@pytest.mark.parametrize("L", [256, 1024, 4096, 24576])
+@pytest.mark.parametrize("n", [32, 200, 256, 1024])
+def test_gather_bytes_paths_match_oracle(n, L, limit_kind):
+    g = np.random.default_rng(n * 31 + L)
+    start = np.asarray(_gather_starts(L, n), np.int64)
+    P = len(start)
+    buf = g.integers(1, 256, (P, L), dtype=np.uint8)   # no zero byte:
+    # a position that reads 0 was masked, one that does not was read
+    limit = {
+        "zero": np.zeros(P, np.int64),
+        # a different cut in every lane, from before the window to past it
+        "inside_window": np.clip(start, -n, L) + g.integers(-2, n + 2, P),
+        "equal_L": np.full(P, L, np.int32),   # as jnp.full_like(off, L)
+        "beyond_L": L + g.integers(1, 2**40, P),
+    }[limit_kind]
+    a, b = both_paths(lambda: ci._gather_bytes(
+        jnp.asarray(buf), jnp.asarray(start), n, jnp.asarray(limit)))
+    want = _gather_ref(buf, start, n, limit)
+    assert a.dtype == b.dtype == np.uint8 and a.shape == b.shape == (P, n)
+    assert (a == want).all(), np.argwhere(a != want)[:5]
+    assert (b == want).all(), np.argwhere(b != want)[:5]
+    if limit_kind != "zero":
+        assert want.any()
+
+
+def test_gather_bytes_odd_widths_and_narrow_starts():
+    """Buffers that are no whole number of rows, windows wider than the
+    buffer, and the int32 starts the PUSH window passes (pc + 1)."""
+    g = np.random.default_rng(5)
+    for L, n in ((100, 32), (33, 32), (31, 32), (8, 32), (448, 32),
+                 (200, 200), (96, 256), (4096, 7)):
+        start = np.asarray([-n, -1, 0, 1, 30, L - n, L - 1, L, L + 40, 2**31 - 1],
+                           np.int64).clip(-2**31, 2**31 - 1).astype(np.int32)
+        P = len(start)
+        buf = g.integers(1, 256, (P, L), dtype=np.uint8)
+        limit = g.integers(0, L + 3, P).astype(np.int32)
+        a, b = both_paths(lambda: ci._gather_bytes(
+            jnp.asarray(buf), jnp.asarray(start), n, jnp.asarray(limit)))
+        want = _gather_ref(buf, start, n, limit)
+        assert (a == want).all() and (b == want).all(), (L, n)
