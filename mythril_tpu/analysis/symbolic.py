@@ -381,6 +381,17 @@ class SymExecWrapper:
     ``engine_seam_states_total{tx,fate}`` counts the last fates, a
     ``superstep`` span after waiting lanes started carries ``round``,
     and the ``harvest`` span has ``paths`` / ``dropped`` per contract.
+
+    ``links`` (a campaign batch that holds linked systems; one entry a
+    contract: None, or ``{"system": name, "address": int}`` as the
+    corpus directory's manifest gave it, members of one system in the
+    manifest's order) puts every member of a system into the account
+    table of that system's lanes at its manifest address
+    (``make_frontier``'s ``systems``), and the creation transaction's
+    seam joins the end states of a system's constructors into ONE world
+    from which every member's message calls start
+    (:meth:`_join_worlds`, a ``system_world`` span inside that
+    ``tx_seam``). Without ``links`` nothing of this runs.
     """
 
     def __init__(
@@ -410,6 +421,7 @@ class SymExecWrapper:
         dynld_limit: int = 4,
         warm_shapes: Optional[set] = None,
         on_first_call: Optional[Callable[[], None]] = None,
+        links: Optional[Sequence[Optional[dict]]] = None,
     ):
         import time as _time
 
@@ -501,6 +513,7 @@ class SymExecWrapper:
                     map(len, creation_bytecodes or ())))
         self.images = images
         self._n_creation = C if with_creation else 0
+        self._member_names = names[-C:]
         with phase_timer("batch_build", stage="corpus"):
             self.corpus = Corpus.from_images(images, self._n_creation)
         self._visited = np.zeros(
@@ -524,6 +537,22 @@ class SymExecWrapper:
         self._dynld_fails: Dict[int, int] = {}  # transient-failure counts
         self.dynld_loaded: List[int] = []  # addresses loaded mid-run
         self._dynld_sha: List[str] = []    # sha256 of each loaded image
+        # linked systems: member indices by system, in the manifest's
+        # order (the order the records came in), and each contract's own
+        systems = None
+        self._systems: Dict[str, List[int]] = {}
+        self.failed_deployments: List[dict] = []
+        if links is not None and any(links):
+            assert len(links) == C
+            for i, ln in enumerate(links):
+                if ln:
+                    self._systems.setdefault(ln["system"], []).append(i)
+            systems = [self._systems[ln["system"]] if ln else None
+                       for ln in links]
+            contract_addrs = [
+                ln["address"] if ln else contract_address(i)
+                for i, ln in enumerate(links)]
+            self._known_addrs = set(contract_addrs)
         with phase_timer("batch_build", stage="frontier") as build:
             P = C * lanes_per_contract
             cid0 = np.repeat(np.arange(C, dtype=np.int32),
@@ -536,6 +565,7 @@ class SymExecWrapper:
                                 if contract_addrs is not None else None),
                 caller=(CREATOR_ADDRESS if with_creation
                         else ATTACKER_ADDRESS),
+                **({} if systems is None else {"systems": systems}),
             )
             if with_creation:
                 # account table resolves calls/extcode against RUNTIME
@@ -910,14 +940,14 @@ class SymExecWrapper:
                 # and per contract, with what the seam's admission
                 # step needs of the frontier as the transaction left it
                 (err_h, act_h, bad_h, dropped_h, rev_h, home_h,
-                 forks_h, floor_h, cd_h) = fetch(
+                 forks_h, floor_h, cd_h, hop_h) = fetch(
                     (sf.base.err_code, sf.base.active, sf.base.error,
                      sf.dropped_total, sf.base.reverted,
                      sf.base.home_contract, sf.dropped_forks,
-                     sf.mem_floor, sf.cd_reads),
+                     sf.mem_floor, sf.cd_reads, sf.hop_stats),
                     "base.err_code,base.active,base.error,dropped_total,"
                     "base.reverted,base.home_contract,dropped_forks,"
-                    "mem_floor,cd_reads")
+                    "mem_floor,cd_reads,hop_stats")
                 trap_counts = _count_traps(err_h)
                 paths, lost = self._count_tx(
                     int((act_h & ~bad_h).sum()), int(dropped_h))
@@ -926,6 +956,7 @@ class SymExecWrapper:
                 harvest.attrs.update(
                     **self._count_dynamic(floor_h[kept], cd_h[kept],
                                           trap_counts.get("loop_bound", 0)),
+                    **self._count_hops(hop_h, act_h & ~bad_h, of, C),
                     paths=paths, dropped=lost,
                     paths_by_contract=np.bincount(
                         of[act_h & ~bad_h], minlength=C).tolist(),
@@ -973,6 +1004,8 @@ class SymExecWrapper:
                     # anything left to extend?), made here so that the
                     # span ends when the handoff has run on the device
                     passed = fetch(sf.base.active, "base.active")
+                    if self._systems and self._tx_kind == "creation":
+                        sf, passed = self._join_worlds(sf, C, passed)
                     sf, self._carried, fates = self._admit_carried(
                         ended, sf, C, passed, act_h,
                         act_h & (rev_h | bad_h), home_h)
@@ -1200,6 +1233,159 @@ class SymExecWrapper:
         return dict(mem_floored_paths=states["floored"],
                     mem_havoc_paths=states["havoc"],
                     cd_selects=int(reads[0]), loop_trapped=loop_trapped)
+
+    def _count_hops(self, hop, ended, of, C: int) -> dict:
+        """What the paths that ended one transaction alive and without
+        error did at their calls (``hop``: their ``hop_stats`` rows;
+        ``of``: each lane's contract): CALL-family instructions by fate
+        (``engine_calls_total{tx,fate}``), calls to members of the
+        lane's world with code by whether a limit sent them down the
+        external path (``engine_member_calls_total{tx,fate}``) and the
+        symbolic words read across a frame boundary by side and fate
+        (``engine_hop_words_total{side,fate}``). Returns the ``harvest``
+        span's share: per contract the paths that ran three contracts
+        deep."""
+        from ..symbolic import state as st
+
+        reg = obs_metrics.REGISTRY
+        tx = str(self._cur_tx)
+        tot = hop[ended].sum(axis=0, dtype=np.int64)
+        for fate, col in (("internal", st.HOP_INTERNAL), ("eoa", st.HOP_EOA),
+                          ("precompile", st.HOP_PRECOMPILE),
+                          ("external", st.HOP_EXTERNAL)):
+            reg.counter("engine_calls_total",
+                        help="CALL-family instructions on the paths that "
+                             "ended a transaction, by what the call did",
+                        labels={"tx": tx, "fate": fate}).inc(int(tot[col]))
+        trapped = int(tot[st.HOP_TRAPPED])
+        for fate, n in (("framed", int(tot[st.HOP_MEMBER]) - trapped),
+                        ("trapped", trapped)):
+            reg.counter("engine_member_calls_total",
+                        help="calls to a member of the lane's world with "
+                             "code: run as a frame, or sent down the "
+                             "external path by a limit (call depth, "
+                             "calldata window, a symbolic window or value)",
+                        labels={"tx": tx, "fate": fate}).inc(n)
+        for side, fate, col in (
+                ("calldata", "exact", st.HOP_CD_EXACT),
+                ("calldata", "havoc", st.HOP_CD_HAVOC),
+                ("return", "exact", st.HOP_RET_EXACT),
+                ("return", "havoc", st.HOP_RET_HAVOC)):
+            reg.counter("engine_hop_words_total",
+                        help="symbolic words read across a frame boundary "
+                             "(a callee's calldata, a caller's return "
+                             "word): the other frame's node, or a havoc "
+                             "leaf",
+                        labels={"side": side, "fate": fate}).inc(
+                            int(tot[col]))
+        deep = ended & (hop[:, st.HOP_DEPTH] >= 2)
+        out = dict(depth3_by_contract=np.bincount(
+            of[deep], minlength=C).tolist())
+        if self._systems:
+            out["contract_names"] = list(self._member_names)
+        return out
+
+    def _join_worlds(self, sf, C: int, passed):
+        """The creation transaction's seam, for linked systems: the end
+        states of a system's constructors become ONE world (every
+        member's storage rows under its account slot, its balance), and
+        every member's lane starts its message calls from it. A member
+        whose constructor left no end state, or more than one, or state
+        tied to symbols of its own lane's tape, fails its system's
+        deployment: the system's lanes retire and the failure is kept in
+        ``failed_deployments``. A world of more rows than the lane's
+        storage table holds is refused (ValueError), not truncated.
+        Returns the frontier and the mask of the lanes that go on."""
+        import jax.numpy as jnp
+
+        from ..core.frontier import ACCT_CONTRACT0
+        from ..symbolic.ops import N_WELL_KNOWN
+
+        with obs_trace.timer("system_world",
+                             systems=len(self._systems)) as span:
+            leaves = ("base.home_contract", "base.st_used", "base.st_keys",
+                      "base.st_vals", "base.st_acct", "st_val_sym",
+                      "st_key_sym", "st_seq", "st_seq_ctr", "base.acct_bal")
+            (home, used, keys, vals, acct, val_sym, key_sym, seq, ctr,
+             bal) = (np.array(a) for a in fetch(
+                 tuple(attrgetter(a)(sf) for a in leaves), ",".join(leaves)))
+            K = used.shape[1]
+            n_wk = N_WELL_KNOWN(self.limits.calldata_bytes)
+            passed = passed.copy()
+            retire = np.zeros_like(passed)
+            rows_joined = 0
+            for name, members in self._systems.items():
+                lanes = [np.flatnonzero(passed & (home % C == i))
+                         for i in members]
+                why = None
+                for i, ls in zip(members, lanes):
+                    if len(ls) != 1:
+                        why = (f"{self._member_names[i]}: constructor ended "
+                               f"in {len(ls)} states")
+                        break
+                    mine = used[ls[0]]
+                    if (val_sym[ls[0]][mine] > n_wk).any() or (
+                            key_sym[ls[0]][mine] > n_wk).any():
+                        why = (f"{self._member_names[i]}: constructor left "
+                               f"symbolic storage")
+                        break
+                if why is not None:
+                    self.failed_deployments.append(
+                        {"system": name, "reason": why})
+                    for i in members:
+                        retire |= passed & (home % C == i)
+                    continue
+                picks = [(ls[0], np.flatnonzero(used[ls[0]]))
+                         for ls in lanes]
+                n = sum(len(r) for _, r in picks)
+                if n > K:
+                    raise ValueError(
+                        f"system {name}: its deployed world has {n} "
+                        f"storage rows, a lane's table holds "
+                        f"storage_slots={K}")
+                rows_joined += n
+
+                # every member's rows, one after the other; the write
+                # order (``st_seq``) restarts at 1, the members' balances
+                # come from their own lanes, and every member's lane gets
+                # the same world
+                first = picks[0][0]
+                for arr in (used, keys, vals, acct, val_sym, key_sym):
+                    world = np.zeros_like(arr[first])
+                    world[:n] = np.concatenate([arr[l][r] for l, r in picks])
+                    for l, _ in picks:
+                        arr[l] = world
+                world_seq = np.zeros_like(seq[first])
+                world_seq[:n] = np.arange(1, n + 1) * (
+                    np.concatenate([seq[l][r] for l, r in picks]) > 0)
+                world_bal = bal[first].copy()
+                for k, (l, _) in enumerate(picks):
+                    world_bal[ACCT_CONTRACT0 + k] = bal[l][ACCT_CONTRACT0 + k]
+                for l, _ in picks:
+                    seq[l], ctr[l], bal[l] = world_seq, n, world_bal
+            passed &= ~retire
+            b = sf.base
+            sf = sf.replace(
+                base=b.replace(
+                    st_used=jnp.asarray(used), st_keys=jnp.asarray(keys),
+                    st_vals=jnp.asarray(vals), st_acct=jnp.asarray(acct),
+                    acct_bal=jnp.asarray(bal)),
+                st_val_sym=jnp.asarray(val_sym),
+                st_key_sym=jnp.asarray(key_sym),
+                st_seq=jnp.asarray(seq), st_seq_ctr=jnp.asarray(ctr))
+            if retire.any():
+                sf = hold_carried(sf, retire)
+            failed = len(self.failed_deployments)
+            span.attrs.update(rows=rows_joined, failed=failed)
+            reg = obs_metrics.REGISTRY
+            reg.counter("engine_systems_deployed_total",
+                        help="linked systems whose constructors' end "
+                             "states were joined into one world").inc(
+                                 len(self._systems) - failed)
+            reg.counter("engine_system_deployments_failed_total",
+                        help="linked systems a member of which left no "
+                             "single concrete end state").inc(failed)
+        return sf, passed
 
     def _dynld_between_txs(self, sf, names):
         """Fetch code for this tx's concrete-but-unknown call targets.

@@ -346,6 +346,7 @@ def make_frontier(
     callvalue: int = 0,
     balance: int = 10**18,
     attacker_balance: int = 10**20,
+    systems: Optional[Sequence[Optional[Sequence[int]]]] = None,
 ) -> Frontier:
     """Fresh frontier with a seeded per-lane world state.
 
@@ -354,6 +355,15 @@ def make_frontier(
     table when ``2 + n_contracts <= max_accounts``; otherwise each lane
     registers only its own contract at slot 2. The executing account
     (``cur_acct``) is the lane's own contract.
+
+    ``systems`` (a campaign batch that holds linked systems): for each
+    contract the indices of the members of ITS system, in the manifest's
+    order and itself among them, or None for a contract that is alone.
+    A member's lanes then hold attacker, creator and the members of that
+    one system from slot 2 on, at ``contract_addrs``' addresses, and
+    nothing of the batch's other contracts; a contract that is alone
+    registers only itself. Without ``systems`` the all-or-own rule above
+    stands as it was.
     """
     P = n_lanes
     L = limits
@@ -388,7 +398,24 @@ def make_frontier(
     bal[:, ACCT_CREATOR] = u256.from_int(attacker_balance)
     used[:, ACCT_CREATOR] = True
     cid_np = np.asarray(contract_id)
-    if ACCT_CONTRACT0 + C <= A:
+    if systems is not None:
+        cur_acct = np.full(P, ACCT_CONTRACT0, dtype=np.int32)
+        for i in range(C):
+            members = list(systems[i]) if systems[i] is not None else [i]
+            if ACCT_CONTRACT0 + len(members) > A:
+                raise ValueError(
+                    f"a system of {len(members)} members does not fit "
+                    f"max_accounts={A} (attacker, creator, then at most "
+                    f"{A - ACCT_CONTRACT0} members)")
+            lanes = np.flatnonzero(cid_np == i)
+            for k, j in enumerate(members):
+                addr[lanes, ACCT_CONTRACT0 + k] = u256.from_int(
+                    contract_addrs[j])
+                code[lanes, ACCT_CONTRACT0 + k] = j
+                bal[lanes, ACCT_CONTRACT0 + k] = u256.from_int(balance)
+                used[lanes, ACCT_CONTRACT0 + k] = True
+            cur_acct[lanes] = ACCT_CONTRACT0 + members.index(i)
+    elif ACCT_CONTRACT0 + C <= A:
         for i, a in enumerate(contract_addrs):
             addr[:, ACCT_CONTRACT0 + i] = u256.from_int(a)
             code[:, ACCT_CONTRACT0 + i] = i

@@ -80,15 +80,22 @@ def corpus_fingerprint(contracts: Sequence[tuple]) -> str:
     the checkpoint shard stamp and the fleet manifest both need (a count
     alone cannot tell "same corpus" from "same size"). Creation code is
     content: two corpora that differ only in a constructor deploy to
-    different storage. A pair hashes as it always did."""
+    different storage. So is a record's place in a linked system (its
+    fourth field, ``mythril/campaign.py`` ``load_corpus_dir``). A pair
+    hashes as it always did."""
     h = hashlib.sha256()
-    for name, code, creation in map(contract_record, contracts):
+    for item in contracts:
+        name, code, creation = contract_record(item)
         h.update(str(name).encode())
         h.update(b"\0")
         h.update(hashlib.sha256(bytes(code)).digest())
         if creation is not None:
             h.update(b"\1")
             h.update(hashlib.sha256(bytes(creation)).digest())
+        if len(item) > 3 and item[3]:
+            # a member of a linked system: which, and where it lives
+            h.update(b"\2")
+            h.update(f"{item[3]['system']}@{item[3]['address']:x}".encode())
     return h.hexdigest()[:16]
 
 

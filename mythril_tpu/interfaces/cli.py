@@ -918,7 +918,8 @@ def _exec_campaign(args) -> int:
               "ledger; drop --corpus (or drop --fleet-follow for a "
               "static fleet)", file=sys.stderr)
         raise SystemExit(2)
-    contracts = [] if fleet_follow else load_corpus_dir(args.corpus)
+    contracts = [] if fleet_follow else load_corpus_dir(
+        args.corpus, max_members=_limits_for(args).max_accounts - 2)
     if args.fleet:
         # the ledger IS the work distribution: every worker sees the
         # whole corpus and claims leased units (docs/fleet.md); a

@@ -95,14 +95,36 @@ _PAD_CREATION = b"\x00"
 _WORKER_WARM = ("worker-resident",)
 
 
-def load_corpus_dir(path: str) -> List[tuple]:
+#: a corpus directory's manifest of one linked system ends in this
+SYSTEM_MANIFEST = ".system.json"
+
+
+def load_corpus_dir(path: str,
+                    max_members: Optional[int] = None) -> List[tuple]:
     """The corpus under ``path``, sorted for a stable batch order: one
     ``(name, runtime bytecode)`` pair for every *.hex / *.bin /
     *.bin-runtime file (hex-encoded, 0x prefix optional), except that
     ``X.bin`` beside ``X.bin-runtime`` (what ``solc --bin --bin-runtime``
     writes) is ONE contract, ``(X, runtime, creation bytecode)``: the
     campaign runs its constructor and starts the message calls from
-    the storage it left."""
+    the storage it left.
+
+    A ``S.system.json`` file is the manifest of a linked system::
+
+        {"system": "S", "members": [{"name": "X", "address": "0x.."}, ..]}
+
+    Its members (files of the directory, named without their suffix) are
+    contracts that call each other at those addresses; the order is the
+    order they deploy in. Each comes back as ``(X, runtime, creation or
+    None, {"system": "S", "address": int})``, the members of a system
+    together and in the manifest's order, where its first member sorts.
+    The campaign keeps a system whole: one batch, one world, one item of
+    retry, bisection and quarantine. ``max_members`` (``max_accounts -
+    2``: attacker, creator, then the members) refuses a larger system
+    here. The manifest's presence is the switch: a directory without one
+    reads as it always did."""
+    import json
+
     from ..disassembler.disassembly import _to_bytes
 
     def read(fn):
@@ -114,7 +136,32 @@ def load_corpus_dir(path: str) -> List[tuple]:
     creation_of = {fn: fn[:-len("-runtime")] for fn in files
                    if fn.endswith(".bin-runtime")
                    and fn[:-len("-runtime")] in files}
-    out = []
+    link_of: Dict[str, dict] = {}
+    members_of: Dict[str, List[str]] = {}
+    for fn in files:
+        if not fn.endswith(SYSTEM_MANIFEST):
+            continue
+        doc = json.loads(read(fn))
+        system = str(doc.get("system") or fn[:-len(SYSTEM_MANIFEST)])
+        members = doc.get("members") or []
+        if max_members is not None and len(members) > max_members:
+            raise ValueError(
+                f"{fn}: a system of {len(members)} members does not fit: "
+                f"a lane's account table holds attacker, creator and at "
+                f"most {max_members} members (max_accounts - 2)")
+        if system in members_of or not members:
+            raise ValueError(f"{fn}: system {system!r} is empty or has a "
+                             f"second manifest")
+        addrs = set()
+        for m in members:
+            name, addr = str(m["name"]), int(str(m["address"]), 16)
+            if name in link_of or addr in addrs:
+                raise ValueError(f"{fn}: member {name!r} or its address "
+                                 f"appears twice")
+            addrs.add(addr)
+            link_of[name] = {"system": system, "address": addr}
+        members_of[system] = [str(m["name"]) for m in members]
+    ordered: List[tuple] = []
     for fn in files:
         if not fn.endswith((".hex", ".bin", ".bin-runtime")) \
                 or fn in creation_of.values():
@@ -124,19 +171,63 @@ def load_corpus_dir(path: str) -> List[tuple]:
             continue
         rec = (fn.rsplit(".", 1)[0], _to_bytes(text))
         creation = read(creation_of[fn]) if fn in creation_of else ""
-        out.append(rec + (_to_bytes(creation),) if creation else rec)
+        ordered.append(rec + (_to_bytes(creation),) if creation else rec)
+    recs = {rec[0]: rec for rec in ordered}
+    missing = sorted(set(link_of) - set(recs))
+    if missing:
+        raise ValueError(f"manifest member(s) without a code file under "
+                         f"{path}: {', '.join(missing)}")
+    out, placed = [], set()
+    for rec in ordered:
+        name = rec[0]
+        if name not in link_of:
+            out.append(rec)
+            continue
+        system = link_of[name]["system"]
+        if system in placed:
+            continue
+        placed.add(system)
+        for member in members_of[system]:
+            n, code, *rest = recs[member]
+            out.append((n, code, rest[0] if rest else None,
+                        link_of[member]))
     if not out:
         raise ValueError(f"no *.hex / *.bin corpus files under {path}")
     return out
 
 
+def record_link(item: Sequence) -> Optional[dict]:
+    """The system a corpus record belongs to (``{"system", "address"}``),
+    or None for a contract that is alone in its world."""
+    return item[3] if len(item) > 3 and item[3] else None
+
+
+def corpus_units(items: Sequence[tuple]) -> List[List[tuple]]:
+    """``items`` cut into the campaign's units: the members of one
+    linked system, which stand together, are ONE unit; every other
+    contract is a unit of its own."""
+    units: List[List[tuple]] = []
+    last = None
+    for it in items:
+        ln = record_link(it)
+        if ln is not None and last == ln["system"]:
+            units[-1].append(it)
+        else:
+            units.append([it])
+        last = ln["system"] if ln is not None else None
+    return units
+
+
 def _split_records(items: Sequence[tuple]):
-    """``(names, runtime codes, creation codes)`` of a batch; the last
-    is None when no contract of it has creation code."""
+    """``(names, runtime codes, creation codes, links)`` of a batch; the
+    creation codes are None when no contract of it has any, the links
+    (:func:`record_link` of each) when no contract belongs to a system."""
     recs = [contract_record(i) for i in items]
     creations = [k for _, _, k in recs]
+    links = [record_link(i) for i in items]
     return ([n for n, _, _ in recs], [c for _, c, _ in recs],
-            creations if any(k is not None for k in creations) else None)
+            creations if any(k is not None for k in creations) else None,
+            links if any(links) else None)
 
 
 class _HostPhaseStart:
@@ -311,6 +402,33 @@ class CorpusCampaign:
         if num_hosts > 1:
             contracts = contracts[host_index::num_hosts]
         self.contracts = contracts
+        # linked systems (``load_corpus_dir``: a manifest's members) are
+        # units of a batch: never split, so the batches are cut on unit
+        # boundaries. Without any the cut is every ``batch_size``
+        # contracts, by arithmetic, as it always was (``_cuts`` None)
+        self._cuts: Optional[List[Tuple[int, int]]] = None
+        if any(record_link(c) for c in contracts):
+            if num_hosts > 1 or fleet_dir is not None or fleet_follow:
+                raise ValueError(
+                    "a corpus with linked systems runs as one campaign: "
+                    "--num-hosts and --fleet cut a corpus by position "
+                    "and would split a system")
+            room = limits.max_accounts - 2
+            self._cuts, start, n = [], 0, 0
+            for unit in corpus_units(contracts):
+                if len(unit) > min(room, batch_size):
+                    raise ValueError(
+                        f"system {record_link(unit[0])['system']!r} has "
+                        f"{len(unit)} members: a lane's account table "
+                        f"holds attacker, creator and at most {room} "
+                        f"members (max_accounts - 2 = {room}) and a "
+                        f"batch {batch_size} contracts")
+                if n + len(unit) > batch_size:
+                    self._cuts.append((start, start + n))
+                    start, n = start + n, 0
+                n += len(unit)
+            if n:
+                self._cuts.append((start, start + n))
         # content identity of THIS host's slice: stamped into campaign
         # checkpoints (a resumed run must prove it is analyzing the same
         # contracts, not just the same count) and the fleet manifest
@@ -469,11 +587,11 @@ class CorpusCampaign:
             raise ValueError(
                 f"worker_isolation {worker_isolation!r}: must be "
                 "'on', 'off' or 'auto'")
-        if isolate and (self.plugins
+        if isolate and (self.plugins or self._cuts is not None
                         or getattr(self.spec, "mesh", None) is not None):
             log.warning("worker isolation disabled: plugins / sharded "
-                        "specs cannot cross the worker process "
-                        "boundary")
+                        "specs / linked systems do not cross the worker "
+                        "process boundary")
             isolate = False
         self.worker_isolation = isolate
         self._supervisor = worker_supervisor
@@ -499,6 +617,25 @@ class CorpusCampaign:
             tier_manager.on_event = self._tier_event
         elif tier_manager is None and backend_tiers is not None:
             self._tier_manager()
+
+    # --- batches -------------------------------------------------------
+    @property
+    def n_batches(self) -> int:
+        if self._cuts is not None:
+            return len(self._cuts)
+        return (len(self.contracts) + self.batch_size - 1) // self.batch_size
+
+    def _batch_items(self, bi: int) -> List[tuple]:
+        """The contracts of batch ``bi``: every ``batch_size`` by
+        position, or, with linked systems, whole units up to it."""
+        lo, hi = (self._cuts[bi] if self._cuts is not None else
+                  (bi * self.batch_size, (bi + 1) * self.batch_size))
+        return self.contracts[lo:hi]
+
+    def _contracts_done(self, batches: int) -> int:
+        if self._cuts is not None:
+            return self._cuts[batches - 1][1] if batches else 0
+        return min(batches * self.batch_size, len(self.contracts))
 
     # --- checkpointing -------------------------------------------------
     @property
@@ -650,7 +787,8 @@ class CorpusCampaign:
                        lanes: Optional[int] = None,
                        width: Optional[int] = None,
                        creations: Optional[List[Optional[bytes]]] = None,
-                       on_first_call=None):
+                       on_first_call=None,
+                       links: Optional[List[Optional[dict]]] = None):
         """DEVICE phase of one batch: pad to the compiled width and run
         the exploration (SymExecWrapper packs the corpus and drives the
         ``sym_run`` chunks — the dispatches are async under JAX; only
@@ -668,8 +806,12 @@ class CorpusCampaign:
         holds ``2 x width`` images, an engine shape class of its own,
         explored as every other batch is (one lane pool, chunks, spill,
         drain). ``on_first_call`` is the wrapper's hook of that name: it
-        fires once, when the first ``sym_run`` call is enqueued. Returns
-        the finished wrapper for :meth:`_harvest_batch`."""
+        fires once, when the first ``sym_run`` call is enqueued. With
+        ``links`` (:func:`record_link` of each contract) the batch holds
+        linked systems: the wrapper gives a system's lanes its members
+        at their addresses and joins its constructors' end states into
+        one world. Returns the finished wrapper for
+        :meth:`_harvest_batch`."""
         from ..analysis import SymExecWrapper
 
         width = self.batch_size if width is None else width
@@ -684,6 +826,9 @@ class CorpusCampaign:
             creations = [k if k is not None else _PAD_CREATION
                          for k in creations]
             creations += [_PAD_CREATION] * (width - len(creations))
+        if links is not None:
+            links = list(links) + [None] * (width - len(links))
+        if creations is not None:
             obs_metrics.REGISTRY.counter(
                 "campaign_contracts_deployed_total",
                 help="contracts whose constructor a campaign batch "
@@ -703,6 +848,7 @@ class CorpusCampaign:
             warm_shapes=self._warm_set(lanes, width,
                                        creations is not None),
             on_first_call=on_first_call,
+            **({} if links is None else {"links": links}),
         )
         # compile counters as of the END of this device phase: device
         # phases never overlap each other, so a batch that compiled no
@@ -977,6 +1123,9 @@ class CorpusCampaign:
             issues.append(d)
         from ..backend import engine_report
 
+        for failed in getattr(sym, "failed_deployments", ()):
+            self._event("system_deploy_failed", batch=bi,
+                        detail=failed["reason"], system=failed["system"])
         return {
             "issues": issues,
             "paths": int(cov.get("surviving_paths", 0)),
@@ -1023,7 +1172,8 @@ class CorpusCampaign:
     def _exec_batch(self, bi: int, names: List[str], codes: List[bytes],
                     lanes: Optional[int] = None,
                     width: Optional[int] = None,
-                    creations: Optional[List[Optional[bytes]]] = None
+                    creations: Optional[List[Optional[bytes]]] = None,
+                    links: Optional[List[Optional[dict]]] = None
                     ) -> Dict:
         """Analyze one (padded) batch; returns the batch's partial
         results. Serial composition of the device + host phases — the
@@ -1034,7 +1184,7 @@ class CorpusCampaign:
         with obs_device.phase_timer("device_phase", bi=bi,
                                     n=len(names)) as dv:
             sym = self._explore_batch(bi, names, codes, lanes, width,
-                                      creations)
+                                      creations, links=links)
         with obs_device.phase_timer("host_phase", bi=bi) as hp:
             out = self._harvest_batch(bi, sym)
         acc = getattr(self, "_phase_acc", None)
@@ -1340,7 +1490,7 @@ class CorpusCampaign:
         falls through to the in-process path on the demoted tier, and
         the tier manager's prober climbs back when the better tier
         probes healthy again (no permanent pin)."""
-        names, codes, creations = _split_records(items)
+        names, codes, creations, links = _split_records(items)
 
         # batch boundaries are where tier transitions land: give a due
         # re-promotion its chance, then fold any transition (from here
@@ -1383,6 +1533,8 @@ class CorpusCampaign:
             # names and runtime codes, as ever
             if self._batch_runner is None:
                 kw = {} if creations is None else {"creations": creations}
+                if links is not None:
+                    kw["links"] = links
                 return self._exec_batch(bi, names, codes, lanes=lanes,
                                         width=width, **kw)
             if not self._runner_degradable:
@@ -1423,7 +1575,7 @@ class CorpusCampaign:
         and the host phase passes the finished result through."""
         if self._worker_enabled():
             return ("out", self._guarded_batch(bi, items))
-        names, codes, creations = _split_records(items)
+        names, codes, creations, links = _split_records(items)
 
         def work():
             if self.fault_injector is not None:
@@ -1435,7 +1587,7 @@ class CorpusCampaign:
                                                   lanes=None, width=None))
             return ("sym", self._explore_batch(
                 bi, names, codes, creations=creations,
-                on_first_call=on_first_call))
+                on_first_call=on_first_call, links=links))
 
         return run_with_watchdog(work, self.batch_timeout,
                                  label=f"batch {bi} device")
@@ -1543,8 +1695,8 @@ class CorpusCampaign:
                         batch=bi, step=rung, lanes=lanes, width=width)
             try:
                 out = {"issues": [], "paths": 0, "dropped": 0, "iprof": {}}
-                for k in range(0, len(items), width):
-                    r = self._guarded_batch(bi, items[k:k + width],
+                for sub in self._sub_batches(items, width):
+                    r = self._guarded_batch(bi, sub,
                                             lanes=lanes, width=width,
                                             on_tier=on_tier)
                     out["issues"].extend(r["issues"])
@@ -1561,6 +1713,19 @@ class CorpusCampaign:
                 log.warning("batch %d still RESOURCE_EXHAUSTED after "
                             "%s (%s)", bi, rung, self._fault_reason(e))
         raise err
+
+    @staticmethod
+    def _sub_batches(items: Sequence[tuple], width: int) -> List[list]:
+        """``items`` in sub-batches of at most ``width`` contracts, cut
+        on unit boundaries (a linked system stays whole; one larger than
+        ``width`` is a sub-batch of its own, which the narrower shape
+        then refuses: the rung fails and the ladder goes on)."""
+        out: List[list] = [[]]
+        for unit in corpus_units(items):
+            if out[-1] and len(out[-1]) + len(unit) > width:
+                out.append([])
+            out[-1].extend(unit)
+        return [b for b in out if b]
 
     def _run_batch_resilient(self, bi: int,
                              items: Sequence[tuple],
@@ -1639,20 +1804,27 @@ class CorpusCampaign:
                 err = e
                 self._note_failure(e)
         # bisect: a failing group splits in half; a failing singleton is
-        # the poison — quarantine it and keep going
-        groups = [list(items)]
+        # the poison — quarantine it and keep going. The unit is what
+        # ``corpus_units`` says: a linked system is one item (its
+        # members are never analysed apart, so a poisoned member
+        # quarantines its system, and every record says which)
+        groups = [corpus_units(items)]
         while groups:
             g = groups.pop()
             try:
-                merge(self._guarded_batch(bi, g))
+                merge(self._guarded_batch(bi, [c for u in g for c in u]))
             except Exception as e:  # noqa: BLE001
                 self._note_failure(e)
                 if len(g) == 1:
-                    out["quarantined"].append({
-                        "name": g[0][0],
-                        "reason": self._fault_reason(e),
-                        "batch": bi,
-                    })
+                    link = record_link(g[0][0])
+                    for member in g[0]:
+                        out["quarantined"].append({
+                            "name": member[0],
+                            "reason": self._fault_reason(e),
+                            "batch": bi,
+                            **({} if link is None
+                               else {"system": link["system"]}),
+                        })
                 else:
                     mid = len(g) // 2
                     groups.append(g[mid:])
@@ -1695,7 +1867,7 @@ class CorpusCampaign:
         operator's 'is it still making progress, and at what cost'
         pulse — without grepping four channels."""
         wall = sum(res.batch_wall)
-        contracts = min(done * self.batch_size, len(self.contracts))
+        contracts = self._contracts_done(done)
         pps = res.paths_total / wall if wall else 0.0
         # contracts/min: the end-to-end headline rate (ROADMAP "Kill the
         # P-scaling cliff" makes it the number next to lane-steps/s) —
@@ -1926,8 +2098,7 @@ class CorpusCampaign:
             for bi in range(start_batch, n_batches):
                 if deadline is not None and time.monotonic() >= deadline:
                     break
-                items = self.contracts[
-                    bi * self.batch_size:(bi + 1) * self.batch_size]
+                items = self._batch_items(bi)
                 t_wall, t_mono = time.time(), time.monotonic()
                 dev_sp = obs_device.phase_timer(
                     "device_phase", bi=bi, n=len(items)).start()
@@ -2254,7 +2425,7 @@ class CorpusCampaign:
                        if self.backend is not None else [])
                     + list(self._events))
 
-        n_batches = (len(self.contracts) + self.batch_size - 1) // self.batch_size
+        n_batches = self.n_batches
         dirty = [False]  # mutable: commit() below flips it
         start_batch = int(state["next_batch"])
         reg = obs_metrics.REGISTRY
@@ -2339,8 +2510,7 @@ class CorpusCampaign:
                     if (deadline is not None
                             and time.monotonic() >= deadline):
                         break
-                    batch = self.contracts[
-                        bi * self.batch_size:(bi + 1) * self.batch_size]
+                    batch = self._batch_items(bi)
                     with obs_trace.timer("batch", bi=bi,
                                          n=len(batch)) as sp:
                         out = self._run_batch_resilient(bi, batch)
@@ -2367,7 +2537,7 @@ class CorpusCampaign:
             raise
 
         res.batches = len(res.batch_wall)
-        res.contracts = min(res.batches * self.batch_size, len(self.contracts))
+        res.contracts = self._contracts_done(res.batches)
         res.wall_sec = time.monotonic() - t_start
         res.compile_sec = res.batch_wall[0] if res.batch_wall else 0.0
         res.backend_events = session_events()

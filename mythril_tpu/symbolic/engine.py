@@ -21,12 +21,28 @@ the lane's ``mem_floor`` after a store or a copy at a symbolic offset
 invalidated memory from its destination's concrete base up (solc's free
 pointer after it decoded a dynamic argument: the scratch words that hash
 every mapping slot stay exact); a ``CALLDATALOAD`` at a symbolic offset
-in the top frame (a ``CD_SELECT`` node over the transaction's bytes).
-Still a havoc leaf: an unaligned access that meets a symbolic word;
-``ADDMOD`` / ``MULMOD`` over symbols; a calldata read beyond the
-modelled window, or at a symbolic offset inside a sub-frame; any word at
-or above the floor, those a ``CALLDATACOPY`` filled among them; all of
-memory after a store or a copy whose destination has no concrete base.
+in the top frame (a ``CD_SELECT`` node over the transaction's bytes);
+**a word stored whole by one ``MSTORE`` at an unaligned offset and read
+back whole at the same offset**, on both sides of a hop (``mem_usym`` /
+``cd_usym``: one byte shift a lane, which is how solc's ABI encoder lays
+a call out, the selector word at ``ptr`` and argument ``k`` at ``ptr + 4
++ 32k``): an ``MLOAD`` there, the callee's ``CALLDATALOAD(4 + 32k)`` of
+an aligned call window, and a concrete argument among symbolic ones; the
+callee's selector, a ``SHR`` of calldata word 0 that keeps only the
+bytes that were concrete under the first argument (``head_node``); a
+return word through an aligned window (``rv_sym``, as before).
+Still a havoc leaf: an unaligned access that meets a symbolic word
+anywhere else (another shift, a word that an aligned store, an
+``MSTORE8``, a copy or a call's output has written into since, a word
+the caller keeps across a call: its own whole words end when the frame
+pops); the ALIGNED words an unaligned symbolic store covers, and the
+partly covered tail word of a call window (one leaf for that word, no
+longer the whole frame's calldata); a call window at an unaligned
+offset that holds a symbol; ``ADDMOD`` / ``MULMOD`` over symbols; a
+calldata read beyond the modelled window, or at a symbolic offset inside
+a sub-frame; any word at or above the floor, those a ``CALLDATACOPY``
+filled among them; all of memory after a store or a copy whose
+destination has no concrete base.
 A havoc leaf is tied to nothing, so the engine may explore infeasible
 paths; it misses no feasible one AS LONG AS what the leaf stands for is
 not read back through concrete state: from concrete storage a mapping
@@ -52,7 +68,10 @@ from ..core.frontier import (Frontier, Env, Corpus, Trap, CAP_TRAPS,
                              CODE_UNKNOWN)
 from ..ops import u256
 from .ops import SymOp, FreeKind, TX_STRIDE, BAL_STRIDE
-from .state import MEM_EXACT, SymFrontier, SymSpec
+from .state import (MEM_EXACT, USYM_CONCRETE, SymFrontier, SymSpec,
+                    HOP_INTERNAL, HOP_EOA, HOP_PRECOMPILE, HOP_EXTERNAL,
+                    HOP_MEMBER, HOP_TRAPPED, HOP_CD_EXACT, HOP_CD_HAVOC,
+                    HOP_RET_EXACT, HOP_RET_HAVOC, HOP_DEPTH, N_HOP)
 # imported here, outside any trace, for its module-level jnp constants:
 # ``_sym_run_impl`` imports from it while it is being traced, and a
 # first import there would build them as tracers of that trace
@@ -529,6 +548,15 @@ def _note_backjump(sf: SymFrontier, mask, src, dest, loop_bound: int) -> SymFron
     )
 
 
+def _hop_count(stats, *counts):
+    """``hop_stats`` with ``(column, i32[P])`` pairs added: a dense add
+    (a column update is a scatter to the TPU)."""
+    cols = jnp.arange(stats.shape[1])[None, :]
+    for col, n in counts:
+        stats = stats + jnp.where(cols == col, n.astype(I32)[:, None], 0)
+    return stats
+
+
 def _fr_set(arr, d, val, mask):
     """arr[P, D, ...]; arr[lane, d[lane]] = val[lane] where mask.
     Backend-adaptive (interpreter._write_slot): scatter on CPU; on TPU a
@@ -798,6 +826,16 @@ def _h_sym_call(sf: SymFrontier, corpus: Corpus, op, m, old_pc,
     # --- frame push for internal calls
     d = f.depth
     mi = internal_go
+    # a partly covered tail word of the callee's calldata with symbolic
+    # content is unknown on its own (the end of the last ABI argument
+    # lies in it): one fresh leaf, not the whole frame's calldata
+    aligned_a = (a_off % 32) == 0
+    w0 = (a_off // 32).astype(I32)
+    tail_w = (a_len // 32).astype(I32)
+    tail_sym = aligned_a & ((a_len % 32) != 0) & (
+        _take_word_sym(sf.mem_sym, w0 + tail_w) != 0)
+    sf, tail_hv = _havoc(sf, mi & tail_sym)
+    f = sf.base
     # EIP-150 gas forwarding: the callee runs under
     # used + min(gas operand, 63/64 * remaining); a symbolic gas operand
     # forwards the cap (all-but-one-64th). pop_frames restores the
@@ -847,8 +885,6 @@ def _h_sym_call(sf: SymFrontier, corpus: Corpus, op, m, old_pc,
     # per-word syms: aligned windows map caller mem_sym; a partially
     # covered tail word or unaligned offset with symbolic content havocs
     # the whole frame calldata (coarse, sound)
-    aligned_a = (a_off % 32) == 0
-    w0 = (a_off // 32).astype(I32)
     W = sf.mem_sym.shape[1]
     wids = jnp.arange(W)[None, :]
     win_lo = (a_off // 32)[:, None]
@@ -856,17 +892,39 @@ def _h_sym_call(sf: SymFrontier, corpus: Corpus, op, m, old_pc,
     any_sym_window = jnp.any(
         (wids >= win_lo) & (wids < win_hi) & (sf.mem_sym != 0), axis=1
     )
-    tail_partial = (a_len % 32) != 0
-    tail_sym = tail_partial & (_take_word_sym(sf.mem_sym, w0 + (a_len // 32).astype(I32)) != 0)
     cd_havoc_new = (_reaches_floor(sf, a_off, a_len)
-                    | (~aligned_a & any_sym_window) | (aligned_a & tail_sym))
+                    | (~aligned_a & any_sym_window))
     cd_sym_new = jnp.zeros_like(sf.cd_sym)
+    cd_usym_new = jnp.zeros_like(sf.cd_usym)
+    ush = sf.mem_ushift.astype(I64)
     for w in range(CDW):
         full_cover = aligned_a & ((32 * (w + 1)) <= a_len)
         src = _take_word_sym(sf.mem_sym, w0 + w)
         cd_sym_new = cd_sym_new.at[:, w].set(
-            jnp.where(mi & full_cover & ~cd_havoc_new, src, 0)
+            jnp.where(mi & ~cd_havoc_new,
+                      jnp.where(full_cover, src,
+                                jnp.where(tail_sym & (tail_w == w),
+                                          tail_hv, 0)), 0)
         )
+        # the words stored whole at the lane's shift, inside the window
+        whole_in = aligned_a & (ush > 0) & ((32 * (w + 1)) + ush <= a_len)
+        cd_usym_new = cd_usym_new.at[:, w].set(
+            jnp.where(mi & whole_in & ~cd_havoc_new,
+                      _take_word_sym(sf.mem_usym, w0 + w), 0))
+    cd_uhead_new = (mi & aligned_a & ~cd_havoc_new & (sf.mem_uhead == w0)
+                    & (a_len >= ush))
+    # calls to a member of the lane's world with code, and those of them
+    # a limit sent down the external path
+    member = m & ~enum_hold & (to_sym == 0) & found & (callee_code >= 0)
+    hop_stats = _hop_count(
+        sf.hop_stats, (HOP_INTERNAL, internal_go), (HOP_EOA, eoa_ok),
+        (HOP_PRECOMPILE, pre), (HOP_EXTERNAL, external),
+        (HOP_MEMBER, member), (HOP_TRAPPED, member & external))
+    # the deepest frame of the path: a maximum, not a count
+    hop_stats = jnp.where(
+        jnp.arange(N_HOP)[None, :] == HOP_DEPTH,
+        jnp.maximum(hop_stats, jnp.where(mi, f.depth + 1, 0)[:, None]),
+        hop_stats)
 
     new_caller = jnp.where(is_deleg[:, None], f.caller_addr, f.self_address).astype(U32)
     new_value = jnp.where(
@@ -908,6 +966,13 @@ def _h_sym_call(sf: SymFrontier, corpus: Corpus, op, m, old_pc,
         base=f2,
         stack_sym=stack_sym,
         mem_sym=jnp.where(mi[:, None], 0, sf.mem_sym),
+        # the callee starts on fresh memory; a precompile writes its
+        # output into the caller's (the caller's own whole words end when
+        # the frame pops: ``pop_frames``)
+        mem_usym=jnp.where((mi | pre)[:, None], 0, sf.mem_usym),
+        mem_ushift=jnp.where(mi, 0, sf.mem_ushift),
+        mem_uhead=jnp.where(mi | pre, -1, sf.mem_uhead),
+        hop_stats=hop_stats,
         mem_floor=jnp.where(mi, MEM_EXACT, _lower_floor(
             sf.mem_floor, havoc_mem, _dest_floor_word(sf, r_off, r_off_s))),
         retdata_sym=jnp.where(mi | eoa_ok | fail0, False,
@@ -915,6 +980,12 @@ def _h_sym_call(sf: SymFrontier, corpus: Corpus, op, m, old_pc,
         cd_from_mem=sf.cd_from_mem | mi,
         cd_havoc=jnp.where(mi, cd_havoc_new, sf.cd_havoc),
         cd_sym=jnp.where(mi[:, None], cd_sym_new, sf.cd_sym),
+        cd_usym=jnp.where(mi[:, None], cd_usym_new, sf.cd_usym),
+        cd_ushift=jnp.where(mi, sf.mem_ushift, sf.cd_ushift),
+        cd_uhead=jnp.where(mi, cd_uhead_new, sf.cd_uhead),
+        fr_cd_usym=_fr_set(sf.fr_cd_usym, d, sf.cd_usym, mi),
+        fr_cd_ushift=_fr_set(sf.fr_cd_ushift, d, sf.cd_ushift, mi),
+        fr_cd_uhead=_fr_set(sf.fr_cd_uhead, d, sf.cd_uhead, mi),
         callvalue_sym=jnp.where(mi, new_value_sym, sf.callvalue_sym),
         caller_sym=jnp.where(mi, new_caller_sym, sf.caller_sym),
         fr_caller_sym=_fr_set(sf.fr_caller_sym, d, sf.caller_sym, mi),
@@ -1470,6 +1541,15 @@ def _push_create_frame(sf: SymFrontier, mi, is_c2, slot, sin, off, ln, salt,
         cd_from_mem=sf.cd_from_mem | mi,
         cd_havoc=jnp.where(mi, False, sf.cd_havoc),
         cd_sym=jnp.where(mi[:, None], 0, sf.cd_sym),
+        mem_usym=jnp.where(mi[:, None], 0, sf.mem_usym),
+        mem_ushift=jnp.where(mi, 0, sf.mem_ushift),
+        mem_uhead=jnp.where(mi, -1, sf.mem_uhead),
+        cd_usym=jnp.where(mi[:, None], 0, sf.cd_usym),
+        cd_ushift=jnp.where(mi, 0, sf.cd_ushift),
+        cd_uhead=jnp.where(mi, False, sf.cd_uhead),
+        fr_cd_usym=_fr_set(sf.fr_cd_usym, d, sf.cd_usym, mi),
+        fr_cd_ushift=_fr_set(sf.fr_cd_ushift, d, sf.cd_ushift, mi),
+        fr_cd_uhead=_fr_set(sf.fr_cd_uhead, d, sf.cd_uhead, mi),
         callvalue_sym=jnp.where(mi, 0, sf.callvalue_sym),
         caller_sym=jnp.where(mi, 0, sf.caller_sym),
         fr_caller_sym=_fr_set(sf.fr_caller_sym, d, sf.caller_sym, mi),
@@ -1548,10 +1628,18 @@ def pop_frames(sf: SymFrontier, corpus: Corpus) -> SymFrontier:
         (jnp.arange(RDW)[None, :] == (n_rd // 32)[:, None]) & (sf.rv_sym != 0),
         axis=1,
     )
-    mem_floor = _lower_floor(mem_floor, has_rd & (
+    rd_havoc = has_rd & (
         (sf.rv_havoc & (r_len > 0)) | (~roff_al & rv_words_sym)
         | (roff_al & tail_sym_rd)
-    ), _floor_word(r_off))
+    )
+    mem_floor = _lower_floor(mem_floor, rd_havoc, _floor_word(r_off))
+    # symbolic return words the caller can read back, by what it reads
+    n_rv = jnp.sum(((jnp.arange(RDW)[None, :] * 32 < n_rd[:, None])
+                    & (sf.rv_sym != 0)).astype(I32), axis=1, dtype=I32)
+    n_rv = jnp.where(has_rd, n_rv, 0)
+    hop_stats = _hop_count(
+        sf.hop_stats, (HOP_RET_EXACT, jnp.where(rd_havoc, 0, n_rv)),
+        (HOP_RET_HAVOC, jnp.where(rd_havoc, jnp.maximum(n_rv, 1), 0)))
 
     # storage + balance rollback on failure
     def roll(cur, snap):
@@ -1688,6 +1776,15 @@ def pop_frames(sf: SymFrontier, corpus: Corpus) -> SymFrontier:
         cd_from_mem=jnp.where(mp, _fr_get(sf.fr_cd_from_mem, d), sf.cd_from_mem),
         cd_havoc=jnp.where(mp, _fr_get(sf.fr_cd_havoc, d), sf.cd_havoc),
         cd_sym=jnp.where(mp[:, None], _fr_get(sf.fr_cd_sym, d), sf.cd_sym),
+        cd_usym=jnp.where(mp[:, None], _fr_get(sf.fr_cd_usym, d), sf.cd_usym),
+        cd_ushift=jnp.where(mp, _fr_get(sf.fr_cd_ushift, d), sf.cd_ushift),
+        cd_uhead=jnp.where(mp, _fr_get(sf.fr_cd_uhead, d), sf.cd_uhead),
+        # the caller's own whole words are not kept across the call (its
+        # aligned words hold leaves where they lay: sound)
+        mem_usym=jnp.where(mp[:, None], 0, sf.mem_usym),
+        mem_ushift=jnp.where(mp, 0, sf.mem_ushift),
+        mem_uhead=jnp.where(mp, -1, sf.mem_uhead),
+        hop_stats=hop_stats,
         callvalue_sym=jnp.where(mp, _fr_get(sf.fr_callvalue_sym, d), sf.callvalue_sym),
         caller_sym=jnp.where(mp, _fr_get(sf.fr_caller_sym, d), sf.caller_sym),
         # a failed value call rolled the balance table back — another change
@@ -1704,6 +1801,13 @@ def pop_frames(sf: SymFrontier, corpus: Corpus) -> SymFrontier:
                                  & (sf.sub_revert_pc < 0),
                                  _fr_get(f.fr_contract_id, d),
                                  sf.sub_revert_cid),
+        # where the callee itself gave up, in its own code: the innermost
+        # frame's, since it pops first
+        sub_fail_pc=jnp.where(fail & f.reverted & ~f.error
+                              & (sf.sub_fail_pc < 0), f.pc, sf.sub_fail_pc),
+        sub_fail_cid=jnp.where(fail & f.reverted & ~f.error
+                               & (sf.sub_fail_pc < 0), f.contract_id,
+                               sf.sub_fail_cid),
     )
 
 
@@ -1832,7 +1936,13 @@ def _overlay(sf: SymFrontier, env: Env, spec: SymSpec, op, m, cls, pre_sp,
     node_op = _J_BINOP[op]
     is_unary = (op == 0x15) | (op == 0x19)  # ISZERO NOT
     any_sym = (s[0] != 0) | (~is_unary & (s[1] != 0))
-    m_node = m_bin & any_sym & (node_op != 0)
+    # SHR of a word whose head is exact in its concrete shadow (a
+    # sub-frame's selector word) by at least the rest: concrete
+    keeps_head = ((op == 0x1C) & (s[0] == 0) & (s[1] != 0)
+                  & (s[1] == sf.head_node)
+                  & (u256.to_u64_saturating(a[0]).astype(I64)
+                     >= 256 - 8 * sf.head_len.astype(I64)))
+    m_node = m_bin & any_sym & (node_op != 0) & ~keeps_head
     sf, aid = _sym_or_const(sf, m_node, s[0], a[0])
     sf, bid = _sym_or_const(sf, m_node & ~is_unary, s[1], a[1])
     bid = jnp.where(is_unary, 0, bid)  # unary nodes must not carry stale b
@@ -1968,7 +2078,27 @@ def _overlay(sf: SymFrontier, env: Env, spec: SymSpec, op, m, cls, pre_sp,
     cda = _cd_sym_at(cw)
     cdb = _cd_sym_at(cw + 1)
     cd_sub = m_env & is_cdload & sub & (s[0] == 0)
-    hv_cd_need = cd_sub & (sf.cd_havoc | (~cd_al & ((cda != 0) | (cdb != 0))))
+    # the word the caller stored whole at this offset of the window
+    cdu = jnp.where(
+        ~cd_al & ((off64 % 32).astype(I32) == sf.cd_ushift) & (cw < CDW)
+        & ~sf.cd_havoc,
+        jnp.take_along_axis(sf.cd_usym, jnp.clip(cw, 0, CDW - 1)[:, None],
+                            axis=1)[:, 0], 0)
+    cd_whole = cd_sub & (cdu != 0)
+    hv_cd_need = cd_sub & ~cd_whole & (
+        sf.cd_havoc | (~cd_al & ((cda != 0) | (cdb != 0))))
+    # word 0 under a concrete selector: its node stands for the whole
+    # word, its concrete shadow is exact in the first ``cd_ushift`` bytes
+    cd_head = (cd_sub & cd_al & (cw == 0) & sf.cd_uhead & ~sf.cd_havoc
+               & (cda != 0))
+    cd_exact = cd_whole | (cd_sub & cd_al & ~sf.cd_havoc & (cda != 0)
+                           & ~cd_head)
+    sf = sf.replace(
+        head_node=jnp.where(cd_head, cda, sf.head_node),
+        head_len=jnp.where(cd_head, sf.cd_ushift, sf.head_len),
+        hop_stats=_hop_count(
+            sf.hop_stats, (HOP_CD_EXACT, cd_exact & (cdu >= 0)),
+            (HOP_CD_HAVOC, hv_cd_need)))
 
     env_hv_need = env_hv_need | hv_cd_need
     sf, env_hv = _havoc(sf, env_hv_need)
@@ -1980,6 +2110,7 @@ def _overlay(sf: SymFrontier, env: Env, spec: SymSpec, op, m, cls, pre_sp,
     r_env = jnp.where(cv_sub, sf.callvalue_sym, r_env)
     r_env = jnp.where(cl_sub, sf.caller_sym, r_env)
     r_env = jnp.where(cd_sub & cd_al & ~sf.cd_havoc, cda, r_env)
+    r_env = jnp.where(cd_whole, jnp.maximum(cdu, 0), r_env)
     r_env = jnp.where(env_hv_need, env_hv, r_env)
     # "executed ORIGIN" flag (DeprecatedOperations SWC-111): the leaf node
     # may pre-exist via seeding, so presence on the tape is not evidence
@@ -2037,20 +2168,29 @@ def _overlay(sf: SymFrontier, env: Env, spec: SymSpec, op, m, cls, pre_sp,
     # words at or above the lane's floor are unknown whatever they hold
     unk_a = wm >= sf.mem_floor
     unk_ab = wm + jnp.where(aligned, 0, 1) >= sf.mem_floor
+    # the word stored whole at this very offset (``mem_usym``): the
+    # unaligned counterpart of ``wsym_a``
+    sh = (off64 % 32).astype(I32)
+    same_shift = ~aligned & (sh == sf.mem_ushift)
+    uent = jnp.where(same_shift & ~unk_ab & (off64 + 32 <= M),
+                     _take_word_sym(sf.mem_usym, wm), 0)
+    whole = uent != 0
     # MLOAD
-    load_sym_needed = m_mem & is_load & (
+    load_sym_needed = m_mem & is_load & ~whole & (
         (aligned & (wsym_a != 0))
         | (~aligned & ((wsym_a != 0) | (wsym_b != 0))) | unk_ab
     )
     hv_load_need = load_sym_needed & (~aligned | unk_ab)
     # unaligned MSTORE: havoc both covered words if anything symbolic
     st_mask = m_mem & ~is_load
-    un_any = st_mask & ~is_store8 & ~aligned & (
+    un_st = st_mask & ~is_store8 & ~aligned
+    un_any = un_st & (
         (s[1] != 0) | (wsym_a != 0) | (wsym_b != 0) | unk_ab
     )
     sf, hv_a = _havoc(sf, hv_load_need | un_any)
     r_mload = jnp.where(
-        load_sym_needed, jnp.where(aligned & ~unk_ab, wsym_a, hv_a), 0
+        load_sym_needed, jnp.where(aligned & ~unk_ab, wsym_a, hv_a),
+        jnp.where(m_mem & is_load & whole, jnp.maximum(uent, 0), 0)
     )
     mstore_aligned = st_mask & ~is_store8 & aligned
     mem_sym = _set_word_sym(sf.mem_sym, wm, s[1], mstore_aligned)
@@ -2061,7 +2201,24 @@ def _overlay(sf: SymFrontier, env: Env, spec: SymSpec, op, m, cls, pre_sp,
     m8_any = st_mask & is_store8 & ((s[1] != 0) | (wsym_a != 0) | unk_a)
     sf, hv_c = _havoc(sf, m8_any)
     mem_sym = _set_word_sym(mem_sym, wm, hv_c, m8_any)
-    sf = sf.replace(mem_sym=mem_sym)
+    # the unaligned words: a store at the lane's shift (or the lane's
+    # first) records its word whole; one at another shift starts anew. An
+    # aligned store or an MSTORE8 ends the two whole words it writes into
+    restart = un_st & (sh != sf.mem_ushift)
+    usym = jnp.where(restart[:, None], 0, sf.mem_usym)
+    usym = _set_word_sym(
+        usym, wm, jnp.where(s[1] != 0, s[1], USYM_CONCRETE), un_st)
+    cut = st_mask & ~un_st
+    usym = _set_word_sym(usym, wm, jnp.zeros_like(wm), cut)
+    usym = _set_word_sym(usym, wm - 1, jnp.zeros_like(wm), cut)
+    # the word whose head stays concrete under the first argument
+    head_kept = un_st & (wsym_a == 0) & ~unk_a
+    uhead = jnp.where(restart, -1, sf.mem_uhead)
+    uhead = jnp.where((cut & (uhead == wm)) | (un_st & (uhead == wm + 1)),
+                      -1, uhead)
+    uhead = jnp.where(head_kept, wm, uhead)
+    sf = sf.replace(mem_sym=mem_sym, mem_usym=usym, mem_uhead=uhead,
+                    mem_ushift=jnp.where(un_st, sh, sf.mem_ushift))
 
     # ---- CLS_COPY (concrete args) ----
     m_cp = m & (cls == ci.CLS_COPY)
@@ -2100,8 +2257,15 @@ def _overlay(sf: SymFrontier, env: Env, spec: SymSpec, op, m, cls, pre_sp,
     edge_hi = ((dst64 + cln64) // 32)[:, None]
     edge = ((wids == edge_lo) | (wids == edge_hi)) & ~full_cover & conc_src[:, None]
     edge_dirty = jnp.any(edge & (sf.mem_sym != 0), axis=1)
+    # a copy ends the whole words it writes into
+    ubyte = wids.astype(I64) * 32 + sf.mem_ushift[:, None]
+    copied_into = ((m_cp & (cln64 > 0))[:, None]
+                   & (ubyte < (dst64 + cln64)[:, None])
+                   & (ubyte + 32 > dst64[:, None]))
     sf = sf.replace(
         mem_sym=mem_sym2,
+        mem_usym=jnp.where(copied_into, 0, sf.mem_usym),
+        mem_uhead=jnp.where(m_cp & (cln64 > 0), -1, sf.mem_uhead),
         mem_floor=_lower_floor(
             sf.mem_floor,
             cd_havoc | (conc_src & edge_dirty)
@@ -2338,6 +2502,8 @@ _POP_FRAME_WRITES = (
     "rv_havoc", "cd_from_mem", "cd_havoc", "cd_sym", "callvalue_sym",
     "caller_sym", "bal_epoch", "st_val_sym", "st_key_sym", "st_seq",
     "sub_revert_pc", "sub_revert_cid",
+    "cd_usym", "cd_ushift", "cd_uhead", "mem_usym", "mem_ushift",
+    "mem_uhead", "hop_stats", "sub_fail_pc", "sub_fail_cid",
 )
 
 
@@ -2554,16 +2720,25 @@ def between_txs(sf: SymFrontier, require_mutation: bool = True,
         retdata_sym=jnp.where(go, False, sf.retdata_sym),
         rv_sym=jnp.where(go[:, None], 0, sf.rv_sym),
         rv_havoc=jnp.where(go, False, sf.rv_havoc),
+        mem_usym=jnp.where(go[:, None], 0, sf.mem_usym),
+        mem_ushift=jnp.where(go, 0, sf.mem_ushift),
+        mem_uhead=jnp.where(go, -1, sf.mem_uhead),
         cd_reads=jnp.zeros_like(sf.cd_reads),
+        hop_stats=jnp.zeros_like(sf.hop_stats),
         cd_from_mem=jnp.where(go, False, sf.cd_from_mem),
         cd_havoc=jnp.where(go, False, sf.cd_havoc),
         cd_sym=jnp.where(go[:, None], 0, sf.cd_sym),
+        cd_usym=jnp.where(go[:, None], 0, sf.cd_usym),
+        cd_ushift=jnp.where(go, 0, sf.cd_ushift),
+        cd_uhead=jnp.where(go, False, sf.cd_uhead),
         callvalue_sym=jnp.where(go, 0, sf.callvalue_sym),
         caller_sym=jnp.where(go, 0, sf.caller_sym),
         # new tx: the (symbolic) incoming callvalue changes balances again
         bal_epoch=sf.bal_epoch + go.astype(I32),
         sub_revert_pc=jnp.where(go, -1, sf.sub_revert_pc),
         sub_revert_cid=jnp.where(go, 0, sf.sub_revert_cid),
+        sub_fail_pc=jnp.where(go, -1, sf.sub_fail_pc),
+        sub_fail_cid=jnp.where(go, 0, sf.sub_fail_cid),
         tx_id=jnp.where(go, sf.tx_id + 1, sf.tx_id),
         # per-tx one-shot event records reset so tx N+1 can't inherit
         # tx N's calls/arith/SSTORE-after-call evidence (the per-tx
@@ -3101,7 +3276,8 @@ def relieve_starved(sf: SymFrontier, n_contracts: int,
 #: with, fetched only where a contract's carried states exceed its share
 SEAM_STORAGE = ("base.st_used", "base.st_written", "base.st_keys",
                 "base.st_vals", "st_key_sym", "st_val_sym", "st_seq",
-                "base.contract_id", "base.pc")
+                "base.contract_id", "base.pc", "sub_fail_cid",
+                "sub_fail_pc")
 
 
 def plan_seam_admission(n_contracts: int, carried, home, ended, failed,
@@ -3141,6 +3317,11 @@ def plan_seam_admission(n_contracts: int, carried, home, ended, failed,
     ``concrete_storage`` a slot that no transaction wrote reads as zero,
     a concrete value like the constructor's: a guard on it
     (``require(members[msg.sender])`` before anyone joined) counts too.
+    A path that failed because a CALLEE's guard did (a member of the
+    lane's system, frames deep: its ``require`` reverts, and every
+    caller's ``require(success)`` after it) tested that guard's slots:
+    the innermost reverted frame's last ``pc`` and image
+    (``sub_fail_pc`` / ``sub_fail_cid``) are read like the path's own.
 
     Where it acts, it holds EVERY contract over its share to it (the
     pool is one, and a neighbour's carried states are what starved the
@@ -3168,7 +3349,9 @@ def plan_seam_admission(n_contracts: int, carried, home, ended, failed,
     over = (n_carried > 1) & (n_carried * fanout > share)
     if not over.any():
         return None
-    used, written, keys, vals, key_sym, val_sym, seq, image, pc = storage()
+    (used, written, keys, vals, key_sym, val_sym, seq, image, pc,
+     *sub) = storage()
+    sub_image, sub_pc = sub if sub else (image, np.full_like(pc, -1))
     named = used & (key_sym == 0)       # entries under a concrete key
     # 32 bytes an entry, compared whole
     kb = np.ascontiguousarray(keys).view("V32")[..., 0]
@@ -3177,16 +3360,19 @@ def plan_seam_admission(n_contracts: int, carried, home, ended, failed,
     guards: dict = {}       # (image, pc) -> the slots tested there
     left = named & (val_sym == 0) & (seq > 0) & ~written
     for lane in np.nonzero(np.asarray(failed) & over[contract])[0]:
-        site = int(image[lane]), int(pc[lane])
-        if site not in guards:
-            guards[site] = [int(k).to_bytes(32, "little")
-                            for k in guard_slots(*site)]
+        sites = [(int(image[lane]), int(pc[lane]))]
+        if sub_pc[lane] >= 0:
+            sites.append((int(sub_image[lane]), int(sub_pc[lane])))
         had = {k.tobytes() for k in kb[lane, left[lane]]}
         held = {k.tobytes() for k in kb[lane, named[lane] & (seq[lane] > 0)]}
-        for k in guards[site]:
-            if k in had or (concrete_storage and k not in held):
-                readers[contract[lane], k] = readers.get(
-                    (contract[lane], k), 0) + 1
+        for site in sites:
+            if site not in guards:
+                guards[site] = [int(k).to_bytes(32, "little")
+                                for k in guard_slots(*site)]
+            for k in guards[site]:
+                if k in had or (concrete_storage and k not in held):
+                    readers[contract[lane], k] = readers.get(
+                        (contract[lane], k), 0) + 1
     novelty = np.zeros(P, dtype=np.int64)
     lanes, slots = np.nonzero(
         named & written & (carried & over[contract])[:, None])

@@ -10,7 +10,11 @@ storage class.
 Granularity choices (documented over-approximations; each introduces
 fresh unconstrained variables rather than wrong values):
 - memory symbolics are tracked per 32-byte word (``mem_sym``);
-- unaligned symbolic stores/loads produce HAVOC leaves;
+- a word stored whole by one MSTORE at an unaligned offset is kept
+  beside them (``mem_usym``, one byte shift a lane: solc's ABI encoder
+  puts every argument at ``ptr + 4 + 32k``) and read back whole at the
+  same offset as the node it was; the aligned words it covers hold
+  HAVOC leaves, as does every other unaligned symbolic store or load;
 - a copy of symbolic calldata, or a store or copy at a symbolic offset,
   lowers ``mem_floor``, the lowest memory word that may be unknown: an
   MLOAD at or above it returns a fresh HAVOC leaf, one below it is exact.
@@ -33,6 +37,21 @@ I32 = jnp.int32
 U32 = jnp.uint32
 #: ``SymFrontier.mem_floor`` of a lane whose memory is exact throughout
 MEM_EXACT = 2**31 - 1
+
+
+#: ``mem_usym`` / ``cd_usym`` entry of a word stored whole with a
+#: concrete value (its bytes in the concrete memory are exact)
+USYM_CONCRETE = -1
+
+#: columns of ``SymFrontier.hop_stats``: CALL-family instructions by
+#: fate, calls whose target was a member of the lane's world with code
+#: (``MEMBER``) and those of them that fell to the external path for a
+#: limit (``TRAPPED``), symbolic words read across a frame boundary by
+#: side and fate, and the deepest frame the path ran in
+(HOP_INTERNAL, HOP_EOA, HOP_PRECOMPILE, HOP_EXTERNAL, HOP_MEMBER,
+ HOP_TRAPPED, HOP_CD_EXACT, HOP_CD_HAVOC, HOP_RET_EXACT, HOP_RET_HAVOC,
+ HOP_DEPTH) = range(11)
+N_HOP = 11
 
 
 def tape_row_hash(op, a, b, imm):
@@ -69,6 +88,13 @@ class SymFrontier:
     # (MEM_EXACT: none). A write or a copy at a symbolic offset lowers it
     # to the word of its concrete base (0 without one); reads, hashes and
     # call windows below it stay exact, those reaching it are havoc
+    mem_usym: jnp.ndarray    # i32[P, M/32] the word stored whole at byte
+    # ``32 * w + mem_ushift``: its node id, ``USYM_CONCRETE`` for a
+    # concrete value, 0 for no such word (or one written into since)
+    mem_ushift: jnp.ndarray  # i32[P] the lane's byte shift (0: none yet)
+    mem_uhead: jnp.ndarray   # i32[P] the aligned word whose first
+    # ``mem_ushift`` bytes were concrete when an unaligned store took the
+    # rest (the selector before the first argument); -1: none
     retdata_sym: jnp.ndarray  # bool[P] returndata of last call is symbolic
     st_val_sym: jnp.ndarray  # i32[P, K]
     st_key_sym: jnp.ndarray  # i32[P, K] sym id of the key stored in the slot
@@ -87,6 +113,15 @@ class SymFrontier:
     # not free symbolic leaves
     cd_havoc: jnp.ndarray    # bool[P] this frame's calldata bytes unknown
     cd_sym: jnp.ndarray      # i32[P, CD/32] per-word sym ids of frame calldata
+    cd_usym: jnp.ndarray     # i32[P, CD/32] ``mem_usym`` of the call's window:
+    # the calldata word at byte ``32 * k + cd_ushift``
+    cd_ushift: jnp.ndarray   # i32[P]
+    cd_uhead: jnp.ndarray    # bool[P] the first ``cd_ushift`` bytes of the
+    # frame's calldata are concrete (``mem_uhead`` was the window's word 0)
+    head_node: jnp.ndarray   # i32[P] node a sub-frame's CALLDATALOAD(0) gave
+    # for a word whose first ``head_len`` bytes are exact in its concrete
+    # shadow: a SHR that keeps only those bytes is concrete (the selector)
+    head_len: jnp.ndarray    # i32[P]
     callvalue_sym: jnp.ndarray  # i32[P] sym id of this frame's callvalue
     caller_sym: jnp.ndarray  # i32[P] sym id of this frame's msg.sender (0 =
     # concrete; a DELEGATECALL frame inherits the caller frame's CALLER leaf)
@@ -99,6 +134,9 @@ class SymFrontier:
     fr_cd_from_mem: jnp.ndarray  # bool[P, D]
     fr_cd_havoc: jnp.ndarray  # bool[P, D]
     fr_cd_sym: jnp.ndarray   # i32[P, D, CD/32]
+    fr_cd_usym: jnp.ndarray  # i32[P, D, CD/32]
+    fr_cd_ushift: jnp.ndarray  # i32[P, D]
+    fr_cd_uhead: jnp.ndarray  # bool[P, D]
     fr_callvalue_sym: jnp.ndarray  # i32[P, D]
     fr_caller_sym: jnp.ndarray  # i32[P, D]
     fr_st_val_sym: jnp.ndarray  # i32[P, D, K] storage-overlay snapshots
@@ -107,6 +145,10 @@ class SymFrontier:
     sub_revert_pc: jnp.ndarray  # i32[P] pc of the CALL whose callee
     # reverted/failed (-1 = none; SWC-123 RequirementsViolation feed)
     sub_revert_cid: jnp.ndarray  # i32[P] contract owning that CALL site
+    sub_fail_pc: jnp.ndarray  # i32[P] where the first callee frame of this
+    # transaction that reverted ended, in ITS code (-1 = none): the guard
+    # a path failed at three contracts deep (the seam's admission step)
+    sub_fail_cid: jnp.ndarray  # i32[P] the image that frame ran
     # --- SSA tape ---
     tape_op: jnp.ndarray     # i32[P, T]
     tape_a: jnp.ndarray      # i32[P, T]
@@ -119,6 +161,9 @@ class SymFrontier:
     cd_reads: jnp.ndarray    # i32[P, 2] this transaction's CALLDATALOADs at a
     # symbolic offset on the lane's path: [answered by a CD_SELECT node,
     # answered by a HAVOC leaf] (the harvest's ``engine_calldata_symreads_total``)
+    hop_stats: jnp.ndarray   # i32[P, N_HOP] this transaction's calls and frame
+    # crossings on the lane's path, by the ``HOP_*`` columns below (the
+    # harvest's ``engine_calls_total`` / ``engine_hop_words_total``)
     create_cnt: jnp.ndarray  # i32[P] CREATE/CREATE2 counter (fresh addresses)
     # --- persistent abstract domains (incremental propagation) ---
     # the tape is SSA append-only, so a node's interval/known-bits never
@@ -266,6 +311,9 @@ def make_sym_frontier(
         stack_sym=z(P, S),
         mem_sym=z(P, L.mem_bytes // 32),
         mem_floor=jnp.full(P, MEM_EXACT, dtype=I32),
+        mem_usym=z(P, L.mem_bytes // 32),
+        mem_ushift=z(P),
+        mem_uhead=jnp.full(P, -1, dtype=I32),
         retdata_sym=jnp.zeros(P, dtype=bool),
         st_val_sym=z(P, K),
         st_key_sym=z(P, K),
@@ -276,6 +324,11 @@ def make_sym_frontier(
         cd_from_mem=jnp.zeros(P, dtype=bool),
         cd_havoc=jnp.zeros(P, dtype=bool),
         cd_sym=z(P, CDW),
+        cd_usym=z(P, CDW),
+        cd_ushift=z(P),
+        cd_uhead=jnp.zeros(P, dtype=bool),
+        head_node=z(P),
+        head_len=z(P),
         callvalue_sym=z(P),
         caller_sym=z(P),
         bal_epoch=z(P),
@@ -284,6 +337,9 @@ def make_sym_frontier(
         fr_cd_from_mem=jnp.zeros((P, D), dtype=bool),
         fr_cd_havoc=jnp.zeros((P, D), dtype=bool),
         fr_cd_sym=z(P, D, CDW),
+        fr_cd_usym=z(P, D, CDW),
+        fr_cd_ushift=z(P, D),
+        fr_cd_uhead=jnp.zeros((P, D), dtype=bool),
         fr_callvalue_sym=z(P, D),
         fr_caller_sym=z(P, D),
         fr_st_val_sym=z(P, D, K),
@@ -291,6 +347,8 @@ def make_sym_frontier(
         fr_st_seq=z(P, D, K),
         sub_revert_pc=jnp.full(P, -1, dtype=I32),
         sub_revert_cid=z(P),
+        sub_fail_pc=jnp.full(P, -1, dtype=I32),
+        sub_fail_cid=z(P),
         tape_op=jnp.asarray(t_op),
         tape_a=jnp.asarray(t_a),
         tape_b=jnp.asarray(t_b),
@@ -301,6 +359,7 @@ def make_sym_frontier(
         tape_len=jnp.full(P, n_wk, dtype=I32),
         havoc_cnt=z(P),
         cd_reads=z(P, 2),
+        hop_stats=z(P, N_HOP),
         create_cnt=z(P),
         iv_lo=jnp.zeros((P, T, 8), dtype=U32),
         iv_hi=jnp.zeros((P, T, 8), dtype=U32),

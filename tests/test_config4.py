@@ -33,6 +33,14 @@ REGEN = bool(os.environ.get("MYTHRIL_REGEN_GOLDENS"))
 # must fit attacker + creator + all THREE contract accounts — at the
 # TEST default (4) the trio doesn't fit the table, cross-contract
 # targets resolve as unknown, and every CALL degrades to external havoc.
+# That is ``make_frontier``'s all-or-own rule, which a batch WITHOUT
+# manifests still follows (every contract of the batch in every lane's
+# table if all fit, else each lane its own contract alone): this file
+# drives ``SymExecWrapper`` directly, at ``contract_address(i)``. A
+# campaign over a corpus directory that holds a system's manifest gives
+# a member's lanes its own system at the manifest's addresses whatever
+# else the batch holds (tests/test_linked_system.py,
+# tests/benchmark/test_bench_linked.py).
 LIMITS = dataclasses.replace(TEST_LIMITS, call_depth=4, max_accounts=6)
 
 
