@@ -594,6 +594,7 @@ class SymExecWrapper:
             # host mirror of the frontier's run-total superstep counter
             # (a chunk's count is the difference across its sym_run call)
             self._steps_seen = 0
+            self._copy_seen = 0     # the same for ``copy_steps``
             # from shapes and dtypes alone: no device sync
             frontier_bytes = sum(x.nbytes for x in jax.tree.leaves(sf))
             obs_metrics.REGISTRY.gauge(
@@ -691,7 +692,7 @@ class SymExecWrapper:
                 """One ``sym_run`` call of at most ``n`` supersteps,
                 inside a span that ends when the device does: the call
                 only enqueues the program, and the read of its results
-                (the coverage bitmap, the run-total step counter and
+                (the coverage bitmap, the run-total step counters and
                 the ``also`` leaves of the new frontier, in ONE
                 transfer) is what waits for it. ``shape`` keys the
                 compiled program in ``warm_shapes``. Returns the
@@ -718,16 +719,19 @@ class SymExecWrapper:
                     enqueue_s = sp.elapsed
                     self._first_call_enqueued()
                     got = fetch(
-                        (vis, sf.steps_total)
+                        (vis, sf.steps_total, sf.copy_steps)
                         + tuple(attrgetter(a)(sf) for a in also),
-                        ",".join(("visited", "steps_total", *also)))
+                        ",".join(("visited", "steps_total", "copy_steps",
+                                  *also)))
                     steps_run = int(got[1]) - self._steps_seen
+                    copy_steps = int(got[2]) - self._copy_seen
                     left_inside = bool(
-                        dict(zip(also, got[2:])).get("fixpoint"))
+                        dict(zip(also, got[3:])).get("fixpoint"))
                     if left_inside:
                         sp.attrs["ended_in"] = "fixpoint"
                     sp.attrs.update(
                         steps_run=steps_run,
+                        copy_steps=copy_steps,
                         enqueue_s=round(enqueue_s, 6),
                         device_wait_s=round(tally()[1] - w0, 6))
                     # timed to here, emitted by ``seal`` once the
@@ -735,6 +739,7 @@ class SymExecWrapper:
                     held.append(sp.hold())
                 self.sym_run_calls.append((sp.t_mono, sp.t_mono + sp.dur))
                 self._steps_seen = int(got[1])
+                self._copy_seen = int(got[2])
                 self._visited |= got[0]
                 reg = obs_metrics.REGISTRY
                 if left_inside:
@@ -757,7 +762,12 @@ class SymExecWrapper:
                     "engine_supersteps_budget_total",
                     help="supersteps the sym_run calls were allowed "
                          "(sum of max_steps)").inc(n)
-                return sf, steps_run, sp.dur, cold, got[2:]
+                reg.counter(
+                    "engine_copy_supersteps_total",
+                    help="supersteps in which some lane ran a copy "
+                         "opcode's handler (dispatch took CLS_COPY's cond)",
+                    labels={"tx": str(self._cur_tx)}).inc(copy_steps)
+                return sf, steps_run, sp.dur, cold, got[3:]
 
             # what a seam of the spill machinery reads of the frontier,
             # in the one transfer of its ``superstep`` call

@@ -199,7 +199,10 @@ def _gather_bytes(buf, start, n_static: int, limit):
     time in every cell until PR 41) and reads a row for about the price
     of an element, so there the window is read as the ``ceil(n / 128) + 1``
     rows of 128 bytes that hold it and shifted left by ``start % 128`` in
-    seven compare-select stages."""
+    seven compare-select stages. A buffer with fewer rows than that (a
+    copy's source under the whole memory's window: ``out[:, j] =
+    buf[:, j + start]`` with ``start = src - dst``) is not gathered from:
+    each row it has is selected into its place among zero rows."""
     n = n_static
     P, L = buf.shape
     W = _ROW_BYTES
@@ -218,10 +221,16 @@ def _gather_bytes(buf, start, n_static: int, limit):
         # buffer (nor does one that wraps): clipping moves no byte of it
         s = jnp.clip(start.astype(I64), -n, L).astype(I32)
         row0 = s // W  # floor: a negative start reads from row -1 (masked)
-        rows = jnp.clip(row0[:, None] + jnp.arange(K, dtype=I32)[None, :],
-                        0, R - 1)
-        x = jnp.take_along_axis(buf.reshape(P, R, W), rows[:, :, None],
-                                axis=1, mode="promise_in_bounds")
+        rows = row0[:, None] + jnp.arange(K, dtype=I32)[None, :]
+        view = buf.reshape(P, R, W)
+        if R < K:
+            x = jnp.zeros((P, K, W), buf.dtype)
+            for r in range(R):
+                x = jnp.where((rows == r)[:, :, None], view[:, r, None, :], x)
+        else:
+            x = jnp.take_along_axis(
+                view, jnp.clip(rows, 0, R - 1)[:, :, None], axis=1,
+                mode="promise_in_bounds")
         x = x.reshape(P, K * W)
         shift = s - row0 * W  # in [0, W)
         b = W >> 1
@@ -516,20 +525,22 @@ def _h_copy(f: Frontier, env: Env, corpus: Corpus, op, m, old_pc):
     P, M = f.memory.shape
     jpos = jnp.arange(M, dtype=I64)[None, :]
     in_window = (jpos >= dst64[:, None]) & (jpos < (dst64 + ln64)[:, None])
-    sidx = jpos - dst64[:, None] + src64[:, None]
+    # source byte per target position: memory[:, j] <- source[:, j + shift],
+    # the whole memory's window of each source (the sum wraps in int64 as
+    # ``j - dst + src`` did)
+    shift = src64 - dst64
 
-    # source byte per target position
-    cd = _take_per_lane(f.calldata, sidx, f.calldata_len.astype(I64))
+    cd = _gather_bytes(f.calldata, shift, M, f.calldata_len)
     code_row = corpus.code[f.contract_id]
-    code = _take_per_lane(code_row, sidx, corpus.code_len[f.contract_id].astype(I64))
+    code = _gather_bytes(code_row, shift, M, corpus.code_len[f.contract_id])
     # CODECOPY inside a constructor copies from the INIT code (this is how
     # constructors materialize the runtime image they RETURN)
     code = jnp.where(
         f.exec_init[:, None],
-        _take_per_lane(f.init_code, sidx, f.init_len.astype(I64)),
+        _gather_bytes(f.init_code, shift, M, f.init_len),
         code,
     )
-    rd = _take_per_lane(f.returndata, sidx, f.returndata_len.astype(I64))
+    rd = _gather_bytes(f.returndata, shift, M, f.returndata_len)
     # EXTCODECOPY: resolve the address against the account table; unknown
     # or codeless accounts copy zeros (EVM: empty code)
     found, slot = f.acct_lookup(_peek(f, 0))
@@ -541,7 +552,7 @@ def _h_copy(f: Frontier, env: Env, corpus: Corpus, op, m, old_pc):
         corpus.code_len[jnp.clip(ext_cid, 0, corpus.code_len.shape[0] - 1)],
         0,
     )
-    ext = _take_per_lane(ext_row, sidx, ext_limit.astype(I64))
+    ext = _gather_bytes(ext_row, shift, M, ext_limit)
     srcb = jnp.where((op == 0x37)[:, None], cd,
                      jnp.where((op == 0x39)[:, None], code,
                                jnp.where((op == 0x3E)[:, None], rd,
@@ -560,15 +571,6 @@ def _deploy_end(f: Frontier, corpus: Corpus, is_payload, end_bytes):
     cut = is_payload & corpus.deploys[f.contract_id] & (f.depth == 0)
     return jnp.where(cut, jnp.minimum(end_bytes, f.memory.shape[1]),
                      end_bytes)
-
-
-def _take_per_lane(buf, idx, limit):
-    """buf[P,L]; gather per-lane idx[P,N] with zero fill past limit[P]."""
-    L = buf.shape[1]
-    safe = jnp.clip(idx, 0, L - 1).astype(I32)
-    vals = jnp.take_along_axis(buf, safe, axis=1)
-    ok = (idx >= 0) & (idx < limit[:, None]) & (idx < L)
-    return jnp.where(ok, vals, 0)
 
 
 def _h_mem(f: Frontier, env: Env, corpus: Corpus, op, m, old_pc):

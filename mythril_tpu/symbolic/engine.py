@@ -1276,7 +1276,7 @@ def _apply_precompiles(sf: SymFrontier, pre, pid, a_off, a_len, r_off,
     n_mem = jnp.minimum(out_len, r_len)
     jpos = jnp.arange(M, dtype=I64)[None, :]
     in_win = (jpos >= r_off[:, None]) & (jpos < (r_off + n_mem)[:, None])
-    src = ci._take_per_lane(out, jpos - r_off[:, None], n_mem)
+    src = ci._gather_bytes(out, -r_off, M, n_mem)
     memory = jnp.where(in_win & conc_res[:, None], src, f.memory).astype(jnp.uint8)
 
     # sym overlay of the output window: concrete results clear covered
@@ -1602,9 +1602,7 @@ def pop_frames(sf: SymFrontier, corpus: Corpus) -> SymFrontier:
     P, M = f.memory.shape
     jpos = jnp.arange(M, dtype=I64)[None, :]
     in_win = (jpos >= r_off[:, None]) & (jpos < (r_off + n_rd)[:, None])
-    src = ci._take_per_lane(
-        f.retval, jpos - r_off[:, None], n_rd
-    )
+    src = ci._gather_bytes(f.retval, -r_off, M, n_rd)
     memory = jnp.where(in_win & has_rd[:, None], src, memory).astype(jnp.uint8)
 
     # sym overlay: restore caller's, then map the returndata words
@@ -2546,7 +2544,9 @@ def sym_superstep(sf: SymFrontier, env: Env, corpus: Corpus,
     )
 
     f = ci.dispatch(sf.base, env, corpus, op, run, old_pc, skip=claimed)
-    sf = sf.replace(base=f)
+    # the supersteps in which ``dispatch`` took ``_h_copy``'s cond
+    copied = jnp.any(run & ~claimed & (cls == ci.CLS_COPY))
+    sf = sf.replace(base=f, copy_steps=sf.copy_steps + copied.astype(I32))
 
     sf = _overlay(sf, env, spec, op, run & ~claimed, cls, pre_sp,
                   pre_stack_sym, a, s, limits)

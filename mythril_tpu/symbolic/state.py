@@ -202,6 +202,9 @@ class SymFrontier:
     dropped_total: jnp.ndarray  # i32[] run total of dropped forks
     steps_total: jnp.ndarray  # i32[] run total of supersteps sym_run's loop ran
     # (each call adds its final loop counter; quiescence ends a call early)
+    copy_steps: jnp.ndarray  # i32[] run total of the supersteps in which
+    # ``dispatch`` took the copy class's cond (some running lane at an
+    # unclaimed CALLDATACOPY / CODECOPY / EXTCODECOPY / RETURNDATACOPY)
     # symbolic-callee enumeration (CALL with symbolic target forks one
     # candidate account per superstep; the fork copy re-executes the CALL
     # with the target stack slot concretized — see _h_sym_call)
@@ -385,6 +388,7 @@ def make_sym_frontier(
         dropped_forks=z(P),
         dropped_total=jnp.zeros((), dtype=I32),
         steps_total=jnp.zeros((), dtype=I32),
+        copy_steps=jnp.zeros((), dtype=I32),
         sym_jump_dest=z(P),
         sym_jump_pc=jnp.full(P, -1, dtype=I32),
         sym_jump_cid=z(P),

@@ -296,8 +296,8 @@ def _load_frontier_inner(path: str, template) -> Tuple[Any, Dict]:
                 # harvested or lost with the old format's fold-in)
                 leaves.append(np.asarray(tmpl_leaf))
                 continue
-            if name.endswith(("steps_total", "fixpoint")):
-                # frontiers written before the superstep counter: the
+            if name.endswith(("steps_total", "copy_steps", "fixpoint")):
+                # frontiers written before the superstep counters: the
                 # count of what ran before the checkpoint is not known,
                 # so it resumes at 0; before ``fixpoint``: no call has
                 # left on the rule yet

@@ -396,9 +396,12 @@ def test_superstep_spans_end_with_the_device_and_count_what_ran(spill):
     assert len(reads) == len(steps)
     # with spill the seam's masks and the scalar that says the call left
     # its loop at the pool's fixpoint ride it too: no read of their own
-    assert reads[0] == ("visited,steps_total,base.active,fork_req,"
-                        "base.running,base.home_contract,fixpoint"
-                        if spill else "visited,steps_total")
+    assert reads[0] == ("visited,steps_total,copy_steps,base.active,"
+                        "fork_req,base.running,base.home_contract,fixpoint"
+                        if spill else "visited,steps_total,copy_steps")
+    # neither program has a copy opcode: the cond was never taken
+    assert [sp["copy_steps"] for sp in steps] == [0] * len(steps)
+    assert int(np.asarray(sym.sf.copy_steps)) == 0
     assert not any("ended_in" in sp for sp in steps)
     assert (sym.sf.fixpoint is None) is (not spill)
     assert [sp["stuck"] for sp in steps] == [False] * len(steps)
@@ -647,8 +650,8 @@ def test_trace_report_says_how_each_transaction_ended(tmp_path, trace):
 
     recs = []
     for t0 in (0.0, 100.0):
-        recs += [call(t0 + 1, 0, False),
-                 call(t0 + 4, 0, False, ended="budget")]
+        recs += [call(t0 + 1, 0, False, copy_steps=3),
+                 call(t0 + 4, 0, False, ended="budget", copy_steps=2)]
         if trace == "witness_calls":
             recs += [call(t0 + 10, 1, True),
                      call(t0 + 13, 1, True, ended="fixpoint", skipped=6)]
@@ -665,6 +668,8 @@ def test_trace_report_says_how_each_transaction_ended(tmp_path, trace):
     cols = text[head + 1].split()
     rows = [dict(zip(cols, ln.split(None, len(cols) - 1)))
             for ln in text[head + 2:head + 4]]
+    # a trace from before the counter has no ``copy_steps``: none counted
+    assert [r["copies"] for r in rows] == ["10", "0"]
     got = [(r["tx"], r["calls"], r["early"], r["unrun"], r["skipped"],
             r["spun"], r["ended"]) for r in rows]
     assert got[0] == ("0", "4", "0", "0", "0", "0", "budget x2")
@@ -676,7 +681,8 @@ def test_trace_report_says_how_each_transaction_ended(tmp_path, trace):
 
 # --- checkpoints written before the counter ----------------------------------
 
-def test_checkpoint_without_the_step_counter_resumes_at_zero(tmp_path):
+@pytest.mark.parametrize("leaf", ["steps_total", "copy_steps"])
+def test_checkpoint_without_the_step_counter_resumes_at_zero(tmp_path, leaf):
     import jax.numpy as jnp
 
     from mythril_tpu.symbolic import make_sym_frontier
@@ -685,16 +691,16 @@ def test_checkpoint_without_the_step_counter_resumes_at_zero(tmp_path):
     sf = make_sym_frontier(4, TEST_LIMITS)
     path = str(tmp_path / "old.npz")
     # a None leaf is no leaf: the file is what an older writer wrote
-    save_frontier(path, sf.replace(steps_total=None), {"tx": 0})
-    template = sf.replace(steps_total=jnp.int32(7))
+    save_frontier(path, sf.replace(**{leaf: None}), {"tx": 0})
+    template = sf.replace(**{leaf: jnp.int32(7)})
     got, meta = load_frontier(path, template)
     assert meta == {"tx": 0}
-    assert int(np.asarray(got.steps_total)) == 0
-    assert np.asarray(got.steps_total).dtype == np.int32
+    assert int(np.asarray(getattr(got, leaf))) == 0
+    assert np.asarray(getattr(got, leaf)).dtype == np.int32
     # and one written today carries it
     save_frontier(path, template, {"tx": 1})
     got, _ = load_frontier(path, sf)
-    assert int(np.asarray(got.steps_total)) == 7
+    assert int(np.asarray(getattr(got, leaf))) == 7
 
 
 @pytest.mark.parametrize("written", ["before_the_leaf", "with_the_leaf",

@@ -142,3 +142,52 @@ def test_gather_bytes_reads_rows_on_the_tpu_write_mode(monkeypatch):
     # the walker sees the CPU's form: one element gather a call site
     step, sym = faults(scatter=True)
     assert len(step) >= 7 and len(sym) > len(step)
+
+
+def _whole_memory_byte_gathers_of(jaxpr, lanes, mem_bytes):
+    """Gathers of ``lanes x mem_bytes`` single bytes (all-ones
+    ``slice_sizes``) from a ``u8[lanes, L]`` operand, whoever made them:
+    one source byte for every position of every lane's memory."""
+    found = []
+    for eqn in scaling_report.all_eqns(jaxpr):
+        if eqn.primitive.name != "gather":
+            continue
+        aval, out = eqn.invars[0].aval, eqn.outvars[0].aval
+        if (aval.dtype == np.uint8 and len(aval.shape) == 2
+                and aval.shape[0] == lanes
+                and set(eqn.params["slice_sizes"]) == {1}
+                and int(np.prod(out.shape)) == lanes * mem_bytes):
+            found.append(tuple(aval.shape))
+    return found
+
+
+def test_copies_read_rows_on_the_tpu_write_mode(monkeypatch):
+    # what the TPU traces must not gather a source byte for every
+    # position of every lane's memory: the four copy opcodes' five
+    # sources, a returning frame's data and a precompile's output did
+    # (4,194,304 single bytes a gather at the cells' shapes; six of the
+    # seven were 46% of linked.campaign's busy time until PR 43)
+    from mythril_tpu.core import interpreter as ci
+    from mythril_tpu.symbolic import SymSpec
+    from mythril_tpu.symbolic.engine import sym_superstep
+
+    P = 16
+    sf0, env0, corpus, L = scaling_report._build_inputs(P)
+    M = sf0.base.memory.shape[1]
+
+    def faults(scatter):
+        monkeypatch.setattr(ci, "_use_scatter", lambda: scatter)
+        # a new function a trace: make_jaxpr keeps what it traced before
+        step = jax.make_jaxpr(
+            lambda f, e: ci.superstep(f, e, corpus))(sf0.base, env0)
+        sym = jax.make_jaxpr(
+            lambda s, e: sym_superstep(s, e, corpus, SymSpec(), L))(sf0, env0)
+        return (_whole_memory_byte_gathers_of(step, P, M),
+                _whole_memory_byte_gathers_of(sym, P, M))
+
+    step, sym = faults(scatter=False)
+    assert step == [] and sym == [], (step, sym)
+    # the walker sees the CPU's form: _h_copy's five sources, and in
+    # sym_superstep the precompile's output and pop_frames' data too
+    step, sym = faults(scatter=True)
+    assert len(step) == 5 and len(sym) == 7, (step, sym)

@@ -703,6 +703,11 @@ def test_sweep_paths_match_oracle_random_tapes(seed):
 # ---------------------------------------------------------------------------
 
 
+def _wrap64(v):
+    """A Python integer as int64 arithmetic leaves it."""
+    return (v + 2**63) % 2**64 - 2**63
+
+
 def _gather_ref(buf, start, n, limit):
     """out[p, k] = buf[p, start[p] + k] where that index (wrapped to
     int64, as the device adds it) lies in [0, min(limit[p], L)), else 0."""
@@ -710,7 +715,7 @@ def _gather_ref(buf, start, n, limit):
     out = np.zeros((P, n), np.uint8)
     for p in range(P):
         for k in range(n):
-            idx = (int(start[p]) + k + 2**63) % 2**64 - 2**63
+            idx = _wrap64(int(start[p]) + k)
             if 0 <= idx < min(int(limit[p]), L):
                 out[p, k] = buf[p, idx]
     return out
@@ -723,9 +728,9 @@ def _gather_starts(L, n):
     saturated = int(np.asarray(
         u256.to_u64_saturating(jnp.asarray(big)).astype(jnp.int64))[0])
     assert saturated == -1
-    return [-(2**63), -n - 1, -n, -n + 1, -33, -1, saturated, 0, 1, 5, 31,
-            32, 33, 127, 129, L // 2 + 3, L - n - 1, L - n, L - n + 1,
-            L - 33, L - 1, L, L + 1, L + n, 2**31 - 1, 2**31 + 7,
+    return [-(2**63), -n - 1, -n, -n + 1, -129, -128, -33, -1, saturated, 0,
+            1, 5, 31, 32, 33, 127, 128, 129, L // 2 + 3, L - n - 1, L - n,
+            L - n + 1, L - 33, L - 1, L, L + 1, L + n, 2**31 - 1, 2**31 + 7,
             2**63 - n, 2**63 - 1]
 
 
@@ -734,7 +739,8 @@ GATHER_LIMITS = ("zero", "inside_window", "equal_L", "beyond_L")
 
 @pytest.mark.parametrize("limit_kind", GATHER_LIMITS)
 @pytest.mark.parametrize("L", [256, 1024, 4096, 24576])
-@pytest.mark.parametrize("n", [32, 200, 256, 1024])
+@pytest.mark.parametrize("n", [32, 200, 256, 1024, 4096])   # 4096: a copy's
+# window, the whole memory (33 rows a lane; L 256 / 1024 / 4096 have fewer)
 def test_gather_bytes_paths_match_oracle(n, L, limit_kind):
     g = np.random.default_rng(n * 31 + L)
     start = np.asarray(_gather_starts(L, n), np.int64)
@@ -773,3 +779,313 @@ def test_gather_bytes_odd_widths_and_narrow_starts():
             jnp.asarray(buf), jnp.asarray(start), n, jnp.asarray(limit)))
         want = _gather_ref(buf, start, n, limit)
         assert (a == want).all() and (b == want).all(), (L, n)
+
+
+# ---------------------------------------------------------------------------
+# A copy's source window: memory[:, j] <- source[:, j + (src - dst)] over all
+# M positions (CALLDATACOPY, CODECOPY, EXTCODECOPY, RETURNDATACOPY, a
+# returning frame's data, a precompile's output)
+# ---------------------------------------------------------------------------
+
+
+def _take_ref(buf, dst, src, M, limit):
+    """The index the copies used until PR 43, position by position:
+    ``idx = j - dst + src`` wrapped to int64 as the device adds it, the
+    byte there if ``0 <= idx < min(limit, L)``, else 0."""
+    P, L = buf.shape
+    out = np.zeros((P, M), np.uint8)
+    for p in range(P):
+        for j in range(M):
+            idx = _wrap64(_wrap64(j - int(dst[p])) + int(src[p]))
+            if 0 <= idx < min(int(limit[p]), L):
+                out[p, j] = buf[p, idx]
+    return out
+
+
+@pytest.mark.parametrize("L", [256, 1024, 24576])
+def test_window_start_is_the_index_the_copies_used(L):
+    """``start = src - dst`` (wrapping) reads what ``j - dst + src`` read,
+    for every pair of operands as the handlers cast them: a saturated
+    ``u64`` is -1, one from 2**63 up is negative."""
+    M = 4096
+    ops = [0, 1, 5, 127, 128, 129, 255, 256, M - 1, M, L - 1, L, L + 1,
+           2**31 + 7, 2**63 - 1, -1, -(2**63), -(2**63) + 5]
+    dst = np.repeat(np.asarray(ops, np.int64), len(ops))
+    src = np.tile(np.asarray(ops, np.int64), len(ops))
+    P = len(dst)
+    g = np.random.default_rng(L)
+    buf = g.integers(1, 256, (P, L), dtype=np.uint8)
+    limit = np.where(g.random(P) < 0.5, L, g.integers(0, L + 1, P))
+    with np.errstate(over="ignore"):
+        start = src - dst
+    a, b = both_paths(lambda: ci._gather_bytes(
+        jnp.asarray(buf), jnp.asarray(start), M, jnp.asarray(limit)))
+    want = _take_ref(buf, dst, src, M, limit)
+    assert (a == want).all(), np.argwhere(a != want)[:5]
+    assert (b == want).all(), np.argwhere(b != want)[:5]
+    assert want.any()
+
+
+_COPY_OPS = {"CALLDATACOPY": 0x37, "CODECOPY": 0x39, "EXTCODECOPY": 0x3C,
+             "RETURNDATACOPY": 0x3E}
+_BIG = 2**256 - 1
+
+
+def _copy_cases(M, n_cd, n_code, n_rd, n_ext):
+    """(opcode, dst, src, len): inside, to the last byte of memory, one
+    past it, length 0, a source that ends inside the window, one past
+    its end, and operands past 2**64."""
+    out = []
+    for name, n_src in (("CALLDATACOPY", n_cd), ("CODECOPY", n_code),
+                        ("EXTCODECOPY", n_ext), ("RETURNDATACOPY", n_rd)):
+        out += [(name, 0, 0, 8), (name, 3, 10, 40), (name, 129, 1, 127),
+                (name, 0, n_src - 5, 70),           # runs off the source
+                (name, 64, n_src, 32), (name, 64, n_src + 9, 32),
+                (name, 7, 0, 0), (name, _BIG, 0, 0),   # length 0
+                (name, M - 24, 0, 24),              # to memory's last byte
+                (name, M - 24, 0, 25),              # one past it: traps
+                (name, M, 0, 1), (name, 0, 0, M + 1),
+                (name, _BIG, 0, 1), (name, 0, 0, _BIG), (name, 2**64, 0, 1),
+                (name, _BIG, 3, 40), (name, 5, _BIG, 40),   # -1 as int64
+                (name, 5, 2**63, 40),               # negative as int64
+                (name, 5, 2**63 - 1, 40)]
+    return out
+
+
+def _run_concrete(f, env, corpus, scatter):
+    """``core.run`` traced anew under one lowering."""
+    import jax
+
+    real, ci._use_scatter = ci._use_scatter, lambda: scatter
+    try:
+        return jax.jit(ci.run.__wrapped__, static_argnames=("max_steps",))(
+            f, env, corpus, max_steps=16)
+    finally:
+        ci._use_scatter = real
+
+
+def test_copy_opcodes_match_the_plain_evm_under_both_lowerings():
+    """``_h_copy`` end to end: one lane a case, the operands read from
+    calldata, memory and return data seeded with a pattern, every lane
+    diffed against ``tests/pyevm_ref.py`` (EXTCODECOPY of a known account
+    against its CODECOPY of that account's code)."""
+    from mythril_tpu.core import Corpus, make_env, make_frontier
+    from mythril_tpu.core.frontier import contract_address
+    from mythril_tpu.disassembler import ContractImage
+    from mythril_tpu.disassembler.asm import assemble
+
+    from pyevm_ref import RefEVM
+
+    L = TEST_LIMITS
+    M = L.mem_bytes
+    g = np.random.default_rng(43)
+    target = bytes(g.integers(1, 256, 300, dtype=np.uint8))
+    segs, at = b"", {}
+    for name, op in _COPY_OPS.items():
+        at[name] = len(segs)
+        segs += assemble(64, "CALLDATALOAD", 32, "CALLDATALOAD",
+                         0, "CALLDATALOAD",
+                         *((96, "CALLDATALOAD") if name == "EXTCODECOPY"
+                           else ()), name, "STOP")
+    prog = segs + bytes(g.integers(1, 256, 200, dtype=np.uint8))
+    rd = bytes(g.integers(1, 256, 100, dtype=np.uint8))
+    cases = _copy_cases(M, L.calldata_bytes, len(prog), len(rd), len(target))
+    P = len(cases)
+    cd = np.zeros((P, L.calldata_bytes), np.uint8)
+    for i, (name, dst, src, ln) in enumerate(cases):
+        words = [dst, src, ln,
+                 contract_address(1) if name == "EXTCODECOPY"
+                 else int.from_bytes(bytes(g.integers(1, 256, 32,
+                                                      dtype=np.uint8)), "big")]
+        cd[i] = np.frombuffer(b"".join(w.to_bytes(32, "big") for w in words),
+                              np.uint8)
+    pattern = g.integers(1, 256, M, dtype=np.uint8)
+    corpus = Corpus.from_images([ContractImage.from_bytecode(c, L.max_code)
+                                 for c in (prog, target)])
+    f = make_frontier(P, L, calldata=cd,
+                      calldata_len=np.full(P, L.calldata_bytes, np.int32),
+                      n_contracts=2)
+    returndata = np.zeros((P, L.returndata_bytes), np.uint8)
+    returndata[:, :len(rd)] = np.frombuffer(rd, np.uint8)
+    f = f.replace(
+        pc=jnp.asarray([at[c[0]] for c in cases], jnp.int32),
+        memory=jnp.asarray(np.tile(pattern, (P, 1))),
+        mem_words=jnp.full(P, M // 32, jnp.int32),
+        returndata=jnp.asarray(returndata),
+        returndata_len=jnp.full(P, len(rd), jnp.int32))
+    outs = [_run_concrete(f, make_env(P), corpus, scatter)
+            for scatter in (True, False)]
+    sources = {"CALLDATACOPY": None, "CODECOPY": prog, "EXTCODECOPY": target,
+               "RETURNDATACOPY": rd}
+    trapped = by_ref = 0
+    for i, (name, dst, src, ln) in enumerate(cases):
+        tag = f"lane {i} {cases[i]}"
+        source = sources[name] or bytes(cd[i])
+        want_err, want_mem = _copy_as_cast(pattern, source, dst, src, ln)
+        if max(dst, src, ln) < 2**63 and not want_err:
+            # the casts change nothing: the plain EVM says the same
+            ref = RefEVM(prog, calldata=bytes(cd[i]))
+            ref.pc, ref.returndata = at[name], rd
+            ref.memory, ref.mem_words = bytearray(pattern.tobytes()), M // 32
+            got = ref.run(max_steps=16)
+            assert not got.error and len(got.memory) == M, tag
+            if name == "EXTCODECOPY":
+                # the reference answers every EXTCODECOPY with zeros: lay
+                # the account's code over them as its CODECOPY lays its own
+                got.memory[dst:dst + ln] = bytes(
+                    target[src + k] if src + k < len(target) else 0
+                    for k in range(ln))
+            assert bytes(got.memory) == want_mem, tag
+            for out in outs:
+                assert int(np.asarray(out.gas_min)[i]) == got.gas_min, tag
+            by_ref += 1
+        trapped += want_err
+        for out in outs:
+            assert bool(np.asarray(out.error)[i]) == want_err, tag
+            assert bool(np.asarray(out.halted)[i]) != want_err, tag
+            # a lane that trapped at the memory model's end wrote nothing
+            assert bytes(np.asarray(out.memory)[i]) == want_mem, tag
+    assert trapped == 3 * len(_COPY_OPS) and by_ref == 9 * len(_COPY_OPS)
+
+
+def _copy_as_cast(memory, source, dst, src, ln):
+    """(trapped, memory after) of one copy as ``_h_copy`` casts its
+    operands: saturated to ``u64``, then read as ``int64`` (2**64 and
+    more is -1, 2**63 and more negative: ``PERF.md`` section 7, "found by
+    PR 41's tests"; below 2**63 this is the EVM). The sums wrap."""
+    d, s, n = (_wrap64(min(v, 2**64 - 1)) for v in (dst, src, ln))
+    M = len(memory)
+    end = _wrap64(d + n)
+    if n > 0 and end > M:
+        return True, memory.tobytes()
+    out = bytearray(memory.tobytes())
+    for j in range(max(d, 0), min(end, M)):
+        k = _wrap64(_wrap64(j - d) + s)
+        out[j] = source[k] if 0 <= k < len(source) else 0
+    return False, bytes(out)
+
+
+def _window_as_cast(memory, data, r_off, r_len):
+    """(trapped, memory after) of a call's output window as
+    ``_h_sym_call`` casts its operands (see :func:`_copy_as_cast`): the
+    first ``min(r_len, len(data))`` bytes of ``data`` at ``r_off``."""
+    d, n = (_wrap64(min(v, 2**64 - 1)) for v in (r_off, r_len))
+    M = len(memory)
+    if n > 0 and _wrap64(d + n) > M:
+        return True, memory.tobytes()
+    out = bytearray(memory.tobytes())
+    for j in range(max(d, 0), min(_wrap64(d + min(n, len(data))), M)):
+        out[j] = data[j - d]
+    return False, bytes(out)
+
+
+def test_returned_and_precompile_data_land_as_in_the_plain_evm():
+    """``pop_frames``' and ``_apply_precompiles``' window under both
+    lowerings: one lane a case, (r_off, r_len, callee, a_len) read from
+    concrete calldata, the caller's memory seeded with a pattern whose
+    first two words tell the callee how much to return and whether to
+    revert. The callee's data comes from ``tests/pyevm_world.py``."""
+    import hashlib
+
+    import jax
+
+    from mythril_tpu.core import Corpus, make_env
+    from mythril_tpu.core.frontier import ATTACKER_ADDRESS, contract_address
+    from mythril_tpu.disassembler import ContractImage
+    from mythril_tpu.disassembler.asm import assemble
+    from mythril_tpu.symbolic import SymSpec, make_sym_frontier
+    from mythril_tpu.symbolic import engine
+
+    from pyevm_world import Account, World
+
+    L = TEST_LIMITS
+    M = L.mem_bytes
+    g = np.random.default_rng(44)
+    word = lambda: int.from_bytes(bytes(g.integers(1, 256, 32,
+                                                   dtype=np.uint8)), "big")
+    caller = assemble(32, "CALLDATALOAD", 0, "CALLDATALOAD",
+                      96, "CALLDATALOAD", 0, 0, 64, "CALLDATALOAD",
+                      ("push2", 50_000), "CALL", "STOP")
+    callee = assemble(
+        *[t for k in range(4) for t in (("push32", word()), 32 * k, "MSTORE")],
+        32, "CALLDATALOAD", ("ref", "rev"), "JUMPI",
+        0, "CALLDATALOAD", 0, "RETURN",
+        ("label", "rev"), "JUMPDEST", 0, "CALLDATALOAD", 0, "REVERT")
+    bad = bytes([0xFE])                                  # INVALID
+    me, you, other = (contract_address(i) for i in range(3))
+    IDENTITY, SHA256 = 4, 2
+    # (r_off, r_len, to, a_len, the callee's return length, reverts)
+    cases = [(0, 32, you, 64, 32, 0), (5, 20, you, 64, 32, 0),
+             (40, 64, you, 64, 32, 0),          # data ends inside the window
+             (131, 128, you, 64, 127, 0), (100, 0, you, 64, 32, 0),
+             (64, 32, you, 64, 0, 0),           # nothing returned
+             (M - 32, 32, you, 64, 32, 0),      # to memory's last byte
+             (M - 16, 32, you, 64, 32, 0),      # one past it: traps
+             (M, 1, you, 64, 32, 0), (_BIG, 0, you, 64, 32, 0),
+             (_BIG, 32, you, 64, 32, 0), (2**64, 40, you, 64, 64, 0),
+             (7, _BIG, you, 64, 32, 0), (7, 2**63 - 1, you, 64, 32, 0),
+             (3, 64, you, 64, 50, 1),           # REVERT carries its data
+             (3, 64, other, 64, 0, 0),          # INVALID: nothing comes back
+             (0, 32, IDENTITY, 64, 0, 0), (70, 100, IDENTITY, 40, 0, 0),
+             (9, 10, IDENTITY, 128, 0, 0), (M - 8, 8, IDENTITY, 128, 0, 0),
+             (M - 8, 9, IDENTITY, 128, 0, 0), (300, 0, IDENTITY, 64, 0, 0),
+             (_BIG, 20, IDENTITY, 64, 0, 0),
+             (0, 32, SHA256, 64, 0, 0), (77, 64, SHA256, 5, 0, 0),
+             (200, 7, SHA256, 0, 0, 0), (M - 32, 32, SHA256, 64, 0, 0)]
+    P = len(cases)
+    cd = np.zeros((P, L.calldata_bytes), np.uint8)
+    memory = np.tile(g.integers(1, 256, M, dtype=np.uint8), (P, 1))
+    for i, (r_off, r_len, to, a_len, n_ret, reverts) in enumerate(cases):
+        cd[i] = np.frombuffer(b"".join(
+            w.to_bytes(32, "big") for w in (r_off, r_len, to, a_len)), np.uint8)
+        memory[i, :64] = np.frombuffer(
+            n_ret.to_bytes(32, "big") + reverts.to_bytes(32, "big"), np.uint8)
+    corpus = Corpus.from_images([ContractImage.from_bytecode(c, L.max_code)
+                                 for c in (caller, callee, bad)])
+    import dataclasses
+
+    L5 = dataclasses.replace(L, max_accounts=5)
+    sf = make_sym_frontier(P, L5, contract_id=np.zeros(P, np.int32),
+                           calldata=cd, n_contracts=3)
+    sf = sf.replace(base=sf.base.replace(
+        memory=jnp.asarray(memory),
+        mem_words=jnp.full(P, M // 32, jnp.int32)))
+    spec = SymSpec(calldata=False, callvalue=False, storage=False,
+                   block_env=False)
+
+    def run(scatter):
+        real, ci._use_scatter = ci._use_scatter, lambda: scatter
+        try:
+            return jax.jit(engine._sym_run_impl,
+                           static_argnames=engine._SYM_RUN_STATIC)(
+                sf, make_env(P), corpus, spec, L5, max_steps=48)
+        finally:
+            ci._use_scatter = real
+
+    outs = [run(True), run(False)]
+    trapped = 0
+    for i, (r_off, r_len, to, a_len, n_ret, reverts) in enumerate(cases):
+        tag = f"lane {i} {cases[i]}"
+        data = bytes(memory[i, :a_len])
+        if to == IDENTITY:
+            ok, ret = True, data
+        elif to == SHA256:
+            ok, ret = True, hashlib.sha256(data).digest()
+        else:
+            world = World(eoas=(ATTACKER_ADDRESS,))
+            world.accounts.update({me: Account(caller), you: Account(callee),
+                                   other: Account(bad)})
+            ok, ret = world.message(me, to, 0, data, ATTACKER_ADDRESS)
+            assert (ok, len(ret)) == (not reverts and to == you, n_ret), tag
+        want_err, want_mem = _window_as_cast(memory[i], ret, r_off, r_len)
+        trapped += want_err
+        for out in outs:
+            b = out.base
+            assert bool(np.asarray(b.error)[i]) == want_err, tag
+            assert bytes(np.asarray(b.memory)[i]) == want_mem, tag
+            if not want_err:
+                assert bool(np.asarray(b.halted)[i]), tag
+                assert int(np.asarray(b.depth)[i]) == 0, tag
+                top = np.asarray(b.stack)[i, int(np.asarray(b.sp)[i]) - 1]
+                assert u256.to_int(top) == int(ok), tag
+    assert trapped == 3

@@ -227,8 +227,10 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
     # with the supersteps of their budget they did not run: ``unrun``),
     # the calls the fixpoint saved whole (``skipped``), those that still
     # started from a stuck seam and could only hand their frontier back
-    # (``spun``: none since the loop itself sees the fixpoint), and what
-    # ended it. Then what the seam's admission step made of the end
+    # (``spun``: none since the loop itself sees the fixpoint), the
+    # supersteps in which some lane ran a copy opcode's handler
+    # (``copies``: the ``copy_steps`` of its calls), and what ended it.
+    # Then what the seam's admission step made of the end
     # states that passed the pruners, one row a (tx, fate), the
     # contracts' shares of the paths and the lost forks, and what
     # decoding dynamic arguments did to a transaction's paths, one row a
@@ -247,13 +249,15 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
         row = by_tx.setdefault((a.get("tx"), a["tx_kind"]), {
             "calls": 0, "sec": 0.0, "paths": 0, "dropped": 0,
             "carried": 0, "seam": 0.0, "skipped": 0, "spun": 0,
-            "spun_sec": 0.0, "early": 0, "unrun": 0, "ended": {}})
+            "spun_sec": 0.0, "early": 0, "unrun": 0, "copies": 0,
+            "ended": {}})
         if s["name"] == "superstep":
             rounds[a.get("tx")] = max(rounds.get(a.get("tx"), 0),
                                       int(a.get("round", 0)))
             row["calls"] += 1
             row["sec"] += s["dur"]
             row["skipped"] += int(a.get("skipped", 0))
+            row["copies"] += int(a.get("copy_steps", 0))
             if a.get("ended_in") == "fixpoint":
                 row["early"] += 1
                 row["unrun"] += int(a.get("steps", 0)) - int(
@@ -294,7 +298,7 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
         out.append("== transactions (tx, tx_kind) ==")
         out.append(f"{'tx':>3} {'kind':<9}{'calls':>6}{'early':>6}"
                    f"{'unrun':>7}{'skipped':>8}"
-                   f"{'spun':>5}{'spun_s':>10}{'sym_run':>10}"
+                   f"{'spun':>5}{'spun_s':>10}{'sym_run':>10}{'copies':>8}"
                    f"{'paths':>8}{'dropped':>9}{'admitted':>10}"
                    f"{'carried':>9}{'seam':>10}  ended")
         for (tx, kind), r in sorted(by_tx.items(), key=lambda kv: str(kv[0])):
@@ -305,7 +309,7 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
                 f"{tx!s:>3} {kind:<9}{r['calls']:>6}{r['early']:>6}"
                 f"{r['unrun']:>7}{r['skipped']:>8}"
                 f"{r['spun']:>5}{_fmt_s(r['spun_sec']):>10}"
-                f"{_fmt_s(r['sec']):>10}"
+                f"{_fmt_s(r['sec']):>10}{r['copies']:>8}"
                 f"{r['paths']:>8}{r['dropped']:>9}"
                 f"{(100.0 * r['paths'] / tot if tot else 100.0):>9.1f}%"
                 f"{r['carried']:>9}{_fmt_s(r['seam']):>10}  {ended}")
