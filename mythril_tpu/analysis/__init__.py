@@ -6,7 +6,8 @@ Reference layout counterpart: ``mythril/analysis/`` (⚠unv) —
 """
 
 from .report import Issue, Report, SWC_TITLES
-from .symbolic import AnalysisContext, SymExecWrapper
+from .symbolic import (AnalysisContext, BatchBuild, SymExecWrapper,
+                       build_batch)
 from .security import fire_lasers
 from .module.base import DetectionModule, EntryPoint
 from .module.loader import ModuleLoader, register_module
@@ -14,6 +15,7 @@ from .module import modules  # noqa: F401  (registers the SWC suite)
 
 __all__ = [
     "Issue", "Report", "SWC_TITLES",
-    "AnalysisContext", "SymExecWrapper", "fire_lasers",
+    "AnalysisContext", "BatchBuild", "SymExecWrapper", "build_batch",
+    "fire_lasers",
     "DetectionModule", "EntryPoint", "ModuleLoader", "register_module",
 ]

@@ -339,6 +339,147 @@ def coverage_summary(tx_contexts) -> dict:
     return out
 
 
+@dataclass
+class BatchBuild:
+    """What :func:`build_batch` hands a :class:`SymExecWrapper`: the
+    batch packed for the device, before anything has run. A wrapper
+    takes it over whole (it grows ``images`` and ``names`` when it loads
+    a callee mid-run), so a bundle starts one exploration."""
+
+    images: list            # creation images [0, n_creation), then runtime
+    names: List[str]        # one an image
+    n_creation: int
+    corpus: Corpus
+    visited: np.ndarray     # bool[len(images), max_code], all False
+    known_addrs: set
+    systems: Dict[str, List[int]]   # member indices by linked system
+    sf: object              # the seeded SymFrontier
+    env: object
+    # where the build ran, for whoever lays its spans beside the phase
+    # that took it: the thread and the clock around the three stages
+    tid: int = 0
+    mono: float = 0.0
+    dur: float = 0.0
+
+
+def build_batch(
+    bytecodes: Sequence[bytes],
+    contract_names: Optional[Sequence[str]] = None,
+    contract_addrs: Optional[Sequence[int]] = None,
+    limits: LimitsConfig = DEFAULT_LIMITS,
+    lanes_per_contract: int = 64,
+    creation_bytecodes: Optional[Sequence[bytes]] = None,
+    spill: bool = True,
+    enable_iprof: bool = False,
+    links: Optional[Sequence[Optional[dict]]] = None,
+) -> BatchBuild:
+    """Pack a batch for the device: the images, the corpus (a host
+    Keccak-256 an image), the seeded frontier and the environment, as
+    three ``batch_build`` spans (``stage`` = ``images`` / ``corpus`` /
+    ``frontier``) on the calling thread. The arguments are
+    :class:`SymExecWrapper`'s of the same names. Nothing here depends on
+    another batch or reads the device, so a caller that knows its next
+    batch may build it on another thread while this one explores (the
+    pipelined campaign does) and hand the bundle to the wrapper as
+    ``build``; a wrapper without one calls this inline."""
+    import threading
+    import time as _time
+
+    import jax
+    import jax.numpy as jnp
+
+    from ..core.frontier import CREATOR_ADDRESS, contract_address
+
+    t0 = _time.monotonic()
+    with phase_timer("batch_build", stage="images") as span:
+        runtime_imgs = [ContractImage.from_bytecode(c, limits.max_code)
+                        for c in bytecodes]
+        C = len(runtime_imgs)
+        names = list(contract_names
+                     or [f"contract_{i}" for i in range(C)])
+        with_creation = creation_bytecodes is not None
+        if with_creation:
+            assert len(creation_bytecodes) == C
+            creation_imgs = [
+                ContractImage.from_bytecode(c, limits.max_code)
+                for c in creation_bytecodes]
+            # corpus layout: creation images [0, C), runtime
+            # images [C, 2C)
+            images = creation_imgs + runtime_imgs
+            names = [f"{n} (constructor)" for n in names] + names
+        else:
+            images = runtime_imgs
+        span.attrs.update(
+            images=len(images),
+            code_bytes=sum(map(len, bytecodes)) + sum(
+                map(len, creation_bytecodes or ())))
+    n_creation = C if with_creation else 0
+    with phase_timer("batch_build", stage="corpus"):
+        corpus = Corpus.from_images(images, n_creation)
+    visited = np.zeros((len(images), limits.max_code), dtype=bool)
+    known_addrs = set(
+        contract_addrs if contract_addrs is not None
+        else [contract_address(i) for i in range(C)])
+    # linked systems: member indices by system, in the manifest's
+    # order (the order the records came in), and each contract's own
+    systems = None
+    by_system: Dict[str, List[int]] = {}
+    if links is not None and any(links):
+        assert len(links) == C
+        for i, ln in enumerate(links):
+            if ln:
+                by_system.setdefault(ln["system"], []).append(i)
+        systems = [by_system[ln["system"]] if ln else None
+                   for ln in links]
+        contract_addrs = [
+            ln["address"] if ln else contract_address(i)
+            for i, ln in enumerate(links)]
+        known_addrs = set(contract_addrs)
+    with phase_timer("batch_build", stage="frontier") as span:
+        P = C * lanes_per_contract
+        cid0 = np.repeat(np.arange(C, dtype=np.int32),
+                         lanes_per_contract)
+        active = np.zeros(P, dtype=bool)
+        active[::lanes_per_contract] = True  # one seed lane a contract
+        sf = make_sym_frontier(
+            P, limits, contract_id=cid0, active=active, n_contracts=C,
+            contract_addrs=(list(contract_addrs)
+                            if contract_addrs is not None else None),
+            caller=(CREATOR_ADDRESS if with_creation
+                    else ATTACKER_ADDRESS),
+            **({} if systems is None else {"systems": systems}),
+        )
+        if with_creation:
+            # account table resolves calls/extcode against RUNTIME
+            # images
+            b = sf.base
+            sf = sf.replace(base=b.replace(
+                acct_code=jnp.where(b.acct_code >= 0, b.acct_code + C,
+                                    b.acct_code),
+            ))
+        if enable_iprof:
+            # per-lane opcode histograms ride the frontier
+            sf = sf.replace(base=sf.base.attach_iprof())
+        if spill:
+            # the scalar every ``defer_starved`` call writes, there
+            # from the first call on: one frontier structure, one
+            # program
+            sf = sf.replace(fixpoint=jnp.zeros((), dtype=bool))
+        env = make_env(P)
+        # from shapes and dtypes alone: no device sync
+        frontier_bytes = sum(x.nbytes for x in jax.tree.leaves(sf))
+        obs_metrics.REGISTRY.gauge(
+            "frontier_bytes",
+            help="bytes of the symbolic frontier's device "
+                 "leaves").set(frontier_bytes)
+        span.attrs["frontier_bytes"] = int(frontier_bytes)
+    return BatchBuild(
+        images=images, names=names, n_creation=n_creation, corpus=corpus,
+        visited=visited, known_addrs=known_addrs, systems=by_system,
+        sf=sf, env=env, tid=threading.get_ident(), mono=t0,
+        dur=_time.monotonic() - t0)
+
+
 class SymExecWrapper:
     """Build + run the symbolic exploration for a batch of contracts.
 
@@ -392,6 +533,12 @@ class SymExecWrapper:
     from which every member's message calls start
     (:meth:`_join_worlds`, a ``system_world`` span inside that
     ``tx_seam``). Without ``links`` nothing of this runs.
+
+    ``build`` (a :class:`BatchBuild`: what :func:`build_batch` made of
+    these same arguments, on whichever thread) is the batch already
+    packed: the constructor goes straight to the exploration. Without it
+    the constructor builds the batch itself, on its own thread, as the
+    first three ``batch_build`` spans of its lead-in.
     """
 
     def __init__(
@@ -422,13 +569,11 @@ class SymExecWrapper:
         warm_shapes: Optional[set] = None,
         on_first_call: Optional[Callable[[], None]] = None,
         links: Optional[Sequence[Optional[dict]]] = None,
+        build: Optional[BatchBuild] = None,
     ):
         import time as _time
 
-        import jax
-
         from .. import compile_cache
-        from ..core.frontier import CREATOR_ADDRESS
         from ..plugin.loader import LaserPluginLoader
 
         # every process that drives the engine does so through here
@@ -485,39 +630,27 @@ class SymExecWrapper:
             else _time.monotonic() + execution_timeout
         )
         # the lead-in: what this thread does before its first
-        # ``sym_run`` call, while the device waits, as four
-        # ``batch_build`` spans (docs/observability.md)
-        with phase_timer("batch_build", stage="images") as build:
-            runtime_imgs = [ContractImage.from_bytecode(c, limits.max_code)
-                            for c in bytecodes]
-            C = len(runtime_imgs)
-            names = list(contract_names
-                         or [f"contract_{i}" for i in range(C)])
-            with_creation = creation_bytecodes is not None
-            if with_creation:
-                assert len(creation_bytecodes) == C
-                creation_imgs = [
-                    ContractImage.from_bytecode(c, limits.max_code)
-                    for c in creation_bytecodes]
-                # corpus layout: creation images [0, C), runtime
-                # images [C, 2C)
-                images = creation_imgs + runtime_imgs
-                runtime_base = C
-                names = [f"{n} (constructor)" for n in names] + names
-            else:
-                images = runtime_imgs
-                runtime_base = 0
-            build.attrs.update(
-                images=len(images),
-                code_bytes=sum(map(len, bytecodes)) + sum(
-                    map(len, creation_bytecodes or ())))
-        self.images = images
-        self._n_creation = C if with_creation else 0
+        # ``sym_run`` call, while the device waits. Without a finished
+        # ``build`` the batch is built here, as three ``batch_build``
+        # spans; the fourth runs from here to the first call
+        # (docs/observability.md)
+        if build is None:
+            build = build_batch(
+                bytecodes, contract_names=contract_names,
+                contract_addrs=contract_addrs, limits=limits,
+                lanes_per_contract=lanes_per_contract,
+                creation_bytecodes=creation_bytecodes, spill=spill,
+                enable_iprof=enable_iprof, links=links)
+        C = len(bytecodes)
+        assert len(build.images) - build.n_creation == C
+        names = build.names
+        with_creation = build.n_creation > 0
+        runtime_base = build.n_creation
+        self.images = build.images
+        self._n_creation = build.n_creation
         self._member_names = names[-C:]
-        with phase_timer("batch_build", stage="corpus"):
-            self.corpus = Corpus.from_images(images, self._n_creation)
-        self._visited = np.zeros(
-            (len(images), limits.max_code), dtype=bool)
+        self.corpus = build.corpus
+        self._visited = build.visited
         # mid-execution dynamic loading (reference: DynLoader.dynld
         # resolving CALL targets as execution reaches them ⚠unv, SURVEY
         # §3.4): the corpus is a static jit shape, so loading happens at
@@ -529,79 +662,26 @@ class SymExecWrapper:
         # case up front). None = offline, no attempt.
         self.dyn_loader = dyn_loader
         self.dynld_limit = dynld_limit
-        from ..core.frontier import contract_address
-        self._known_addrs = set(
-            contract_addrs if contract_addrs is not None
-            else [contract_address(i) for i in range(C)])
+        self._known_addrs = build.known_addrs
         self._dynld_miss: set = set()
         self._dynld_fails: Dict[int, int] = {}  # transient-failure counts
         self.dynld_loaded: List[int] = []  # addresses loaded mid-run
         self._dynld_sha: List[str] = []    # sha256 of each loaded image
         # linked systems: member indices by system, in the manifest's
-        # order (the order the records came in), and each contract's own
-        systems = None
-        self._systems: Dict[str, List[int]] = {}
+        # order (the order the records came in)
+        self._systems = build.systems
         self.failed_deployments: List[dict] = []
-        if links is not None and any(links):
-            assert len(links) == C
-            for i, ln in enumerate(links):
-                if ln:
-                    self._systems.setdefault(ln["system"], []).append(i)
-            systems = [self._systems[ln["system"]] if ln else None
-                       for ln in links]
-            contract_addrs = [
-                ln["address"] if ln else contract_address(i)
-                for i, ln in enumerate(links)]
-            self._known_addrs = set(contract_addrs)
-        with phase_timer("batch_build", stage="frontier") as build:
-            P = C * lanes_per_contract
-            cid0 = np.repeat(np.arange(C, dtype=np.int32),
-                             lanes_per_contract)
-            active = np.zeros(P, dtype=bool)
-            active[::lanes_per_contract] = True  # one seed lane a contract
-            sf = make_sym_frontier(
-                P, limits, contract_id=cid0, active=active, n_contracts=C,
-                contract_addrs=(list(contract_addrs)
-                                if contract_addrs is not None else None),
-                caller=(CREATOR_ADDRESS if with_creation
-                        else ATTACKER_ADDRESS),
-                **({} if systems is None else {"systems": systems}),
-            )
-            if with_creation:
-                # account table resolves calls/extcode against RUNTIME
-                # images
-                b = sf.base
-                import jax.numpy as jnp
-                sf = sf.replace(base=b.replace(
-                    acct_code=jnp.where(b.acct_code >= 0, b.acct_code + C,
-                                        b.acct_code),
-                ))
-            # instruction profiler (reference: --enable-iprof ⚠unv,
-            # SURVEY §5.1): per-lane opcode histograms ride the frontier;
-            # the host harvests + zeroes them at each tx boundary so slot
-            # recycling can't lose or double-count a retired lane's rows
-            self.enable_iprof = enable_iprof
-            self._iprof = np.zeros(256, dtype=np.int64)
-            if enable_iprof:
-                sf = sf.replace(base=sf.base.attach_iprof())
-            if spill:
-                # the scalar every ``defer_starved`` call writes, there
-                # from the first call on: one frontier structure, one
-                # program
-                import jax.numpy as jnp
-                sf = sf.replace(fixpoint=jnp.zeros((), dtype=bool))
-            env = make_env(P)
-            # host mirror of the frontier's run-total superstep counter
-            # (a chunk's count is the difference across its sym_run call)
-            self._steps_seen = 0
-            self._copy_seen = 0     # the same for ``copy_steps``
-            # from shapes and dtypes alone: no device sync
-            frontier_bytes = sum(x.nbytes for x in jax.tree.leaves(sf))
-            obs_metrics.REGISTRY.gauge(
-                "frontier_bytes",
-                help="bytes of the symbolic frontier's device "
-                     "leaves").set(frontier_bytes)
-            build.attrs["frontier_bytes"] = int(frontier_bytes)
+        sf, env = build.sf, build.env
+        # instruction profiler (reference: --enable-iprof ⚠unv,
+        # SURVEY §5.1): per-lane opcode histograms ride the frontier;
+        # the host harvests + zeroes them at each tx boundary so slot
+        # recycling can't lose or double-count a retired lane's rows
+        self.enable_iprof = enable_iprof
+        self._iprof = np.zeros(256, dtype=np.int64)
+        # host mirror of the frontier's run-total superstep counter
+        # (a chunk's count is the difference across its sym_run call)
+        self._steps_seen = 0
+        self._copy_seen = 0     # the same for ``copy_steps``
         # from here to the first ``sym_run`` call: the plugins, the
         # read of ``base.active``, ``on_tx_start``
         self._lead_in = phase_timer("batch_build", stage="start").start()

@@ -782,13 +782,53 @@ class CorpusCampaign:
         self._last_ckpt_mono = time.monotonic()
 
     # --- one engine pass -----------------------------------------------
+    def _padded(self, names: Sequence[str], codes: Sequence[bytes],
+                width: Optional[int] = None,
+                creations: Optional[Sequence[Optional[bytes]]] = None,
+                links: Optional[Sequence[Optional[dict]]] = None):
+        """A batch's records padded to ``width`` (default
+        ``batch_size``), the constant compiled shape: short batches get
+        STOP stubs, a contract without creation code a stub
+        constructor."""
+        width = self.batch_size if width is None else width
+        names = list(names)
+        codes = list(codes)
+        while len(codes) < width:
+            names.append(f"_pad_{len(codes)}")
+            codes.append(_PAD_BYTECODE)
+        if creations is not None:
+            creations = [k if k is not None else _PAD_CREATION
+                         for k in creations]
+            creations += [_PAD_CREATION] * (width - len(creations))
+        if links is not None:
+            links = list(links) + [None] * (width - len(links))
+        return names, codes, creations, links
+
+    def _build_batch(self, items: Sequence[tuple], tctx=None):
+        """The look-ahead's job, on the pipeline's worker thread: pack
+        ``items`` as :meth:`_explore_batch` would at the default rung
+        and return the bundle its wrapper starts from. ``tctx``
+        re-enters the submitting thread's trace scope."""
+        from ..analysis import symbolic
+
+        names, codes, creations, links = _split_records(items)
+        names, codes, creations, links = self._padded(
+            names, codes, creations=creations, links=links)
+        with obs_trace.apply_context(tctx):
+            return symbolic.build_batch(
+                codes, contract_names=names, limits=self.limits,
+                lanes_per_contract=self.lanes_per_contract,
+                creation_bytecodes=creations,
+                enable_iprof=self.enable_iprof, links=links)
+
     def _explore_batch(self, bi: int, names: List[str],
                        codes: List[bytes],
                        lanes: Optional[int] = None,
                        width: Optional[int] = None,
                        creations: Optional[List[Optional[bytes]]] = None,
                        on_first_call=None,
-                       links: Optional[List[Optional[dict]]] = None):
+                       links: Optional[List[Optional[dict]]] = None,
+                       build=None):
         """DEVICE phase of one batch: pad to the compiled width and run
         the exploration (SymExecWrapper packs the corpus and drives the
         ``sym_run`` chunks — the dispatches are async under JAX; only
@@ -810,24 +850,16 @@ class CorpusCampaign:
         ``links`` (:func:`record_link` of each contract) the batch holds
         linked systems: the wrapper gives a system's lanes its members
         at their addresses and joins its constructors' end states into
-        one world. Returns the finished wrapper for
-        :meth:`_harvest_batch`."""
+        one world. ``build`` is this batch already packed
+        (:meth:`_build_batch` of the same records at the default rung,
+        by the pipeline's look-ahead): the wrapper starts from it.
+        Returns the finished wrapper for :meth:`_harvest_batch`."""
         from ..analysis import SymExecWrapper
 
         width = self.batch_size if width is None else width
         lanes = self.lanes_per_contract if lanes is None else lanes
-        names = list(names)
-        codes = list(codes)
-        # constant compiled shape: pad short batches with STOP stubs
-        while len(codes) < width:
-            names.append(f"_pad_{len(codes)}")
-            codes.append(_PAD_BYTECODE)
-        if creations is not None:
-            creations = [k if k is not None else _PAD_CREATION
-                         for k in creations]
-            creations += [_PAD_CREATION] * (width - len(creations))
-        if links is not None:
-            links = list(links) + [None] * (width - len(links))
+        names, codes, creations, links = self._padded(
+            names, codes, width, creations, links)
         if creations is not None:
             obs_metrics.REGISTRY.counter(
                 "campaign_contracts_deployed_total",
@@ -849,6 +881,7 @@ class CorpusCampaign:
                                        creations is not None),
             on_first_call=on_first_call,
             **({} if links is None else {"links": links}),
+            build=build,
         )
         # compile counters as of the END of this device phase: device
         # phases never overlap each other, so a batch that compiled no
@@ -1557,14 +1590,19 @@ class CorpusCampaign:
 
     # --- pipelined phases (docs/performance.md) ------------------------
     def _device_phase(self, bi: int, items: Sequence[tuple],
-                      on_first_call=None):
+                      on_first_call=None, prebuilt=None,
+                      note: Optional[Dict] = None):
         """Pipelined attempt, first half: fault-injection check + corpus
         packing + exploration, under the watchdog (a hung compile
         surfaces as BatchTimeout instead of stalling BOTH pipeline
         stages). Returns an opaque handle for :meth:`_host_phase_work`.
         ``on_first_call`` fires when the exploration has enqueued its
         first ``sym_run`` call; a phase that makes none (below, or one
-        that fails first) never fires it.
+        that fails first) never fires it. ``prebuilt`` is the future of
+        this batch's :meth:`_build_batch`, if the look-ahead submitted
+        one: an exploration takes it (:meth:`_take_prebuilt`, which
+        leaves what became of it in ``note``) after the injector has
+        fired, so a fault lands where it does without a look-ahead.
         A custom ``batch_runner`` has no device/host seam — the runner
         IS the whole attempt, so its finished result rides the handle
         and the host phase degenerates to a pass-through (same code
@@ -1587,10 +1625,51 @@ class CorpusCampaign:
                                                   lanes=None, width=None))
             return ("sym", self._explore_batch(
                 bi, names, codes, creations=creations,
-                on_first_call=on_first_call, links=links))
+                on_first_call=on_first_call, links=links,
+                build=self._take_prebuilt(prebuilt, note)))
 
         return run_with_watchdog(work, self.batch_timeout,
                                  label=f"batch {bi} device")
+
+    @staticmethod
+    def _take_prebuilt(prebuilt, note: Optional[Dict] = None):
+        """The bundle of the look-ahead's build, on the thread that
+        explores the batch: inside a ``batch_build`` span
+        ``stage="prebuilt"`` whose ``dur`` is the wait for it (about 0
+        when the build is done) and which says where the build ran
+        (``built_tid``, ``built_mono``, ``built_dur``: the worker's
+        three stage spans lie there). None where no build was submitted
+        or the build raised: what it raised is dropped, the phase builds
+        for itself and fails where it fails without a look-ahead.
+        ``pipeline_prebuilt_total{used}`` and ``note["prebuilt"]`` say
+        which: ``taken`` (done when asked for), ``waited``, ``failed``,
+        ``inline`` (none submitted)."""
+        build, used = None, "inline"
+        if prebuilt is not None:
+            with obs_device.phase_timer("batch_build",
+                                        stage="prebuilt") as sp:
+                used = "taken" if prebuilt.done() else "waited"
+                try:
+                    build = prebuilt.result()
+                except Exception as e:  # noqa: BLE001 — built inline
+                    used = "failed"
+                    log.debug("look-ahead build failed (%s): building "
+                              "inline", e)
+                else:
+                    sp.attrs.update(built_tid=build.tid,
+                                    built_mono=round(build.mono, 6),
+                                    built_dur=round(build.dur, 6))
+                sp.attrs["used"] = used
+        obs_metrics.REGISTRY.counter(
+            "pipeline_prebuilt_total",
+            help="pipelined explorations by what became of the "
+                 "look-ahead's build of their batch: taken (done when "
+                 "asked for), waited, failed (raised; built inline), "
+                 "inline (none was submitted)",
+            labels={"used": used}).inc()
+        if note is not None:
+            note["prebuilt"] = used
+        return build
 
     def _host_phase_work(self, bi: int, handle) -> Dict:
         """Pipelined attempt, second half: modules + solver + merge,
@@ -1972,13 +2051,33 @@ class CorpusCampaign:
         The ``host_phase`` span carries ``after`` and
         ``pipeline_host_phase_starts_total{after}`` counts them.
 
+        The look-ahead: the same hook submits the BUILD of batch *i+2*
+        (:meth:`_build_batch`: images, corpus, seeded frontier; nothing
+        of it depends on an earlier batch) to the same one-worker pool,
+        behind host phase *i*, so both run under phase *i+1*'s
+        ``sym_run`` calls, and phase *i+2* starts from the finished
+        bundle (:meth:`_take_prebuilt`) instead of building in its
+        lead-in with the device idle. A phase builds for itself where
+        there is no bundle of its batch: the window's first, one after
+        a drain (the bundle is dropped) or after a phase that made no
+        call, and one whose build raised (the error is dropped and the
+        phase's own build raises it where it always did).
+        ``pipeline_prebuilt_total{used}`` and ``prebuilt`` on the
+        ``device_phase`` span say which.
+
         Invariants that keep results byte-identical to the serial loop:
 
         - at most ONE host phase is in flight, and ``commit`` runs
           strictly in batch order (batch *i* commits before *i+1*'s
           host phase is even submitted);
         - the fault injector fires once per pipelined attempt, in the
-          device phase — the same cadence as a serial first attempt;
+          device phase — the same cadence as a serial first attempt
+          (a build never fires it);
+        - a bundle is what the phase's own build would have made, and
+          none is built for a batch past the last, under worker
+          isolation or a custom ``batch_runner`` (neither fires the
+          hook); leaving the loop cancels a build that has not started
+          and drops one that has;
         - ANY phase failure drains: the outstanding host phase commits
           first, then the failed batch re-enters
           ``_run_batch_resilient`` with ``first_err`` set, so degrade/
@@ -2015,6 +2114,31 @@ class CorpusCampaign:
                                   thread_name_prefix="host-phase")
         inflight: Optional[Dict] = None
         host_idle_since: Optional[float] = None
+        # the look-ahead: ``{"bi", "future"}`` of the one batch built
+        # ahead of its phase, if any
+        ahead: Optional[Dict] = None
+
+        def first_call(bi: int, start: Optional[_HostPhaseStart]) -> None:
+            """Phase ``bi`` has enqueued its first ``sym_run`` call:
+            from here on its thread blocks in reads with the interpreter
+            lock released, so the worker gets the previous batch's host
+            phase and, behind it in the pool's queue, the build of the
+            next batch."""
+            nonlocal ahead
+            if start is not None:
+                start.release("first_call")
+            if bi + 1 < n_batches:
+                ahead = {"bi": bi + 1, "future": pool.submit(
+                    self._build_batch, self._batch_items(bi + 1),
+                    obs_trace.context_snapshot())}
+
+        def drop_ahead() -> None:
+            """Give up the look-ahead's build: one that has not started
+            never runs, one that has is dropped when it ends."""
+            nonlocal ahead
+            if ahead is not None:
+                ahead["future"].cancel()
+                ahead = None
 
         def account_overlap(host_dur: float, done_mono: float,
                             beside) -> float:
@@ -2046,6 +2170,7 @@ class CorpusCampaign:
                          stall: float = 0.0) -> None:
             """Pipelined attempt failed: replay the serial machinery
             (skipping the already-paid first attempt) and commit."""
+            drop_ahead()
             rec = obs_trace.timer("batch_drain", bi=bi).start()
             out = self._run_batch_resilient(bi, items, first_err=err)
             rec.stop()
@@ -2104,16 +2229,28 @@ class CorpusCampaign:
                     "device_phase", bi=bi, n=len(items)).start()
                 handle = None
                 first_err: Optional[BaseException] = None
+                # only the build of THIS batch will do (a phase that an
+                # expired watchdog walked away from may fire its hook
+                # late)
+                if ahead is not None and ahead["bi"] != bi:
+                    drop_ahead()
+                mine = ahead
                 try:
                     # the PREVIOUS batch's host phase starts at this
-                    # phase's first ``sym_run`` call
+                    # phase's first ``sym_run`` call, and the NEXT
+                    # batch's build behind it
                     handle = self._device_phase(
-                        bi, items, on_first_call=(
-                            None if inflight is None else partial(
-                                inflight["start"].release, "first_call")))
+                        bi, items, on_first_call=partial(
+                            first_call, bi,
+                            inflight and inflight["start"]),
+                        prebuilt=mine and mine["future"],
+                        note=dev_sp.attrs)
                 except Exception as e:  # noqa: BLE001 — drained below
                     first_err = e
                 dev_dur = dev_sp.stop()
+                if ahead is mine:
+                    # no first call replaced it: nothing is built ahead
+                    drop_ahead()
                 # commit the PREVIOUS batch only now: its host phase ran
                 # concurrently with the device phase that just finished
                 # (all of it after that phase, if it made no call)
@@ -2144,6 +2281,7 @@ class CorpusCampaign:
             # its thread either way
             if inflight is not None:
                 inflight["start"].release(None)
+            drop_ahead()
             pool.shutdown(wait=False)
 
     # --- elastic fleet mode (docs/fleet.md) -----------------------------

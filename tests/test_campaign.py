@@ -117,3 +117,51 @@ def test_campaign_host_index_validation(tmp_path):
     corpus = write_corpus(tmp_path)
     with pytest.raises(ValueError, match="host_index"):
         CorpusCampaign(load_corpus_dir(corpus), num_hosts=2, host_index=2)
+
+
+def test_only_a_pipelined_first_attempt_is_handed_a_bundle(tmp_path):
+    """The look-ahead lives in the pipelined loop alone: the serial
+    loop, a degrade rung and the batch after a drain build for
+    themselves (``_explore_batch`` gets no ``build``)."""
+    from mythril_tpu.resilience import FaultInjector
+
+    corpus = write_corpus(tmp_path, n=14)
+
+    def run(pipeline):
+        camp = CorpusCampaign(
+            load_corpus_dir(corpus), batch_size=4, lanes_per_contract=8,
+            limits=TEST_LIMITS, max_steps=64, transaction_count=1,
+            modules=["AccidentallyKillable"], pipeline=pipeline,
+            fault_injector=FaultInjector.from_string(
+                "oom:batch=1:times=1"))
+        seen, built = [], []
+        explore, build = camp._explore_batch, camp._build_batch
+
+        def spy(bi, names, codes, lanes=None, width=None, creations=None,
+                **kw):
+            seen.append((bi, lanes, kw.get("build") is not None))
+            # (the rung's narrower shape is not what is under test:
+            # explore at the warm one)
+            return explore(bi, names, codes, creations=creations, **kw)
+
+        def spy_build(items, tctx=None):
+            built.append(items[0][0])
+            return build(items, tctx)
+
+        camp._explore_batch, camp._build_batch = spy, spy_build
+        return camp.run(), seen, built
+
+    serial, seen, built = run(False)
+    assert serial.batch_status == ["ok", "ok-degraded:halve-lanes", "ok",
+                                   "ok"]
+    assert seen == [(0, None, False), (1, 4, False), (2, None, False),
+                    (3, None, False)] and built == []
+    piped, seen, built = run(True)
+    assert piped.batch_status == serial.batch_status
+    assert sorted(i["contract"] for i in piped.issues) == sorted(
+        i["contract"] for i in serial.issues)
+    # batch 1's bundle was built beside batch 0's calls and dropped at
+    # the drain; batch 2 follows a drain; batch 3 was built beside 2's
+    assert built == ["c004", "c012"]
+    assert seen == [(0, None, False), (1, 4, False), (2, None, False),
+                    (3, None, True)]

@@ -201,6 +201,18 @@ def _starts(before, after):
     return {k: int(v) for k, v in got.items() if v}
 
 
+USED = ("taken", "waited", "failed", "inline")
+
+
+def _prebuilt(before, after):
+    """``pipeline_prebuilt_total{used}`` over a run, without the labels
+    that stayed at 0."""
+    got = {k: _delta(before, after,
+                     f'pipeline_prebuilt_total{{used="{k}"}}')
+           for k in USED}
+    return {k: int(v) for k, v in got.items() if v}
+
+
 def _first_call(recs, dev):
     """The first ``superstep`` span of ``dev``'s thread inside it."""
     return next(c for c in _named(recs, "superstep")
@@ -276,47 +288,95 @@ def lead_in_runs(tmp_path_factory):
             "occupancy": after["gauges"]["pipeline_occupancy"],
             "hidden": _delta(before, after,
                              "pipeline_host_hidden_seconds_total"),
-            "starts": _starts(before, after)}
+            "starts": _starts(before, after),
+            "prebuilt": _prebuilt(before, after)}
     return runs
 
 
+BUILT = STAGES[:3]      # what ``build_batch`` emits, on whichever thread
+
+
+def _feeder_builds(recs, dev):
+    """The ``batch_build`` spans on ``dev``'s thread inside it."""
+    end = dev["mono"] + dev["dur"]
+    return [b for b in _named(recs, "batch_build")
+            if b["tid"] == dev["tid"] and dev["mono"] <= b["mono"]
+            and b["mono"] + b["dur"] <= end + 1e-5]
+
+
+def _built_ahead(recs, taken):
+    """The spans of the build a ``stage="prebuilt"`` span took: on the
+    thread and inside the interval it names."""
+    lo = taken["built_mono"] - 1e-5
+    hi = taken["built_mono"] + taken["built_dur"] + 1e-5
+    return [b for b in _named(recs, "batch_build")
+            if b["tid"] == taken["built_tid"]
+            and lo <= b["mono"] and b["mono"] + b["dur"] <= hi]
+
+
+@pytest.mark.parametrize("which", ["first", "later"])
 def test_batch_build_stages_once_a_batch_on_the_feeder_in_order(
-        lead_in_runs):
-    recs = lead_in_runs["traced"]["recs"]
+        lead_in_runs, which):
+    """The window's first batch is built by its own phase: four stages
+    on the feeder. Every later one is built ahead, three stages on the
+    pipeline's worker inside the phase before, after that phase's first
+    call; its own phase takes the bundle (``prebuilt``) and starts. (The
+    run whose calls wait with the lock released, as on the chip: where a
+    call runs inside its enqueue the build may still be under way when
+    it is asked for, ``waited``.)"""
+    run = lead_in_runs["slow_call"]
+    recs = run["recs"]
+    assert lead_in_runs["traced"]["prebuilt"] in (
+        {"inline": 1, "taken": 1}, {"inline": 1, "waited": 1})
     devs = _named(recs, "device_phase")
     builds = _named(recs, "batch_build")
-    assert len(devs) == 2 and len(builds) == 2 * len(STAGES)
-    for d in devs:
-        end = d["mono"] + d["dur"]
-        mine = [b for b in builds if b["tid"] == d["tid"]
-                and d["mono"] <= b["mono"]
-                and b["mono"] + b["dur"] <= end + 1e-5]
+    assert len(devs) == 2
+    assert len(builds) == len(STAGES) + len(BUILT) + 2
+    assert [d["prebuilt"] for d in devs] == ["inline", "taken"]
+    if which == "first":
+        mine = _feeder_builds(recs, devs[0])
         assert [b["stage"] for b in mine] == STAGES
-        images, _, frontier, _ = mine
-        assert images["images"] == 4 and images["code_bytes"] > 0
-        assert frontier["frontier_bytes"] > 0
+        built = mine[:3]
+    else:
+        mine = _feeder_builds(recs, devs[1])
+        assert [b["stage"] for b in mine] == ["prebuilt", "start"]
+        taken = mine[0]
+        assert taken["used"] == "taken"
+        assert taken["built_tid"] != devs[1]["tid"]
+        built = _built_ahead(recs, taken)
+        assert [b["stage"] for b in built] == BUILT
+        # beside the first phase's calls: nobody's lead-in
+        first = _first_call(recs, devs[0])
+        assert built[0]["mono"] >= first["mono"] + first["enqueue_s"] - 1e-5
+        assert (built[-1]["mono"] + built[-1]["dur"]
+                <= devs[0]["mono"] + devs[0]["dur"])
+        assert run["prebuilt"] == {"inline": 1, "taken": 1}
+    images, _, frontier = built
+    assert images["images"] == 4 and images["code_bytes"] > 0
+    assert frontier["frontier_bytes"] > 0
 
 
+@pytest.mark.parametrize("which", ["first", "later"])
 def test_batch_build_stages_cover_the_lead_in_without_overlap(
-        lead_in_runs):
+        lead_in_runs, which):
     recs = lead_in_runs["traced"]["recs"]
-    builds = _named(recs, "batch_build")
-    for d in _named(recs, "device_phase"):
-        end = d["mono"] + d["dur"]
-        mine = [b for b in builds if d["mono"] <= b["mono"] <= end]
-        first = next(c for c in _named(recs, "superstep")
-                     if c["tid"] == d["tid"]
-                     and d["mono"] <= c["mono"] <= end)
-        # one after the other, the last ends where the call starts
-        for a, b in zip(mine, mine[1:]):
-            assert a["mono"] + a["dur"] <= b["mono"] + 2e-6
-        last = mine[-1]
-        assert last["stage"] == "start"
-        assert last["mono"] + last["dur"] <= first["mono"] + 2e-6
-        # what no stage holds is the hand-overs between them (a loaded
-        # machine may take the thread off the CPU in one: 0.1 s of room)
-        lead_in = first["mono"] - d["mono"]
-        assert lead_in - sum(b["dur"] for b in mine) <= 0.05 * lead_in + 0.1
+    d = _named(recs, "device_phase")[which == "later"]
+    end = d["mono"] + d["dur"]
+    mine = _feeder_builds(recs, d)
+    assert len(mine) == (2 if which == "later" else len(STAGES))
+    first = next(c for c in _named(recs, "superstep")
+                 if c["tid"] == d["tid"]
+                 and d["mono"] <= c["mono"] <= end)
+    # one after the other, the last ends where the call starts
+    for a, b in zip(mine, mine[1:]):
+        assert a["mono"] + a["dur"] <= b["mono"] + 2e-6
+    last = mine[-1]
+    assert last["stage"] == "start"
+    assert last["mono"] + last["dur"] <= first["mono"] + 2e-6
+    # what no stage holds is the hand-overs between them (a loaded
+    # machine may take the thread off the CPU in one: 0.1 s of room)
+    lead_in = first["mono"] - d["mono"]
+    assert lead_in - sum(b["dur"] for b in mine) <= 0.05 * lead_in + 0.1
 
 
 @pytest.mark.parametrize("name", PHASES)
@@ -438,8 +498,14 @@ def test_trace_report_reads_hidden_and_the_lead_in_off_the_spans(
     at = text.splitlines().index(
         "lead-in of each device phase (start to first sym_run call), "
         "then its batch_build stages:")
-    rows = text.splitlines()[at + 2:at + 2 + 2 * (1 + len(STAGES))]
-    assert [r.split()[0] for r in rows] == ["0", *STAGES, "1", *STAGES]
+    assert text.splitlines()[at + 1].startswith("(^: built ahead")
+    rows = text.splitlines()[at + 3:at + 3 + 2 * (1 + len(STAGES)) + 1]
+    # batch 1 was built ahead: its phase took the bundle and started,
+    # and the worker's three stages are listed under it, marked
+    assert [r.split()[0] for r in rows] == [
+        "0", *STAGES, "1", "prebuilt", "start", *("^" + b for b in BUILT)]
+    assert rows[1 + len(STAGES)].split()[1] in ("taken", "waited")
+    assert rows[0].split()[1] == "inline"
     # no lead-in has a host phase beside it, batch 1's either (batch
     # 0's host phase waits for batch 1's first call): the last column
     assert rows[0].split()[-1] == "0.00ms"
@@ -511,6 +577,300 @@ def test_kill_leaves_no_host_phase_thread_waiting(tmp_path):
     assert _starts(before, after) == {}
     assert not _named(recs, "host_phase")
     assert [d["bi"] for d in _named(recs, "device_phase")] == [0]
+
+
+# --- the look-ahead: batch k+1 is built while batch k's calls run --------
+
+def _wallets():
+    """The deploying shape of tests/test_campaign_creation.py: a wallet
+    anyone may initialise, and one its constructor handed to the
+    creator."""
+    from test_campaign_creation import (CTOR_OPEN, CTOR_OWNER, GUARDED,
+                                        WALLET)
+
+    return [(WALLET, CTOR_OPEN), (GUARDED, CTOR_OWNER), (WALLET, CTOR_OWNER),
+            (GUARDED, CTOR_OPEN)]
+
+
+def _corpus_campaign(kind, tmp_path, **kw):
+    """A campaign of three or four batches over a corpus of pairs, one
+    whose contracts deploy, or one of linked systems of two."""
+    import dataclasses
+
+    from mythril_tpu.symbolic import SymSpec
+
+    if kind == "pairs":
+        corpus = str(tmp_path / "corpus")
+        if not os.path.isdir(corpus):
+            write_corpus(tmp_path, n=10)
+        return make_campaign(corpus, **kw)
+    common = dict(spec=SymSpec(storage=False), transaction_count=2,
+                  modules=["AccidentallyKillable", "EtherThief"],
+                  lanes_per_contract=16, **kw)
+    if kind == "deploying":
+        recs = [(f"d{i}", code, ctor)
+                for i, (code, ctor) in enumerate(_wallets())]
+        return CorpusCampaign(recs, batch_size=1, limits=TEST_LIMITS,
+                              max_steps=128, **common)
+    recs = [(f"s{k}_{j}", code, ctor,
+             {"system": f"s{k}", "address": 0x1000 * (k + 1) + j})
+            for k in range(12)
+            for j, (code, ctor) in enumerate(_wallets()[k % 2::2])]
+    return CorpusCampaign(
+        recs, batch_size=8, max_steps=256,
+        limits=dataclasses.replace(TEST_LIMITS, max_accounts=6), **common)
+
+
+def _spy_builds(camp):
+    """Record the batches ``camp``'s look-ahead builds: the contract
+    names of every ``_build_batch`` call and the thread it ran on."""
+    calls = []
+    real = camp._build_batch
+
+    def spy(items, tctx=None):
+        calls.append(([i[0] for i in items],
+                      threading.current_thread().name))
+        return real(items, tctx)
+
+    camp._build_batch = spy
+    return calls
+
+
+def _no_pool_thread_left():
+    workers = [t for t in threading.enumerate()
+               if t.name.startswith("host-phase")]
+    for t in workers:
+        t.join(timeout=20.0)
+    return not [t.name for t in workers if t.is_alive()]
+
+
+@pytest.mark.parametrize("kind", ["pairs", "deploying", "linked"])
+def test_look_ahead_equals_the_serial_loop(tmp_path, kind):
+    """Every batch but the window's first starts from a bundle built on
+    the worker while the batch before explored, and nothing a run
+    reports can tell: issues with their witnesses' steps, paths, dropped
+    forks, statuses, and no program compiled by either loop once the
+    shapes are warm."""
+    _corpus_campaign(kind, tmp_path).run()          # compiles, if cold
+    serial = _corpus_campaign(kind, tmp_path, pipeline=False).run()
+    camp = _corpus_campaign(kind, tmp_path, pipeline=True)
+    built = _spy_builds(camp)
+    piped, recs, before, after = _traced_run(camp)
+    assert _sig(piped) == _sig(serial)
+    assert _sig(piped)["issues"]
+    assert ([(i["contract"], i["swc-id"], len(i.get("tx_sequence") or ()))
+             for i in piped.issues]
+            == [(i["contract"], i["swc-id"], len(i.get("tx_sequence") or ()))
+                for i in serial.issues])
+    # (``engine_compiles`` counts a campaign's first use of a shape)
+    assert piped.engine["xla_compiles"] == serial.engine["xla_compiles"]
+    n = piped.batches
+    assert n == serial.batches >= 3
+    # one build a batch but the first, on the worker, in batch order
+    assert [names for names, _ in built] == [
+        [i[0] for i in camp._batch_items(bi)] for bi in range(1, n)]
+    assert all(t.startswith("host-phase") for _, t in built)
+    got = _prebuilt(before, after)
+    assert got.pop("inline") == 1
+    assert sum(got.values()) == n - 1 and set(got) <= {"taken", "waited"}
+    devs = _named(recs, "device_phase")
+    assert devs[0]["prebuilt"] == "inline"
+    assert all(d["prebuilt"] in ("taken", "waited") for d in devs[1:])
+    assert _no_pool_thread_left()
+
+
+@pytest.mark.parametrize("kind", ["pairs", "deploying", "linked"])
+def test_wrapper_handed_a_bundle_is_the_one_that_built_inline(kind,
+                                                              tmp_path):
+    """``build_batch`` on another thread gives the leaves it gives
+    inline, and a wrapper that starts from the bundle ends where one
+    that built for itself does."""
+    import jax
+    import numpy as np
+    from concurrent.futures import ThreadPoolExecutor
+
+    from mythril_tpu.mythril.campaign import _split_records
+
+    camp = _corpus_campaign(kind, tmp_path)
+    items = camp._batch_items(0)
+    with ThreadPoolExecutor(max_workers=1) as pool:
+        ahead = pool.submit(camp._build_batch, items).result()
+    here = camp._build_batch(items)
+    assert ahead.tid != here.tid == threading.get_ident()
+    assert ahead.names == here.names
+    assert ahead.n_creation == here.n_creation
+    assert ahead.known_addrs == here.known_addrs
+    assert ahead.systems == here.systems
+
+    def same(a, b):
+        la, ta = jax.tree.flatten(a)
+        lb, tb = jax.tree.flatten(b)
+        assert ta == tb and len(la) == len(lb)
+        for x, y in zip(la, lb):
+            assert x.dtype == y.dtype and x.shape == y.shape
+            assert np.array_equal(np.asarray(x), np.asarray(y))
+
+    for leaf in ("sf", "corpus", "env"):
+        same(getattr(ahead, leaf), getattr(here, leaf))
+    names, codes, creations, links = _split_records(items)
+    handed = camp._explore_batch(0, names, codes, creations=creations,
+                                 links=links, build=ahead)
+    inline = camp._explore_batch(0, names, codes, creations=creations,
+                                 links=links)
+    same(handed.sf, inline.sf)
+    same(handed.corpus, inline.corpus)
+    assert handed.images is ahead.images
+    out = [camp._harvest_batch(0, sym) for sym in (handed, inline)]
+    assert out[0]["issues"] == out[1]["issues"]
+    assert (out[0]["paths"], out[0]["dropped"]) == (
+        out[1]["paths"], out[1]["dropped"])
+
+
+def test_wrapper_without_a_bundle_builds_on_its_own_thread_in_order():
+    """``analyze``, ``serve``, a fleet unit and the serial loop pass no
+    bundle: four ``batch_build`` spans on the calling thread, in order,
+    as ever."""
+    from mythril_tpu.analysis import SymExecWrapper
+    from mythril_tpu.obs import trace as obs_trace
+
+    obs_trace.close()
+    tracer = obs_trace.configure(buffer=True)
+    try:
+        SymExecWrapper([KILLABLE, SAFE, KILLABLE, SAFE], limits=TEST_LIMITS,
+                       lanes_per_contract=8, max_steps=64)
+    finally:
+        recs = tracer.drain_buffer()
+        obs_trace.close()
+    builds = _named(recs, "batch_build")
+    assert [b["stage"] for b in builds] == STAGES
+    assert {b["tid"] for b in builds} == {threading.get_ident()}
+
+
+def test_a_build_that_raises_is_built_inline_and_fails_where_it_did(
+        tmp_path, monkeypatch):
+    """A batch whose build raises: the look-ahead's error is dropped,
+    the phase builds for itself and raises there, and the batch drains
+    through retry and bisection exactly as in the serial loop, the
+    injector fired once an attempt; the batch after the drain is built
+    inline."""
+    from mythril_tpu.analysis import symbolic
+
+    real = symbolic.build_batch
+
+    def poisoned(bytecodes, contract_names=None, **kw):
+        if "c005" in (contract_names or ()):
+            raise ValueError("cannot pack c005")
+        return real(bytecodes, contract_names=contract_names, **kw)
+
+    monkeypatch.setattr(symbolic, "build_batch", poisoned)
+    write_corpus(tmp_path, n=10)
+    fired = {}
+
+    def run(pipeline):
+        camp = make_campaign(str(tmp_path / "corpus"),
+                             fault="raise:contract=nobody",
+                             pipeline=pipeline)
+        fire = camp.fault_injector.fire
+        fired[pipeline] = []
+
+        def counting(**kw):
+            fired[pipeline].append(kw["batch"])
+            return fire(**kw)
+
+        camp.fault_injector.fire = counting
+        return camp
+
+    serial = run(False).run()
+    piped, recs, before, after = _traced_run(run(True))
+    assert _sig(piped) == _sig(serial)
+    assert [q["name"] for q in piped.quarantined] == ["c005"]
+    assert piped.batch_status == ["ok", "quarantined:1", "ok"]
+    assert piped.retries == serial.retries == 1
+    assert fired[True] == fired[False]
+    # batch 0 and, after the drain, batch 2 built for themselves; batch
+    # 1's build raised on the worker and again in its own phase
+    assert _prebuilt(before, after) == {"inline": 2, "failed": 1}
+    failed = _named(recs, "device_phase")[1]
+    assert failed["prebuilt"] == "failed"
+    assert [b["stage"] for b in _feeder_builds(recs, failed)] == ["prebuilt"]
+    assert _no_pool_thread_left()
+
+
+@pytest.mark.parametrize("how", ["last_batch", "deadline", "kill"])
+def test_no_build_beyond_the_run(tmp_path, how):
+    """Nothing is built for a batch past ``n_batches``; a build whose
+    batch the deadline or an ``InjectedKill`` cut off is dropped, not
+    committed, and no pool thread is left."""
+    import time
+
+    write_corpus(tmp_path, n=10)
+    camp = make_campaign(str(tmp_path / "corpus"), pipeline=True,
+                         fault="kill:batch=1" if how == "kill" else None)
+    built = _spy_builds(camp)
+    if how == "deadline":
+        # the window's deadline passes while batch 0 explores, with
+        # batch 1's build at hand or under way
+        loop, phase, at = camp._run_pipelined, camp._device_phase, []
+
+        def with_deadline(start_batch, n_batches, deadline, commit):
+            at.append(time.monotonic() + 2.0)
+            return loop(start_batch, n_batches, at[0], commit)
+
+        def outlasting(bi, items, **kw):
+            handle = phase(bi, items, **kw)
+            time.sleep(max(0.0, at[0] - time.monotonic()) + 0.01)
+            return handle
+
+        camp._run_pipelined, camp._device_phase = with_deadline, outlasting
+    res, recs, before, after = _traced_run(camp)
+    names = [n for n, _ in built]
+    if how == "last_batch":
+        assert res.batches == 3
+        assert names == [[f"c{i:03d}" for i in range(4, 8)],
+                         ["c008", "c009"]]
+        got = _prebuilt(before, after)
+        assert got.pop("inline") == 1
+        assert sum(got.values()) == 2 and set(got) <= {"taken", "waited"}
+    elif how == "kill":
+        assert isinstance(res, InjectedKill)
+        # batch 1 was built beside batch 0's calls; the kill fires
+        # before its phase asks for the bundle
+        assert names == [[f"c{i:03d}" for i in range(4, 8)]]
+        assert _prebuilt(before, after) == {"inline": 1}
+        assert [d["bi"] for d in _named(recs, "device_phase")] == [0]
+    else:
+        assert res.batches == 1 and res.batch_status == ["ok"]
+        assert names == [[f"c{i:03d}" for i in range(4, 8)]]
+        assert _prebuilt(before, after) == {"inline": 1}
+        assert [d["bi"] for d in _named(recs, "device_phase")] == [0]
+    assert _no_pool_thread_left()
+
+
+def test_no_look_ahead_without_an_exploration_on_this_side(tmp_path):
+    """A stub ``batch_runner`` and a worker-isolated batch explore
+    nothing in this process: no build is submitted."""
+    from test_campaign_creation import Spy
+
+    def runner(bi, names, codes, lanes=None, width=None):
+        return {"issues": [], "paths": len(names), "dropped": 0,
+                "iprof": {}}
+
+    from mythril_tpu.obs import metrics as obs_metrics
+
+    before = obs_metrics.REGISTRY.snapshot()
+    stub = CorpusCampaign([(f"c{i:03d}", b"\x00") for i in range(8)],
+                          batch_size=2, batch_runner=runner, pipeline=True,
+                          fault_injector=None)
+    built = _spy_builds(stub)
+    assert stub.run().batches == 4 and built == []
+    isolated = CorpusCampaign(
+        [(f"c{i:03d}", KILLABLE) for i in range(6)], batch_size=2,
+        lanes_per_contract=8, limits=TEST_LIMITS, max_steps=64,
+        pipeline=True, worker_isolation="on", worker_supervisor=Spy())
+    built = _spy_builds(isolated)
+    assert isolated.run().batches == 3 and built == []
+    assert _prebuilt(before, obs_metrics.REGISTRY.snapshot()) == {}
+    assert _no_pool_thread_left()
 
 
 def test_a_start_is_released_once_whoever_races_for_it():

@@ -19,9 +19,11 @@ Reads either output of the span tracer — the Chrome-trace JSON
      what the ``pipeline_occupancy`` gauge counts), what released the
      host phases' starts (``after``: the next batch's first call, the
      end of its device phase, no next phase), and one row a
-     device phase for its lead-in: the ``batch_build`` stages' split,
-     the first call's ``enqueue_s`` and the host phase that ran beside
-     it — docs/performance.md),
+     device phase for its lead-in: whether its batch was built ahead,
+     the ``batch_build`` stages' split (a bundle built ahead: the
+     worker's stages under the phase's own, marked ``^``), the first
+     call's ``enqueue_s`` and the host phase that ran beside it —
+     docs/performance.md),
   6. a fleet summary (unit leases claimed/committed/reclaimed/lost and
      the reclaim/lost timeline — docs/fleet.md),
   7. solver totals (attempts / sat / unsat / unknown and the unknown
@@ -136,10 +138,13 @@ def _overlap(lo: float, hi: float, intervals) -> float:
 
 def _lead_in_rows(dev: List[Dict], host: List[Dict],
                   spans: List[Dict]) -> List[str]:
-    """One row a device phase: its lead-in (start to first ``sym_run``
-    call), how much of it the ``batch_build`` stages hold and their
+    """One row a device phase: what became of the look-ahead's build of
+    its batch (``prebuilt``: ``inline`` / ``taken`` / ``waited`` /
+    ``failed``), its lead-in (start to first ``sym_run`` call), how
+    much of it the ``batch_build`` stages on its thread hold and their
     split, the first call's enqueue, and the host phase that ran beside
-    the lead-in; under it a line a stage."""
+    the lead-in; under it a line a stage, and for a bundle built ahead
+    the worker's stages, marked ``^``."""
     builds = [s for s in spans if s["name"] == "batch_build"]
     calls = sorted((s for s in spans if s["name"] == "superstep"),
                    key=lambda s: s["mono"])
@@ -164,17 +169,31 @@ def _lead_in_rows(dev: List[Dict], host: List[Dict],
         proc = sum(s["args"].get("proc_cpu_s", 0.0) for s in stages)
         enq = first["args"].get("enqueue_s")
         rows.append(
-            f"{d['args'].get('bi', '?')!s:>6}{_fmt_s(lead_in):>10}"
+            f"{d['args'].get('bi', '?')!s:>6}"
+            f"{d['args'].get('prebuilt', '-'):>8}{_fmt_s(lead_in):>10}"
             f"{_fmt_s(held):>10}{_fmt_s(cpu):>10}{_fmt_s(wait):>10}"
             f"{_fmt_s(held - cpu - wait):>10}{_fmt_s(proc):>10}"
             f"{(proc / held if held else 0.0):>7.2f}"
             f"{(_fmt_s(enq) if enq is not None else '-'):>10}"
             f"{_fmt_s(beside):>10}")
+        # a phase that took a bundle built ahead says where the build
+        # ran: the worker's stages, marked, under the phase's own
+        ahead = []
         for s in stages:
+            a = s["args"]
+            if a.get("stage") == "prebuilt" and "built_tid" in a:
+                t0 = a["built_mono"] - 1e-5
+                t1 = a["built_mono"] + a["built_dur"] + 1e-5
+                ahead += sorted(
+                    (b for b in builds if b.get("tid") == a["built_tid"]
+                     and t0 <= b["mono"] and b["mono"] + b["dur"] <= t1),
+                    key=lambda b: b["mono"])
+        for s, mark in [(s, "") for s in stages] + [(s, "^") for s in ahead]:
             a = s["args"]
             c, w = a.get("cpu_s", 0.0), a.get("device_wait_s", 0.0)
             rows.append(
-                f"{'':>6}{a.get('stage', '?'):>10}{_fmt_s(s['dur']):>10}"
+                f"{'':>6}{mark + a.get('stage', '?'):>18}"
+                f"{_fmt_s(s['dur']):>10}"
                 f"{_fmt_s(c):>10}{_fmt_s(w):>10}"
                 f"{_fmt_s(s['dur'] - c - w):>10}"
                 f"{_fmt_s(a.get('proc_cpu_s', 0.0)):>10}")
@@ -486,7 +505,11 @@ def report(spans: List[Dict], instants: List[Dict], top: int = 10) -> str:
         if rows:
             out.append("lead-in of each device phase (start to first "
                        "sym_run call), then its batch_build stages:")
-            out.append(f"{'bi':>6}{'lead-in':>10}{'in stages':>10}"
+            out.append("(^: built ahead on the pipeline's worker, beside "
+                       "the previous phase's sym_run calls: in nobody's "
+                       "lead-in)")
+            out.append(f"{'bi':>6}{'built':>8}{'lead-in':>10}"
+                       f"{'in stages':>10}"
                        f"{'cpu':>10}{'dev reads':>10}{'rest':>10}"
                        f"{'proc cpu':>10}{'/dur':>7}{'enqueue':>10}"
                        f"{'host by':>10}")
